@@ -89,8 +89,8 @@ from pathway_tpu.internals import config as _config
 from pathway_tpu.internals.config import set_license_key, set_monitoring_config
 
 # persistent XLA compilation cache for the whole package (engine runs,
-# tests, bench) — opt-in via PATHWAY_TPU_COMPILE_CACHE=<dir>, no-op otherwise
-_config.maybe_enable_compile_cache()
+# tests, bench): $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
+_config.enable_compile_cache()
 
 # submodule namespaces (populated lazily to avoid import cycles)
 from pathway_tpu import asynchronous  # noqa: E402

@@ -8,9 +8,9 @@ traced exactly like a jitted function, so host effects inside it are
 the same bug), ``pl.BlockSpec(shape, index_map)`` index-map functions
 (an index map runs at trace/grid-resolution time inside the Pallas
 machinery — the flash/paged kernels name theirs as top-level functions
-precisely so this pass can see them) and ``shard_map`` /
-``compat_shard_map`` boundaries (the serving mesh's paged-attention
-seam: the mapped function traces under the SPMD per-shard view) —
+precisely so this pass can see them) and ``shard_map`` boundaries
+(the serving mesh's paged-attention seam: the mapped function traces
+under the SPMD per-shard view) —
 then walks the call graph across modules
 (import-alias resolution, absolute and relative) and flags, inside the
 reachable set:
@@ -171,9 +171,9 @@ def _block_spec_index_map(call: ast.Call) -> ast.AST | None:
 
 
 def _is_shard_map(node: ast.AST, imps: _Imports) -> bool:
-    """``jax.shard_map`` / ``jax.experimental.shard_map.shard_map`` /
-    the repo's ``compat_shard_map`` version shim (any from-import
-    alias) — the mapped function is a trace boundary exactly like
+    """``jax.shard_map`` / ``jax.experimental.shard_map.shard_map``
+    (or any from-import alias of either) — the mapped function is a
+    trace boundary exactly like
     ``jax.jit``'s argument, and it additionally runs under the SPMD
     per-shard view, so the GL1xx purity rules apply to its body (the
     serving mesh routes paged attention through this seam)."""
@@ -185,11 +185,8 @@ def _is_shard_map(node: ast.AST, imps: _Imports) -> bool:
         "jax", "jax.experimental.shard_map"
     ):
         return True
-    if tail == "compat_shard_map" and imps.module_of(head):
-        return True
     if not tail:
-        orig = imps.from_names.get(head, ("", ""))[1]
-        return orig in ("shard_map", "compat_shard_map")
+        return imps.from_names.get(head, ("", ""))[1] == "shard_map"
     return False
 
 

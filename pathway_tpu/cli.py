@@ -62,6 +62,42 @@ def checkout_repository(repository_url: str | None, branch: str | None):
     return temp_root_directory
 
 
+def _local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device nodes —
+    the launcher itself must stay off JAX (a parent that touched the
+    backend would hold the chips its children need)."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def _check_children_can_have_devices(processes: int, env: dict) -> None:
+    """``spawn`` gives every child the same environment and assigns no
+    chip to any of them, so on a TPU host each of M > 1 children asks for
+    EVERY chip: the first to load the TPU runtime takes them all and the
+    rest fail at backend start-up or hang waiting (a chip belongs to one
+    process at a time). Refuse that launch up front with the way out,
+    instead of letting the children hang. Children held to the CPU
+    (``JAX_PLATFORMS=cpu``) never ask for a chip and are fine."""
+    if processes <= 1:
+        return
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    chips = _local_tpu_chips()
+    if chips == 0:
+        return
+    raise click.ClickException(
+        f"spawn --processes {processes} on a host with {chips} TPU "
+        f"chip(s): the launcher assigns no chip to a child, so all "
+        f"{processes} children would ask for the same chip(s) and all but "
+        f"one would fail or hang. Run ONE process (-n 1 drives all "
+        f"{chips} chip(s)), or set JAX_PLATFORMS=cpu to run the workers "
+        f"on the CPU."
+    )
+
+
 def spawn_program(
     *,
     threads: int,
@@ -75,6 +111,7 @@ def spawn_program(
 ) -> None:
     """Launch ``processes`` copies of ``program`` with the worker-topology env
     contract (reference ``cli.py:53-109``)."""
+    _check_children_can_have_devices(processes, env_base)
     temp_root_directory = checkout_repository(repository_url, branch)
     if temp_root_directory is not None:
         repository_path, venv_path = get_temporary_paths(temp_root_directory)

@@ -47,16 +47,46 @@ import time
 from pathway_tpu.analysis.annotations import guarded_by
 from pathway_tpu.analysis.runtime import make_lock
 
-# v5e peak: 197 TFLOP/s bf16 MXU, ~819 GB/s HBM (public TPU v5e specs)
-V5E_PEAK_BF16_FLOPS = 197e12
-V5E_PEAK_HBM_BYTES = 819e9
+# What a utilization reads when the device selected no peak to divide by
+NOT_MEASURED = "not measured"
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published peaks of ONE chip: the denominators of every utilization."""
+
+    bf16_flops: float       # FLOP/s
+    hbm_bytes_per_s: float  # bytes/s
+    source: str
+
+
+# keyed by ``jax.devices()[0].device_kind``; a device that is not in the
+# table has no utilization — never another device's peaks
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, hbm_bytes_per_s=819e9,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+
+def device_peaks(device_kind: str | None = None) -> DevicePeaks | None:
+    """The peaks row for ``device_kind`` (default: this process's first
+    device, which initialises the backend), or ``None`` for a device the
+    table does not hold — the roofline functions below then report
+    :data:`NOT_MEASURED` in every peak-derived field."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
 
 
 # --------------------------------------------------------------------- #
 # the unified metrics registry
 
 # log-bucketed (factor 2) latency bounds: 100us .. ~105s, 21 buckets +
-# one +Inf overflow. Wide enough for relay-chip TTFTs, fine enough that
+# one +Inf overflow. Wide enough for queued-request TTFTs, fine enough that
 # interpolated p50/p95 stay within a 2x bucket of the truth.
 _DEFAULT_HIST_BOUNDS = tuple(1e-4 * (2.0 ** i) for i in range(21))
 
@@ -1130,10 +1160,9 @@ def bubble_attribution(wall_s: float, stages: dict[str, float] | None = None) ->
 def roofline_ceiling(
     flops: float,
     bytes_moved: float,
+    peaks: DevicePeaks | None,
     *,
     wall_s: float | None = None,
-    peak_flops: float = V5E_PEAK_BF16_FLOPS,
-    peak_bytes: float = V5E_PEAK_HBM_BYTES,
 ) -> dict:
     """The roofline-implied CEILING for a workload, not just its score.
 
@@ -1145,7 +1174,19 @@ def roofline_ceiling(
     wall is the unavoidable bound vs overhead above it. This turns "MFU is
     34%" into either "the ceiling itself is 41% — we are at 83% of
     attainable" or "the ceiling is 95% — the other 60% is ours to close".
+    ``peaks=None`` (a device outside :data:`DEVICE_PEAKS`) leaves only
+    the shape-derived intensity; the rest reads :data:`NOT_MEASURED`.
     """
+    if peaks is None:
+        return {
+            "arith_intensity": round(flops / max(bytes_moved, 1.0), 2),
+            **dict.fromkeys(
+                ("bound", "ridge_intensity", "ceiling_mfu_pct",
+                 "ceiling_hbm_pct", "attained_of_ceiling_pct"),
+                NOT_MEASURED,
+            ),
+        }
+    peak_flops, peak_bytes = peaks.bf16_flops, peaks.hbm_bytes_per_s
     t_compute = flops / peak_flops
     t_memory = bytes_moved / peak_bytes
     t_lb = max(t_compute, t_memory, 1e-12)
@@ -1177,45 +1218,45 @@ class PhaseRoofline:
     bytes_moved: float = 0.0
     dispatches: int = 0
 
-    def summary(
-        self,
-        peak_flops: float = V5E_PEAK_BF16_FLOPS,
-        peak_bytes: float = V5E_PEAK_HBM_BYTES,
-    ) -> dict:
-        s = max(self.seconds, 1e-12)
-        mfu = self.flops / (s * peak_flops)
-        bw_util = self.bytes_moved / (s * peak_bytes)
-        # arithmetic intensity vs the machine's ridge point decides which
-        # ceiling the phase is under; the far-from-both case is overhead
+    def summary(self, peaks: DevicePeaks | None) -> dict:
         ai = self.flops / max(self.bytes_moved, 1.0)
-        ridge = peak_flops / peak_bytes
-        bound = "compute" if ai >= ridge else "memory"
-        if max(mfu, bw_util) < 0.05:
-            bound = "overhead"
-        return {
+        out = {
             "phase": self.name,
             "seconds": round(self.seconds, 6),
             "gflops": round(self.flops / 1e9, 3),
             "gbytes": round(self.bytes_moved / 1e9, 3),
             "dispatches": self.dispatches,
-            "mfu_pct": round(100.0 * mfu, 2),
-            "hbm_util_pct": round(100.0 * bw_util, 2),
+            "mfu_pct": NOT_MEASURED,
+            "hbm_util_pct": NOT_MEASURED,
             "arith_intensity": round(ai, 2),
-            "bound": bound,
+            "bound": NOT_MEASURED,
         }
+        if peaks is None:
+            return out
+        s = max(self.seconds, 1e-12)
+        mfu = self.flops / (s * peaks.bf16_flops)
+        bw_util = self.bytes_moved / (s * peaks.hbm_bytes_per_s)
+        # arithmetic intensity vs the machine's ridge point decides which
+        # ceiling the phase is under; the far-from-both case is overhead
+        ridge = peaks.bf16_flops / peaks.hbm_bytes_per_s
+        bound = "compute" if ai >= ridge else "memory"
+        if max(mfu, bw_util) < 0.05:
+            bound = "overhead"
+        out.update(
+            mfu_pct=round(100.0 * mfu, 2),
+            hbm_util_pct=round(100.0 * bw_util, 2),
+            bound=bound,
+        )
+        return out
 
 
 @guarded_by(phases="_lock")
 class RooflineModel:
-    """Per-phase (seconds, FLOPs, bytes) ledger -> MFU / bandwidth report."""
+    """Per-phase (seconds, FLOPs, bytes) ledger -> MFU / bandwidth report
+    against ``peaks`` (:func:`device_peaks` of the device that ran it)."""
 
-    def __init__(
-        self,
-        peak_flops: float = V5E_PEAK_BF16_FLOPS,
-        peak_bytes: float = V5E_PEAK_HBM_BYTES,
-    ):
-        self.peak_flops = peak_flops
-        self.peak_bytes = peak_bytes
+    def __init__(self, peaks: DevicePeaks | None):
+        self.peaks = peaks
         self._lock = make_lock("probes.roofline")
         self.phases: dict[str, PhaseRoofline] = {}
 
@@ -1240,7 +1281,7 @@ class RooflineModel:
     def summary(self) -> dict:
         with self._lock:
             return {
-                name: p.summary(self.peak_flops, self.peak_bytes)
+                name: p.summary(self.peaks)
                 for name, p in self.phases.items()
             }
 

@@ -4,9 +4,9 @@ Worker-topology / persistence env vars: PATHWAY_THREADS /
 PATHWAY_PROCESSES / PATHWAY_PROCESS_ID / PATHWAY_FIRST_PORT,
 PATHWAY_IGNORE_ASSERTS, PATHWAY_RUNTIME_TYPECHECKING,
 PATHWAY_PERSISTENT_STORAGE, PATHWAY_LICENSE_KEY (accepted, unused — no
-license gating in this build), PATHWAY_TPU_COMPILE_CACHE=<dir>
-(persistent XLA compilation cache for the whole package, not just
-bench.py).
+license gating in this build). The persistent XLA compilation cache is
+placed by JAX's own ``JAX_COMPILATION_CACHE_DIR`` (see
+:func:`enable_compile_cache`).
 
 Every performance knob — the ``PATHWAY_TPU_*`` family plus
 ``PATHWAY_FUSION`` — is declared exactly once in :data:`FLAG_REGISTRY`
@@ -1388,36 +1388,33 @@ _install_flag_properties()
 
 pathway_config = PathwayConfig()
 
-_compile_cache_dir: str | None = None
+def enable_compile_cache() -> str:
+    """The ONE place the persistent XLA compilation cache is configured,
+    for library use, tests and the bench alike. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own handling of it stands
+    and no directory is set here; where it is not, the cache lives at the
+    fixed ``<checkout>/.jax_cache`` (the directory is part of the cache
+    key's environment, so it must not move between runs). Returns the
+    directory in effect. A cold run is JAX's own switch:
+    ``JAX_ENABLE_COMPILATION_CACHE=false``."""
+    import jax
 
-
-def maybe_enable_compile_cache() -> str | None:
-    """Point JAX's persistent compilation cache at
-    ``$PATHWAY_TPU_COMPILE_CACHE`` (package-wide: engine runs, tests and
-    the bench all reuse cached executables across processes). No-op when
-    the env var is unset or jax is unavailable; idempotent otherwise.
-    Returns the cache dir in effect, or None."""
-    global _compile_cache_dir
-    cache_dir = os.environ.get("PATHWAY_TPU_COMPILE_CACHE")
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
-        return None
-    if _compile_cache_dir == cache_dir:
-        return _compile_cache_dir
-    try:
-        import jax
-
-        cache_dir = os.path.abspath(cache_dir)
-        os.makedirs(cache_dir, exist_ok=True)
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)
+            ))),
+            ".jax_cache",
+        )
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache even fast compiles: streaming graphs compile many small
-        # bucket-shaped kernels whose individual compile times sit under
-        # the default threshold but add up across runs
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 - optional: cache must never break runs
-        return None
-    _compile_cache_dir = cache_dir
-    return _compile_cache_dir
+    # cache even fast compiles: streaming graphs compile many small
+    # bucket-shaped kernels whose individual compile times sit under
+    # the default threshold but add up across runs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir
+
 
 _persistence_config: Any = None
 

@@ -311,8 +311,8 @@ class ApplyExpression(ColumnExpression):
         # two-phase batched UDFs: ``submit`` dispatches one microbatch and
         # returns a handle WITHOUT waiting for the device; ``resolve`` turns
         # a list of handles into a list of result-lists with ONE device
-        # drain. On a remote/tunneled accelerator this pipelines the chunks
-        # of an epoch instead of paying a round trip per chunk.
+        # drain. This pipelines the chunks of an epoch instead of paying
+        # a device round trip per chunk.
         self._submit_fun = submit
         self._resolve_fun = resolve
         # deferred=True (fully-async two-phase): the Rowwise operator

@@ -1,4 +1,4 @@
-"""Logstash sink — HTTP-input-plugin wrapper (reference
+"""Logstash sink — HTTP-input wrapper (reference
 ``python/pathway/io/logstash/__init__.py:14-70``: delegates to
 ``pw.io.http.write`` against the Logstash HTTP input endpoint)."""
 

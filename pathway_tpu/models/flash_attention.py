@@ -36,9 +36,12 @@ downstream and logits read the last real position), so flash-on
 equivalence is judged on logits/tokens, at kernel level on live rows.
 
 ``interpret`` defaults to True off-TPU so tier-1 (JAX_PLATFORMS=cpu)
-runs the same kernel bodies through the Pallas interpreter. Native TPU
-compilation wants lane-aligned tiles — ``head_dim`` and the block sizes
-in multiples of the (8, 128) register shape; tune via
+runs the same kernel bodies through the Pallas interpreter; on a TPU the
+same calls compile natively, and nothing falls back to the interpreter
+there. Tile row counts are kept 8-aligned (or the whole axis) and every
+block's last dim is the array's, which the TPU lowering accepts at
+head_dim 64 and 32 alike (``tests/test_tpu_compile.py`` compiles all
+three kernels for a v5e). Tile sizes tune via
 ``PATHWAY_TPU_FLASH_BLOCK_Q`` / ``PATHWAY_TPU_FLASH_BLOCK_K``
 (``configure_blocks`` installs them at construction time).
 """
@@ -87,9 +90,12 @@ def _round8(n):
 
 
 def _pick_block(n, want):
-    """Largest divisor of ``n`` that is <= ``want`` (cache rows cannot be
-    padded without copying the whole row, so the tile must divide C)."""
-    for b in range(min(int(want), int(n)), 0, -1):
+    """Largest divisor of ``n`` that is <= ``want`` and a multiple of 8
+    (cache rows cannot be padded without copying the whole row, so the
+    tile must divide C, and the TPU lowering wants the tile's row count
+    8-aligned); a row with no such divisor rides as ONE tile, which is
+    legal at any length because it spans the whole axis."""
+    for b in range(min(int(want), int(n)) // 8 * 8, 0, -8):
         if n % b == 0:
             return b
     return int(n)
@@ -110,7 +116,7 @@ def _kv_tile_map(b, qt, kt):
 
 
 def _mask_tile_map(b, qt, kt):
-    return (b, kt)
+    return (b, kt, 0, 0)
 
 
 def _self_attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
@@ -137,8 +143,7 @@ def _self_attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * sm_scale                                # (nh, Bq, Bk)
-        live = jnp.broadcast_to(mask_ref[0][None, :] > 0,
-                                (block_q, block_k))
+        live = jnp.broadcast_to(mask_ref[0, 0] > 0, (block_q, block_k))
         if causal:
             rows = qt * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -224,7 +229,10 @@ def flash_attn(q, k, v, mask, *, causal=True, sm_scale=None,
             pl.BlockSpec((1, nh, bq, hd), _q_tile_map),
             pl.BlockSpec((1, nh, bk, hd), _kv_tile_map),
             pl.BlockSpec((1, nh, bk, hd), _kv_tile_map),
-            pl.BlockSpec((1, bk), _mask_tile_map),
+            # (B, k_tiles, 1, bk): a block whose last two dims equal the
+            # array's — the TPU lowering refuses a (1, bk) block of the
+            # flat (B, S) mask (rows neither 8-aligned nor the whole axis)
+            pl.BlockSpec((1, 1, 1, bk), _mask_tile_map),
         ],
         out_specs=pl.BlockSpec((1, nh, bq, hd), _q_tile_map),
         out_shape=jax.ShapeDtypeStruct((B, nh, Sq + pq, hd), jnp.float32),
@@ -234,7 +242,7 @@ def flash_attn(q, k, v, mask, *, causal=True, sm_scale=None,
             pltpu.VMEM((nh, bq, hd), jnp.float32),  # unnormalized context
         ],
         interpret=interpret,
-    )(q, k, v, mask)
+    )(q, k, v, mask.reshape(B, n_kt, 1, bk))
     return out[:, :, :Sq, :] if pq else out
 
 
@@ -253,7 +261,7 @@ def _chunk_kv_map(i, meta):
 
 
 def _chunk_mask_map(i, meta):
-    return (0, i)
+    return (i, 0, 0)
 
 
 def _paged_chunk_kv_map(i, meta):
@@ -297,7 +305,7 @@ def _chunk_kernel(meta_ref, *refs, sm_scale, block_t, block_k, n_kt, quant):
             jnp.int32, (block_t, block_k), 0)
         cols = i * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_t, block_k), 1)
-        live = jnp.broadcast_to(mask_ref[0][None, :] > 0,
+        live = jnp.broadcast_to(mask_ref[0] > 0,
                                 (block_t, block_k)) & (cols <= rows)
         s = jnp.where(live[None, :, :], s, _NEG)
 
@@ -332,7 +340,8 @@ def _chunk_call(meta, q, kv_operands, kv_specs, row_mask, *,
         num_scalar_prefetch=1,
         grid=(n_kt,),
         in_specs=[pl.BlockSpec((nh, block_t, hd), _chunk_q_map)] + kv_specs
-        + [pl.BlockSpec((1, block_k), _chunk_mask_map)],
+        # row mask as (k_tiles, 1, block_k), see flash_attn's mask spec
+        + [pl.BlockSpec((1, 1, block_k), _chunk_mask_map)],
         out_specs=pl.BlockSpec((nh, block_t, hd), _chunk_q_map),
         scratch_shapes=[
             pltpu.VMEM((nh, block_t), jnp.float32),
@@ -348,7 +357,7 @@ def _chunk_call(meta, q, kv_operands, kv_specs, row_mask, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nh, block_t, hd), jnp.float32),
         interpret=interpret,
-    )(meta, q, *kv_operands, row_mask)
+    )(meta, q, *kv_operands, row_mask.reshape(n_kt, 1, block_k))
 
 
 def flash_chunk_attn(q, k_row, v_row, row_mask, start, *,
@@ -388,7 +397,7 @@ def flash_chunk_attn(q, k_row, v_row, row_mask, start, *,
         kv_operands += [k_scale[None], v_scale[None]]
         kv_specs += [pl.BlockSpec((1, nh, bk, 1), _chunk_kv_map)] * 2
     return _chunk_call(
-        meta, q, kv_operands, kv_specs, row_mask.astype(jnp.int32)[None],
+        meta, q, kv_operands, kv_specs, row_mask.astype(jnp.int32),
         sm_scale=sm_scale, block_t=T, block_k=bk, n_kt=n_kt,
         quant=quant, interpret=interpret, nh=nh, hd=hd,
     )
@@ -437,7 +446,7 @@ def flash_chunk_attn_paged(q, kb, vb, kb_scale, vb_scale, tbl_row,
         kv_operands += [kb_scale, vb_scale]
         kv_specs += [pl.BlockSpec((1, nh, Bk, 1), _paged_chunk_kv_map)] * 2
     return _chunk_call(
-        meta, q, kv_operands, kv_specs, row_mask.astype(jnp.int32)[None],
+        meta, q, kv_operands, kv_specs, row_mask.astype(jnp.int32),
         sm_scale=sm_scale, block_t=T, block_k=Bk, n_kt=M,
         quant=quant, interpret=interpret, nh=nh, hd=hd,
     )

@@ -22,11 +22,13 @@ reference path, and the kernel is pinned to it at tolerance by
 ``tests/test_paged_kv.py``.
 
 ``interpret`` defaults to True off-TPU, so tier-1 (JAX_PLATFORMS=cpu)
-exercises the same kernel body through the Pallas interpreter. Native
-TPU compilation additionally wants lane-aligned tiles (``head_dim`` and
-``block`` in multiples of the (8, 128) register shape); the serving
-defaults satisfy ``head_dim=64``-class models only in interpret mode —
-size ``PATHWAY_TPU_PAGED_KV_BLOCK`` accordingly when compiling native.
+exercises the same kernel body through the Pallas interpreter; on a TPU
+the same call compiles natively, and nothing falls back to the
+interpreter there. Every block's last two dims equal the array's (the
+KV planes' ``(block, head_dim)``, the scales' ``(block, 1)``, the mask
+reshaped to ``(1, block)`` rows), which is what the TPU lowering accepts
+at any ``head_dim`` and block size — ``tests/test_tpu_compile.py``
+compiles it for a v5e at head_dim 64 with bf16 and int8 KV.
 """
 
 from __future__ import annotations
@@ -64,30 +66,32 @@ def _decode_kernel(tbl_ref, *refs, sm_scale, n_blk, quant):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)            # (nh, hd)
+    q = q_ref[0].astype(jnp.float32)            # (nh, 1, hd)
     k = kb_ref[0].astype(jnp.float32)           # (nh, Bk, hd)
     v = vb_ref[0].astype(jnp.float32)
     if quant:
         k = k * ks_ref[0].astype(jnp.float32)   # (nh, Bk, 1) broadcasts
         v = v * vs_ref[0].astype(jnp.float32)
-    # s[n, t] = q[n] . k[n, t] — batched over heads on the MXU
+    # s[n, 0, t] = q[n, 0] . k[n, t] — batched over heads on the MXU. The
+    # query keeps a unit row axis: the TPU compiler refuses a batched
+    # dot whose left operand has no free dimension.
     s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (0,))),
+        q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    ) * sm_scale                                # (nh, Bk)
-    live = mask_ref[0] > 0                      # (Bk,)
-    s = jnp.where(live[None, :], s, _NEG)
+    ) * sm_scale                                # (nh, 1, Bk)
+    live = mask_ref[0] > 0                      # (1, 1, Bk) broadcasts over heads
+    s = jnp.where(live, s, _NEG)
 
-    m_prev = m_ref[...]                         # (nh, 1)
+    m_prev = m_ref[...]                         # (nh, 1, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    p = jnp.where(live[None, :], p, 0.0)        # fully-masked block -> 0
+    p = jnp.where(live, p, 0.0)                 # fully-masked block -> 0
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
-        p, v, (((1,), (1,)), ((0,), (0,))),
+        p, v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    )                                           # (nh, hd)
+    )                                           # (nh, 1, hd)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
 
@@ -138,33 +142,38 @@ def paged_attn_decode(q, kb, vb, kb_scale, vb_scale, tbl, slot_mask, *,
     # turns the logical block step into a physical block-plane index
     blk = lambda shp: pl.BlockSpec(shp, lambda b, m, t: (t[b, m],) + (0,) * (len(shp) - 1))
     in_specs = [
-        pl.BlockSpec((1, nh, hd), lambda b, m, t: (b, 0, 0)),   # q
+        pl.BlockSpec((1, nh, 1, hd), lambda b, m, t: (b, 0, 0, 0)),  # q
         blk((1, nh, Bk, hd)),                                   # kb
         blk((1, nh, Bk, hd)),                                   # vb
     ]
-    operands = [q, kb, vb]
+    operands = [q[:, :, None, :], kb, vb]
     if quant:
         in_specs += [blk((1, nh, Bk, 1)), blk((1, nh, Bk, 1))]
         operands += [kb_scale, vb_scale]
-    in_specs.append(pl.BlockSpec((1, Bk), lambda b, m, t: (b, m)))  # mask
-    operands.append(slot_mask)
+    # the mask rides as (n_slots, M, 1, Bk) so its block's last two dims
+    # equal the array's: the TPU lowering refuses a (1, Bk) block of the
+    # flat (n_slots, C) mask (rows neither 8-aligned nor the whole axis)
+    in_specs.append(
+        pl.BlockSpec((1, 1, 1, Bk), lambda b, m, t: (b, m, 0, 0)))
+    operands.append(slot_mask.reshape(B, M, 1, Bk))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, M),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nh, hd), lambda b, m, t: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, 1, hd), lambda b, m, t: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((nh, 1), jnp.float32),   # running max
-            pltpu.VMEM((nh, 1), jnp.float32),   # running denom
-            pltpu.VMEM((nh, hd), jnp.float32),  # unnormalized context
+            pltpu.VMEM((nh, 1, 1), jnp.float32),   # running max
+            pltpu.VMEM((nh, 1, 1), jnp.float32),   # running denom
+            pltpu.VMEM((nh, 1, hd), jnp.float32),  # unnormalized context
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _decode_kernel, sm_scale=sm_scale, n_blk=M, quant=quant,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nh, 1, hd), q.dtype),
         interpret=interpret,
     )(tbl, *operands)
+    return out[:, :, 0, :]

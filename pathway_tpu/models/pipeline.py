@@ -96,11 +96,7 @@ def encode_pipelined(params: dict, input_ids: jax.Array,
 
         # initial carries must be marked pp-varying: they flow through
         # ppermute / per-stage writes, which produce varying values
-        # (jax < 0.7 has no pcast and no varying-mentions tracking — there
-        # the shard_map runs with the replication check disabled instead)
         def varying(a):
-            if not hasattr(jax.lax, "pcast"):
-                return a
             return jax.lax.pcast(a, ("pp",), to="varying")
 
         cur0 = varying(jnp.zeros((mb, S, cfg.hidden), cfg.dtype))
@@ -112,9 +108,7 @@ def encode_pipelined(params: dict, input_ids: jax.Array,
         # outputs are populated only on the last stage; psum replicates
         return jax.lax.psum(outputs, "pp")
 
-    from pathway_tpu.parallel.mesh import compat_shard_map
-
-    staged = compat_shard_map(
+    staged = jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(P("pp"), P(), P()),

@@ -16,7 +16,6 @@ Architecture parity targets (reference consumes these as opaque torch models):
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
@@ -337,24 +336,14 @@ def _attention(x, lp, mask_bias, cfg: TransformerConfig, core=None):
     if core is not None:
         ctx = core(q, k, v).astype(cfg.dtype)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H)
-    elif hasattr(jax.nn, "dot_product_attention"):
-        # XLA's fused attention: numerically IDENTICAL to the explicit
-        # softmax path below (max drift 0.0 measured on v5e) and ~8%
-        # faster end-to-end — the (B, nh, S, S) scores/probs tensors
+    else:
+        # XLA's fused attention: the (B, nh, S, S) scores/probs tensors
         # never round-trip HBM
         ctx = jax.nn.dot_product_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), bias=mask_bias.astype(cfg.dtype),
         )
         ctx = ctx.reshape(B, S, H)
-    else:
-        scores = jnp.einsum("bnqd,bnkd->bnqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(hd) + mask_bias
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        ctx = jnp.einsum("bnqk,bnkd->bnqd", probs, v,
-                         preferred_element_type=jnp.float32).astype(cfg.dtype)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H)
     out = _wq_einsum("bsh,hk->bsk", ctx, lp, "attn_out_w", cfg)
     return out + lp["attn_out_b"].astype(cfg.dtype)
 

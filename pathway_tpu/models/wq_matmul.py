@@ -20,7 +20,8 @@ switch on top of ``PATHWAY_TPU_WEIGHT_QUANT``'s.
 
 ``interpret`` defaults to True off-TPU so tier-1 (JAX_PLATFORMS=cpu)
 runs the same kernel body through the Pallas interpreter, exactly like
-flash/paged attention. Native TPU compilation wants lane-aligned tiles:
+flash/paged attention; on a TPU the same call compiles natively
+(``tests/test_tpu_compile.py``). Native compilation wants lane-aligned tiles:
 int8 operands want (32, 128) minimum register shapes, so the auto tile
 sizes below stay in multiples of 128 on the output-channel axis and the
 full (unpadded) K on the contracted axis — decoder K is the hidden or

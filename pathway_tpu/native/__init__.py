@@ -11,8 +11,12 @@ hot host-side loops the reference implements in Rust:
 * ``split_lines`` — newline tokenizer for line-based connectors
   (reference src/connectors/data_tokenize.rs)
 
-Everything degrades gracefully: if the toolchain is missing the Python/numpy
-paths are used and ``AVAILABLE`` is False.
+There is ONE way to get the module: the self-build, named by the content
+hash of ``_native.cpp``, so the binary a process loads always came from the
+source that sits next to it (an installed copy ships the source and builds
+the same way). If the toolchain is missing the Python/numpy paths are used,
+``AVAILABLE`` is False and the reason is logged as a warning — a caller that
+must not measure the Python paths (``chip_smoke.py``) checks ``AVAILABLE``.
 """
 
 from __future__ import annotations
@@ -22,7 +26,6 @@ import importlib.util
 import logging
 import os
 import subprocess
-import sys
 import sysconfig
 
 logger = logging.getLogger(__name__)
@@ -49,8 +52,8 @@ def _compile(out_path: str) -> bool:
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-    except Exception as exc:  # noqa: BLE001
-        logger.info("native build unavailable: %s", exc)
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        logger.warning("native build unavailable: %s", exc)
         return False
     if proc.returncode != 0:
         logger.warning("native build failed:\n%s", proc.stderr[-2000:])
@@ -64,25 +67,6 @@ def _load():
 
     if pathway_config.disable_native:
         return
-    # a pip-built extension (setup.py) is preferred when it is at least as
-    # new as the source; a stale binary (source edited after `pip install
-    # -e .`) falls through to the JIT path, which content-hashes the source
-    # and rebuilds
-    try:
-        import importlib
-        import importlib.util
-
-        spec = importlib.util.find_spec("pathway_tpu.native._native")
-        if (
-            spec is not None
-            and spec.origin
-            and os.path.getmtime(spec.origin) >= os.path.getmtime(_SRC)
-        ):
-            lib = importlib.import_module("pathway_tpu.native._native")
-            AVAILABLE = True
-            return
-    except (ImportError, OSError):
-        pass
     path = _build_path()
     if not os.path.exists(path):
         tmp = path + f".tmp{os.getpid()}"

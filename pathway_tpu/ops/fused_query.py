@@ -2,9 +2,8 @@
 
 The reference answers a query in stages (embed the query, search the
 index, gather documents, rerank — ``xpacks/llm/vector_store.py:440``,
-``question_answering.py``), each a separate host round trip. On a remote /
-relayed TPU every stage costs a full dispatch RTT, so the stages dominate
-end-to-end latency. TPU-first redesign: keep everything the query touches
+``question_answering.py``), each a separate host round trip with its own dispatch, so the stages
+dominate end-to-end latency. TPU-first redesign: keep everything the query touches
 RESIDENT in HBM — the embedding corpus (the brute-force index matrix) AND
 the documents' token ids — and compile the whole pipeline into a single
 executable:

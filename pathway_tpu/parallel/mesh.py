@@ -15,27 +15,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "dp"
 TENSOR_AXIS = "tp"
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``shard_map`` across jax versions: jax >= 0.8 spells the replication
-    check ``check_vma`` while the 0.4.x experimental API calls it
-    ``check_rep``. The parallel layer always calls THIS wrapper with the
-    new-style keyword; we translate to whatever the installed jax accepts."""
-    kw = {}
-    if check_vma is not None:
-        kw[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def data_axis() -> str:
     return DATA_AXIS
 

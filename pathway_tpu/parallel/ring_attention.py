@@ -24,9 +24,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from pathway_tpu.parallel.mesh import compat_shard_map as shard_map
 
 _MASK_BIAS = -1e9
 

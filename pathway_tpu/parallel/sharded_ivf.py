@@ -25,13 +25,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pathway_tpu.parallel.mesh import (
-    DATA_AXIS,
-    MeshRef as _MeshRef,
-    compat_shard_map as shard_map,
-)
+from pathway_tpu.parallel.mesh import DATA_AXIS, MeshRef as _MeshRef
 
 _NEG_INF = -1e30
 
@@ -40,20 +37,36 @@ def _local_ivf_topk(cells, valid, centroids, q, k: int, nprobe: int,
                     metric: str):
     """One shard's IVF search: (C, cap, d) cells -> (Q, k) local best.
     Returns (scores, flat local slot = cell * cap + slot)."""
-    if metric == "l2":
-        qn = jnp.sum(q * q, axis=1, keepdims=True)
-        cn = jnp.sum(centroids * centroids, axis=1)[None, :]
-        cent_scores = -(qn + cn - 2.0 * q @ centroids.T)
+    if nprobe >= cells.shape[0]:
+        # exhaustive probing (what the KNN factories ask for): every query
+        # scans every cell, so there is nothing to gather — a per-query
+        # copy of the WHOLE shard, (Q, C, cap, d), is 24 GiB at 1M rows a
+        # shard and the chip's compiler refuses it
+        probe = jnp.broadcast_to(
+            jnp.arange(cells.shape[0], dtype=jnp.int32)[None, :],
+            (q.shape[0], cells.shape[0]),
+        )
+        cand_valid = valid[None]                               # (1,C,cap)
+        dots = jnp.einsum("qd,pcd->qpc", q.astype(jnp.bfloat16), cells,
+                          preferred_element_type=jnp.float32)
+        cn = (jnp.sum(cells.astype(jnp.float32) ** 2, axis=2)[None]
+              if metric == "l2" else None)
     else:
-        cent_scores = q @ centroids.T
-    _, probe = jax.lax.top_k(cent_scores, nprobe)              # (Q, nprobe)
-    cand = jnp.take(cells, probe, axis=0)                      # (Q,np,cap,d)
-    cand_valid = jnp.take(valid, probe, axis=0)                # (Q,np,cap)
-    dots = jnp.einsum("qd,qpcd->qpc", q.astype(jnp.bfloat16), cand,
-                      preferred_element_type=jnp.float32)
+        if metric == "l2":
+            qn = jnp.sum(q * q, axis=1, keepdims=True)
+            cent_n = jnp.sum(centroids * centroids, axis=1)[None, :]
+            cent_scores = -(qn + cent_n - 2.0 * q @ centroids.T)
+        else:
+            cent_scores = q @ centroids.T
+        _, probe = jax.lax.top_k(cent_scores, nprobe)          # (Q, nprobe)
+        cand = jnp.take(cells, probe, axis=0)                  # (Q,np,cap,d)
+        cand_valid = jnp.take(valid, probe, axis=0)            # (Q,np,cap)
+        dots = jnp.einsum("qd,qpcd->qpc", q.astype(jnp.bfloat16), cand,
+                          preferred_element_type=jnp.float32)
+        cn = (jnp.sum(cand.astype(jnp.float32) ** 2, axis=3)
+              if metric == "l2" else None)
     if metric == "l2":
         qn = jnp.sum(q * q, axis=1)[:, None, None]
-        cn = jnp.sum(cand.astype(jnp.float32) ** 2, axis=3)
         scores = -(qn + cn - 2.0 * dots)
     else:
         scores = dots
@@ -421,15 +434,14 @@ class ShardedIvfIndex:
 
     def _device_state(self):
         if self._dev is None:
+            # cast on the HOST and put each shard straight onto its own
+            # device: jnp.asarray would first commit the whole f32 mirror
+            # to device 0, which at 1M rows a shard is more than one chip
             shd = NamedSharding(self.mesh, P(DATA_AXIS))
             self._dev = (
-                jax.device_put(
-                    jnp.asarray(self._h_cells, self.dtype), shd
-                ),
-                jax.device_put(jnp.asarray(self._h_valid), shd),
-                jax.device_put(
-                    jnp.asarray(self._h_centroids, jnp.float32), shd
-                ),
+                jax.device_put(self._h_cells.astype(self.dtype), shd),
+                jax.device_put(self._h_valid, shd),
+                jax.device_put(self._h_centroids, shd),
             )
         return self._dev
 

@@ -26,14 +26,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pathway_tpu.ops.knn import knn_scores
-from pathway_tpu.parallel.mesh import (
-    DATA_AXIS,
-    MeshRef as _MeshRef,
-    compat_shard_map as shard_map,
-)
+from pathway_tpu.parallel.mesh import DATA_AXIS, MeshRef as _MeshRef
 
 _NEG_INF = -1e30
 

@@ -92,7 +92,7 @@ class CrossEncoderReranker(pw.UDF):
         return [float(s) for s in scores]
 
     # two-phase protocol (UDF._call_batched): chunks of an epoch all
-    # dispatch, then ONE device drain — per-chunk syncs cost a relay RTT
+    # dispatch, then ONE device drain instead of a sync per chunk
     def submit_batch(self, doc: list[str], query: list[str], **kwargs):
         pairs = [(q or "", d or "") for q, d in zip(query, doc)]
         return self.model.score_submit(pairs)

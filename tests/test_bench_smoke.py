@@ -83,16 +83,23 @@ def test_bench_smoke_schema():
         assert tp["sheds"] == 0
     assert s["tuned_tok_s"] > 0 and s["default_tok_s"] > 0
     assert s["ingest_elapsed_s"] > 0 and s["ingest_docs"] > 0
+    # this run is on the CPU, which is not in probes.DEVICE_PEAKS: every
+    # utilization must read "not measured", never a share of v5e's peaks
     ceil = s["ingest_ceiling"]
-    assert ceil["bound"] in ("compute", "memory")
-    assert ceil["ceiling_mfu_pct"] > 0
+    assert s["ingest_mfu_pct"] == "not measured"
+    assert ceil["bound"] == ceil["ceiling_mfu_pct"] == "not measured"
+    assert ceil["arith_intensity"] > 0
+    assert all(
+        row["mfu_pct"] == row["hbm_util_pct"] == "not measured"
+        for row in s["ingest_roofline"].values()
+    )
     sh = s["sharded_ivf"]
     assert sh.get("error") is None, sh
     assert sh["rows_total"] == sh["shards"] * sh["rows_per_shard"] > 0
     assert 0.0 < sh["recall_at_10"] <= 1.0
-    # mesh-sharded serving (PR 14): the 8-virtual-device arm ran in its
-    # pinned subprocess, emitted the exact single-chip token stream, and
-    # the per-device HBM ledger saw every mesh device
+    # mesh-sharded serving (PR 14): on this CPU run the arm took its 8
+    # devices from a fresh CPU child, emitted the exact single-chip token
+    # stream, and the per-device HBM ledger saw every mesh device
     ms = s["mesh_serving"]
     assert ms.get("error") is None, ms
     assert ms["mesh_tok_s"] > 0 and ms["single_chip_tok_s"] > 0

@@ -55,3 +55,22 @@ def test_spawn_from_env(tmp_path):
     )
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == "0 1 3 10000"
+
+
+def test_spawn_refuses_children_that_would_share_chips(monkeypatch):
+    """``spawn -n M`` assigns no chip to a child: on a TPU host M > 1
+    children would all ask for the same chips and all but one fail or
+    hang, so the launcher refuses up front — unless the children are
+    held to the CPU, or there is only one of them."""
+    import click
+    import pytest
+
+    from pathway_tpu import cli
+
+    monkeypatch.setattr(cli, "_local_tpu_chips", lambda: 4)
+    with pytest.raises(click.ClickException, match="4 TPU chip"):
+        cli._check_children_can_have_devices(2, {})
+    cli._check_children_can_have_devices(1, {})
+    cli._check_children_can_have_devices(2, {"JAX_PLATFORMS": "cpu"})
+    monkeypatch.setattr(cli, "_local_tpu_chips", lambda: 0)
+    cli._check_children_can_have_devices(2, {})

@@ -74,7 +74,7 @@ def test_sigkill_midrun_then_restart_exactly_once(tmp_path):
         # kill windows are calibrated against cold-start pacing; a warm
         # persistent compile cache would let a cycle finish before its
         # SIGKILL, leaving the recovery path nothing to exercise
-        PATHWAY_TPU_COMPILE_CACHE="",
+        JAX_ENABLE_COMPILATION_CACHE="false",
     )
 
     # phase 1: stream two files in, then SIGKILL without warning
@@ -156,7 +156,7 @@ def test_kill_restart_cycles_exactly_once(tmp_path):
             WC_STOP=str(stop_marker),
             PATHWAY_REPLAY_STORAGE=str(store),
             JAX_PLATFORMS="cpu",
-            PATHWAY_TPU_COMPILE_CACHE="",  # cold pacing: see test above
+            JAX_ENABLE_COMPILATION_CACHE="false",  # cold pacing: see above
         )
 
     kill_delays = [1.0, 2.5, 4.0, 1.5]
@@ -224,7 +224,7 @@ def test_recovery_torture_at_scale(tmp_path):
             WC_STOP=str(stop_marker),
             PATHWAY_REPLAY_STORAGE=str(store),
             JAX_PLATFORMS="cpu",
-            PATHWAY_TPU_COMPILE_CACHE="",  # cold pacing: see test above
+            JAX_ENABLE_COMPILATION_CACHE="false",  # cold pacing: see above
         )
 
     # three SIGKILLs at staggered points mid-ingest (late enough that

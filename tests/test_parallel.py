@@ -172,9 +172,7 @@ def test_ring_attention_core_vs_softmax():
     ref = jnp.einsum("bnqk,bnkd->bnqd", jax.nn.softmax(scores, -1), v)
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("sp",))
-    from pathway_tpu.parallel.mesh import compat_shard_map
-
-    out = compat_shard_map(
+    out = jax.shard_map(
         lambda q_, k_, v_, m_: ring_attention_core(q_, k_, v_, m_, "sp", 8),
         mesh=mesh,
         in_specs=(PartitionSpec(None, None, "sp", None),) * 3

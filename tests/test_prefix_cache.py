@@ -388,6 +388,8 @@ def _serve_t2(tiny_params, prefix_t2_mb):
     distinct 3-block heads evict each other (demoting under a tier-2
     budget), then the first head comes back — a tier-2 hit that
     promotes — and a final same-head request lands the tier-1 hit."""
+    import time
+
     from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
 
     probes.reset_prefix_stats()
@@ -401,6 +403,27 @@ def _serve_t2(tiny_params, prefix_t2_mb):
     texts = []
     try:
         srv = chat._server
+        # A promotion is adopted only if it reaches the tree before the
+        # SAME request's own prefill inserts the head (else it is, validly,
+        # dropped as stale). The four prefill ticks are asynchronous
+        # dispatches (~0.6 ms in all), so the staging thread wins only when
+        # the loop happens to block on an earlier request's drain — about
+        # half the runs. The trace under test is the round trip, not that
+        # race: hold the request's insert (on the loop thread, where
+        # adoption runs too) until its promotion has been staged and
+        # adopted.
+        insert = srv._prefix_insert
+
+        def insert_after_promotions(*args, **kwargs):
+            deadline = time.monotonic() + 10.0
+            while srv._t2_pending > 0 and time.monotonic() < deadline:
+                if srv._promote_ready:
+                    srv._drain_promotions()
+                else:
+                    time.sleep(0.001)
+            return insert(*args, **kwargs)
+
+        srv._prefix_insert = insert_after_promotions
 
         def run(p):
             r = chat.submit_batch([p], max_new_tokens=NEW)[0]

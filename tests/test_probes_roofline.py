@@ -6,6 +6,8 @@ import pytest
 
 from pathway_tpu.engine import probes
 
+V5E = probes.DEVICE_PEAKS["TPU v5 lite"]
+
 
 # ------------------------------------------------------------- roofline
 
@@ -13,10 +15,10 @@ from pathway_tpu.engine import probes
 def test_phase_roofline_compute_bound():
     # 1s at half of peak FLOPs, tiny byte traffic -> compute bound
     ph = probes.PhaseRoofline(
-        name="x", seconds=1.0, flops=probes.V5E_PEAK_BF16_FLOPS * 0.5,
+        name="x", seconds=1.0, flops=V5E.bf16_flops * 0.5,
         bytes_moved=1e9, dispatches=4,
     )
-    s = ph.summary(probes.V5E_PEAK_BF16_FLOPS, probes.V5E_PEAK_HBM_BYTES)
+    s = ph.summary(V5E)
     assert s["mfu_pct"] == pytest.approx(50.0, abs=0.1)
     assert s["bound"] == "compute"
     assert s["dispatches"] == 4
@@ -26,9 +28,9 @@ def test_phase_roofline_memory_bound():
     # saturate HBM, negligible FLOPs -> memory bound
     ph = probes.PhaseRoofline(
         name="x", seconds=1.0, flops=1e12,
-        bytes_moved=probes.V5E_PEAK_HBM_BYTES * 0.8, dispatches=1,
+        bytes_moved=V5E.hbm_bytes_per_s * 0.8, dispatches=1,
     )
-    s = ph.summary(probes.V5E_PEAK_BF16_FLOPS, probes.V5E_PEAK_HBM_BYTES)
+    s = ph.summary(V5E)
     assert s["bound"] == "memory"
     assert s["hbm_util_pct"] == pytest.approx(80.0, abs=0.5)
 
@@ -38,12 +40,16 @@ def test_phase_roofline_overhead_bound():
     ph = probes.PhaseRoofline(
         name="x", seconds=1.0, flops=1e12, bytes_moved=1e9, dispatches=999,
     )
-    s = ph.summary(probes.V5E_PEAK_BF16_FLOPS, probes.V5E_PEAK_HBM_BYTES)
+    s = ph.summary(V5E)
     assert s["bound"] == "overhead"
+    # a device outside the peaks table has no utilization, never v5e's
+    assert probes.device_peaks("cpu") is None
+    s = ph.summary(None)
+    assert s["mfu_pct"] == s["hbm_util_pct"] == s["bound"] == "not measured"
 
 
 def test_roofline_model_ledger():
-    m = probes.RooflineModel()
+    m = probes.RooflineModel(V5E)
     m.add("ingest", seconds=2.0, flops=4e12, bytes_moved=8e9, dispatches=10)
     m.add("drain", seconds=0.5, flops=0.0, bytes_moved=1e9, dispatches=1)
     out = m.summary()

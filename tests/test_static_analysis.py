@@ -181,18 +181,18 @@ def outer(mesh, x, specs):
     assert found[0].symbol == "mapped"
 
 
-def test_gl101_compat_shard_map_alias_is_a_root():
-    """The repo's version shim (any from-import alias) is the same
-    trace boundary."""
+def test_gl101_shard_map_from_import_alias_is_a_root():
+    """A from-import alias of ``jax.shard_map`` (how the parallel layer
+    spells it) is the same trace boundary."""
     src = """
-from pathway_tpu.parallel.mesh import compat_shard_map as shard_map
+from jax import shard_map as smap
 
 def mapped(x):
     print(x)
     return x
 
 def outer(mesh, x, specs):
-    return shard_map(
+    return smap(
         mapped, mesh=mesh, in_specs=specs, out_specs=specs
     )(x)
 """
