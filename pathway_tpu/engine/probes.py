@@ -96,6 +96,15 @@ _DEFAULT_HIST_BOUNDS = tuple(1e-4 * (2.0 ** i) for i in range(21))
 METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
     "device_dispatch": (
         "counter", "kind", "Accelerator round trips by dispatch kind"),
+    "knn_search_queries": (
+        "counter", "padded", "Queries per brute-force search dispatch: "
+        "as asked (padded=0) and as searched, rounded up to the pow2 "
+        "bucket (padded=1); over device_dispatch{kind=knn_search}"),
+    "compiles": (
+        "counter", None, "XLA backend compilations in this process"),
+    "compile_seconds": (
+        "counter", None, "Seconds JAX spent tracing, lowering and "
+        "compiling in this process"),
     "cascade_pairs": (
         "counter", "stage", "Rerank pairs scored per cascade stage"),
     "cascade_flops": (
@@ -852,6 +861,16 @@ def record_device_dispatch(kind: str, n: int = 1) -> None:
         op.dispatches += n
 
 
+def record_knn_search(queries: int, bucket: int) -> None:
+    """One ``knn_search`` dispatch: the queries asked for (``padded=0``)
+    and the rows of the pow2 bucket searched for them (``padded=1``: one
+    query is searched as sixteen)."""
+    record_device_dispatch("knn_search")
+    REGISTRY.counter_add_many(
+        "knn_search_queries", "padded", {0: queries, 1: bucket}
+    )
+
+
 def dispatch_counts() -> dict[str, int]:
     return {
         k: int(v)
@@ -861,6 +880,26 @@ def dispatch_counts() -> dict[str, int]:
 
 def reset_dispatch_counts() -> None:
     REGISTRY.remove("device_dispatch")
+
+
+# --------------------------------------------------------------------- #
+# compilations (ROADMAP S8): a compile inside a serving window is a stall
+# an operator has to be able to see
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    if event.startswith("/jax/core/compile/"):
+        REGISTRY.counter_add("compile_seconds", duration)
+        if event.endswith("backend_compile_duration"):
+            REGISTRY.counter_add("compiles")
+
+
+def watch_compiles() -> None:
+    """Count every XLA backend compilation, and the seconds JAX spends
+    tracing, lowering and compiling, into the registry. Called once, by
+    ``import pathway_tpu``."""
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 # --------------------------------------------------------------------- #

@@ -17,7 +17,7 @@ from typing import Any, Callable
 
 from pathway_tpu.engine.batch import Batch, concat_batches, consolidate
 from pathway_tpu.engine.graph import EngineGraph, Node, fuse_chains
-from pathway_tpu.engine import probes
+from pathway_tpu.engine import probes, tracing
 from pathway_tpu.engine.probes import SchedulerStats, _current_op
 
 
@@ -110,6 +110,13 @@ class Scheduler:
         self._async_inflight = 0
         self._stopped = False
         self.current_time: int = -1
+        # time -> the `epoch` span opened by the first injection for it
+        # (the run's outer pump only: a fixpoint round's sub-scheduler
+        # runs inside an operator step of the outer epoch)
+        self._epoch_spans: dict[int, tracing.Span] = {}
+        # request id -> perf_counter reading at which its epoch began,
+        # until the connector that tagged the injection takes it
+        self._admitted: dict = {}
         # operator-telemetry kill switch, read ONCE here so the per-step
         # hot path never touches the environment (PATHWAY_TPU_METRICS,
         # the master switch, is still checked per call inside the
@@ -136,13 +143,31 @@ class Scheduler:
             self._source_frontiers.pop(node.id, None)
             self._lock.notify_all()
 
-    def inject(self, node: Node, time: int, batch: Batch) -> None:
-        """Thread-safe event injection (connector threads, async UDF results)."""
+    def inject(self, node: Node, time: int, batch: Batch,
+               request_id=None) -> None:
+        """Thread-safe event injection (connector threads, async UDF
+        results). ``request_id`` tags the epoch's span with the request
+        this batch carries (the REST connector's row key), so the spans of
+        one request share an identifier."""
         if batch is None or len(batch) == 0:
             return
         with self._lock:
             self._pending[time][node.id].append(batch)
+            if self.allow_deferred:
+                span = self._epoch_spans.get(time)
+                if span is None:
+                    span = self._epoch_spans[time] = tracing.start_span(
+                        "epoch", t=time)
+                if request_id is not None and span is not tracing.NULL_SPAN:
+                    span.attrs.setdefault("requests", []).append(request_id)
             self._lock.notify_all()
+
+    def admitted_at(self, request_id) -> "float | None":
+        """When the epoch that carried ``request_id`` began (a
+        ``perf_counter`` reading; None before it has, or for an untagged
+        injection). Taken once: the entry goes with the call."""
+        with self._lock:
+            return self._admitted.pop(request_id, None)
 
     def pending_backlog(self) -> int:
         """How many injected epoch times wait to be pumped. A cheap peek
@@ -209,7 +234,10 @@ class Scheduler:
                         and self._async_inflight == 0
                     ):
                         return
-                    self._lock.wait(timeout=0.5)
+                    # no time below every frontier yet: the heartbeat
+                    # floor lives here
+                    with tracing.region("pw.engine.wait_ready"):
+                        self._lock.wait(timeout=0.5)
                 injected = self._pending.pop(t)
             self._run_epoch(t, injected)
 
@@ -315,24 +343,31 @@ class Scheduler:
         ):
             self.stats.record_skip()
             return
-        started = time.perf_counter()
-        op_stats = self.stats.operator(node.id, node.name)
-        _current_op.stats = op_stats  # device dispatches attribute here
-        try:
-            out = node.step(t, ins)
-        except Exception as exc:
-            from pathway_tpu.internals.trace import add_error_trace
-
-            raise add_error_trace(exc, node.trace)
-        finally:
-            _current_op.stats = None
-        if extra:
-            out = concat_batches([out] + extra) if out is not None else concat_batches(extra)
-        result = consolidate(out) if out is not None else None
-        outputs[node.id] = result
         rows_in = sum(len(b) for b in ins if b is not None) + sum(
             len(b) for b in (extra or [])
         )
+        started = time.perf_counter()
+        op_stats = self.stats.operator(node.id, node.name)
+        # `Rowwise:9`: the graph has many nodes of one name, and the id
+        # tells them apart; a fused chain's name lists its members
+        with tracing.region("pw.engine.op", op=f"{node.name}:{node.id}",
+                            rows_in=rows_in):
+            _current_op.stats = op_stats  # device dispatches attribute here
+            try:
+                out = node.step(t, ins)
+            except Exception as exc:
+                from pathway_tpu.internals.trace import add_error_trace
+
+                raise add_error_trace(exc, node.trace)
+            finally:
+                _current_op.stats = None
+            if extra:
+                out = concat_batches([out] + extra) if out is not None else concat_batches(extra)
+            result = None
+            if out is not None:
+                with tracing.region("pw.engine.consolidate", rows=len(out)):
+                    result = consolidate(out)
+        outputs[node.id] = result
         if rows_in or result is not None:
             rows_out = len(result) if result is not None else 0
             dt = time.perf_counter() - started
@@ -354,6 +389,24 @@ class Scheduler:
             probes.record_frontier_lag(frontier - t - 1)
 
     def _run_epoch(self, t: int, injected: dict[int, list[Batch]]) -> None:
+        rows = sum(len(b) for batches in injected.values() for b in batches)
+        started = time.perf_counter()
+        with self._lock:
+            span = self._epoch_spans.pop(t, tracing.NULL_SPAN)
+            if span is not tracing.NULL_SPAN:
+                for request_id in span.attrs.get("requests", ()):
+                    self._admitted[request_id] = started
+        span.event("admit", at=started)
+        skipped = self.stats.steps_skipped
+        with tracing.region("pw.engine.epoch", t=t, rows=rows):
+            self._pump_epoch(t, injected)
+        span.event("drain")
+        # only an epoch that carried rows has a span (the heartbeat's
+        # empty epochs are counted by `epochs_total`)
+        span.finish(rows=rows, operators_stepped=len(self.order)
+                    - (self.stats.steps_skipped - skipped))
+
+    def _pump_epoch(self, t: int, injected: dict[int, list[Batch]]) -> None:
         self.current_time = t
         self.stats.current_time = t
         self.stats.epochs_total += 1
@@ -389,6 +442,8 @@ class Scheduler:
                 self._step_node(node, t, outputs, injected)
         # epoch complete: notify operators; collect late emissions
         for node in self._sweep_nodes:
-            for future_t, batch in node.on_time_end(t):
-                assert future_t > t, f"{node} emitted at non-future time {future_t}"
-                self.inject(node, future_t, batch)
+            with tracing.region("pw.engine.on_time_end",
+                                op=f"{node.name}:{node.id}"):
+                for future_t, batch in node.on_time_end(t):
+                    assert future_t > t, f"{node} emitted at non-future time {future_t}"
+                    self.inject(node, future_t, batch)

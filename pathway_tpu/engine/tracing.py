@@ -1,19 +1,32 @@
-"""Per-request serving spans (reference: xLLM-style SLO telemetry).
+"""Per-request spans and host regions on the device profiler's clock.
+
+Two instruments, one module. A :func:`region` is for work that stays on
+one thread: a nested interval that IS a ``jax.profiler.TraceAnnotation``
+named ``pw.<layer>.<what>``, so inside a profiler session (the
+benchmark's traced slice, an operator's ``GET /debug/profile?ms=N``) it
+lands in the trace's host plane on the same time base as the device's
+``XLA Ops``, and outside one it records nothing. A :class:`Span` is for
+work that crosses threads.
 
 A :class:`Span` is one request's timeline through a serving loop: a
 monotonic start plus timestamped events — ``enqueue`` (implicit, at
 construction), ``admit``, ``prefix_match``, ``prefill_chunk``,
 ``spec_cycle``, ``decode_chunk``, ``first_token``, ``drain`` — attached
 by the continuous decoder server (``xpacks/llm/llms.py``), the
-``QueryServer`` micro-batcher and the embed pipeline. :meth:`Span.finish`
+``QueryServer`` micro-batcher, the embed pipeline, the engine's outer
+pump (kind ``epoch``: first injection for a time → the epoch begins →
+its ``on_time_end`` sweep is done) and the REST connector (kind
+``rest``: arrival → commit → its epoch begins → resolve → reply
+returned). :meth:`Span.finish`
 derives the SLO metrics the histograms in ``engine/probes.py`` serve
 (queue-wait = admit − enqueue, TTFT = first_token − enqueue, TPOT =
 (drain − first_token)/(tokens − 1), e2e = drain − enqueue), feeds them
 into the registry with the span's ``kind`` as the ``phase`` label, and
 hands the serialized span to three sinks:
 
-* a bounded in-process ring buffer (``PATHWAY_TPU_TRACE_RING`` spans,
-  oldest evicted) behind :func:`recent_traces`;
+* a bounded in-process ring buffer behind :func:`recent_traces`: the
+  last ``PATHWAY_TPU_TRACE_RING`` spans OF EACH KIND (oldest evicted),
+  so a burst of ``embed`` spans cannot evict the ``epoch`` ones;
 * an optional JSONL flight recorder (``PATHWAY_TPU_TRACE_DIR``), one
   line per span, append-only per pid, through a persistent buffered
   handle flushed every :data:`_JSONL_FLUSH_EVERY` spans and drained by
@@ -31,18 +44,22 @@ byte-identical either way.
 from __future__ import annotations
 
 import atexit
+import gc
+import heapq
 import itertools
 import json
 import os
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from pathway_tpu.analysis.runtime import make_lock
 from pathway_tpu.engine import probes
 
 __all__ = [
     "Span", "NULL_SPAN", "start_span", "recent_traces", "reset_traces",
-    "flush_traces",
+    "flush_traces", "region",
 ]
 
 # lock-discipline declaration for module globals (enforced by
@@ -50,7 +67,7 @@ __all__ = [
 # the flight recorder's file-handle state and the lazy telemetry
 # singleton may only be touched under their locks.
 _GUARDED_BY = {
-    "_ring": "_ring_lock",
+    "_rings": "_ring_lock",
     "_jsonl_file": "_jsonl_lock",
     "_jsonl_path": "_jsonl_lock",
     "_jsonl_unflushed": "_jsonl_lock",
@@ -63,7 +80,7 @@ class _NullSpan:
 
     __slots__ = ()
 
-    def event(self, name: str, **attrs) -> None:
+    def event(self, name: str, at: float | None = None, **attrs) -> None:
         pass
 
     def finish(self, **attrs) -> None:
@@ -74,7 +91,9 @@ NULL_SPAN = _NullSpan()
 
 _ids = itertools.count(1)
 _ring_lock = make_lock("tracing.ring")
-_ring: deque = deque()
+# kind -> deque of (sequence number, span dict): one ring a kind
+_rings: dict[str, deque] = {}
+_seq = itertools.count()
 _jsonl_lock = make_lock("tracing.jsonl")
 _telemetry = None
 _telemetry_lock = make_lock("tracing.telemetry")
@@ -101,8 +120,12 @@ class Span:
         self.events: list = [("enqueue", self.t0, None)]
         self._finished = False
 
-    def event(self, name: str, **attrs) -> None:
-        self.events.append((name, time.perf_counter(), attrs or None))
+    def event(self, name: str, at: float | None = None, **attrs) -> None:
+        """Stamp ``name`` now, or at ``at`` (a ``perf_counter`` reading
+        another thread took, e.g. the scheduler's of an epoch's start)."""
+        self.events.append(
+            (name, time.perf_counter() if at is None else at, attrs or None)
+        )
 
     def first_t(self, name: str) -> float | None:
         for n, t, _ in self.events:
@@ -194,17 +217,19 @@ def recent_traces(server: str | None = None, kind: str | None = None,
     by the ``server`` tag and/or span ``kind``, truncated to the last
     ``n``."""
     with _ring_lock:
-        spans = list(_ring)
+        if kind is not None:
+            rings = [list(_rings.get(kind, ()))]
+        else:
+            rings = [list(r) for r in _rings.values()]
+    spans = [s for _seq, s in heapq.merge(*rings, key=lambda e: e[0])]
     if server is not None:
         spans = [s for s in spans if s.get("server") == server]
-    if kind is not None:
-        spans = [s for s in spans if s.get("kind") == kind]
     return spans[-n:] if n else spans
 
 
 def reset_traces() -> None:
     with _ring_lock:
-        _ring.clear()
+        _rings.clear()
 
 
 def _record(span_dict: dict) -> None:
@@ -212,13 +237,89 @@ def _record(span_dict: dict) -> None:
 
     limit = max(1, pathway_config.trace_ring)
     with _ring_lock:
-        _ring.append(span_dict)
-        while len(_ring) > limit:
-            _ring.popleft()
+        ring = _rings.get(span_dict["kind"])
+        if ring is None:
+            ring = _rings[span_dict["kind"]] = deque()
+        ring.append((next(_seq), span_dict))
+        while len(ring) > limit:
+            ring.popleft()
     trace_dir = pathway_config.trace_dir
     if trace_dir:
         _write_jsonl(trace_dir, span_dict)
     _export_otel(span_dict)
+
+
+# --------------------------------------------------------------------- #
+# host regions on the profiler's clock
+#
+# Placing one: at a layer boundary, never inside a per-row loop; ids are
+# small scalars (t, rows, op, queries); an ``async def`` body never holds
+# one open across an ``await`` (a TraceMe belongs to its thread), which
+# is why REST requests are spans and not regions.
+
+_STAGES = ("tokenize", "h2d", "dispatch", "drain", "append")
+
+
+class _StageRegion:
+    """A region that also feeds ``probes.record_stage`` its own duration
+    on exit: the five ingest stages only."""
+
+    __slots__ = ("_stage", "_items", "_annotation", "_t0")
+
+    def __init__(self, annotation, stage: str, items: int):
+        self._annotation = annotation
+        self._stage = stage
+        self._items = items
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        probes.record_stage(
+            self._stage, time.perf_counter() - self._t0, self._items
+        )
+        return False
+
+
+def region(name: str, stage: str | None = None, items: int = 1, **ids):
+    """``with region("pw.engine.epoch", t=t, rows=n): ...`` — a nested,
+    thread-local interval named ``pw.<layer>.<what>`` with its ids as
+    stats. The profiler session is the only switch: with none running
+    (every production minute) the TraceMe records nothing; inside one the
+    region lands in the ``.xplane.pb`` host plane beside the device's
+    ops. ``stage=`` (one of the five ingest stages) also accumulates the
+    region's duration, over ``items``, into ``probes.stage_seconds()``."""
+    annotation = TraceAnnotation(name, **ids)
+    if stage is None:
+        return annotation
+    if stage not in _STAGES:
+        raise ValueError(f"unknown ingest stage {stage!r}")
+    return _StageRegion(annotation, stage, items)
+
+
+# full collections hold the interpreter for a quarter of a second and
+# more once millions of rows are tracked: a ``pw.gc`` region on whichever
+# thread triggered one, so that an idle gap under it has a name. The
+# collector runs one collection at a time, so one slot does.
+_gc_region = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_region
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_region = region("pw.gc")
+        _gc_region.__enter__()
+    elif _gc_region is not None:
+        _gc_region.__exit__(None, None, None)
+        _gc_region = None
+
+
+gc.callbacks.append(_on_gc)
 
 
 # flight-recorder file state: ONE persistent buffered append handle per

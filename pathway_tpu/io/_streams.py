@@ -13,6 +13,7 @@ import threading
 import time as time_mod
 from typing import Any, Callable, Iterable
 
+from pathway_tpu.engine import tracing
 from pathway_tpu.engine.batch import Batch
 from pathway_tpu.engine.graph import Node
 
@@ -70,17 +71,20 @@ class BaseConnector:
 
     # -- session API used by run() implementations -------------------------
     def emit(
-        self, time: int, rows: "list[tuple[int, tuple, int]] | Batch"
+        self, time: int, rows: "list[tuple[int, tuple, int]] | Batch",
+        request_id=None,
     ) -> None:
         """Inject rows at ``time``. Accepts either per-row triples or an
         already-columnar ``Batch`` (bulk readers build batches directly so
-        400k-row commits skip the row-tuple round trip)."""
+        400k-row commits skip the row-tuple round trip). ``request_id``:
+        see ``Scheduler.inject``."""
         if isinstance(rows, Batch):
             if len(rows):
-                self._sched.inject(self.node, time, rows)
+                self._sched.inject(self.node, time, rows, request_id)
         elif rows:
             self._sched.inject(
-                self.node, time, Batch.from_rows(self.node.column_names, rows)
+                self.node, time,
+                Batch.from_rows(self.node.column_names, rows), request_id,
             )
 
     def advance(self, new_time: int) -> None:
@@ -89,7 +93,7 @@ class BaseConnector:
         self._sched.advance_source(self.node, new_time)
 
     def commit_rows(
-        self, rows: "list[tuple[int, tuple, int]] | Batch"
+        self, rows: "list[tuple[int, tuple, int]] | Batch", request_id=None,
     ) -> int:
         """Atomically emit ``rows`` at a fresh commit time and advance the
         frontier past it (safe against the heartbeat)."""
@@ -97,9 +101,12 @@ class BaseConnector:
             # raise BEFORE the commit: the batch is either fully injected
             # or not at all, like a real source read failure
             self._chaos_read.maybe_fail()
-        with self._time_mutex:
+        # the mutex wait, the columnar batch and the injection
+        with tracing.region("pw.connector.commit",
+                            connector=self.node.name, rows=len(rows)), \
+                self._time_mutex:
             t = next_commit_time()
-            self.emit(t, rows)
+            self.emit(t, rows, request_id)
             if self._snapshot_writer is not None:
                 row_list = list(rows.rows()) if isinstance(rows, Batch) else rows
                 self._snapshot_writer.write_rows(row_list)

@@ -13,9 +13,11 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 import uuid
 from typing import Any
 
+from pathway_tpu.engine import tracing
 from pathway_tpu.engine.operators.core import InputNode
 from pathway_tpu.engine.operators.output import SubscribeNode
 from pathway_tpu.engine.value import Pointer, hash_values
@@ -174,15 +176,34 @@ class _RestConnector(BaseConnector):
         dtypes = {n: c.dtype for n, c in self.schema.__columns__.items()}
         values = parse_record_fields(payload, cols, dtypes, self.schema)
         key = hash_values(str(uuid.uuid4()))
+        # a request crosses threads (this loop, the engine, back), so it
+        # is a span and not a region; every event is stamped from here
+        span = tracing.start_span("rest", request_id=key, server=self.route)
         loop = asyncio.get_event_loop()
         fut: asyncio.Future = loop.create_future()
         with self._pending_lock:
             self._pending[key] = (fut, loop)
         row = tuple(values[c] for c in cols)
-        self.commit_rows([(key, row, 1)])
-        result = await fut
-        if self.delete_completed:
-            self.commit_rows([(key, row, -1)])
+        t = self.commit_rows([(key, row, 1)], request_id=key)
+        t_commit = time.perf_counter()
+        span.event("commit", at=t_commit, t=t)
+        t_resolved = None
+        try:
+            result, t_resolved = await fut
+            if self.delete_completed:
+                self.commit_rows([(key, row, -1)])
+        finally:
+            # `admit`: the request's own epoch began (never before the
+            # commit it carries). The reply may come from a later epoch
+            # and from the subscriber's formatter thread.
+            sched = self._sched
+            t_admit = sched.admitted_at(key) if sched is not None else None
+            if t_admit is not None:
+                span.event("admit", at=max(t_admit, t_commit))
+            if t_resolved is not None:
+                span.event("resolve", at=t_resolved)
+            span.event("drain")
+            span.finish()
         if isinstance(result, dict) and "_pw_http_error" in result:
             # typed failure envelope from the serving layers (see
             # xpacks/llm/servers.map_serving_errors): surface it as the
@@ -202,8 +223,9 @@ class _RestConnector(BaseConnector):
         if entry is None:
             return
         fut, loop = entry
+        reply = (result, time.perf_counter())
         loop.call_soon_threadsafe(
-            lambda: fut.set_result(result) if not fut.done() else None
+            lambda: fut.set_result(reply) if not fut.done() else None
         )
 
     def run(self):

@@ -17,6 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from pathway_tpu.engine.tracing import region
 from pathway_tpu.models.tokenizer import HashTokenizer, pad_to_buckets
 from pathway_tpu.models.transformer import (
     TransformerConfig,
@@ -137,27 +138,28 @@ class CrossEncoderModel:
     def score_batch(self, pairs: list[tuple[str, str]]) -> np.ndarray:
         if not pairs:
             return np.zeros((0,), dtype=np.float32)
-        (out, n) = self.score_submit(pairs)
-        return np.asarray(jax.device_get(out))[:n]
+        return self.score_resolve([self.score_submit(pairs)])[0]
 
     # -- two-phase path: dispatch many pair-batches, drain once ------------
     def score_submit(self, pairs: list[tuple[str, str]]):
         """Tokenize + dispatch WITHOUT waiting; resolve the returned handle
         via :meth:`score_resolve` (same pipelining contract as
         ``SentenceEmbedderModel.embed_submit``)."""
-        ids, mask, types = self.tokenizer.encode_pairs(
-            pairs, max_length=self.max_length, return_types=True
-        )
-        ids, mask, types = pad_to_buckets(ids, mask, types)
-        out = score_fn(self.params, self.head, jnp.asarray(ids),
-                       jnp.asarray(mask), self.cfg, jnp.asarray(types),
-                       flash=self.flash_prefill)
+        with region("pw.rerank.score", pairs=len(pairs)):
+            ids, mask, types = self.tokenizer.encode_pairs(
+                pairs, max_length=self.max_length, return_types=True
+            )
+            ids, mask, types = pad_to_buckets(ids, mask, types)
+            out = score_fn(self.params, self.head, jnp.asarray(ids),
+                           jnp.asarray(mask), self.cfg, jnp.asarray(types),
+                           flash=self.flash_prefill)
         _record_rerank_attn(self.cfg, ids.shape[0], ids.shape[1],
                             self.flash_prefill)
         return (out, len(pairs))
 
     def score_resolve(self, handles) -> list[np.ndarray]:
-        fetched = jax.device_get([h for h, _ in handles])
+        with region("pw.rerank.score", pairs=sum(n for _, n in handles)):
+            fetched = jax.device_get([h for h, _ in handles])
         return [np.asarray(o)[:n] for o, (_, n) in zip(fetched, handles)]
 
     def __call__(self, pairs: list[tuple[str, str]]) -> np.ndarray:

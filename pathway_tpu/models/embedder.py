@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import functools
 import threading
-import time
 import warnings
 
 import numpy as np
@@ -29,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from pathway_tpu.engine.async_runtime import StageWorker
-from pathway_tpu.engine.probes import record_device_dispatch, record_stage
+from pathway_tpu.engine.probes import record_device_dispatch
+from pathway_tpu.engine.tracing import region
 from pathway_tpu.models.tokenizer import (
     HashTokenizer,
     load_tokenizer,
@@ -221,11 +221,12 @@ class _IngestPipeline:
         texts, handle, kind, dc = item
         try:
             model = self._model
-            t0 = time.perf_counter()
             handle.span.event("admit")
-            ids, mask = model.tokenizer(texts, max_length=model.max_length)
-            ids, mask = pad_to_buckets(ids, mask)
-            record_stage("tokenize", time.perf_counter() - t0)
+            with region("pw.embed.tokenize", stage="tokenize",
+                        rows=len(texts)):
+                ids, mask = model.tokenizer(
+                    texts, max_length=model.max_length)
+                ids, mask = pad_to_buckets(ids, mask)
             handle.span.event("tokenize", texts=len(texts))
         except BaseException as exc:  # noqa: BLE001 - surfaces at resolve
             handle._error = exc
@@ -267,52 +268,52 @@ class _IngestPipeline:
             self._chaos_h2d.maybe_fail()
         model = self._model
         fused = pathway_config.fused_h2d
-        t0 = time.perf_counter()
-        if fused:
-            # one contiguous transfer instead of two (ids and mask are
-            # both int32, so the stack is a cheap host-side copy)
-            dev_packed = jax.device_put(np.stack((ids, mask)))
-        else:
-            dev_ids = jax.device_put(ids)
-            dev_mask = jax.device_put(mask)
-        t1 = time.perf_counter()
-        record_stage("h2d", t1 - t0)
+        with region("pw.embed.h2d", stage="h2d", rows=n):
+            if fused:
+                # one contiguous transfer instead of two (ids and mask are
+                # both int32, so the stack is a cheap host-side copy)
+                dev_packed = jax.device_put(np.stack((ids, mask)))
+            else:
+                dev_ids = jax.device_put(ids)
+                dev_mask = jax.device_put(mask)
         handle.span.event("h2d")
         flash = model.flash_prefill
-        if kind == "tokens":
-            proj = model.late_projection_matrix(dc)
-            if fused:
-                out = _token_states_packed(
-                    model.params, dev_packed, proj, model.cfg, flash=flash
-                )
-            else:
-                from pathway_tpu.ops.late_bank import doc_token_states
+        with region("pw.embed.dispatch", stage="dispatch", rows=n):
+            if kind == "tokens":
+                proj = model.late_projection_matrix(dc)
+                if fused:
+                    out = _token_states_packed(
+                        model.params, dev_packed, proj, model.cfg,
+                        flash=flash,
+                    )
+                else:
+                    from pathway_tpu.ops.late_bank import doc_token_states
 
-                out = doc_token_states(
-                    model.params, dev_ids, dev_mask, proj, model.cfg,
-                    flash=flash,
-                )
-            record_device_dispatch("token_bank_dispatch")
-            # int8 payload + f32 scales: already transport-compact, no
-            # precision cast needed before the drain
-        else:
-            if fused:
-                out = _embed_fn_packed(model.params, dev_packed, model.cfg,
-                                       flash=flash)
+                    out = doc_token_states(
+                        model.params, dev_ids, dev_mask, proj, model.cfg,
+                        flash=flash,
+                    )
+                record_device_dispatch("token_bank_dispatch")
+                # int8 payload + f32 scales: already transport-compact, no
+                # precision cast needed before the drain
             else:
-                out = _embed_fn_donated(
-                    model.params, dev_ids, dev_mask, model.cfg, flash=flash
-                )
-            record_device_dispatch("embed_dispatch")
-            out = out.astype(jnp.float16)
-        _record_encoder_attn(model.cfg, int(ids.shape[0]),
-                             int(ids.shape[1]), flash)
-        for leaf in jax.tree.leaves(out):
-            try:
-                leaf.copy_to_host_async()
-            except Exception:  # noqa: BLE001 - platform-optional fast path
-                pass
-        record_stage("dispatch", time.perf_counter() - t1)
+                if fused:
+                    out = _embed_fn_packed(model.params, dev_packed,
+                                           model.cfg, flash=flash)
+                else:
+                    out = _embed_fn_donated(
+                        model.params, dev_ids, dev_mask, model.cfg,
+                        flash=flash,
+                    )
+                record_device_dispatch("embed_dispatch")
+                out = out.astype(jnp.float16)
+            _record_encoder_attn(model.cfg, int(ids.shape[0]),
+                                 int(ids.shape[1]), flash)
+            for leaf in jax.tree.leaves(out):
+                try:
+                    leaf.copy_to_host_async()
+                except Exception:  # noqa: BLE001 - platform-optional fast path
+                    pass
         handle.span.event("dispatch", rows=n)
         handle._value = (out, n)
 
@@ -513,10 +514,11 @@ class SentenceEmbedderModel:
         resolved = [
             h.wait() if isinstance(h, _PendingEmbed) else h for h in handles
         ]
-        t0 = time.perf_counter()
-        fetched = jax.device_get([out for out, _ in resolved])
+        # the host WAITING for the device, and named so
+        with region("pw.embed.drain", stage="drain",
+                    rows=sum(n for _, n in resolved)):
+            fetched = jax.device_get([out for out, _ in resolved])
         record_device_dispatch("embed_drain")
-        record_stage("drain", time.perf_counter() - t0)
         for h in handles:
             if isinstance(h, _PendingEmbed):
                 h.span.event("drain")
@@ -577,10 +579,10 @@ class SentenceEmbedderModel:
         resolved = [
             h.wait() if isinstance(h, _PendingEmbed) else h for h in handles
         ]
-        t0 = time.perf_counter()
-        fetched = jax.device_get([out for out, _ in resolved])
+        with region("pw.embed.drain", stage="drain",
+                    rows=sum(n for _, n in resolved)):
+            fetched = jax.device_get([out for out, _ in resolved])
         record_device_dispatch("token_bank_drain")
-        record_stage("drain", time.perf_counter() - t0)
         for h in handles:
             if isinstance(h, _PendingEmbed):
                 h.span.event("drain")

@@ -20,7 +20,11 @@ from __future__ import annotations
 import functools
 import math
 
-from pathway_tpu.engine.probes import record_device_dispatch, record_stage
+from pathway_tpu.engine.probes import (
+    record_device_dispatch,
+    record_knn_search,
+)
+from pathway_tpu.engine.tracing import region
 from pathway_tpu.ops import canonical_metric, next_pow2, prep_host_vectors
 from typing import Any
 
@@ -327,15 +331,12 @@ class BruteForceKnnIndex:
         """Host-side half of an append: key -> slot bookkeeping (one home
         for both the plain and the fused ingest paths). zip/update/extend
         keep the whole batch in C — this sits on the per-batch ingest path."""
-        import time
-
-        t0 = time.perf_counter()
-        self._slot_of.update(zip(keys, range(start, start + len(keys))))
-        self._keys.extend(keys)
-        self.n += len(keys)
         # "append" = the host-side index bookkeeping share of the ingest
         # wall; the vector write itself rides the fused device dispatch
-        record_stage("append", time.perf_counter() - t0)
+        with region("pw.index.append", stage="append", rows=len(keys)):
+            self._slot_of.update(zip(keys, range(start, start + len(keys))))
+            self._keys.extend(keys)
+            self.n += len(keys)
 
     def add_embed(self, keys: list, params, input_ids, attention_mask,
                   cfg, embed, pad_id: int = 0, query_rows: int = 0,
@@ -457,10 +458,11 @@ class BruteForceKnnIndex:
             q = jnp.asarray(q)
         k_eff = min(k, self.capacity)
         normalize = self.metric == "cos"
-        scores, idx = _search_kernel(self._corpus, self._valid, q, k_eff,
-                                     self.metric, normalize=normalize,
-                                     f32_scores=self.f32_scores)
-        record_device_dispatch("knn_search")
+        with region("pw.index.search", queries=nq, bucket=bucket):
+            scores, idx = _search_kernel(self._corpus, self._valid, q, k_eff,
+                                         self.metric, normalize=normalize,
+                                         f32_scores=self.f32_scores)
+        record_knn_search(nq, bucket)
         return scores, idx
 
     def resolve(self, scores, idx, nq: int, k: int) -> list[list[tuple[Any, float]]]:
@@ -487,10 +489,12 @@ class BruteForceKnnIndex:
         nq = 1 if queries.ndim == 1 else queries.shape[0]
         if self.n == 0:
             return [[] for _ in range(nq)]
-        # one round trip for both result arrays
-        scores, idx = jax.device_get(self.search_device(queries, k))
-        record_device_dispatch("knn_drain")
-        return self.resolve(scores, idx, nq, k)
+        handles = self.search_device(queries, k)
+        with region("pw.index.fetch", queries=nq):
+            # one round trip for both result arrays
+            scores, idx = jax.device_get(handles)
+            record_device_dispatch("knn_drain")
+            return self.resolve(scores, idx, nq, k)
 
     def __len__(self) -> int:
         return self.n
