@@ -114,6 +114,10 @@ class Scheduler:
         # (the run's outer pump only: a fixpoint round's sub-scheduler
         # runs inside an operator step of the outer epoch)
         self._epoch_spans: dict[int, tracing.Span] = {}
+        # node id -> the last time ``inject_open`` opened for that node; it
+        # is still open while it is a key of ``_pending``, which the pumps'
+        # ``_pending.pop(t)`` ends under the same lock
+        self._open_times: dict[int, int] = {}
         # request id -> perf_counter reading at which its epoch began,
         # until the connector that tagged the injection takes it
         self._admitted: dict = {}
@@ -161,6 +165,22 @@ class Scheduler:
                 if request_id is not None and span is not tracing.NULL_SPAN:
                     span.attrs.setdefault("requests", []).append(request_id)
             self._lock.notify_all()
+
+    def inject_open(self, node: Node, fresh_time: int, batch: Batch,
+                    request_id=None) -> int:
+        """Inject at the time ``node`` opened last through this method if
+        no pump has taken that time yet, else at ``fresh_time``, which
+        becomes the node's open time; returns the time used. One critical
+        section: a separate "is it still open?" and ``inject`` would let the
+        pump pop the time in between, and an epoch would run in the past.
+        Whoever joins an open time has already advanced its frontier past
+        it and must not advance to it again."""
+        with self._lock:
+            t = self._open_times.get(node.id)
+            if t is None or t not in self._pending:
+                t = self._open_times[node.id] = fresh_time
+            self.inject(node, t, batch, request_id)
+        return t
 
     def admitted_at(self, request_id) -> "float | None":
         """When the epoch that carried ``request_id`` began (a

@@ -105,18 +105,25 @@ class BaseConnector:
         with tracing.region("pw.connector.commit",
                             connector=self.node.name, rows=len(rows)), \
                 self._time_mutex:
-            t = next_commit_time()
-            self.emit(t, rows, request_id)
-            if self._snapshot_writer is not None:
-                row_list = list(rows.rows()) if isinstance(rows, Batch) else rows
-                self._snapshot_writer.write_rows(row_list)
-                self._snapshot_writer.advance(t, offset=self.current_offset())
-            self.advance(t + 1)
+            t = self._emit_commit(rows, request_id)
             if self._sched is not None:
                 self._sched.stats.record_connector_commit(
                     self.node.id, self._stat_name(), len(rows)
                 )
             return t
+
+    def _emit_commit(self, rows, request_id) -> int:
+        """Under ``_time_mutex``: a commit is a unit its subscribers count
+        (one ``on_time_end``, one snapshot advance), so it gets a time of
+        its own."""
+        t = next_commit_time()
+        self.emit(t, rows, request_id)
+        if self._snapshot_writer is not None:
+            row_list = list(rows.rows()) if isinstance(rows, Batch) else rows
+            self._snapshot_writer.write_rows(row_list)
+            self._snapshot_writer.advance(t, offset=self.current_offset())
+        self.advance(t + 1)
+        return t
 
     def _stat_name(self) -> str:
         return f"{type(self).__name__}[{self.node.name}]"

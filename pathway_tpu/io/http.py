@@ -18,6 +18,7 @@ import uuid
 from typing import Any
 
 from pathway_tpu.engine import tracing
+from pathway_tpu.engine.batch import Batch
 from pathway_tpu.engine.operators.core import InputNode
 from pathway_tpu.engine.operators.output import SubscribeNode
 from pathway_tpu.engine.value import Pointer, hash_values
@@ -170,6 +171,26 @@ class _RestConnector(BaseConnector):
         self.delete_completed = delete_completed_queries
         self._pending: dict[int, asyncio.Future] = {}
         self._pending_lock = threading.Lock()
+
+    def _emit_commit(self, rows, request_id) -> int:
+        """A request (or the retraction of a completed one) joins the time
+        this connector opened last while the pump has not taken it: what
+        arrives while the engine is busy rides ONE epoch, and a lone
+        request at an idle engine opens a time that is taken at once. No
+        timer and no window: the batch is whatever the engine kept
+        waiting."""
+        if self._snapshot_writer is not None:
+            # a snapshot advances once a commit time
+            return super()._emit_commit(rows, request_id)
+        fresh = next_commit_time()
+        t = self._sched.inject_open(
+            self.node, fresh,
+            Batch.from_rows(self.node.column_names, rows), request_id)
+        if t == fresh:
+            # a joined time lies below this frontier already (the opening
+            # commit and every heartbeat since advanced past it)
+            self.advance(t + 1)
+        return t
 
     async def _handle(self, payload: dict):
         cols = list(self.node.column_names)

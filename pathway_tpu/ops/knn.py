@@ -76,6 +76,12 @@ def _normalize(v):
 
 
 _TOPK_BLOCK = 8192
+# the query buckets of one search dispatch, each its own executable. The
+# kernel holds bucket x capacity f32 scores and their block winners, 96 MiB
+# a query at capacity 8,388,608 (3 GiB at 32, beside a 6.44 GB index on a
+# 16 GB chip; 64 would not fit): ``search`` splits what is above the largest.
+_SEARCH_BUCKETS = (16, 32)
+_MAX_SEARCH_BUCKET = _SEARCH_BUCKETS[-1]
 
 
 def topk_scores(scores, k: int):
@@ -265,6 +271,8 @@ class BruteForceKnnIndex:
         self.n = 0
         self._keys: list[Any] = []
         self._slot_of: dict[Any, int] = {}
+        # (k, capacity) whose every bucket ``search`` has compiled
+        self._compiled: set[tuple[int, int]] = set()
 
     # ------------------------------------------------------------------ sizing
     def _grow(self, needed: int) -> None:
@@ -450,20 +458,35 @@ class BruteForceKnnIndex:
         if q.ndim == 1:
             q = q[None, :]
         nq = q.shape[0]
-        bucket = next_pow2(nq, 16)
+        bucket = next_pow2(nq, _SEARCH_BUCKETS[0])
         if bucket > nq:
             pad_spec = ((0, bucket - nq), (0, 0))
             q = jnp.pad(q, pad_spec) if is_device else np.pad(q, pad_spec)
         if not is_device:
             q = jnp.asarray(q)
-        k_eff = min(k, self.capacity)
-        normalize = self.metric == "cos"
         with region("pw.index.search", queries=nq, bucket=bucket):
-            scores, idx = _search_kernel(self._corpus, self._valid, q, k_eff,
-                                         self.metric, normalize=normalize,
-                                         f32_scores=self.f32_scores)
+            scores, idx = self._dispatch(q, k)
         record_knn_search(nq, bucket)
         return scores, idx
+
+    def _dispatch(self, q, k: int):
+        return _search_kernel(self._corpus, self._valid, q,
+                              min(k, self.capacity), self.metric,
+                              normalize=self.metric == "cos",
+                              f32_scores=self.f32_scores)
+
+    def _compile_buckets(self, k: int) -> None:
+        """With the first ``search`` at ``k``, one dispatch of every bucket:
+        a served index meets its largest bucket when requests pile up behind
+        a busy engine, the one moment a compile of seconds must not come
+        (6.44 GB of rows: 5-9 s an executable). Costs a batch job one
+        executable it may not use."""
+        if (k, self.capacity) in self._compiled:
+            return
+        self._compiled.add((k, self.capacity))
+        for bucket in _SEARCH_BUCKETS:
+            self._dispatch(
+                jnp.asarray(np.zeros((bucket, self.dim), np.float32)), k)
 
     def resolve(self, scores, idx, nq: int, k: int) -> list[list[tuple[Any, float]]]:
         """Map fetched (host) score/index arrays back to [(key, score)] rows."""
@@ -486,15 +509,26 @@ class BruteForceKnnIndex:
         """Return per-query [(key, score)] sorted by decreasing score."""
         if not isinstance(queries, (np.ndarray, jax.Array)):
             queries = np.asarray(queries)
-        nq = 1 if queries.ndim == 1 else queries.shape[0]
+        if queries.ndim == 1:
+            queries = queries[None, :]
+        nq = queries.shape[0]
         if self.n == 0:
             return [[] for _ in range(nq)]
-        handles = self.search_device(queries, k)
+        self._compile_buckets(k)
+        # a guard, not a batching policy: an epoch's queries are one
+        # dispatch up to the largest bucket, and as many as it takes above
+        chunks = [queries[s:s + _MAX_SEARCH_BUCKET]
+                  for s in range(0, nq, _MAX_SEARCH_BUCKET)]
+        handles = [self.search_device(chunk, k) for chunk in chunks]
         with region("pw.index.fetch", queries=nq):
-            # one round trip for both result arrays
-            scores, idx = jax.device_get(handles)
+            # one round trip for every result array
+            fetched = jax.device_get(handles)
             record_device_dispatch("knn_drain")
-            return self.resolve(scores, idx, nq, k)
+            return [
+                row
+                for chunk, (scores, idx) in zip(chunks, fetched)
+                for row in self.resolve(scores, idx, len(chunk), k)
+            ]
 
     def __len__(self) -> int:
         return self.n

@@ -267,13 +267,40 @@ def test_two_process_streaming_updates(tmp_path):
         counts = t.groupby(t.word).reduce(t.word, c=pw.reducers.count())
         pw.io.jsonlines.write(counts, "out.jsonl")
 
+        def counts_now():
+            # the net state over BOTH processes' shards, whole lines only
+            net = {}
+            for pid in range(2):
+                try:
+                    with open(f"out.jsonl.{pid}") as f:
+                        lines = f.read().split("\\n")[:-1]
+                except FileNotFoundError:
+                    continue
+                for r in map(json.loads, lines):
+                    k = (r["word"], r["c"])
+                    net[k] = net.get(k, 0) + r["diff"]
+            return {w: c for (w, c), d in net.items() if d > 0}
+
+        def wait_for(least):
+            # a state, not a time: under six test workers a fixed sleep is
+            # a guess at how long a peer takes to start and to poll (counts
+            # only grow here, and a late process may find them grown)
+            deadline = time.monotonic() + 120
+            while time.monotonic() < deadline:
+                now = counts_now()
+                if all(now.get(w, 0) >= c for w, c in least.items()):
+                    return
+                time.sleep(0.05)
+
         def feeder():
-            time.sleep(1.0)
+            wait_for({"alpha": 1, "beta": 1})
             if os.environ["PATHWAY_PROCESS_ID"] == "0":
-                with open("in/late1.jsonl", "w") as f:
+                # appears in one piece: the reader never sees half a file
+                with open("late1.tmp", "w") as f:
                     f.write(json.dumps({"word": "alpha"}) + "\\n")
                     f.write(json.dumps({"word": "gamma"}) + "\\n")
-            time.sleep(2.0)
+                os.replace("late1.tmp", "in/late1.jsonl")
+            wait_for({"alpha": 2, "beta": 1, "gamma": 1})
             for c in pw.G.connectors:
                 c._stop.set()
                 c.close()
