@@ -1,8 +1,11 @@
 """The comparison that decides ``correct``: what the TIMED path produced, at
-the timed sizes, against the plain references (:mod:`harness.reference`),
-each number beside a limit of its own (the configuration's ``limits``).
+the timed sizes, against the plain references (each model's layout brings
+its own: :mod:`harness.layouts`), each number beside a limit of its own
+(the configuration's ``limits``).
 
-A check is named in the mix's file (``"check"``) and has two halves:
+A check is named in the mix's file (``"check"``) and found by
+``manifest.resolve``: one of :data:`CHECKS`, or ``Check`` of a
+``<path>/checks/<name>.py``. It has two halves:
 ``collect`` runs while the system is alive and takes only what the window
 produced (index rows, replies); ``compare`` runs after the system's state is
 freed and computes the references. ``control=True`` also computes every
@@ -15,7 +18,6 @@ from __future__ import annotations
 import numpy as np
 
 from . import reference as R
-from . import work
 from .system import System
 
 
@@ -34,6 +36,7 @@ class Check:
         self.seed = seed
         self.dep = config["deployment"]
         self.models = config["models"]
+        self.layouts = config["layouts"]
         self.rng = np.random.default_rng(seed + 1)
 
     # numbers that need no reference: name -> value (limit 0)
@@ -51,12 +54,14 @@ class Check:
         raise NotImplementedError
 
     def _embed(self, params, texts, precision="f32"):
-        return R.embed_texts(params["embedder"], self.models["embedder"],
-                             texts, self.dep["embed_max_length"], precision)
+        return self.layouts["embedder"].embed(
+            params["embedder"], self.models["embedder"], texts,
+            self.dep["embed_max_length"], precision)
 
     def _score(self, params, pairs, precision="f32"):
-        return R.score_pairs(params["reranker"], self.models["reranker"],
-                             pairs, self.dep["rerank_max_length"], precision)
+        return self.layouts["reranker"].score(
+            params["reranker"], self.models["reranker"], pairs,
+            self.dep["rerank_max_length"], precision)
 
 
 class IngestCheck(Check):
@@ -65,7 +70,7 @@ class IngestCheck(Check):
     (index)."""
 
     def needed_flops(self, window) -> float:
-        per_doc = work.encoder_flops(
+        per_doc = self.layouts["embedder"].encoder_flops(
             self.models["embedder"], _tokens_single(self.dep["doc_words"]))
         return window.counters["docs_landed"] * per_doc
 
@@ -130,8 +135,10 @@ class RetrieveCheck(Check):
 
     def needed_flops(self, window) -> float:
         q = self.traffic["query_words"]
-        per = work.encoder_flops(self.models["embedder"], _tokens_single(q)) \
-            + self.dep["rerank_candidates"] * work.encoder_flops(
+        per = self.layouts["embedder"].encoder_flops(
+            self.models["embedder"], _tokens_single(q)) \
+            + self.dep["rerank_candidates"] \
+            * self.layouts["reranker"].encoder_flops(
                 self.models["reranker"],
                 _tokens_pair(q, self.dep["doc_words"]))
         return window.counters["requests_completed"] * per
@@ -260,7 +267,8 @@ class AnswerCheck(RetrieveCheck):
         srv = self.dep["decoder_server"]
         prompt = window.facts.get("prompt_tokens_median") or (
             self.dep["search_topk"] * self.dep["doc_words"])
-        per = work.answer_flops(dec, int(prompt), srv["max_new_tokens"])
+        per = self.layouts["decoder"].answer_flops(
+            dec, int(prompt), srv["max_new_tokens"])
         return super().needed_flops(window) \
             + window.counters["requests_completed"] * per
 
@@ -329,27 +337,27 @@ class AnswerCheck(RetrieveCheck):
     def compare(self, got, params, control):
         numbers, ctrl = self.compare_docs(got, params, control)
         numbers["prompt_context_mismatch"] = got["prompt_mismatch"]
-        dec = self.models["decoder"]
+        dec, layout = self.models["decoder"], self.layouts["decoder"]
         cap = self.dep["decoder_server"]["max_prompt_tokens"]
         if not got["answers"]:
             numbers["token_logit_gap"] = 1e9
             return numbers, ctrl
-        p32 = R.prepare_decoder(params["decoder"])
+        p32 = layout.prepare(params["decoder"], "f32")
         ref = []
         for prompt, toks in got["answers"]:
             prompt = prompt[-cap:]
-            ref.append(R.gpt2_logits(p32, dec, prompt + toks[:-1],
-                                     len(prompt) - 1))
+            ref.append(layout.logits(p32, dec, prompt + toks[:-1],
+                                     len(prompt) - 1, "f32"))
         del p32
         numbers["token_logit_gap"] = max(
             float((lg.max(axis=1) - lg[np.arange(len(toks)), toks]).max())
             for lg, (_p, toks) in zip(ref, got["answers"]))
         if control:
-            p8 = R.prepare_decoder(params["decoder"], "fp8")
+            p8 = layout.prepare(params["decoder"], "fp8")
             worst = 0.0
             for lg, (prompt, toks) in zip(ref, got["answers"]):
                 prompt = prompt[-cap:]
-                low = R.gpt2_logits(p8, dec, prompt + toks[:-1],
+                low = layout.logits(p8, dec, prompt + toks[:-1],
                                     len(prompt) - 1, "fp8")
                 first = low.argmax(axis=1)
                 worst = max(worst, float(

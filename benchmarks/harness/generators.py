@@ -1,6 +1,9 @@
 """Traffic generators: ONE general generator per kind of load, driven by a
 mix's data file (``benchmarks/traffic/<mix>.json``: lengths, clients,
-outstanding commits, sharing). A later PR adds a mix by adding a file.
+outstanding commits, sharing). A later PR adds a mix by adding a file. The
+mix names its generator (``"generator"``), found by ``manifest.resolve``:
+one of :data:`GENERATORS`, or ``Generator`` of a
+``<path>/generators/<name>.py``.
 
 Each generator has ``prepare`` (before the window: inputs made from the
 seed, the served path warmed at the window's own concurrency — set-up) and

@@ -31,6 +31,7 @@ from .manifest import ROOT
 # thread, name, start_ns, duration_ns, stats
 Region = tuple[int, str, int, int, dict]
 
+ENGINE = "pw.engine."
 WAIT = "pw.engine.wait_ready"
 EPOCH = "pw.engine.epoch"
 OP = "pw.engine.op"
@@ -134,14 +135,22 @@ def attribute_idle(gaps: list[tuple[int, int]], regions: list[Region]):
     cause is the deepest region of any other thread, else the wait itself;
     time under no region is ``unattributed``. Returns ``(nanoseconds by
     region name, nanoseconds by operator under pw.engine.op)``, or
-    ``None`` where no thread ran an epoch."""
+    ``None`` where no thread ran the engine.
+
+    The profiler keeps a region only if it began AND ended inside the
+    session, and a slice of 3 s holds one whole epoch of cell 3 as a rule
+    (6 of 8 slices; in 1 of 13 none: my chip runs, PR 27). So where no
+    epoch is whole the engine's thread is the one with the most
+    ``pw.engine.`` regions (its operators and waits are whole), and the
+    epoch's own time outside them reads ``unattributed``."""
     threads: dict[int, list[Region]] = {}
     for r in regions:
         threads.setdefault(r[0], []).append(r)
-    epochs = {th: sum(r[1] == EPOCH for r in rs)
-              for th, rs in threads.items()}
-    engine = max(epochs, key=epochs.get, default=None)
-    if engine is None or not epochs[engine]:
+    engine = max(threads, default=None, key=lambda th: (
+        sum(r[1] == EPOCH for r in threads[th]),
+        sum(r[1].startswith(ENGINE) for r in threads[th])))
+    if engine is None or not any(r[1].startswith(ENGINE)
+                                 for r in threads[engine]):
         return None
     segments = {th: leaf_segments(rs) for th, rs in threads.items()}
     starts = {th: [s[0] for s in segs] for th, segs in segments.items()}

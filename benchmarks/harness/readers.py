@@ -6,17 +6,23 @@ returns ``None`` and the metric is left out of the line — never 0 for a
 share. A share of a roofline or of a peak above 100 % is a fault of the
 count: the reader raises, and the run fails, rather than print it.
 
-``ctx``: ``trace`` (:class:`harness.trace.TraceSummary` or None),
-``counters`` (whole window), ``slice_counters`` (the traced slice),
+A roofline's work is named in the metric's file (``params.work``) and found
+by ``manifest.resolve``: one of :data:`WORK`, or ``work(ctx, runs)`` of a
+``<path>/work/<name>.py``.
+
+``ctx``: ``manifest``, ``trace`` (:class:`harness.trace.TraceSummary` or
+None), ``counters`` (whole window), ``slice_counters`` (the traced slice),
 ``lifetime_counters`` (since the process began),
-``spans`` (name -> list of ms), ``peaks``, ``config``, ``traffic``,
-``window_s``, ``facts`` (readers may add what they learned: which bound).
+``spans`` (name -> list of ms), ``peaks``, ``config`` (with ``layouts``:
+role -> its model's layout), ``traffic``, ``window_s``, ``facts`` (readers
+may add what they learned: which bound).
 """
 
 from __future__ import annotations
 
 import statistics
 
+from . import manifest as M
 from . import work
 
 
@@ -65,11 +71,12 @@ def _embed_step(ctx, runs):
     """Each run of the embed executable carried the embedder's documents
     per dispatch, at the tokens a document needs (padding is not work)."""
     model = ctx["config"]["models"]["embedder"]
+    layout = ctx["config"]["layouts"]["embedder"]
     docs = _per_run(ctx, "embed_dedup_misses", "dispatch_embed_dispatch")
     tokens = ctx["config"]["deployment"]["doc_words"] + 2
     ctx["facts"]["embed_docs_per_dispatch"] = docs
-    return (runs * docs * work.encoder_flops(model, tokens),
-            runs * work.encoder_bytes(model, docs, tokens))
+    return (runs * docs * layout.encoder_flops(model, tokens),
+            runs * layout.encoder_bytes(model, docs, tokens))
 
 
 def _knn_search(ctx, runs):
@@ -96,7 +103,8 @@ def trace_module_roofline(ctx, params):
     runs, seconds = t.module_seconds(params["modules"])
     if not runs or seconds <= 0:
         return None
-    flops, nbytes = WORK[params["work"]](ctx, runs)
+    flops, nbytes = M.resolve(ctx["manifest"], "work", params["work"])(
+        ctx, runs)
     if flops <= 0 and nbytes <= 0:
         return None
     least, bound = work.least_seconds(flops, nbytes, ctx["peaks"])

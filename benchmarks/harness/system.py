@@ -4,8 +4,11 @@ way a user wires them, from a configuration's file. The pieces are copies of
 argument: ``warm_factory``, ``RerankingStore``, ``make_window_feeder``,
 ``Clock``, ``device_barrier``, ``require_tpu``.
 
-A builder is named in the configuration's file (``"builder"``) and found in
-:data:`BUILDERS`.
+A builder is named in the configuration's file (``"builder"``) and found by
+``manifest.resolve``: one of :data:`BUILDERS`, or ``build`` of a
+``<path>/builders/<name>.py``. What a model's entry in that file means (the
+program's config, the weight tree) is its layout's to say
+(:mod:`harness.layouts`, ``config["layouts"]``).
 """
 
 from __future__ import annotations
@@ -14,8 +17,6 @@ import queue
 import sys
 import threading
 import time
-
-import numpy as np
 
 from . import weights as W
 from .corpus import Corpus, WordTokenizer
@@ -207,37 +208,6 @@ def make_commit_feeder():
     return CommitFeeder()
 
 
-def _transformer_config(model: dict):
-    import jax.numpy as jnp
-
-    from pathway_tpu.models.transformer import TransformerConfig
-
-    if model["torch_dtype"] != "bfloat16":
-        raise ValueError("the encoder cells state bfloat16")
-    return TransformerConfig(
-        vocab_size=model["vocab_size"], hidden=model["hidden_size"],
-        layers=model["num_hidden_layers"], heads=model["num_attention_heads"],
-        intermediate=model["intermediate_size"],
-        max_position=model["max_position_embeddings"],
-        type_vocab=model["type_vocab_size"],
-        layer_norm_eps=model["layer_norm_eps"], dtype=jnp.bfloat16,
-    )
-
-
-def _decoder_config(model: dict):
-    import jax.numpy as jnp
-
-    from pathway_tpu.models.decoder import DecoderConfig
-
-    return DecoderConfig(
-        vocab_size=model["vocab_size"], hidden=model["n_embd"],
-        layers=model["n_layer"], heads=model["n_head"],
-        intermediate=model.get("n_inner") or 4 * model["n_embd"],
-        max_position=model["n_positions"],
-        layer_norm_eps=model["layer_norm_epsilon"], dtype=jnp.bfloat16,
-    )
-
-
 class System:
     """The running system under test and what the benchmark holds of it."""
 
@@ -280,15 +250,19 @@ class System:
         )
         from pathway_tpu.xpacks.llm.rerankers import CrossEncoderReranker
 
-        models = self.config["models"]
+        models, layouts = self.config["models"], self.config["layouts"]
         emb, rer = models["embedder"], models["reranker"]
+        emb_layout, rer_layout = layouts["embedder"], layouts["reranker"]
         self.params["embedder"] = W.make_params(
-            self.seed, W.STREAM_EMBEDDER, W.encoder_spec(emb, head=False))
+            self.seed, W.STREAM_EMBEDDER,
+            emb_layout.weight_spec(emb, "embedder"))
         self.params["reranker"] = W.make_params(
-            self.seed, W.STREAM_RERANKER, W.encoder_spec(rer, head=True))
+            self.seed, W.STREAM_RERANKER,
+            rer_layout.weight_spec(rer, "reranker"))
         self.embedder = SentenceTransformerEmbedder(
             model=SentenceEmbedderModel(
-                cfg=_transformer_config(emb), params=self.params["embedder"],
+                cfg=emb_layout.program_config(emb),
+                params=self.params["embedder"],
                 max_length=self.dep["embed_max_length"]),
             max_batch_size=self.dep["embed_max_batch"])
         # the cross-encoder keeps float32 leaves (it casts at each use);
@@ -297,19 +271,21 @@ class System:
                            self.params["reranker"])
         head = f32.pop("head")
         self.reranker = CrossEncoderReranker(CrossEncoderModel(
-            cfg=_transformer_config(rer), params=f32, head=head,
+            cfg=rer_layout.program_config(rer), params=f32, head=head,
             max_length=self.dep["rerank_max_length"]))
 
     def build_decoder(self) -> None:
         from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
 
         model = self.config["models"]["decoder"]
+        layout = self.config["layouts"]["decoder"]
         srv = self.dep["decoder_server"]
         self.params["decoder"] = W.make_params(
-            self.seed, W.STREAM_DECODER, W.decoder_spec(model))
-        self.tokenizer = WordTokenizer(model["vocab_size"], self.seed)
+            self.seed, W.STREAM_DECODER, layout.weight_spec(model, "decoder"))
+        cfg = layout.program_config(model)
+        self.tokenizer = WordTokenizer(cfg.vocab_size, self.seed)
         self.chat = TPUDecoderChat(
-            params=self.params["decoder"], cfg=_decoder_config(model),
+            params=self.params["decoder"], cfg=cfg,
             tokenizer=self.tokenizer, max_new_tokens=srv["max_new_tokens"],
             temperature=srv["temperature"],
             max_prompt_tokens=srv["max_prompt_tokens"],
@@ -470,12 +446,12 @@ def _warm_encoders(system: System, traffic: dict) -> None:
         while pairs <= most:
             system.reranker.model.score_batch([(query, doc)] * pairs)
             pairs *= 2
-        for ix in system.instances:
-            q = 16
-            while q <= max(16, clients):
-                ix.search(np.zeros((q, dep["index_dimensions"]), np.float32)
-                          + 1.0, dep["rerank_candidates"])
-                q *= 2
+        # the search at the k the GRAPH asks for (the index node fetches
+        # more than ``rerank_candidates`` beside a filter column): one
+        # request through the served route; the index dispatches every
+        # query bucket with its first search at a k
+        post(f"{system.url}/v1/retrieve", {"query": query, "k": 1},
+             timeout=600)
     device_barrier()
 
 

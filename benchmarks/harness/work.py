@@ -83,13 +83,16 @@ def prefill_flops(cfg: dict, prompt_tokens: int) -> float:
         + 2.0 * cfg["vocab_size"] * h
 
 
-def answer_flops(cfg: dict, prompt_tokens: int, new_tokens: int) -> float:
+def answer_flops(cfg: dict, prompt_tokens: int, new_tokens: int,
+                 prefill=prefill_flops, step=decode_step_flops) -> float:
     """Decoder FLOPs one greedy answer needs: one prefill, then
     ``new_tokens - 1`` single-token steps over a growing cache (the first
-    token comes from the prefill's logits). Counted once per answer."""
-    total = prefill_flops(cfg, prompt_tokens)
+    token comes from the prefill's logits). Counted once per answer.
+    ``prefill`` and ``step`` are the model layout's counts (GPT-2's where
+    none are given)."""
+    total = prefill(cfg, prompt_tokens)
     for t in range(1, new_tokens):
-        total += decode_step_flops(cfg, 1, prompt_tokens + t)
+        total += step(cfg, 1, prompt_tokens + t)
     return total
 
 

@@ -13,7 +13,10 @@ stamped with the device. What was compared, beside its limits, is the last
 lines of standard error and the last key of the result line.
 
 The cell, its configuration, its traffic mix and its per-layer metrics are
-DATA, found by name from ``BENCHMARK.json`` (``harness/manifest.py``).
+DATA, found by name from ``BENCHMARK.json`` (``harness/manifest.py``); the
+code they name (builder, generator, check, model layouts, a roofline's
+work, a metric's reader) is found by name too: a built-in of the harness or
+a file under ``paths`` (``manifest.resolve``).
 """
 
 from __future__ import annotations
@@ -42,6 +45,7 @@ def per_layer_metrics(man: dict, cell: dict, ctx: dict) -> dict:
     from harness.readers import READERS
 
     out = {}
+    ctx = dict(ctx, manifest=man)
     for m in cell["per_layer"]:
         spec = M.load_json_named(man, "metrics", m["name"])
         own = M.load_reader_module(man, m["name"])
@@ -89,18 +93,19 @@ class Tracer:
 def run_cell(man: dict, name: str, seed: int, seconds: float, trace: bool,
              control: bool, device: dict, t_start: float = T_START) -> dict:
     from harness import trace as T
-    from harness.checks import CHECKS, judge
-    from harness.generators import GENERATORS, HostWatch
+    from harness.checks import judge
+    from harness.generators import HostWatch
     from harness.peaks import peaks_for
-    from harness.system import BUILDERS, peak_bytes
+    from harness.system import peak_bytes
 
     cell = M.cell(man, name)
     config, traffic = cell["config"], cell["traffic"]
     peaks = peaks_for(device["kind"]) if trace else None
 
-    system = BUILDERS[config["builder"]](config, traffic, seed)
-    gen = GENERATORS[traffic["generator"]](traffic)
-    check = CHECKS[traffic["check"]](config, traffic, seed)
+    system = M.resolve(man, "builders", config["builder"])(
+        config, traffic, seed)
+    gen = M.resolve(man, "generators", traffic["generator"])(traffic)
+    check = M.resolve(man, "checks", traffic["check"])(config, traffic, seed)
     gen.prepare(system)
     system.step_done("generator_prepare")
     emit(device, phase="setup", steps=system.setup_steps,
@@ -130,7 +135,8 @@ def run_cell(man: dict, name: str, seed: int, seconds: float, trace: bool,
     counters.update(window.counters)
 
     numbers = {"failed_requests": window.failed,
-               "generator_problems": len(window.problems)}
+               "generator_problems": len(window.problems),
+               "compiles_in_window": counters["compiles"]}
     numbers.update(check.exact(system, window))
     got = check.collect(system, window)
     memory_peak = peak_bytes()

@@ -11,7 +11,7 @@ import pytest
 
 import bench_paths  # noqa: F401 - sets sys.path
 
-from harness import reference as R, weights as W
+from harness import manifest as M, reference as R, weights as W
 from harness.checks import AnswerCheck, IngestCheck, RetrieveCheck, judge
 from harness.corpus import Corpus, WordTokenizer
 
@@ -30,6 +30,9 @@ CONFIG = {
                    "decoder_server": {"max_prompt_tokens": 128,
                                       "max_new_tokens": 8, "n_slots": 4}},
 }
+# the layouts a cell's configuration gets from ``manifest.cell``: here the
+# roles' present ones (``bert``, ``gpt2``), as the models name none
+CONFIG["layouts"] = M.layouts_for(M.load_manifest(), CONFIG)
 TRAFFIC = {"query_words": 20, "body": {"k": 2}, "check_requests": 4,
            "check_answers": 4, "check_docs": 8}
 SEED = 2 ** 31 + 9

@@ -80,8 +80,18 @@ def test_idle_is_split_by_time_among_the_deepest_regions():
     }
     assert by_op == {"Rowwise:9": 30}
     assert sum(by_region.values()) == 40 + 130
-    # no thread ran an epoch: nothing to attribute to
-    assert P.attribute_idle([(0, 10)], regions[3:]) is None
+    # no epoch is whole inside the slice (the profiler keeps a region that
+    # began and ended in the session): the engine's thread is still the one
+    # that ran its operators and waits; the epoch's own time is unnamed
+    by_region, by_op = P.attribute_idle([(0, 40), (90, 130)], regions[1:])
+    assert by_region == {
+        "unattributed": 10 + 10 + 20, "pw.engine.op": 10 + 10,
+        "pw.embed.drain": 10, "pw.connector.commit": 30,
+        "pw.embed.tokenize": 10, "pw.engine.wait_ready": 20 + 40}
+    assert by_op == {"Rowwise:9": 30}
+    # no thread ran the engine: nothing to attribute to
+    assert P.attribute_idle([(0, 10)], regions[4:]) is None
+    assert P.attribute_idle([(0, 10)], []) is None
 
 
 def recorded():

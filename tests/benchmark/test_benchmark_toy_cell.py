@@ -1,5 +1,7 @@
-"""A fourth cell made ONLY of new files (``tests/benchmark/toy``: a
-configuration, mixes, a metric and a manifest that names them) runs through
+"""Cells made ONLY of new files (``tests/benchmark/toy``: configurations,
+mixes, metrics and a manifest that names them; for ``toy_answer_byname``
+also its own builder, generator, check, model layout and roofline work,
+found by name) run through
 the same harness with no edit to any file of ``benchmarks/``; a run whose
 timed path is broken underneath comes out as not ``correct``; and so does
 the control (the fp8 reference put in the program's place), by the run's own
@@ -14,6 +16,8 @@ import os
 import subprocess
 import sys
 import time
+
+import pytest
 
 from bench_paths import BENCH, REPO, TOY_MANIFEST
 
@@ -30,6 +34,11 @@ def drive(cell: str, control: bool = False, seconds: float = 2.0,
                               device_stamp(), time.perf_counter())
 
 
+BYNAME = [("builders", "toy_qa"), ("generators", "toy_posts"),
+          ("checks", "toy_answer"), ("layouts", "toy_renamed"),
+          ("work", "toy_step")]
+
+
 def failing(table: dict) -> set:
     return {k for k, v in table.items() if v["value"] > v["limit"]}
 
@@ -43,6 +52,9 @@ def test_the_toy_cell_is_made_of_new_files_only():
                                        "toy.commit_ms_p50.json"))
     assert os.path.exists(os.path.join(toy, "metrics",
                                        "toy.docs_per_commit.py"))
+    for kind, name in BYNAME:
+        assert os.path.exists(os.path.join(toy, kind, name + ".py"))
+        assert not os.path.exists(os.path.join(BENCH, kind, name + ".py"))
     for root, _dirs, files in os.walk(BENCH):
         for name in files:
             if name.endswith((".py", ".json")):
@@ -140,6 +152,89 @@ def test_the_answer_path_runs_and_is_correct_and_its_control_is_not():
     assert {"knn_dist_err", "rerank_score_err"} <= failing(
         result["control_compared"])
     assert "control_correct" not in drive("toy_retrieve")
+
+
+def test_a_cell_whose_code_is_found_by_name_runs_and_its_control_fails(
+        monkeypatch):
+    """``toy_answer_byname``: builder, generator, check, the decoder's
+    layout (other key names than GPT-2's) and a roofline's work are files
+    under ``tests/benchmark/toy`` that the harness has never heard of."""
+    man = M.load_manifest(TOY_MANIFEST)
+    cell = M.cell(man, "toy_answer_byname")
+    assert "n_embd" not in cell["config"]["models"]["decoder"]
+    loaded = {kind: M.load_named_module(man, kind, name)
+              for kind, name in BYNAME}
+    ran = []
+    real_build = loaded["builders"].build
+    real_payload = loaded["generators"].Generator.payload
+    real_logits = type(loaded["layouts"].layout).logits
+    monkeypatch.setattr(loaded["builders"], "build", lambda *a: (
+        ran.append("builder"), real_build(*a))[1])
+    monkeypatch.setattr(loaded["generators"].Generator, "payload",
+                        lambda self, q: (ran.append("generator"),
+                                         real_payload(self, q))[1])
+    monkeypatch.setattr(type(loaded["layouts"].layout), "logits",
+                        lambda self, *a: (ran.append("layout"),
+                                          real_logits(self, *a))[1])
+    result = drive("toy_answer_byname", control=True)
+    assert result["correct"] is True, result["compared"]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert {"builder", "generator", "layout"} <= set(ran)
+    # the check's own number, beside the limit its configuration gives it
+    assert result["compared"]["answers_not_compared"] == {"value": 0,
+                                                          "limit": 0}
+    assert "compiles_in_window" in result["compared"]
+    assert result["compared"]["token_logit_gap"]["value"] < 0.05
+    assert result["control_correct"] is False, result["control_compared"]
+    assert {"knn_dist_err", "rerank_score_err"} <= failing(
+        result["control_compared"])
+    # its roofline reads the work its file names, by the layout's counts
+    from harness.peaks import peaks_for
+
+    class Slice:
+        def module_seconds(self, patterns):
+            assert patterns == "^jit_pool_decode"
+            return 10, 1e-3
+
+    layout, model = cell["config"]["layouts"]["decoder"], cell["config"][
+        "models"]["decoder"]
+    ctx = {"trace": Slice(), "peaks": peaks_for("TPU v5 lite"),
+           "config": cell["config"], "facts": {}, "counters": {},
+           "spans": {}}
+    got = bench_run.per_layer_metrics(man, cell, ctx)
+    fact = ctx["facts"]["roofline"]["toy_step"]
+    assert fact["flops"] == 10 * layout.decode_step_flops(model, 4, 512)
+    assert fact["bytes"] == 10 * layout.decode_step_bytes(model, 512)
+    assert got["toy.decode_step_roofline"]["value"] == pytest.approx(
+        100 * fact["least_s"] / 1e-3)
+    ctx["trace"] = None         # nothing to read: nothing in the line
+    assert "toy.decode_step_roofline" not in bench_run.per_layer_metrics(
+        man, cell, ctx)
+
+
+def test_a_compile_inside_the_window_makes_the_run_incorrect(monkeypatch):
+    """A shape the set-up did not warm compiles in the window: that is a
+    number compared, with the limit 0, and no longer a line to overlook."""
+    real_run = GENERATORS["commit_feeder"].run
+
+    def run_and_compile(self, system, seconds, on_tick=None):
+        import jax
+        import jax.numpy as jnp
+
+        def tick(now, opened):
+            if opened and not tick.done:
+                tick.done = True
+                jax.jit(lambda x: x * 3 + now)(jnp.ones((7, 3)))
+            if on_tick is not None:
+                on_tick(now, opened)
+
+        tick.done = False
+        return real_run(self, system, seconds, tick)
+
+    monkeypatch.setattr(GENERATORS["commit_feeder"], "run", run_and_compile)
+    result = drive("toy_ingest")
+    assert result["correct"] is False
+    assert failing(result["compared"]) == {"compiles_in_window"}
 
 
 def test_a_token_altered_where_it_is_produced_makes_the_run_incorrect(
