@@ -200,6 +200,10 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
     "preemptions": (
         "counter", "tenant", "Over-budget requests preempted out of "
         "their slot (KV parked, request requeued) per tenant"),
+    "moe_assignments": (
+        "counter", "held", "Token-to-expert assignments the routers made, "
+        "by whether the expert is held here (held=1) and by phase; summed "
+        "on the device, read with the tokens at drain"),
     "kv_migrated_blocks": (
         "counter", "server", "KV blocks handed from the prefill lane to "
         "the decode lane at prompt completion (PATHWAY_TPU_DISAGG)"),

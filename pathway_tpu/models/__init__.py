@@ -66,7 +66,7 @@ __all__ = [
     "make_decoder_train_step",
     "MoEConfig",
     "init_moe_params",
-    "moe_ffn",
+    "moe_mlp",
     "moe_partition_specs",
     "encode_pipelined",
 ]
@@ -74,7 +74,7 @@ __all__ = [
 from pathway_tpu.models.moe import (  # noqa: E402
     MoEConfig,
     init_moe_params,
-    moe_ffn,
+    moe_mlp,
     moe_partition_specs,
 )
 from pathway_tpu.models.pipeline import encode_pipelined  # noqa: E402

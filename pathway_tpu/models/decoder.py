@@ -36,6 +36,11 @@ from jax.sharding import PartitionSpec as P
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
+    """One decoder block, driven by its configuration. The defaults are
+    GPT-2's (pre-LN LayerNorm, learned positions, full multi-head
+    attention, tanh gelu, biases, the head tied to ``wte``); every other
+    architecture is a configuration of the same block."""
+
     vocab_size: int = 50257
     hidden: int = 768
     layers: int = 12
@@ -49,76 +54,243 @@ class DecoderConfig:
     # (models/wq_matmul.py) — a CONFIG field, not a module global, so the
     # jit caches key on it and a rebuilt server cannot serve stale traces
     wq_kernel: bool = False
+    # -- what other architectures set --------------------------------------
+    kv_heads: int | None = None     # grouped-query heads (None: = heads)
+    head_size: int | None = None    # None: hidden // heads
+    norm: str = "layernorm"         # layernorm | rmsnorm
+    sandwich_norm: bool = False     # a norm AFTER attention and after MLP
+    qk_norm: bool = False           # per-head RMSNorm of q and k
+    attn_gate: bool = False         # ctx * sigmoid(x Wg) before Wo
+    # learned | rotary | none; a tuple gives one per layer
+    positions: Any = "learned"
+    rope_theta: float = 10000.0
+    mlp: str = "gelu"               # gelu | swiglu
+    bias: bool = True
+    tied_head: bool = True
+    embed_scale: float = 1.0
+    layer_types: tuple | None = None  # window | full per layer (None: full)
+    sliding_window: int = 0
+    dense_layers: int = 0           # leading dense-MLP layers before `moe`
+    moe: Any = None                 # models.moe.MoEConfig: routed experts
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.heads
+        return self.head_size or self.hidden // self.heads
+
+    @property
+    def n_kv(self) -> int:
+        return self.kv_heads or self.heads
+
+    def layer_kind(self, i: int) -> tuple:
+        """``(window | full, learned | rotary | none, dense | moe)`` of
+        layer ``i``."""
+        attn = self.layer_types[i] if self.layer_types else "full"
+        pos = self.positions if isinstance(self.positions, str) \
+            else self.positions[i]
+        mlp = "moe" if self.moe is not None and i >= self.dense_layers \
+            else "dense"
+        return attn, pos, mlp
+
+    def runs(self, n_layers: int | None = None) -> tuple:
+        """Stacks of consecutive like layers, ``(kind, first, count)``
+        each: ``lax.scan`` runs over one stack at a time. GPT-2 is one."""
+        out: list = []
+        for i in range(self.layers if n_layers is None else n_layers):
+            kind = self.layer_kind(i)
+            if out and out[-1][0] == kind:
+                out[-1][2] += 1
+            else:
+                out.append([kind, i, 1])
+        return tuple((k, f, n) for k, f, n in out)
+
+    @property
+    def uniform(self) -> bool:
+        """One stack of like layers: ``params["layers"]`` is one dict of
+        stacked leaves (GPT-2's layout), else ``{"run0": ..., "run1": ...}``
+        with one such dict per run."""
+        return len(self.runs()) == 1
+
+    def n_layers_of(self, attn: str, n_layers: int | None = None) -> int:
+        return sum(n for (a, _p, _m), _f, n in self.runs(n_layers)
+                   if a == attn)
+
+    @property
+    def learned_positions(self) -> bool:
+        return any(k[1] == "learned" for k, _f, _n in self.runs())
 
 
 GPT2_SMALL = DecoderConfig()
 GPT2_MEDIUM = DecoderConfig(hidden=1024, layers=24, heads=16, intermediate=4096)
 
 
+class UnsupportedForLayout(TypeError):
+    """A serving mechanism that this configuration's layers cannot ride
+    yet. Raised at construction, naming the mechanism: never a silent
+    fallback to another path."""
+
+    def __init__(self, mechanism: str, why: str):
+        super().__init__(
+            f"{mechanism} is not supported for this decoder layout: {why}")
+        self.mechanism = mechanism
+
+
+def gpt2_block(cfg: DecoderConfig) -> bool:
+    """The block is GPT-2's own (the only one the paged pool, the Pallas
+    kernels, int8 KV and weights, the lane migration and the serving mesh
+    have been written for)."""
+    return (cfg.n_kv == cfg.heads and cfg.norm == "layernorm"
+            and not cfg.sandwich_norm and not cfg.qk_norm
+            and not cfg.attn_gate and cfg.positions == "learned"
+            and cfg.mlp == "gelu" and cfg.bias and cfg.tied_head
+            and cfg.embed_scale == 1.0 and cfg.moe is None
+            and not cfg.sliding_window and cfg.head_size is None
+            and (cfg.layer_types is None
+                 or all(t == "full" for t in cfg.layer_types)))
+
+
+def require_gpt2_block(cfg: DecoderConfig, mechanism: str) -> None:
+    if not gpt2_block(cfg):
+        raise UnsupportedForLayout(
+            mechanism, "it is written for full multi-head layers with "
+            "learned positions, LayerNorm, gelu and a tied head; this "
+            "configuration's layers differ")
+
+
 def _init(key, shape, dtype, scale=0.02):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
+def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
+    """One layer's leaves for a run of ``kind``: name -> (shape, how, tp
+    axis). ``how``: ``one``/``zero`` constants, or the index of the key a
+    random matrix draws from (GPT-2's four keep their historical keys, so
+    its initial weights are what they always were)."""
+    _attn, _pos, mlp = kind
+    h, hd, nq, nkv = cfg.hidden, cfg.head_dim, cfg.heads, cfg.n_kv
+    ln = cfg.norm == "layernorm"
+    out: dict = {}
+
+    def norm(name, width=h):
+        out[name + "_scale"] = ((width,), "one", None)
+        if ln:
+            out[name + "_bias"] = ((width,), "zero", None)
+
+    norm("ln1")
+    out["qkv_w"] = ((h, (nq + 2 * nkv) * hd), 2, 1)
+    if cfg.bias:
+        out["qkv_b"] = (((nq + 2 * nkv) * hd,), "zero", 0)
+    if cfg.qk_norm:
+        out["q_norm_scale"] = ((hd,), "one", None)
+        out["k_norm_scale"] = ((hd,), "one", None)
+    if cfg.attn_gate:
+        out["gate_w"] = ((h, nq * hd), 6, 1)
+    out["attn_out_w"] = ((nq * hd, h), 3, 0)
+    if cfg.bias:
+        out["attn_out_b"] = ((h,), "zero", None)
+    if cfg.sandwich_norm:
+        norm("ln1p")
+    norm("ln2")
+    if mlp == "dense":
+        i = cfg.intermediate
+        out["mlp_in_w"] = ((h, i), 4, 1)
+        if cfg.bias:
+            out["mlp_in_b"] = ((i,), "zero", 0)
+        if cfg.mlp == "swiglu":
+            out["mlp_up_w"] = ((h, i), 7, 1)
+        out["mlp_out_w"] = ((i, h), 5, 0)
+        if cfg.bias:
+            out["mlp_out_b"] = ((h,), "zero", None)
+    else:
+        moe = cfg.moe
+        count, w = moe.held_range[1], moe.width
+        out["router_w"] = ((h, moe.experts), 8, None)
+        out["router_bias"] = ((moe.experts,), "zero", None)
+        out["moe_in_w"] = ((count, h, w), 10, None)
+        out["moe_up_w"] = ((count, h, w), 11, None)
+        out["moe_out_w"] = ((count, w, h), 12, None)
+        if moe.shared:
+            out["shared_in_w"] = ((h, moe.shared * w), 13, 1)
+            out["shared_up_w"] = ((h, moe.shared * w), 14, 1)
+            out["shared_out_w"] = ((moe.shared * w, h), 15, 0)
+    if cfg.sandwich_norm:
+        norm("ln2p")
+    return out
+
+
+# leaves the forward consumes in float32 whatever the compute type: norm
+# gains and biases, the router (float32 as published)
+_F32_LEAVES = frozenset(
+    [f"{ln}_{leaf}" for ln in ("ln1", "ln2", "ln_f", "ln1p", "ln2p",
+                               "q_norm", "k_norm")
+     for leaf in ("scale", "bias")] + ["router_w", "router_bias"])
+
+
 def init_params(rng: jax.Array, cfg: DecoderConfig) -> dict:
     pd = cfg.param_dtype
-    n, h, i = cfg.layers, cfg.hidden, cfg.intermediate
+    h = cfg.hidden
     ks = jax.random.split(rng, 8)
 
-    def stack(key, shape, scale=0.02):
-        return _init(key, (n, *shape), pd, scale)
+    def key_of(run: int, idx: int):
+        key = ks[idx] if idx < 8 else jax.random.fold_in(rng, idx)
+        return key if run == 0 else jax.random.fold_in(key, 1000 + run)
 
-    return {
-        "wte": _init(ks[0], (cfg.vocab_size, h), pd),
-        "wpe": _init(ks[1], (cfg.max_position, h), pd, 0.01),
-        "layers": {
-            "ln1_scale": jnp.ones((n, h), pd),
-            "ln1_bias": jnp.zeros((n, h), pd),
-            "qkv_w": stack(ks[2], (h, 3 * h)),
-            "qkv_b": jnp.zeros((n, 3 * h), pd),
-            "attn_out_w": stack(ks[3], (h, h)),
-            "attn_out_b": jnp.zeros((n, h), pd),
-            "ln2_scale": jnp.ones((n, h), pd),
-            "ln2_bias": jnp.zeros((n, h), pd),
-            "mlp_in_w": stack(ks[4], (h, i)),
-            "mlp_in_b": jnp.zeros((n, i), pd),
-            "mlp_out_w": stack(ks[5], (i, h)),
-            "mlp_out_b": jnp.zeros((n, h), pd),
-        },
-        "ln_f_scale": jnp.ones((h,), pd),
-        "ln_f_bias": jnp.zeros((h,), pd),
-        # LM head is weight-tied to wte (GPT-2); no separate tensor
-    }
+    def run_params(r: int, kind: tuple, n: int) -> dict:
+        out = {}
+        for name, (shape, how, _tp) in _layer_leaves(cfg, kind).items():
+            dt = jnp.float32 if name in _F32_LEAVES else pd
+            if how == "one":
+                out[name] = jnp.ones((n, *shape), dt)
+            elif how == "zero":
+                out[name] = jnp.zeros((n, *shape), dt)
+            else:
+                out[name] = _init(key_of(r, how), (n, *shape), dt)
+        return out
+
+    runs = [run_params(r, kind, n)
+            for r, (kind, _first, n) in enumerate(cfg.runs())]
+    params = {"wte": _init(ks[0], (cfg.vocab_size, h), pd)}
+    if cfg.learned_positions:
+        params["wpe"] = _init(ks[1], (cfg.max_position, h), pd, 0.01)
+    params["layers"] = runs[0] if cfg.uniform else {
+        f"run{r}": run for r, run in enumerate(runs)}
+    params["ln_f_scale"] = jnp.ones((h,), pd)
+    if cfg.norm == "layernorm":
+        params["ln_f_bias"] = jnp.zeros((h,), pd)
+    if not cfg.tied_head:
+        # GPT-2's head is weight-tied to wte; an untied one is its own leaf
+        params["lm_head"] = _init(jax.random.fold_in(rng, 9),
+                                  (cfg.vocab_size, h), pd)
+    return params
 
 
 def param_partition_specs(cfg: DecoderConfig, tp_axis: str = "tp") -> dict:
     """Megatron TP: QKV/MLP-in shard output features, attn-out/MLP-out shard
     input features (one psum per block, inserted by XLA); embeddings shard
-    the vocab dim, which also shards the tied-LM-head logits."""
+    the vocab dim, which also shards the LM-head logits. Experts stay whole
+    (their own axis is ``ep``: ``models/moe.py``)."""
     t = tp_axis
-    return {
-        "wte": P(t, None),
-        "wpe": P(None, None),
-        "layers": {
-            "ln1_scale": P(None, None),
-            "ln1_bias": P(None, None),
-            "qkv_w": P(None, None, t),
-            "qkv_b": P(None, t),
-            "attn_out_w": P(None, t, None),
-            "attn_out_b": P(None, None),
-            "ln2_scale": P(None, None),
-            "ln2_bias": P(None, None),
-            "mlp_in_w": P(None, None, t),
-            "mlp_in_b": P(None, t),
-            "mlp_out_w": P(None, t, None),
-            "mlp_out_b": P(None, None),
-        },
-        "ln_f_scale": P(None),
-        "ln_f_bias": P(None),
-    }
+
+    def run_specs(kind: tuple) -> dict:
+        out = {}
+        for name, (shape, _how, tp) in _layer_leaves(cfg, kind).items():
+            spec = [None] * (1 + len(shape))
+            if tp is not None:
+                spec[1 + tp] = t
+            out[name] = P(*spec)
+        return out
+
+    runs = [run_specs(kind) for kind, _first, _n in cfg.runs()]
+    specs = {"wte": P(t, None)}
+    if cfg.learned_positions:
+        specs["wpe"] = P(None, None)
+    specs["layers"] = runs[0] if cfg.uniform else {
+        f"run{r}": run for r, run in enumerate(runs)}
+    specs["ln_f_scale"] = P(None)
+    if cfg.norm == "layernorm":
+        specs["ln_f_bias"] = P(None)
+    if not cfg.tied_head:
+        specs["lm_head"] = P(t, None)
+    return specs
 
 
 # ---- serving-mesh placement (PATHWAY_TPU_MESH) ----------------------------
@@ -139,6 +311,7 @@ def validate_decoder_mesh(cfg: DecoderConfig, mesh) -> None:
     ``mesh``'s tp axis: heads, ffn features and vocab must all divide."""
     from pathway_tpu.parallel.mesh import SERVE_TP_AXIS, MeshShapeError
 
+    require_gpt2_block(cfg, "mesh")
     tp = int(mesh.shape.get(SERVE_TP_AXIS, 1))
     bad = []
     if cfg.heads % tp != 0:
@@ -338,6 +511,7 @@ def quantize_params(params: dict, cfg: DecoderConfig) -> dict:
     :func:`cast_params_for_inference` treatment. Scales are computed from
     the ORIGINAL full-precision leaves — quantizing after a bf16 cast
     would bake the cast's mantissa loss into the scales."""
+    require_gpt2_block(cfg, "weight_quant")
     out = dict(cast_params_for_inference(params, cfg))
     out["wte"], out["wte_scale"] = _wq_quant(params["wte"], axis=-1)
     layers = dict(out["layers"])
@@ -399,17 +573,67 @@ def params_device_bytes(params: dict) -> dict[str, int]:
     return out
 
 
-def _block_qkv(x, lp, cfg: DecoderConfig):
-    """Pre-LN + fused QKV projection, head-split: ``(q, k_new, v_new)``
-    each (B, nh, S, hd). Shared by :func:`_block` and the paged-kernel
-    decode path, so both read identical projections."""
-    nh, hd = cfg.heads, cfg.head_dim
-    h1 = _ln(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layer_norm_eps)
+def _rms(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps) * scale.astype(jnp.float32)
+
+
+def _norm(x, lp, name: str, cfg: DecoderConfig):
+    """The configuration's norm (float32 out), by the leaves' prefix."""
+    if cfg.norm == "layernorm":
+        return _ln(x, lp[name + "_scale"], lp[name + "_bias"],
+                   cfg.layer_norm_eps)
+    return _rms(x, lp[name + "_scale"], cfg.layer_norm_eps)
+
+
+def _rope(t, pos, theta: float):
+    """Rotary positions on ``t`` (B, n, S, hd) at ``pos`` (B, S), halves
+    rotated against each other (the released code's ``rotate_half``)."""
+    half = t.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None, :, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    t = t.astype(jnp.float32)
+    a, b = t[..., :half], t[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+_GPT2_KIND = ("full", "learned", "dense")
+
+
+def _project(x, lp, cfg: DecoderConfig, kind: tuple, pos, want_q: bool):
+    """The block's first norm and fused QKV projection, head-split, with
+    what the configuration puts on q and k (per-head RMSNorm, rotary
+    positions on a rotary layer): ``(q | None, k, v)``, q (B, nq, S, hd),
+    k and v (B, nkv, S, hd)."""
+    nq, nkv, hd = cfg.heads, cfg.n_kv, cfg.head_dim
+    h1 = _norm(x, lp, "ln1", cfg)
     qkv = _wq_matmul("bsh,hk->bsk", h1.astype(cfg.dtype), lp, "qkv_w", cfg)
-    qkv = qkv + lp["qkv_b"].astype(cfg.dtype)
-    q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-    return (_split_heads(q, nh, hd), _split_heads(k_new, nh, hd),
-            _split_heads(v_new, nh, hd))
+    if cfg.bias:
+        qkv = qkv + lp["qkv_b"].astype(cfg.dtype)
+    q, k, v = jnp.split(qkv, [nq * hd, (nq + nkv) * hd], axis=-1)
+    q = _split_heads(q, nq, hd) if want_q else None
+    k, v = _split_heads(k, nkv, hd), _split_heads(v, nkv, hd)
+    if cfg.qk_norm:
+        if want_q:
+            q = _rms(q, lp["q_norm_scale"], cfg.layer_norm_eps)
+        k = _rms(k, lp["k_norm_scale"], cfg.layer_norm_eps)
+    if kind[1] == "rotary":
+        if want_q:
+            q = _rope(q, pos, cfg.rope_theta)
+        k = _rope(k, pos, cfg.rope_theta)
+    if want_q:
+        q = q.astype(cfg.dtype)
+    return q, k.astype(cfg.dtype), v.astype(cfg.dtype)
+
+
+def _block_qkv(x, lp, cfg: DecoderConfig, kind: tuple = _GPT2_KIND,
+               pos=None):
+    """First norm + fused QKV projection, head-split: ``(q, k_new,
+    v_new)``. Shared by :func:`_block` and the paged-kernel decode path,
+    so both read identical projections."""
+    return _project(x, lp, cfg, kind, pos, True)
 
 
 def _attn_ctx(q, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
@@ -420,10 +644,22 @@ def _attn_ctx(q, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
     every dense decode/prefill variant funnels through, so quantized
     serving cannot fork the numerics. The Pallas paged kernel
     (``models/paged_attention.py``) is the block-table counterpart of
-    exactly this function."""
+    exactly this function. With fewer key-value heads than query heads
+    each is shared by ``heads // kv_heads`` query heads (grouped query)."""
     if k_scale is not None:
         k = (k.astype(jnp.float32) * k_scale).astype(cfg.dtype)
         v = (v.astype(jnp.float32) * v_scale).astype(cfg.dtype)
+    if k.shape[1] != q.shape[1]:
+        B, nq, Sq, hd = q.shape
+        nkv = k.shape[1]
+        qg = q.reshape(B, nkv, nq // nkv, Sq, hd)
+        scores = jnp.einsum("bngqd,bnkd->bngqk", qg, k.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+        scores = scores / math.sqrt(cfg.head_dim) + mask_bias[:, :, None]
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        return jnp.einsum("bngqk,bnkd->bngqd", probs, v.astype(cfg.dtype),
+                          preferred_element_type=jnp.float32
+                          ).astype(cfg.dtype).reshape(B, nq, Sq, hd)
     scores = jnp.einsum("bnqd,bnkd->bnqk", q, k.astype(cfg.dtype),
                         preferred_element_type=jnp.float32)
     scores = scores / math.sqrt(cfg.head_dim) + mask_bias
@@ -435,47 +671,81 @@ def _attn_ctx(q, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
                       preferred_element_type=jnp.float32).astype(cfg.dtype)
 
 
-def _block_finish(x, lp, ctx, cfg: DecoderConfig):
-    """Post-attention half of the block: output projection, residual,
-    MLP. ``ctx`` is the attention read (B, nh, S, hd)."""
-    B, S, H = x.shape
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H)
+def _block_finish(x, lp, ctx, cfg: DecoderConfig,
+                  kind: tuple = _GPT2_KIND):
+    """Post-attention half of the block: output gate, output projection,
+    residual, MLP (dense, or routed experts: ``models/moe.py``). ``ctx``
+    is the attention read (B, nh, S, hd). Returns ``(x, counts)``:
+    ``counts`` the expert layer's (held, all) assignments, else None."""
+    B, S, _H = x.shape
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, cfg.heads * cfg.head_dim)
+    if cfg.attn_gate:
+        h1 = _norm(x, lp, "ln1", cfg).astype(cfg.dtype)
+        gate = _wq_matmul("bsh,hk->bsk", h1, lp, "gate_w", cfg)
+        ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)
+                                   ).astype(cfg.dtype)
     attn = _wq_matmul("bsh,hk->bsk", ctx, lp, "attn_out_w", cfg)
-    x = x + attn + lp["attn_out_b"].astype(cfg.dtype)
-    h2 = _ln(x, lp["ln2_scale"], lp["ln2_bias"], cfg.layer_norm_eps)
-    m = _wq_matmul("bsh,hi->bsi", h2.astype(cfg.dtype), lp, "mlp_in_w", cfg)
-    # gelu_new (tanh approximation) — what GPT-2 checkpoints are trained with
-    m = jax.nn.gelu(m + lp["mlp_in_b"].astype(cfg.dtype), approximate=True)
-    m = _wq_matmul("bsi,ih->bsh", m, lp, "mlp_out_w", cfg)
-    x = x + m + lp["mlp_out_b"].astype(cfg.dtype)
-    return x.astype(cfg.dtype)
+    if cfg.sandwich_norm:
+        attn = _norm(attn, lp, "ln1p", cfg).astype(cfg.dtype)
+    if cfg.bias:
+        x = x + attn + lp["attn_out_b"].astype(cfg.dtype)
+    else:
+        x = x + attn
+    h2 = _norm(x, lp, "ln2", cfg)
+    counts = None
+    if kind[2] == "moe":
+        from pathway_tpu.models import moe as _moe
+
+        m, counts = _moe.moe_mlp(h2, lp, cfg.moe, cfg.dtype)
+    else:
+        m = _wq_matmul("bsh,hi->bsi", h2.astype(cfg.dtype), lp, "mlp_in_w",
+                       cfg)
+        if cfg.bias:
+            m = m + lp["mlp_in_b"].astype(cfg.dtype)
+        if cfg.mlp == "swiglu":
+            up = _wq_matmul("bsh,hi->bsi", h2.astype(cfg.dtype), lp,
+                            "mlp_up_w", cfg)
+            m = jax.nn.silu(m) * up
+        else:
+            # gelu_new (tanh approximation) — what GPT-2 checkpoints are
+            # trained with
+            m = jax.nn.gelu(m, approximate=True)
+        m = _wq_matmul("bsi,ih->bsh", m, lp, "mlp_out_w", cfg)
+    if cfg.sandwich_norm:
+        m = _norm(m, lp, "ln2p", cfg).astype(cfg.dtype)
+    if cfg.bias:
+        x = x + m + lp["mlp_out_b"].astype(cfg.dtype)
+    else:
+        x = x + m
+    return x.astype(cfg.dtype), counts
 
 
 def _block(x, lp, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
-           v_scale=None, ctx_fn=None):
-    """One pre-LN GPT-2 block over ALREADY-PROJECTED k/v (B, nh, Skv, hd).
+           v_scale=None, ctx_fn=None, kind: tuple = _GPT2_KIND, pos=None):
+    """One decoder block over ALREADY-PROJECTED k/v (B, nkv, Skv, hd).
 
     The caller owns the KV source — the in-sequence keys for prefill, the
     cache for decode — so prefill and decode share one block body and
     cannot diverge numerically. Composed of :func:`_block_qkv` →
     :func:`_attn_ctx` → :func:`_block_finish`; matmul outputs / bias /
-    gelu / residuals stay in cfg.dtype (the MXU accumulates f32
-    internally; attention SCORES and layernorm statistics stay f32) —
-    same HBM-traffic optimization as the encoder's _layer, bit-unchanged
-    for f32 configs.
+    activation / residuals stay in cfg.dtype (the MXU accumulates f32
+    internally; attention SCORES and norm statistics stay f32) — same
+    HBM-traffic optimization as the encoder's _layer, bit-unchanged for
+    f32 configs. ``kind`` is the layer's (:meth:`DecoderConfig.layer_kind`);
+    ``pos`` (B, S) the queries' positions, read by rotary layers.
 
     ``ctx_fn(q, k, v, k_scale, v_scale) -> (B, nh, Sq, hd)`` swaps the
     dense :func:`_attn_ctx` read for an alternative (the flash-prefill
     Pallas kernels); it owns scaling and masking, mirroring the
     encoder's ``core`` seam. ``None`` (default) keeps the dense path
-    byte-identical."""
-    q, k_new, v_new = _block_qkv(x, lp, cfg)
-    if ctx_fn is None:
-        ctx = _attn_ctx(q, k, v, mask_bias, cfg, k_scale, v_scale)
-    else:
-        ctx = ctx_fn(q, k, v, k_scale, v_scale).astype(cfg.dtype)
-    x = _block_finish(x, lp, ctx, cfg)
-    return x, k_new, v_new
+    byte-identical. Returns ``(x, counts)`` (:func:`_block_finish`)."""
+    q, _k_new, _v_new = _block_qkv(x, lp, cfg, kind, pos)
+    with jax.named_scope("decoder.attn." + kind[0]):
+        if ctx_fn is None:
+            ctx = _attn_ctx(q, k, v, mask_bias, cfg, k_scale, v_scale)
+        else:
+            ctx = ctx_fn(q, k, v, k_scale, v_scale).astype(cfg.dtype)
+    return _block_finish(x, lp, ctx, cfg, kind)
 
 
 def _flash_self_attn_fn(mesh):
@@ -548,9 +818,14 @@ def _flash_chunk_attn_fn(mesh, quant):
 
 
 def _logits(params, x, cfg):
-    h = _ln(x, params["ln_f_scale"], params["ln_f_bias"], cfg.layer_norm_eps)
+    if cfg.norm == "layernorm":
+        h = _ln(x, params["ln_f_scale"], params["ln_f_bias"],
+                cfg.layer_norm_eps)
+    else:
+        h = _rms(x, params["ln_f_scale"], cfg.layer_norm_eps)
+    head = params["wte"] if cfg.tied_head else params["lm_head"]
     out = jnp.einsum("bsh,vh->bsv", h.astype(cfg.dtype),
-                     params["wte"].astype(cfg.dtype),
+                     head.astype(cfg.dtype),
                      preferred_element_type=jnp.float32)
     s = params.get("wte_scale")
     if s is not None:
@@ -558,6 +833,193 @@ def _logits(params, x, cfg):
         # scale per vocab row == per output channel of this einsum
         out = out * s[:, 0]
     return out
+
+
+def _embed(params, ids, pos, cfg: DecoderConfig):
+    """Token rows (scaled where the configuration says so) plus learned
+    positions where it has them: ``ids``/``pos`` (B, S) -> (B, S, H)."""
+    x = _tok_embed(params, ids)
+    if cfg.embed_scale != 1.0:
+        x = x.astype(jnp.float32) * cfg.embed_scale
+    if cfg.learned_positions:
+        x = x + params["wpe"][pos]
+    return x.astype(cfg.dtype)
+
+
+# ---- stacks of like layers -------------------------------------------------
+#
+# Every pass over the layers goes through :func:`_scan_layers`, which runs
+# one ``lax.scan`` per run of like layers (:meth:`DecoderConfig.runs`) over
+# that run's own KV arrays. GPT-2 is one run: its KV is ``k``/``v`` (and the
+# int8 pool's ``k_scale``/``v_scale``), one scan over the whole stack, as it
+# always was. A model of several runs keeps ONE pair of arrays per run,
+# named for the run's kind and number (``kf2``/``vf2``: run 2, full
+# attention, rows of ``cache_len``; ``kw3``/``vw3``: run 3, window layers,
+# rings): no pass ever slices or reassembles a stack.
+
+def _kv_names(cfg: DecoderConfig, r: int, kind: tuple) -> tuple:
+    """Names of run ``r``'s (k, v, k_scale, v_scale) arrays in a pool or a
+    prefill cache."""
+    if cfg.uniform:
+        return ("k", "v", "k_scale", "v_scale")
+    t = "w" if kind[0] == "window" else "f"
+    return (f"k{t}{r}", f"v{t}{r}", None, None)
+
+
+def _is_kv(name: str, window: bool | None = None) -> bool:
+    """``name`` is a run's KV array (of a window run / of a full run where
+    ``window`` says which)."""
+    if name in ("k", "v"):
+        return not window
+    ok = (len(name) > 2 and name[0] in "kv" and name[1] in "fw"
+          and name[2:].isdigit())
+    return ok and (window is None or (name[1] == "w") == window)
+
+
+def _kv_stacks(pool: dict) -> dict:
+    """The pool's KV arrays (and int8 scale planes), by name."""
+    return {n: a for n, a in pool.items()
+            if _is_kv(n) or n in ("k_scale", "v_scale")}
+
+
+def _run_stacks(params: dict, cfg: DecoderConfig) -> list:
+    """The stacked leaves of each run of like layers, in layer order:
+    ``params["layers"]`` itself where the model is one run (GPT-2's
+    layout), else its entries ``run0``, ``run1``, ..."""
+    if cfg.uniform:
+        return [params["layers"]]
+    return [params["layers"][f"run{r}"] for r in range(len(cfg.runs()))]
+
+
+_EXPERT_LEAVES = ("moe_in_w", "moe_up_w", "moe_out_w")
+
+
+def _scan_run(step, x, lp: dict, kvl, n: int):
+    """``lax.scan`` of ``step(x, (lp_l, kvl_l))`` over one run's ``n``
+    stacked layers. A run of expert layers scans a layer INDEX beside its
+    other leaves and reads its experts out of the whole stack in place
+    (``models/moe.py:_expert``): as scanned leaves, one layer's experts —
+    nine tenths of its bytes — would be copied out at every step."""
+    if "moe_in_w" not in lp:
+        return jax.lax.scan(step, x, (lp, kvl))
+    stacked = {k: lp[k] for k in _EXPERT_LEAVES}
+    rest = {k: v for k, v in lp.items() if k not in _EXPERT_LEAVES}
+
+    def indexed(x, inp):
+        lp_l, kvl_l, layer = inp
+        return step(x, ({**lp_l, **stacked, "moe_layer": layer}, kvl_l))
+
+    return jax.lax.scan(indexed, x,
+                        (rest, kvl, jnp.arange(n, dtype=jnp.int32)))
+
+
+def _scan_layers(cfg: DecoderConfig, params: dict, x, kv: dict, body,
+                 n_layers: int | None = None):
+    """Run ``body(x, lp, kvl, kind) -> (x, kvl, counts | None)`` over the
+    first ``n_layers`` layers (all by default). ``kvl`` holds the layer's
+    ``k``, ``v``, ``k_scale``, ``v_scale`` (None where ``kv`` has none: a
+    pass over whole sequences starts from ``{}`` and gets its keys and
+    values back). Returns ``(x, kv_out, counts)``: ``kv_out`` is ``kv``
+    with what ``body`` returned for the visited runs, ``counts`` the expert
+    layers' summed (held, all) or None."""
+    layers = _run_stacks(params, cfg)
+    out = dict(kv)
+    counts = None
+    for r, (kind, _first, n) in enumerate(cfg.runs(n_layers)):
+        lp = layers[r]
+        names = _kv_names(cfg, r, kind)
+        whole = {short: (kv.get(name) if name else None)
+                 for short, name in zip(("k", "v", "k_scale", "v_scale"),
+                                        names)}
+        cut = jax.tree_util.tree_leaves(lp)[0].shape[0] != n
+        if cut:     # a depth-prefix ends inside this run
+            lp = jax.tree.map(lambda a: a[:n], lp)
+        kvl = {short: (a[:n] if cut and a is not None else a)
+               for short, a in whole.items()}
+
+        def step(x, inp, kind=kind):
+            x, kvl, cnt = body(x, inp[0], inp[1], kind)
+            return x, (kvl, cnt)
+
+        x, (kv_run, cnt) = _scan_run(step, x, lp, kvl, n)
+        for short, name in zip(("k", "v", "k_scale", "v_scale"), names):
+            new = kv_run[short]
+            if name is None or new is None:
+                continue
+            if cut and whole[short] is not None:
+                new = jnp.concatenate([new, whole[short][n:]], axis=0)
+            out[name] = new
+        if cnt is not None:
+            cnt = cnt.sum(axis=0)
+            counts = cnt if counts is None else counts + cnt
+    return x, out, counts
+
+
+def _ring_cols(hi, R: int):
+    """A window layer's slot row is a RING of ``R`` rows indexed by cache
+    column mod ``R``. With ``hi`` (...,) the newest column written, ring
+    index ``r`` holds the largest column <= ``hi`` congruent to ``r``:
+    returns those columns (..., R); negative where none was written."""
+    r = jnp.arange(R, dtype=jnp.int32)
+    return hi[..., None] - jnp.mod(hi[..., None] - r, R)
+
+
+def _ring_bias(cols, live, qcol, window: int):
+    """Mask bias of a ring read: ``cols`` (B, R) the column each ring
+    index holds, ``live`` (B, [Q,] R) whether that column is attendable,
+    ``qcol`` (B, [Q]) the queries' columns. A key is read when live, not
+    ahead of the query and inside its window."""
+    if live.ndim == 3:
+        cols, q = cols[:, None, :], qcol[:, :, None]
+    else:
+        q = qcol[:, None]
+    ok = live & (cols >= 0) & (cols <= q) & (cols > q - window)
+    bias = jnp.where(ok, 0.0, -1e9).astype(jnp.float32)
+    return bias[:, None, None, :] if live.ndim == 2 else bias[:, None, :, :]
+
+
+def _live_at(slot_mask, cols):
+    """``slot_mask`` (B, C) read at the ring's columns (B, R)."""
+    return jnp.take_along_axis(
+        slot_mask, jnp.clip(cols, 0, slot_mask.shape[1] - 1), axis=1) > 0
+
+
+def _causal_bias(attention_mask, S: int, window: int = 0):
+    causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
+    if window:
+        causal = causal & ~jnp.tril(jnp.ones((S, S), jnp.bool_), -window)
+    allowed = (causal[None, None, :, :]
+               & (attention_mask[:, None, None, :] > 0))
+    return jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
+
+
+def _self_attend(params, input_ids, attention_mask, cfg: DecoderConfig,
+                 flash: bool, mesh, keep_kv: bool):
+    """The causal forward over whole sequences that :func:`forward` and
+    :func:`prefill` share: ``(x, kv, counts)``, ``kv`` every layer's
+    in-sequence keys and values by stack (``keep_kv``), unpadded."""
+    B, S = input_ids.shape
+    pos = jnp.clip(jnp.cumsum(attention_mask, axis=1) - 1, 0)
+    x = _embed(params, input_ids, pos, cfg)
+    ctx_fn = None
+    bias = {"full": None, "window": None}
+    if flash:
+        require_gpt2_block(cfg, "flash_prefill")
+        attn = _flash_self_attn_fn(mesh)
+        ctx_fn = lambda q, k, v, ks, vs: attn(q, k, v, attention_mask)
+    else:
+        bias["full"] = _causal_bias(attention_mask, S)
+        if cfg.n_layers_of("window"):
+            bias["window"] = _causal_bias(attention_mask, S,
+                                          cfg.sliding_window)
+
+    def body(carry, lp, kvl, kind):
+        k, v = _prefill_kv(carry, lp, cfg, kind, pos)
+        x, cnt = _block(carry, lp, k, v, bias[kind[0]], cfg, ctx_fn=ctx_fn,
+                        kind=kind, pos=pos)
+        return x, ({**kvl, "k": k, "v": v} if keep_kv else kvl), cnt
+
+    return _scan_layers(cfg, params, x, {}, body)
 
 
 def forward(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
@@ -577,38 +1039,17 @@ def forward(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
     tolerance; fully-masked query rows (left-padding) produce different
     hidden states (flash: zeros) that never reach live positions.
     ``mesh`` shard-maps the kernel over tp shards (heads split)."""
-    B, S = input_ids.shape
-    pos = jnp.clip(jnp.cumsum(attention_mask, axis=1) - 1, 0)
-    x = (_tok_embed(params, input_ids) + params["wpe"][pos]).astype(cfg.dtype)
-    ctx_fn = mask_bias = None
-    if flash:
-        attn = _flash_self_attn_fn(mesh)
-        ctx_fn = lambda q, k, v, ks, vs: attn(q, k, v, attention_mask)
-    else:
-        causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
-        allowed = (causal[None, None, :, :]
-                   & (attention_mask[:, None, None, :] > 0))
-        mask_bias = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
-
-    def body(carry, lp):
-        k, v = _prefill_kv(carry, lp, cfg)
-        x, _, _ = _block(carry, lp, k, v, mask_bias, cfg, ctx_fn=ctx_fn)
-        return x, None
-
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _kv, _counts = _self_attend(params, input_ids, attention_mask, cfg,
+                                   flash, mesh, False)
     return _logits(params, x, cfg)
 
 
-def _prefill_kv(x, lp, cfg):
-    """Project this layer's k/v from the in-sequence activations (pre-LN
-    applied inside, mirroring _block's own projection)."""
-    h1 = _ln(x, lp["ln1_scale"], lp["ln1_bias"], cfg.layer_norm_eps)
-    qkv = _wq_matmul("bsh,hk->bsk", h1.astype(cfg.dtype), lp, "qkv_w", cfg)
-    qkv = qkv + lp["qkv_b"].astype(cfg.dtype)
-    _, k, v = jnp.split(qkv, 3, axis=-1)
-    nh, hd = cfg.heads, cfg.head_dim
-    return _split_heads(k.astype(cfg.dtype), nh, hd), \
-        _split_heads(v.astype(cfg.dtype), nh, hd)
+def _prefill_kv(x, lp, cfg, kind: tuple = _GPT2_KIND, pos=None):
+    """Project this layer's k/v from the in-sequence activations (the
+    block's first norm applied inside, mirroring _block's own
+    projection)."""
+    _q, k, v = _project(x, lp, cfg, kind, pos, False)
+    return k, v
 
 
 def prefill(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
@@ -616,7 +1057,9 @@ def prefill(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
             mesh=None):
     """Causal forward over the (left-padded) prompt, returning
     ``(last_logits (B, V), cache)`` with per-layer K/V written into a cache
-    padded to ``cache_len`` slots.
+    padded to ``cache_len`` slots (``k``/``v``; one pair per run of like
+    layers where the model has several, :func:`_kv_names` — window layers
+    here at full length too: only the slot pool keeps them as rings).
 
     ``flash``/``mesh`` as in :func:`forward` — the flash arm's cached KV
     at fully-masked (padding) columns differs from dense, but those
@@ -624,29 +1067,11 @@ def prefill(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
     read, so decode streams see identical attention inputs."""
     B, S = input_ids.shape
     assert cache_len >= S
-    pos = jnp.clip(jnp.cumsum(attention_mask, axis=1) - 1, 0)
-    x = (_tok_embed(params, input_ids) + params["wpe"][pos]).astype(cfg.dtype)
-    ctx_fn = mask_bias = None
-    if flash:
-        attn = _flash_self_attn_fn(mesh)
-        ctx_fn = lambda q, k, v, ks, vs: attn(q, k, v, attention_mask)
-    else:
-        causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
-        allowed = (causal[None, None, :, :]
-                   & (attention_mask[:, None, None, :] > 0))
-        mask_bias = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
-
-    def body(carry, lp):
-        k, v = _prefill_kv(carry, lp, cfg)
-        x, _, _ = _block(carry, lp, k, v, mask_bias, cfg, ctx_fn=ctx_fn)
-        return x, (k, v)
-
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
+    x, kv, _counts = _self_attend(params, input_ids, attention_mask, cfg,
+                                  flash, mesh, True)
     pad = [(0, 0), (0, 0), (0, 0), (0, cache_len - S), (0, 0)]
-    cache = {
-        "k": jnp.pad(ks, pad),  # (L, B, nh, cache_len, hd)
-        "v": jnp.pad(vs, pad),
-    }
+    # (L, B, nkv, cache_len, hd)
+    cache = {name: jnp.pad(a, pad) for name, a in kv.items()}
     return _logits(params, x[:, -1:, :], cfg)[:, 0, :], cache
 
 
@@ -658,34 +1083,31 @@ def decode_step(params: dict, token: jax.Array, step_pos: jax.Array,
     live cache slots INCLUDING the one being written. Returns
     ``(logits (B, V), cache)``.
 
-    ``n_layers`` runs only the first N blocks (plus the final LN + tied
+    ``n_layers`` runs only the first N blocks (plus the final norm + the
     head) — the cascade-rerank trick (``transformer.encode(n_layers=)``)
     applied to decode: the shallow stack is the self-speculative DRAFT
     model, its KV a depth-prefix of the same cache (layers >= N pass
     through untouched), no second parameter set anywhere."""
-    B = token.shape[0]
-    x = (_tok_embed(params, token)[:, None, :]
-         + params["wpe"][step_pos][:, None, :]).astype(cfg.dtype)
-    mask_bias = jnp.where(slot_mask[:, None, None, :] > 0, 0.0, -1e9
-                          ).astype(jnp.float32)
-    layers, ck, cv = params["layers"], cache["k"], cache["v"]
-    if n_layers is not None:
-        layers = jax.tree.map(lambda a: a[:n_layers], layers)
-        ck, cv = ck[:n_layers], cv[:n_layers]
+    pos = step_pos[:, None]
+    x = _embed(params, token[:, None], pos, cfg)
+    live = slot_mask[:, None, None, :] > 0
+    bias = {"full": jnp.where(live, 0.0, -1e9).astype(jnp.float32)}
+    if cfg.n_layers_of("window"):
+        idxs = jnp.arange(slot_mask.shape[1])[None, None, None, :]
+        bias["window"] = jnp.where(
+            live & (idxs > slot - cfg.sliding_window), 0.0, -1e9
+        ).astype(jnp.float32)
 
-    def body(x, inp):
-        lp, kl, vl = inp
-        k_new, v_new = _prefill_kv(x, lp, cfg)  # (B, nh, 1, hd)
-        kl = jax.lax.dynamic_update_slice(kl, k_new, (0, 0, slot, 0))
-        vl = jax.lax.dynamic_update_slice(vl, v_new, (0, 0, slot, 0))
-        x, _, _ = _block(x, lp, kl, vl, mask_bias, cfg)
-        return x, (kl, vl)
+    def body(x, lp, kvl, kind):
+        k_new, v_new = _prefill_kv(x, lp, cfg, kind, pos)  # (B, nkv, 1, hd)
+        kl = jax.lax.dynamic_update_slice(kvl["k"], k_new, (0, 0, slot, 0))
+        vl = jax.lax.dynamic_update_slice(kvl["v"], v_new, (0, 0, slot, 0))
+        x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg, kind=kind,
+                        pos=pos)
+        return x, {**kvl, "k": kl, "v": vl}, cnt
 
-    x, (ks, vs) = jax.lax.scan(body, x, (layers, ck, cv))
-    if n_layers is not None:
-        ks = cache["k"].at[:n_layers].set(ks)
-        vs = cache["v"].at[:n_layers].set(vs)
-    return _logits(params, x, cfg)[:, 0, :], {"k": ks, "v": vs}
+    x, out, _counts = _scan_layers(cfg, params, x, cache, body, n_layers)
+    return _logits(params, x, cfg)[:, 0, :], out
 
 
 def _filter_logits(logits, top_k: int | None, top_p: float | None):
@@ -837,7 +1259,8 @@ def generate(params: dict, prompt_ids: jax.Array, attention_mask: jax.Array,
 
 def pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
               cache_len: int, arena_blocks: int = 0,
-              arena_block: int = 0, kv_quant: bool = False) -> dict:
+              arena_block: int = 0, kv_quant: bool = False,
+              window_slack: int = 256) -> dict:
     """Empty serving pool: per-slot KV caches, last logits, attention
     slot masks and cursors. ``cache_len`` must cover the largest
     admitted prompt + its budget + one chunk of overrun slack per
@@ -862,30 +1285,60 @@ def pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
     trailing dim 1) — ~1.88x the tokens per HBM byte at hd=64. Every
     pool function quantizes on write and ``_block`` dequantizes on
     read; the ``k_scale`` key doubles as the format marker."""
-    L, nh, hd = cfg.layers, cfg.heads, cfg.head_dim
+    nh, hd = cfg.n_kv, cfg.head_dim
     del params
+    if kv_quant:
+        require_gpt2_block(cfg, "kv_quant")
     kv_dtype = jnp.int8 if kv_quant else cfg.dtype
-    pool = {
-        "k": jnp.zeros((L, n_slots, nh, cache_len, hd), kv_dtype),
-        "v": jnp.zeros((L, n_slots, nh, cache_len, hd), kv_dtype),
+    # a window layer's slot row is a ring of window + slack rows, indexed by
+    # cache column mod its length (_ring_cols); the slack covers what one
+    # dispatch writes ahead of the committed cursor (a speculative cycle's
+    # rejected tail)
+    ring = min(cache_len, cfg.sliding_window + window_slack)
+    pool = {}
+    for r, (kind, _first, n) in enumerate(cfg.runs()):
+        kn, vn, ksn, vsn = _kv_names(cfg, r, kind)
+        rows = ring if kind[0] == "window" else cache_len
+        pool[kn] = jnp.zeros((n, n_slots, nh, rows, hd), kv_dtype)
+        pool[vn] = jnp.zeros((n, n_slots, nh, rows, hd), kv_dtype)
+        if kv_quant:
+            pool[ksn] = jnp.zeros((n, n_slots, nh, rows, 1), jnp.float32)
+            pool[vsn] = jnp.zeros((n, n_slots, nh, rows, 1), jnp.float32)
+        if arena_blocks > 0:
+            shape = (arena_blocks, n, nh, arena_block, hd)
+            pool["arena_" + kn] = jnp.zeros(shape, kv_dtype)
+            pool["arena_" + vn] = jnp.zeros(shape, kv_dtype)
+            if kv_quant:
+                ashape = (arena_blocks, n, nh, arena_block, 1)
+                pool["arena_" + ksn] = jnp.zeros(ashape, jnp.float32)
+                pool["arena_" + vsn] = jnp.zeros(ashape, jnp.float32)
+    pool.update({
         "logits": jnp.zeros((n_slots, cfg.vocab_size), jnp.float32),
         "slot_mask": jnp.zeros((n_slots, cache_len), jnp.int32),
         "pos": jnp.zeros((n_slots,), jnp.int32),    # next position id
         "write": jnp.zeros((n_slots,), jnp.int32),  # next cache slot
-    }
-    if kv_quant:
-        sshape = (L, n_slots, nh, cache_len, 1)
-        pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
-        pool["v_scale"] = jnp.zeros(sshape, jnp.float32)
-    if arena_blocks > 0:
-        shape = (arena_blocks, L, nh, arena_block, hd)
-        pool["arena_k"] = jnp.zeros(shape, kv_dtype)
-        pool["arena_v"] = jnp.zeros(shape, kv_dtype)
-        if kv_quant:
-            ashape = (arena_blocks, L, nh, arena_block, 1)
-            pool["arena_k_scale"] = jnp.zeros(ashape, jnp.float32)
-            pool["arena_v_scale"] = jnp.zeros(ashape, jnp.float32)
+    })
+    if cfg.moe is not None:
+        # (held, all) expert assignments since the pool was built, by
+        # phase (row 0: prefill, row 1: decode), summed on the device by
+        # every prefill and decode op; wraps mod 2**32
+        pool["moe_counts"] = jnp.zeros((2, 2), jnp.uint32)
     return pool
+
+
+def pool_ring(pool: dict) -> int:
+    """Rows of a window layer's ring (0: the pool has no window layer)."""
+    for name, a in pool.items():
+        if _is_kv(name, window=True):
+            return a.shape[3]
+    return 0
+
+
+def _add_counts(pool: dict, out: dict, counts, decode: bool = False) -> dict:
+    if counts is not None and "moe_counts" in pool:
+        out["moe_counts"] = pool["moe_counts"].at[int(decode)].add(
+            counts.astype(jnp.uint32))
+    return out
 
 
 def pool_component_bytes(pool: dict) -> dict[str, int]:
@@ -897,9 +1350,8 @@ def pool_component_bytes(pool: dict) -> dict[str, int]:
     ledger (``probes.record_hbm``) records these per component at pool
     build; :func:`pool_bytes` sums them for the historical total."""
     out: dict[str, int] = {}
-    for component, keys in _HBM_COMPONENT_KEYS.items():
-        n = sum(int(pool[c].size) * pool[c].dtype.itemsize
-                for c in keys if c in pool)
+    for component, keys in _component_keys(pool).items():
+        n = sum(int(pool[c].size) * pool[c].dtype.itemsize for c in keys)
         if n:
             out[component] = n
     return out
@@ -914,6 +1366,26 @@ _HBM_COMPONENT_KEYS = {
     "prefix_arena": ("arena_k", "arena_v"),
     "arena_scales": ("arena_k_scale", "arena_v_scale"),
 }
+
+
+def _component_keys(pool: dict) -> dict:
+    """ledger component -> the pool's keys it accounts: the fixed names
+    above, and a several-run model's per-run arrays (``slot_pool``: the
+    full-attention runs' rows of ``cache_len``; ``slot_pool_window``: the
+    window runs' rings; their arena blocks under ``prefix_arena``)."""
+    out = {c: [k for k in keys if k in pool]
+           for c, keys in _HBM_COMPONENT_KEYS.items()}
+    out["slot_pool_window"] = []
+    for name in pool:
+        if name in ("k", "v"):
+            continue
+        if _is_kv(name):
+            out["slot_pool_window" if name[1] == "w" else "slot_pool"
+                ].append(name)
+        elif name.startswith("arena_") and _is_kv(name[6:]) \
+                and name[6:] not in ("k", "v"):
+            out["prefix_arena"].append(name)
+    return out
 
 
 def _device_bytes(arr) -> dict[str, int]:
@@ -940,11 +1412,9 @@ def pool_component_device_bytes(pool: dict) -> dict[str, dict[str, int]]:
     full — exactly what capacity planning needs to size the block
     allocator against the TIGHTEST device."""
     out: dict[str, dict[str, int]] = {}
-    for component, keys in _HBM_COMPONENT_KEYS.items():
+    for component, keys in _component_keys(pool).items():
         per_dev: dict[str, int] = {}
         for c in keys:
-            if c not in pool:
-                continue
             for dev, n in _device_bytes(pool[c]).items():
                 per_dev[dev] = per_dev.get(dev, 0) + n
         if any(per_dev.values()):
@@ -1114,6 +1584,7 @@ def paged_pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
         )
     if n_blocks < 2:
         raise ValueError("paged pool needs >= 2 blocks (one sentinel)")
+    require_gpt2_block(cfg, "paged_kv")
     L, nh, hd = cfg.layers, cfg.heads, cfg.head_dim
     del params
     kv_dtype = jnp.int8 if kv_quant else cfg.dtype
@@ -1228,28 +1699,24 @@ def pool_admit(params: dict, ids: jax.Array, mask: jax.Array, pool: dict,
             pool, pool_admit(params, ids, mask, _paged_gather(pool),
                              slot, cfg, flash=flash, mesh=mesh)
         )
-    C = pool["k"].shape[3]
+    C = pool["slot_mask"].shape[1]
     S = ids.shape[1]
-    last_logits, cache = prefill(params, ids, mask, cfg, cache_len=C,
-                                 flash=flash, mesh=mesh)
+    last_logits, cache, counts = _prefill_for_pool(
+        params, ids, mask, pool, cfg, flash, mesh)
     upd = {}
     if pool_quantized(pool):
-        ck, sk = _kv_quant(cache["k"])
-        cv, sv = _kv_quant(cache["v"])
+        cache["k"], sk = _kv_quant(cache["k"])
+        cache["v"], sv = _kv_quant(cache["v"])
         upd["k_scale"] = jax.lax.dynamic_update_slice(
             pool["k_scale"], sk, (0, slot, 0, 0, 0)
         )
         upd["v_scale"] = jax.lax.dynamic_update_slice(
             pool["v_scale"], sv, (0, slot, 0, 0, 0)
         )
-    else:
-        ck, cv = cache["k"], cache["v"]
-    k = jax.lax.dynamic_update_slice(
-        pool["k"], ck.astype(pool["k"].dtype), (0, slot, 0, 0, 0)
-    )
-    v = jax.lax.dynamic_update_slice(
-        pool["v"], cv.astype(pool["v"].dtype), (0, slot, 0, 0, 0)
-    )
+    for name, new in cache.items():
+        upd[name] = jax.lax.dynamic_update_slice(
+            pool[name], new.astype(pool[name].dtype), (0, slot, 0, 0, 0)
+        )
     row_mask = jnp.concatenate(
         [mask.astype(jnp.int32), jnp.zeros((1, C - S), jnp.int32)], axis=1
     )
@@ -1264,8 +1731,31 @@ def pool_admit(params: dict, ids: jax.Array, mask: jax.Array, pool: dict,
     write = jax.lax.dynamic_update_slice(
         pool["write"], jnp.full((1,), S, jnp.int32), (slot,)
     )
-    return {**pool, **upd, "k": k, "v": v, "logits": logits,
-            "slot_mask": slot_mask, "pos": pos, "write": write}
+    return _add_counts(pool, {
+        **pool, **upd, "logits": logits,
+        "slot_mask": slot_mask, "pos": pos, "write": write}, counts)
+
+
+def _prefill_for_pool(params, ids, mask, pool, cfg, flash, mesh):
+    """One-shot prefill in the pool's own layout: ``(last_logits, cache,
+    counts)``; the full layers' keys and values padded to the slot row,
+    the window layers' LAST ring-length columns laid out as the ring holds
+    them (column ``c`` at index ``c`` mod its length)."""
+    C, S = pool["slot_mask"].shape[1], ids.shape[1]
+    x, kv, counts = _self_attend(params, ids, mask, cfg, flash, mesh, True)
+    R = pool_ring(pool)
+    cache = {}
+    for name, new in kv.items():
+        if not _is_kv(name, window=True):
+            cache[name] = jnp.pad(
+                new, [(0, 0), (0, 0), (0, 0), (0, C - S), (0, 0)])
+        elif S <= R:
+            cache[name] = jnp.pad(
+                new, [(0, 0), (0, 0), (0, 0), (0, R - S), (0, 0)])
+        else:
+            cache[name] = jnp.roll(new[:, :, :, S - R:, :], (S - R) % R,
+                                   axis=3)
+    return _logits(params, x[:, -1:, :], cfg)[:, 0, :], cache, counts
 
 
 def pool_admit_batch(params: dict, ids: jax.Array, mask: jax.Array,
@@ -1288,20 +1778,18 @@ def pool_admit_batch(params: dict, ids: jax.Array, mask: jax.Array,
             pool, pool_admit_batch(params, ids, mask, _paged_gather(pool),
                                    slots, cfg, flash=flash, mesh=mesh)
         )
-    C = pool["k"].shape[3]
+    C = pool["slot_mask"].shape[1]
     M, S = ids.shape
-    last_logits, cache = prefill(params, ids, mask, cfg, cache_len=C,
-                                 flash=flash, mesh=mesh)
+    last_logits, cache, counts = _prefill_for_pool(
+        params, ids, mask, pool, cfg, flash, mesh)
     upd = {}
     if pool_quantized(pool):
-        ck, sk = _kv_quant(cache["k"])
-        cv, sv = _kv_quant(cache["v"])
+        cache["k"], sk = _kv_quant(cache["k"])
+        cache["v"], sv = _kv_quant(cache["v"])
         upd["k_scale"] = pool["k_scale"].at[:, slots].set(sk)
         upd["v_scale"] = pool["v_scale"].at[:, slots].set(sv)
-    else:
-        ck, cv = cache["k"], cache["v"]
-    k = pool["k"].at[:, slots].set(ck.astype(pool["k"].dtype))
-    v = pool["v"].at[:, slots].set(cv.astype(pool["v"].dtype))
+    for name, new in cache.items():
+        upd[name] = pool[name].at[:, slots].set(new.astype(pool[name].dtype))
     row_mask = jnp.concatenate(
         [mask.astype(jnp.int32), jnp.zeros((M, C - S), jnp.int32)], axis=1
     )
@@ -1310,8 +1798,9 @@ def pool_admit_batch(params: dict, ids: jax.Array, mask: jax.Array,
     n_prompt = jnp.sum(mask, axis=1).astype(jnp.int32)  # (M,)
     pos = pool["pos"].at[slots].set(n_prompt)
     write = pool["write"].at[slots].set(jnp.full((M,), S, jnp.int32))
-    return {**pool, **upd, "k": k, "v": v, "logits": logits,
-            "slot_mask": slot_mask, "pos": pos, "write": write}
+    return _add_counts(pool, {
+        **pool, **upd, "logits": logits,
+        "slot_mask": slot_mask, "pos": pos, "write": write}, counts)
 
 
 def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
@@ -1357,11 +1846,12 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
                 flash=flash, mesh=mesh,
             )
         )
-    C = pool["k"].shape[3]
+    C = pool["slot_mask"].shape[1]
     T = ids.shape[1]
-    nh, hd = cfg.heads, cfg.head_dim
+    nh, hd = cfg.n_kv, cfg.head_dim
+    R, W = pool_ring(pool), cfg.sliding_window
     p = jnp.clip(pos, 0, cfg.max_position - 1)
-    x = (_tok_embed(params, ids) + params["wpe"][p]).astype(cfg.dtype)
+    x = _embed(params, ids, p, cfg)
     if first:
         row_mask = jnp.zeros((1, C), jnp.int32)
     else:
@@ -1373,8 +1863,10 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         pool["slot_mask"], row_mask, (slot, 0)
     )
     quant = pool_quantized(pool)
-    ctx_fn = mask_bias = None
+    ctx_fn = mask_bias = ring_bias = None
+    qcol = (start + jnp.arange(T))[None, :]             # (1, T)
     if flash:
+        require_gpt2_block(cfg, "flash_prefill")
         # the kernel rebuilds the same live-&-causal predicate from
         # row_mask and start internally, with int8 dequant fused into
         # the cache tile read — no (1, 1, T, C) bias, no f32 KV row
@@ -1387,13 +1879,48 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         # prefix) — elementwise the same predicate as prefill()'s
         # causal & pad mask
         idxs = jnp.arange(C)[None, None, None, :]
-        qpos = (start + jnp.arange(T))[None, None, :, None]
-        allowed = (row_mask[:, None, None, :] > 0) & (idxs <= qpos)
+        allowed = (row_mask[:, None, None, :] > 0) \
+            & (idxs <= qcol[:, None, :, None])
         mask_bias = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
+    if R:
+        # a window layer reads its ring as the EARLIER pieces left it
+        # (columns < start) beside this piece's own keys, then writes the
+        # piece in: the ring needs no room for the piece itself
+        if T > R:
+            raise ValueError(f"a prefill piece of {T} columns does not "
+                             f"fit a window layer's ring of {R}")
+        cols = _ring_cols(jnp.reshape(start - 1, (1,)), R)      # (1, R)
+        live = jnp.broadcast_to(_live_at(row_mask, cols)[:, None, :],
+                                (1, T, R))
+        old = _ring_bias(cols, live, qcol, W)               # (1, 1, T, R)
+        j = jnp.arange(T)
+        own = (mask[:, None, :] > 0) & (j[None, None, :] <= j[None, :, None]) \
+            & (j[None, :, None] - j[None, None, :] < W)
+        ring_bias = jnp.concatenate(
+            [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]],
+            axis=-1)
+        ring_idx = jnp.mod(start + jnp.arange(T), R)
 
-    def layer(x, inp):
-        lp, kl, vl, ksl, vsl = inp
-        k_new, v_new = _prefill_kv(x, lp, cfg)  # (1, nh, T, hd)
+    def layer(x, lp, kvl, kind):
+        kl, vl, ksl, vsl = kvl["k"], kvl["v"], kvl["k_scale"], kvl["v_scale"]
+        k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (1, nh, T, hd)
+        if kind[0] == "window":
+            k_old = jax.lax.dynamic_slice(kl, (slot, 0, 0, 0), (1, nh, R, hd))
+            v_old = jax.lax.dynamic_slice(vl, (slot, 0, 0, 0), (1, nh, R, hd))
+            x, cnt = _block(
+                x, lp, jnp.concatenate([k_old, k_new.astype(kl.dtype)], 2),
+                jnp.concatenate([v_old, v_new.astype(vl.dtype)], 2),
+                ring_bias, cfg, kind=kind, pos=p)
+            # only REAL tokens enter the ring: a pad column's index still
+            # holds an earlier column that a later query may read
+            real = (mask[0] > 0)[:, None, None]
+            kl = kl.at[slot, :, ring_idx, :].set(jnp.where(
+                real, k_new[0].transpose(1, 0, 2).astype(kl.dtype),
+                kl[slot, :, ring_idx, :]))
+            vl = vl.at[slot, :, ring_idx, :].set(jnp.where(
+                real, v_new[0].transpose(1, 0, 2).astype(vl.dtype),
+                vl[slot, :, ring_idx, :]))
+            return x, {**kvl, "k": kl, "v": vl}, cnt
         ks_row = vs_row = None
         if quant:
             k_new, sk = _kv_quant(k_new)
@@ -1414,18 +1941,13 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         )
         k_row = jax.lax.dynamic_slice(kl, (slot, 0, 0, 0), (1, nh, C, hd))
         v_row = jax.lax.dynamic_slice(vl, (slot, 0, 0, 0), (1, nh, C, hd))
-        x, _, _ = _block(x, lp, k_row, v_row, mask_bias, cfg,
-                         k_scale=ks_row, v_scale=vs_row, ctx_fn=ctx_fn)
-        return x, (kl, vl, ksl, vsl)
+        x, cnt = _block(x, lp, k_row, v_row, mask_bias, cfg,
+                        k_scale=ks_row, v_scale=vs_row, ctx_fn=ctx_fn,
+                        kind=kind, pos=p)
+        return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
 
-    x, (k, v, ks, vs) = jax.lax.scan(
-        layer, x,
-        (params["layers"], pool["k"], pool["v"],
-         pool.get("k_scale"), pool.get("v_scale")),
-    )
-    out = {**pool, "k": k, "v": v, "slot_mask": slot_mask}
-    if quant:
-        out["k_scale"], out["v_scale"] = ks, vs
+    x, kv, counts = _scan_layers(cfg, params, x, pool, layer)
+    out = _add_counts(pool, {**kv, "slot_mask": slot_mask}, counts)
     if last:
         if last_col is None:
             x_last = x[:, -1:, :]
@@ -1439,7 +1961,10 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         out["pos"] = jax.lax.dynamic_update_slice(
             pool["pos"], n_prompt.astype(jnp.int32), (slot,)
         )
-        write_end = start + jnp.full((1,), T, jnp.int32)
+        # the next cache column: right after the last REAL token, so that
+        # decode leaves no dead columns behind it (a window counts columns)
+        write_end = start + jnp.full(
+            (1,), T if last_col is None else last_col + 1, jnp.int32)
         out["write"] = jax.lax.dynamic_update_slice(
             pool["write"], write_end, (slot,)
         )
@@ -1450,7 +1975,10 @@ def _kv_channels(pool: dict) -> list[tuple[str, str]]:
     """(cache key, arena key) pairs the block copies move — the int8
     scale planes ride along whenever the pool is quantized, so extract/
     insert/admit_cached stay format-agnostic."""
-    ch = [("k", "arena_k"), ("v", "arena_v")]
+    # every run's arrays; a window run's blocks are layout-exact only while
+    # its ring has not wrapped (ring index == cache column), which the HOST
+    # guarantees before it publishes or seeds a prefix (`_ContinuousServer`)
+    ch = [(n, "arena_" + n) for n in pool if _is_kv(n)]
     if pool_quantized(pool):
         ch += [("k_scale", "arena_k_scale"), ("v_scale", "arena_v_scale")]
     return ch
@@ -1472,12 +2000,11 @@ def kv_extract(pool: dict, slot: jax.Array, start: jax.Array,
             "prefixes by pinning its own blocks (paged_admit_cached)"
         )
     del cfg
-    L, _, nh, _, _ = pool["k"].shape
-    Bk = pool["arena_k"].shape[3]
     n = idxs.shape[0]
     out = dict(pool)
     for c, a in _kv_channels(pool):
-        d = pool[c].shape[-1]  # hd for payloads, 1 for scale planes
+        L, _, nh, _, d = pool[c].shape  # d: hd for payloads, 1 for scales
+        Bk = pool[a].shape[3]
         span = jax.lax.dynamic_slice(
             pool[c], (0, slot, 0, start, 0), (L, 1, nh, n * Bk, d)
         )
@@ -1500,12 +2027,11 @@ def kv_insert(pool: dict, slot: jax.Array, start: jax.Array,
             "cached prefixes by table edit (paged_admit_cached)"
         )
     del cfg
-    L, _, nh, _, _ = pool["k"].shape
-    Bk = pool["arena_k"].shape[3]
     n = idxs.shape[0]
     out = dict(pool)
     for c, a in _kv_channels(pool):
-        d = pool[c].shape[-1]
+        L, _, nh, _, d = pool[c].shape
+        Bk = pool[a].shape[3]
         span = pool[a][idxs]  # (n, L, nh, Bk, d)
         span = span.transpose(1, 2, 0, 3, 4).reshape(L, nh, n * Bk, d)
         out[c] = jax.lax.dynamic_update_slice(
@@ -1524,7 +2050,7 @@ def _block_store_channels(pool: dict) -> list[tuple[str, str]]:
         if pool_quantized(pool):
             ch += [("k_scale", "kb_scale"), ("v_scale", "vb_scale")]
         return ch
-    ch = [("k", "arena_k"), ("v", "arena_v")]
+    ch = [(n, "arena_" + n) for n in pool if _is_kv(n)]
     if pool_quantized(pool):
         ch += [("k_scale", "arena_k_scale"), ("v_scale", "arena_v_scale")]
     return ch
@@ -1590,8 +2116,8 @@ def pool_admit_cached(pool: dict, slot: jax.Array, idxs: jax.Array,
             "shared blocks copy-on-write (paged_admit_cached)"
         )
     out = kv_insert(pool, slot, jnp.int32(0), idxs, cfg)
-    C = pool["k"].shape[3]
-    Bk = pool["arena_k"].shape[3]
+    C = pool["slot_mask"].shape[1]
+    Bk = next(a.shape[3] for n, a in pool.items() if n.startswith("arena_"))
     n_cached = idxs.shape[0] * Bk
     row_mask = (jnp.arange(C)[None, :] < n_cached).astype(jnp.int32)
     out["slot_mask"] = jax.lax.dynamic_update_slice(
@@ -1635,15 +2161,17 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
         )
         return _paged_scatter(pool, view), toks
     B = pool["logits"].shape[0]
-    C = pool["k"].shape[3]
+    C = pool["slot_mask"].shape[1]
+    R, W = pool_ring(pool), cfg.sliding_window
     b_idx = jnp.arange(B)
     act_i = active.astype(jnp.int32)
     act_b = active[:, None, None]
     quant = pool_quantized(pool)
     sample = _sample_fn(temperature, top_k, top_p)
+    stacks = _kv_stacks(pool)
 
     def body(carry, _):
-        k_c, v_c, ks_c, vs_c, logits, slot_mask, pos, write, key = carry
+        kv, logits, slot_mask, pos, write, counts, key = carry
         key, sub = jax.random.split(key)
         tok = sample(logits, sub)
         w = jnp.minimum(write, C - 1)
@@ -1652,58 +2180,61 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
             active[:, None] & (jnp.arange(C)[None, :] == w[:, None]),
             1, slot_mask,
         )
-        p = jnp.minimum(pos, cfg.max_position - 1)
-        x = (_tok_embed(params, tok)[:, None, :]
-             + params["wpe"][p][:, None, :]).astype(cfg.dtype)
-        mask_bias = jnp.where(
+        p = jnp.minimum(pos, cfg.max_position - 1)[:, None]
+        x = _embed(params, tok[:, None], p, cfg)
+        bias = {"full": jnp.where(
             slot_mask[:, None, None, :] > 0, 0.0, -1e9
-        ).astype(jnp.float32)
+        ).astype(jnp.float32)}
+        col = {"full": w}
+        if R:
+            cols = _ring_cols(w, R)
+            bias["window"] = _ring_bias(cols, _live_at(slot_mask, cols), w, W)
+            col["window"] = jnp.mod(w, R)
 
-        def layer(x, inp):
-            lp, kl, vl, ksl, vsl = inp
-            k_new, v_new = _prefill_kv(x, lp, cfg)  # (B, nh, 1, hd)
+        def layer(x, lp, kvl, kind):
+            kl, vl, ksl, vsl = (kvl["k"], kvl["v"], kvl["k_scale"],
+                                kvl["v_scale"])
+            c = col[kind[0]]
+            k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B, nh, 1, hd)
             if quant:
                 k_new, sk = _kv_quant(k_new)
                 v_new, sv = _kv_quant(v_new)
-                ksl = ksl.at[b_idx, :, w, :].set(
-                    jnp.where(act_b, sk[:, :, 0, :], ksl[b_idx, :, w, :])
+                ksl = ksl.at[b_idx, :, c, :].set(
+                    jnp.where(act_b, sk[:, :, 0, :], ksl[b_idx, :, c, :])
                 )
-                vsl = vsl.at[b_idx, :, w, :].set(
-                    jnp.where(act_b, sv[:, :, 0, :], vsl[b_idx, :, w, :])
+                vsl = vsl.at[b_idx, :, c, :].set(
+                    jnp.where(act_b, sv[:, :, 0, :], vsl[b_idx, :, c, :])
                 )
             # per-ROW write position (each lane is at its own slot)
-            kl = kl.at[b_idx, :, w, :].set(
-                jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, w, :])
+            kl = kl.at[b_idx, :, c, :].set(
+                jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, c, :])
             )
-            vl = vl.at[b_idx, :, w, :].set(
-                jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, w, :])
+            vl = vl.at[b_idx, :, c, :].set(
+                jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
             )
-            x, _, _ = _block(x, lp, kl, vl, mask_bias, cfg,
-                             k_scale=ksl, v_scale=vsl)
-            return x, (kl, vl, ksl, vsl)
+            x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
+                            k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
+            return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
 
-        x, (k_c, v_c, ks_c, vs_c) = jax.lax.scan(
-            layer, x, (params["layers"], k_c, v_c, ks_c, vs_c)
-        )
+        x, kv, cnt = _scan_layers(cfg, params, x, kv, layer)
+        if cnt is not None:
+            counts = counts + cnt
         new_logits = _logits(params, x, cfg)[:, 0, :]
         logits = jnp.where(active[:, None], new_logits, logits)
-        return (k_c, v_c, ks_c, vs_c, logits, slot_mask, pos + act_i,
-                write + act_i, key), tok
+        return (kv, logits, slot_mask, pos + act_i, write + act_i, counts,
+                key), tok
 
-    (k_c, v_c, ks_c, vs_c, logits, slot_mask, pos, write, _), toks = \
+    (kv, logits, slot_mask, pos, write, counts, _), toks = \
         jax.lax.scan(
             body,
-            (pool["k"], pool["v"], pool.get("k_scale"), pool.get("v_scale"),
-             pool["logits"], pool["slot_mask"], pool["pos"], pool["write"],
-             key),
+            (stacks, pool["logits"], pool["slot_mask"], pool["pos"],
+             pool["write"], jnp.zeros((2,), jnp.uint32), key),
             None,
             length=n_steps,
         )
-    out = {**pool, "k": k_c, "v": v_c, "logits": logits,
+    out = {**pool, **kv, "logits": logits,
            "slot_mask": slot_mask, "pos": pos, "write": write}
-    if quant:
-        out["k_scale"], out["v_scale"] = ks_c, vs_c
-    return out, toks
+    return _add_counts(pool, out, counts, decode=True), toks
 
 
 def _paged_attn_fn(mesh, quant):
@@ -1812,7 +2343,7 @@ def _paged_decode_chunk_kernel(params, pool, active, key, cfg, n_steps,
             ctx = attn(
                 q[:, :, 0, :], kbl, vbl, kbsl, vbsl, tbl, slot_mask,
             )
-            x = _block_finish(x, lp, ctx[:, :, None, :], cfg)
+            x, _cnt = _block_finish(x, lp, ctx[:, :, None, :], cfg)
             return x, (kbl, vbl, kbsl, vbsl)
 
         x, (kb_c, vb_c, kbs_c, vbs_c) = jax.lax.scan(
@@ -1858,75 +2389,84 @@ def _paged_decode_chunk_kernel(params, pool, active, key, cfg, n_steps,
 # the same slot pool.
 
 
-def _draft_scan(params, cfg: DecoderConfig, kd, vd, ksd, vsd, slot_mask,
-                pos, w, t0, active, n_draft: int):
-    """``n_draft`` greedy draft steps over a DEPTH-PREFIX KV stack.
+def _draft_scan(params, cfg: DecoderConfig, kv: dict, slot_mask,
+                pos, w, t0, active, n_draft: int, n_layers: int):
+    """``n_draft`` greedy draft steps with the first ``n_layers`` layers.
 
-    ``kd``/``vd`` carry the first D layers' caches only (D = their
-    leading dim); ``ksd``/``vsd`` are the matching scale planes (None
-    when unquantized). Starting from certain token ``t0`` at cache
+    ``kv`` carries the pool's KV stacks (``k``/``v``, scale planes,
+    ``kw``/``vw``); only the depth-prefix the draft runs is read and
+    written, in a LOCAL copy. Starting from certain token ``t0`` at cache
     column ``w`` / position ``pos``, each step writes the fed token's
-    shallow KV at its column and predicts the next via the final LN +
-    tied head over the truncated stack. Returns ``(drafts (B, n_draft),
-    kd, vd, ksd, vsd)`` — the drafted continuation d_1..d_k and the
-    updated depth-prefix (callers fusing a verify pass discard it: the
+    shallow KV at its column and predicts the next via the final norm +
+    the head over the truncated stack. Returns ``drafts (B, n_draft)``,
+    the drafted continuation d_1..d_k (the shallow KV is discarded: the
     verify rewrites those columns for ALL layers)."""
-    D = kd.shape[0]
-    layers_d = jax.tree.map(lambda a: a[:D], params["layers"])
-    B, C = t0.shape[0], kd.shape[3]
+    B, C = t0.shape[0], slot_mask.shape[1]
+    R, W = pool_ring(kv), cfg.sliding_window
     b_idx = jnp.arange(B)
     act_b = active[:, None, None]
     idxs = jnp.arange(C)[None, :]
-    quant = ksd is not None
+    quant = kv.get("k_scale") is not None
 
     def step(carry, j):
-        kd, vd, ksd, vsd, tok = carry
+        kv, tok = carry
         col = jnp.minimum(w + j, C - 1)
-        p = jnp.clip(pos + j, 0, cfg.max_position - 1)
-        x = (_tok_embed(params, tok)[:, None, :]
-             + params["wpe"][p][:, None, :]).astype(cfg.dtype)
+        p = jnp.clip(pos + j, 0, cfg.max_position - 1)[:, None]
+        x = _embed(params, tok[:, None], p, cfg)
         # attend the live cache plus every column this cycle already
         # wrote (w..col) — the draft's own freshly-drafted context
         allowed = (slot_mask > 0) | ((idxs >= w[:, None])
                                      & (idxs <= col[:, None]))
-        mask_bias = jnp.where(allowed, 0.0, -1e9
-                              ).astype(jnp.float32)[:, None, None, :]
+        bias = {"full": jnp.where(allowed, 0.0, -1e9
+                                  ).astype(jnp.float32)[:, None, None, :]}
+        at = {"full": col}
+        if R:
+            cols = _ring_cols(col, R)
+            live = _live_at(slot_mask, cols) | (cols >= w[:, None])
+            bias["window"] = _ring_bias(cols, live, col, W)
+            at["window"] = jnp.mod(col, R)
 
-        def layer(x, inp):
-            lp, kl, vl, ksl, vsl = inp
-            k_new, v_new = _prefill_kv(x, lp, cfg)  # (B, nh, 1, hd)
+        def layer(x, lp, kvl, kind):
+            kl, vl, ksl, vsl = (kvl["k"], kvl["v"], kvl["k_scale"],
+                                kvl["v_scale"])
+            c = at[kind[0]]
+            k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B, nh, 1, hd)
             if quant:
                 k_new, sk = _kv_quant(k_new)
                 v_new, sv = _kv_quant(v_new)
-                ksl = ksl.at[b_idx, :, col, :].set(
+                ksl = ksl.at[b_idx, :, c, :].set(
                     jnp.where(act_b, sk[:, :, 0, :],
-                              ksl[b_idx, :, col, :])
+                              ksl[b_idx, :, c, :])
                 )
-                vsl = vsl.at[b_idx, :, col, :].set(
+                vsl = vsl.at[b_idx, :, c, :].set(
                     jnp.where(act_b, sv[:, :, 0, :],
-                              vsl[b_idx, :, col, :])
+                              vsl[b_idx, :, c, :])
                 )
-            kl = kl.at[b_idx, :, col, :].set(
-                jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, col, :])
+            kl = kl.at[b_idx, :, c, :].set(
+                jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, c, :])
             )
-            vl = vl.at[b_idx, :, col, :].set(
-                jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, col, :])
+            vl = vl.at[b_idx, :, c, :].set(
+                jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
             )
-            x, _, _ = _block(x, lp, kl, vl, mask_bias, cfg,
-                             k_scale=ksl, v_scale=vsl)
-            return x, (kl, vl, ksl, vsl)
+            x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
+                            k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
+            return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
 
-        x, (kd, vd, ksd, vsd) = jax.lax.scan(
-            layer, x, (layers_d, kd, vd, ksd, vsd)
-        )
+        x, kv, _cnt = _scan_layers(cfg, params, x, kv, layer, n_layers)
         nxt = jnp.argmax(_logits(params, x, cfg)[:, 0, :], axis=-1
                          ).astype(jnp.int32)
-        return (kd, vd, ksd, vsd, nxt), nxt
+        return (kv, nxt), nxt
 
-    (kd, vd, ksd, vsd, _), drafts = jax.lax.scan(
-        step, (kd, vd, ksd, vsd, t0), jnp.arange(n_draft)
+    # only the depth-prefix is carried: the draft never touches the rest
+    prefix = {}
+    for r, (kind, _first, n) in enumerate(cfg.runs(n_layers)):
+        for name in _kv_names(cfg, r, kind):
+            if name and kv.get(name) is not None:
+                prefix[name] = kv[name][:n]
+    (_kv, _), drafts = jax.lax.scan(
+        step, (prefix, t0), jnp.arange(n_draft)
     )
-    return drafts.T, kd, vd, ksd, vsd  # drafts (B, n_draft)
+    return drafts.T  # (B, n_draft)
 
 
 def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
@@ -1945,18 +2485,13 @@ def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
             params, _paged_gather(pool), active, cfg,
             draft_layers=draft_layers, n_draft=n_draft,
         )
-    C = pool["k"].shape[3]
-    D = draft_layers
-    quant = pool_quantized(pool)
+    C = pool["slot_mask"].shape[1]
     t0 = jnp.argmax(pool["logits"], axis=-1).astype(jnp.int32)
     w = jnp.minimum(pool["write"], C - n_draft)
-    drafts, *_ = _draft_scan(
-        params, cfg, pool["k"][:D], pool["v"][:D],
-        pool["k_scale"][:D] if quant else None,
-        pool["v_scale"][:D] if quant else None,
-        pool["slot_mask"], pool["pos"], w, t0, active, n_draft,
+    return _draft_scan(
+        params, cfg, _kv_stacks(pool), pool["slot_mask"], pool["pos"], w,
+        t0, active, n_draft, draft_layers,
     )
-    return drafts
 
 
 def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
@@ -1981,7 +2516,9 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
     correction token — it becomes the next cycle's certain t0), and the
     rejected tail's columns simply stay masked out of ``slot_mask`` —
     the rewind is a mask, not a copy; the next cycle's verify overwrites
-    them. Inactive lanes compute but do not advance.
+    them. A window layer's ring takes the rejected tail too: it lands on
+    columns that have left every later query's window (the ring's slack
+    is at least ``n_spec``). Inactive lanes compute but do not advance.
 
     Returns ``(pool, toks (n_cycles, n_slots, n_spec + 1), n_emit
     (n_cycles, n_slots))``: the host consumes each cycle's first
@@ -1997,7 +2534,12 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
         )
         return _paged_scatter(pool, view), toks, n_emit
     B = pool["logits"].shape[0]
-    C = pool["k"].shape[3]
+    C = pool["slot_mask"].shape[1]
+    R, W = pool_ring(pool), cfg.sliding_window
+    if R and R < C and R < W + n_spec:
+        raise ValueError(
+            f"a window layer's ring of {R} rows has no room for "
+            f"{n_spec} speculated columns past the window of {W}")
     D, k = draft_layers, n_spec
     quant = pool_quantized(pool)
     b_idx = jnp.arange(B)
@@ -2006,20 +2548,18 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
     act_bt = active[:, None, None, None]
 
     def cycle(carry, _):
-        k_c, v_c, ks_c, vs_c, logits, slot_mask, pos, write = carry
+        kv, logits, slot_mask, pos, write, counts = carry
         # verify writes k+1 columns; clamp like pool_decode_chunk's w so
         # an over-budget lane (tokens still draining) never writes past
         # the cache — the host sizes slack so live lanes never clamp
         w = jnp.minimum(write, C - 1 - k)
         t0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        drafts, *_ = _draft_scan(
-            params, cfg, k_c[:D], v_c[:D],
-            ks_c[:D] if quant else None, vs_c[:D] if quant else None,
-            slot_mask, pos, w, t0, active, k,
+        drafts = _draft_scan(
+            params, cfg, kv, slot_mask, pos, w, t0, active, k, D,
         )
         u = jnp.concatenate([t0[:, None], drafts], axis=1)  # (B, k+1)
         p = jnp.clip(pos[:, None] + offs[None, :], 0, cfg.max_position - 1)
-        x = (_tok_embed(params, u) + params["wpe"][p]).astype(cfg.dtype)
+        x = _embed(params, u, p, cfg)
         qcol = w[:, None] + offs[None, :]  # (B, k+1) per-query column
         # query i attends the live cache plus this cycle's columns up to
         # its own (w..w+i) — causal within the speculated window, the
@@ -2028,42 +2568,52 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
             (idxs[None, None, :] >= w[:, None, None])
             & (idxs[None, None, :] <= qcol[:, :, None])
         )
-        mask_bias = jnp.where(allowed, 0.0, -1e9
-                              ).astype(jnp.float32)[:, None, :, :]
+        bias = {"full": jnp.where(allowed, 0.0, -1e9
+                                  ).astype(jnp.float32)[:, None, :, :]}
+        at = {"full": qcol}
+        if R:
+            cols = _ring_cols(w + k, R)                     # (B, R)
+            live = (_live_at(slot_mask, cols) | (cols >= w[:, None])
+                    )[:, None, :]
+            bias["window"] = _ring_bias(
+                cols, jnp.broadcast_to(live, (B, k + 1, R)), qcol, W)
+            at["window"] = jnp.mod(qcol, R)
 
-        def vlayer(x, inp):
-            lp, kl, vl, ksl, vsl = inp
-            k_new, v_new = _prefill_kv(x, lp, cfg)  # (B, nh, k+1, hd)
+        def vlayer(x, lp, kvl, kind):
+            kl, vl, ksl, vsl = (kvl["k"], kvl["v"], kvl["k_scale"],
+                                kvl["v_scale"])
+            c = at[kind[0]]
+            k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B,nh,k+1,hd)
             kt = k_new.transpose(0, 2, 1, 3)  # (B, k+1, nh, hd)
             vt = v_new.transpose(0, 2, 1, 3)
             if quant:
                 kt, skt = _kv_quant(kt)
                 vt, svt = _kv_quant(vt)
-                ksl = ksl.at[b_idx[:, None], :, qcol, :].set(
+                ksl = ksl.at[b_idx[:, None], :, c, :].set(
                     jnp.where(act_bt, skt,
-                              ksl[b_idx[:, None], :, qcol, :])
+                              ksl[b_idx[:, None], :, c, :])
                 )
-                vsl = vsl.at[b_idx[:, None], :, qcol, :].set(
+                vsl = vsl.at[b_idx[:, None], :, c, :].set(
                     jnp.where(act_bt, svt,
-                              vsl[b_idx[:, None], :, qcol, :])
+                              vsl[b_idx[:, None], :, c, :])
                 )
             # advanced indexing (b, col) pairs land each row's k+1 new
             # entries at ITS columns; inactive lanes keep their bytes
-            kl = kl.at[b_idx[:, None], :, qcol, :].set(
+            kl = kl.at[b_idx[:, None], :, c, :].set(
                 jnp.where(act_bt, kt.astype(kl.dtype),
-                          kl[b_idx[:, None], :, qcol, :])
+                          kl[b_idx[:, None], :, c, :])
             )
-            vl = vl.at[b_idx[:, None], :, qcol, :].set(
+            vl = vl.at[b_idx[:, None], :, c, :].set(
                 jnp.where(act_bt, vt.astype(vl.dtype),
-                          vl[b_idx[:, None], :, qcol, :])
+                          vl[b_idx[:, None], :, c, :])
             )
-            x, _, _ = _block(x, lp, kl, vl, mask_bias, cfg,
-                             k_scale=ksl, v_scale=vsl)
-            return x, (kl, vl, ksl, vsl)
+            x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
+                            k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
+            return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
 
-        x, (k_c, v_c, ks_c, vs_c) = jax.lax.scan(
-            vlayer, x, (params["layers"], k_c, v_c, ks_c, vs_c)
-        )
+        x, kv, cnt = _scan_layers(cfg, params, x, kv, vlayer)
+        if cnt is not None:
+            counts = counts + cnt
         out_logits = _logits(params, x, cfg)  # (B, k+1, V) f32
         g = jnp.argmax(out_logits, axis=-1).astype(jnp.int32)  # (B, k+1)
         # g[:, i] is the TRUE next token after u_0..u_i; accept drafts
@@ -2084,19 +2634,16 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
                 & (idxs[None, :] <= (w + acc)[:, None])
                 & active[:, None])
         slot_mask = jnp.where(live, 1, slot_mask)
-        return (k_c, v_c, ks_c, vs_c, logits, slot_mask,
-                pos + n_emit, write + n_emit), (u, n_emit)
+        return (kv, logits, slot_mask, pos + n_emit, write + n_emit,
+                counts), (u, n_emit)
 
-    carry0 = (pool["k"], pool["v"], pool.get("k_scale"),
-              pool.get("v_scale"), pool["logits"], pool["slot_mask"],
-              pool["pos"], pool["write"])
-    (k_c, v_c, ks_c, vs_c, logits, slot_mask, pos, write), (toks, n_emit) = \
+    carry0 = (_kv_stacks(pool), pool["logits"], pool["slot_mask"],
+              pool["pos"], pool["write"], jnp.zeros((2,), jnp.uint32))
+    (kv, logits, slot_mask, pos, write, counts), (toks, n_emit) = \
         jax.lax.scan(cycle, carry0, None, length=n_cycles)
-    out = {**pool, "k": k_c, "v": v_c, "logits": logits,
+    out = {**pool, **kv, "logits": logits,
            "slot_mask": slot_mask, "pos": pos, "write": write}
-    if quant:
-        out["k_scale"], out["v_scale"] = ks_c, vs_c
-    return out, toks, n_emit
+    return _add_counts(pool, out, counts, decode=True), toks, n_emit
 
 
 def cast_params_for_inference(params: dict, cfg: DecoderConfig) -> dict:
@@ -2113,11 +2660,7 @@ def cast_params_for_inference(params: dict, cfg: DecoderConfig) -> dict:
     if cfg.dtype == jnp.float32:
         return params
 
-    _LN_LEAVES = frozenset(
-        f"{ln}_{leaf}"
-        for ln in ("ln1", "ln2", "ln_f")
-        for leaf in ("scale", "bias")
-    )
+    _LN_LEAVES = _F32_LEAVES
 
     def cast(path, p):
         # exact leaf names, not an "ln" substring test — a future matmul

@@ -72,6 +72,18 @@ def decode_serve_error(text: Any) -> dict | None:
         return {"reason": "serve_failed"}
 
 
+def _answer_key(ids, max_new: int) -> bytes:
+    """What names one greedy answer: a digest of the prompt's ids and the
+    token budget (a prompt is thousands of ids; the key is 16 bytes)."""
+    import hashlib
+
+    import numpy as np
+
+    h = hashlib.blake2b(np.asarray(ids, np.int64).tobytes(), digest_size=16)
+    h.update(int(max_new).to_bytes(8, "little"))
+    return h.digest()
+
+
 class BaseChat(pw.UDF):
     """Base chat UDF (reference ``BaseChat``, llms.py:27)."""
 
@@ -355,6 +367,8 @@ class TPUDecoderChat(BaseChat):
 
             cfg = dataclasses.replace(cfg, wq_kernel=True)
         if self.weight_quant:
+            # raises UnsupportedForLayout("weight_quant") for a block
+            # other than GPT-2's: no silent fall back to full precision
             self.params = jax.device_put(quantize_params(params, cfg))
         else:
             # compute-dtype weights: the decode phase reads the full
@@ -392,6 +406,20 @@ class TPUDecoderChat(BaseChat):
         # (rows, prompt_len, max_new, temperature, top_k, top_p) -> jitted
         # generate executable
         self._jitted: dict[tuple, Any] = {}
+        # answers a greedy server has just given, by a digest of (prompt
+        # ids, budget): the engine's deferred two-phase path RE-DERIVES a
+        # deterministic UDF's value when the row is retracted (a REST
+        # request's row is, in the epoch after its reply), and the value
+        # is known — without this every answer was admitted, prefilled and
+        # decoded a second time (slot admissions over replies read 2.0).
+        # It lives a few rounds of the slots, as the retraction does; the
+        # submitting and resolving threads share it under a lock.
+        from collections import OrderedDict
+        import threading
+
+        self._answered: OrderedDict = OrderedDict()
+        self._answered_lock = threading.Lock()
+        self._answered_cap = max(64, 4 * int(n_slots))
         self._server: _ContinuousServer | None = None
         if continuous:
             self._server = _ContinuousServer(
@@ -479,6 +507,17 @@ class TPUDecoderChat(BaseChat):
         reqs = []
         for m in messages:
             ids = self.tokenizer.encode(self._format_prompt(m))[-prompt_cap:]
+            known = None
+            if self.deterministic:
+                with self._answered_lock:
+                    known = self._answered.get(_answer_key(ids, max_new))
+            if known is not None:
+                # the same greedy answer again (a re-derivation): known
+                req = _PendingCompletion(ids, max_new)
+                req.text = known
+                req.done.set()
+                reqs.append(req)
+                continue
             reqs.append(self._server.submit(
                 ids, max_new, priority=priority, tenant=tenant,
             ))
@@ -499,6 +538,12 @@ class TPUDecoderChat(BaseChat):
                     ))
                 else:
                     texts.append(req.text)
+                    if self.deterministic:
+                        with self._answered_lock:
+                            self._answered[
+                                _answer_key(req.ids, req.max_new)] = req.text
+                            while len(self._answered) > self._answered_cap:
+                                self._answered.popitem(last=False)
             out.append(texts)
         return out
 
@@ -1002,7 +1047,7 @@ class _ContinuousServer:
                 cfg.head_dim + 4 if self.kv_quant
                 else cfg.head_dim * itemsize
             )
-            block_bytes = 2 * cfg.layers * cfg.heads * blk * per_tok
+            block_bytes = 2 * cfg.layers * cfg.n_kv * blk * per_tok
             n_blocks = int(mb * (1 << 20) // block_bytes)
             if n_blocks >= 1:
                 self.prefix_block = blk
@@ -1050,7 +1095,7 @@ class _ContinuousServer:
             else cfg.head_dim * _np_mod.dtype(cfg.dtype).itemsize
         )
         self._block_kv_bytes = (
-            2 * cfg.layers * cfg.heads * self.paged_block * per_tok_kv
+            2 * cfg.layers * cfg.n_kv * self.paged_block * per_tok_kv
             if self.paged_kv else 0
         )
         # autotune candidates: halvings of the constructor's chunk_steps
@@ -1082,6 +1127,23 @@ class _ContinuousServer:
         # is carried for stats/traces only — the format marker on the
         # pytree itself (``wte_scale``) is what the forward paths read
         self.weight_quant = weight_quant
+        if not decoder_mod.gpt2_block(cfg):
+            # what this configuration's layers cannot ride yet refuses by
+            # TYPE, here, naming the mechanism: never a silent fallback to
+            # another path. (Default-off, every one; self-speculative
+            # decoding, chunked prefill, batched admission and the prefix
+            # cache are written for every layout.)
+            for mechanism, on in (
+                ("paged_kv", self.paged_kv),
+                ("paged_kernel", self.paged_kernel),
+                ("flash_prefill", self.flash_prefill),
+                ("kv_quant", bool(self.kv_quant)),
+                ("weight_quant", bool(weight_quant)),
+                ("disagg", self.disagg),
+                ("mesh", self.mesh is not None),
+            ):
+                if on:
+                    decoder_mod.require_gpt2_block(cfg, mechanism)
         if self.mesh is not None:
             self.params = decoder_mod.shard_decoder_params(
                 self.params, cfg, self.mesh
@@ -1192,17 +1254,102 @@ class _ContinuousServer:
             "shed": 0, "leaked_thread": 0, "paged_oom": 0,
             "preemptions": 0, "kv_migrated_blocks": 0,
             "t2_hit_requests": 0, "t2_promoted_blocks": 0,
+            "prefix_declined": 0,
         }
+        self._moe_seen = None       # the device's (phase, held|all) totals
+        self._has_moe = cfg.moe is not None
         # in-flight chunk records, oldest first; an attribute (not a loop
         # local) so the failure sweep can fail eagerly-freed requests
         # whose tokens never drained
         self._inflight: deque = deque()
         # tags this server's request spans in the global trace ring
         self._trace_tag = f"decode:{id(self):x}"
+        self._warm_start()
         self.thread = threading.Thread(
             target=self._run_safe, daemon=True, name="pathway:decoder-serve"
         )
         self.thread.start()
+
+    def _warm_start(self) -> None:
+        """Compile, before the loop starts, every executable the loop can
+        dispatch for this configuration, by running each once over idle
+        lanes: one-shot admits for every prompt bucket up to the prefill
+        chunk (each batch size batched admission can form), the chunked
+        prefill pieces (first, middle, last; the cached path's too), the
+        prefix cache's copies, every decode-chunk step count the autotuner
+        can pick and every speculative cycle count those map to. Nothing
+        then compiles under traffic. The prompt buckets are the powers of
+        two up to ``max_prompt_tokens``'s. The pool is rebuilt afterwards:
+        the warm-up leaves no trace in it."""
+        import time as time_mod
+
+        import jax
+        import numpy as np
+
+        t0 = time_mod.perf_counter()
+        P = self.prefill_chunk
+        buckets, b = [], 8
+        while b <= self.max_prompt_bucket:
+            buckets.append(b)
+            b *= 2
+        chunked = [b for b in buckets if self.chunked_prefill and b > P]
+        direct = [b for b in buckets if b not in chunked]
+        slot0 = np.int32(0)
+        for s in direct:
+            ids = np.zeros((1, s), np.int32)
+            mask = np.zeros((1, s), np.int32)
+            mask[0, -1] = 1
+            self.pool = self._admit_fn(s)(
+                self.params, ids, mask, self.pool, slot0)
+            m = 2
+            while self.batch_admit and m <= self.n_slots:
+                self.pool = self._admit_batch_fn(m, s)(
+                    self.params, np.repeat(ids, m, 0), np.repeat(mask, m, 0),
+                    self.pool, np.arange(m, dtype=np.int32))
+                m *= 2
+        if chunked or (self.prefix is not None and buckets
+                       and buckets[-1] > self.prefix_block):
+            ids = np.zeros((1, P), np.int32)
+            mask = np.ones((1, P), np.int32)
+            pos = np.arange(P, dtype=np.int32)[None, :]
+            n_prompt = np.asarray([P], np.int32)
+            variants = [(True, False, False), (False, False, False),
+                        (False, True, False)]
+            if self.prefix is not None:
+                variants.append((False, True, True))
+            for first, last, with_col in variants:
+                args = (self.params, ids, mask, pos, self.pool, slot0,
+                        np.int32(0), n_prompt)
+                if with_col:
+                    args += (np.int32(0),)
+                self.pool = self._prefill_fn(P, first, last, with_col)(*args)
+        if self.prefix is not None and not self.paged_kv and buckets:
+            B = self.prefix_block
+            most = min(buckets[-1] // B, self.prefix.capacity_blocks)
+            ring = self._D.pool_ring(self.pool)
+            if ring:
+                most = min(most, ring // B)
+            for n in range(1, most + 1):
+                idxs = np.zeros((n,), np.int32)
+                self.pool = self._extract_fn(n)(
+                    self.pool, slot0, np.int32(0), idxs)
+                self.pool = self._admit_cached_fn(n)(self.pool, slot0, idxs)
+        idle = np.zeros(self.n_slots, dtype=bool)
+        cycles = set()
+        for steps in self._step_cands:
+            out = self._chunk_fn_for(steps)(
+                self.params, self.pool, idle, self._key)
+            self.pool = out[0]
+            cycles.add(max(1, steps // (self.spec_k + 1)))
+        if self.spec_decode:
+            for n_cycles in sorted(cycles):
+                out = self._spec_fn_for(n_cycles)(
+                    self.params, self.pool, idle)
+                self.pool = out[0]
+        jax.block_until_ready(self.pool)
+        self.pool = None        # free it before the fresh one is built
+        self.pool = self._build_pool()
+        self.warm_seconds = time_mod.perf_counter() - t0
 
     def recent_traces(self, n: int | None = None) -> list[dict]:
         """Completed per-request spans of THIS server (oldest first),
@@ -1245,6 +1392,9 @@ class _ContinuousServer:
                 ),
                 arena_block=self.prefix_block,
                 kv_quant=bool(self.kv_quant),
+                # a window layer's ring: room past the window for what one
+                # dispatch writes ahead of the committed cursor
+                window_slack=max(256, self.spec_k + 1),
             )
         # commit the pool onto the serving mesh (head axis over tp) —
         # no-op off-mesh; the supervised restart path lands here too,
@@ -1544,7 +1694,7 @@ class _ContinuousServer:
                tenant: str = "default") -> _PendingCompletion:
         import time as time_mod
 
-        from pathway_tpu.engine import tracing
+        from pathway_tpu.engine import probes, tracing
 
         req = _PendingCompletion(prompt_ids, max_new)
         req.priority = int(priority)
@@ -1554,6 +1704,7 @@ class _ContinuousServer:
             prompt_tokens=len(prompt_ids), max_new=max_new,
             tenant=req.tenant,
         )
+        req.span.event("submit")
         now = time_mod.perf_counter()
         if self._deadline_s > 0:
             # monotonic, matching the loop's queue sweep clock
@@ -1667,12 +1818,17 @@ class _ContinuousServer:
             temp, tk, tp = self._temperature, self._top_k, self._top_p
             pk, msh = self.paged_kernel, self.mesh
 
+            moe = self._has_moe
+
             def chunk(params_, pool, active, key):
-                return D.pool_decode_chunk(
+                pool, toks = D.pool_decode_chunk(
                     params_, pool, active, key, cfgc, steps,
                     temperature=temp, top_k=tk, top_p=tp,
                     paged_kernel=pk, mesh=msh,
                 )
+                # the expert counters ride out with the tokens (the pool
+                # itself is donated to the next dispatch): no extra sync
+                return pool, toks, (pool["moe_counts"] + 0 if moe else None)
 
             fn = jax.jit(chunk, donate_argnums=(1,))
             self._chunk_fns[steps] = fn
@@ -1686,11 +1842,15 @@ class _ContinuousServer:
             D, cfgc = self._D, self.cfg
             dl, kk = self.spec_draft_layers, self.spec_k
 
+            moe = self._has_moe
+
             def spec(params_, pool, active):
-                return D.pool_decode_spec(
+                pool, toks, n_emit = D.pool_decode_spec(
                     params_, pool, active, cfgc, n_cycles,
                     draft_layers=dl, n_spec=kk,
                 )
+                return pool, toks, n_emit, (
+                    pool["moe_counts"] + 0 if moe else None)
 
             fn = jax.jit(spec, donate_argnums=(1,))
             self._spec_fns[n_cycles] = fn
@@ -1792,12 +1952,31 @@ class _ContinuousServer:
             self._extract_fns[n] = fn
         return fn
 
-    def _prefix_insert(self, slot: int, req, e: list, base: int) -> None:
+    def _prefix_insert(self, slot: int, req, e: list, base: int,
+                       written: int = 0) -> None:
         """Publish ``slot``'s freshly-prefilled full blocks of prompt
         ``e`` into the radix tree + arena. ``base`` is the cache column
         of token 0 (``s - n`` for a left-padded miss admission, 0 for
-        the right-padded cached path). Moves the request's ref to the
-        deepest node so the whole prefix stays pinned while it decodes."""
+        the right-padded cached path); ``written`` how many cache columns
+        the admission wrote (its padded extent). Moves the request's ref
+        to the deepest node so the whole prefix stays pinned while it
+        decodes."""
+        import numpy as np
+
+        from pathway_tpu.engine import probes, tracing
+
+        ring = self._D.pool_ring(self.pool)
+        if ring and written > ring:
+            # a window layer's ring has wrapped: the prompt's early rows
+            # are gone from it, so its blocks cannot be published whole.
+            # Decline by what the server can see; never a wrong hit later
+            self.stats["prefix_declined"] += 1
+            return
+        with tracing.region("pw.decode.prefix_insert", slot=int(slot),
+                            tokens=len(e)):
+            self._prefix_publish(slot, req, e, base)
+
+    def _prefix_publish(self, slot: int, req, e: list, base: int) -> None:
         import numpy as np
 
         from pathway_tpu.engine import probes
@@ -1921,15 +2100,15 @@ class _ContinuousServer:
 
         import jax
 
-        from pathway_tpu.engine.probes import record_stage
+        from pathway_tpu.engine import tracing
 
         tokens, j, keys, blobs = item
         try:
-            t0 = time_mod.perf_counter()
-            staged = {c: jax.device_put(v) for c, v in blobs.items()}
-            for v in staged.values():
-                v.block_until_ready()
-            record_stage("h2d", time_mod.perf_counter() - t0, len(keys))
+            with tracing.region("pw.decode.h2d", stage="h2d",
+                                items=len(keys), blocks=len(keys)):
+                staged = {c: jax.device_put(v) for c, v in blobs.items()}
+                for v in staged.values():
+                    v.block_until_ready()
             self._promote_ready.append((tokens, j, keys, staged))
         except Exception:  # noqa: BLE001 - drop the hit, keep serving
             with self.lock:
@@ -2192,6 +2371,7 @@ class _ContinuousServer:
         here to this request alone."""
         import numpy as np
 
+        from pathway_tpu.engine import probes
         from pathway_tpu.engine.probes import record_prefix
         from pathway_tpu.ops import next_pow2
 
@@ -2279,7 +2459,7 @@ class _ContinuousServer:
             lc = (n - 1) - (W - P)
             meta = {
                 "last_col": None if lc == P - 1 else lc,
-                "insert": (req, e, 0),
+                "insert": (req, e, 0, n),
             }
             self._pending_prefill[slot] = (pieces, n_prompt, meta)
             self.stats["admitted"] += 1
@@ -2298,7 +2478,7 @@ class _ContinuousServer:
             mask[0, -1] = 1
         if ins is not None:
             # left-padded admission: token 0 sits at column s-n
-            ins = (req, e, s - n)
+            ins = (req, e, s - n, s)
         if self.chunked_prefill and s > self.prefill_chunk:
             # split into fixed-size pieces, dispatched ONE per
             # loop tick below — the active lanes keep decoding
@@ -2309,11 +2489,16 @@ class _ContinuousServer:
             )[None, :].astype(np.int32)
             n_prompt = np.asarray([int(mask.sum())], np.int32)
             P = self.prefill_chunk
+            # a piece that lies wholly in the left padding has nothing to
+            # compute or to write: the first piece with a real token opens
+            # the row (`first` clears the slot's stale mask) in its place
             pieces = [
                 (ids[:, o:o + P], mask[:, o:o + P], pos[:, o:o + P], o)
-                for o in range(0, s, P)
+                for o in range(0, s, P) if mask[0, o:o + P].any()
             ]
-            meta = {"insert": ins} if ins is not None else None
+            meta = {"first_off": pieces[0][3]}
+            if ins is not None:
+                meta["insert"] = ins
             self._pending_prefill[slot] = (pieces, n_prompt, meta)
         else:
             direct.append((slot, ids, mask, s))
@@ -2365,7 +2550,7 @@ class _ContinuousServer:
         lc = (n - 1) - (W - P)
         meta = {"last_col": None if lc == P - 1 else lc}
         if self.prefix is not None and n >= B:
-            meta["insert"] = (req, e, 0)
+            meta["insert"] = (req, e, 0, 0)
         self._pending_prefill[slot] = (pieces, n_prompt, meta)
         self.stats["admitted"] += 1
         self._update_fragmentation()
@@ -2482,7 +2667,7 @@ class _ContinuousServer:
         lc = (n - 1) - (W - P)
         meta = {"last_col": None if lc == P - 1 else lc}
         if self.prefix is not None and n >= B:
-            meta["insert"] = (req, e, 0)
+            meta["insert"] = (req, e, 0, 0)
         self._pending_prefill[slot] = (pieces, n_prompt, meta)
         self.stats["admitted"] += 1
         self._update_fragmentation()
@@ -2492,23 +2677,28 @@ class _ContinuousServer:
         supervised serving can rewind just this slot on a fault)."""
         import numpy as np
 
+        from pathway_tpu.engine import tracing
+
         pieces, n_prompt, meta = self._pending_prefill[slot]
         p_ids, p_mask, p_pos, off = pieces.pop(0)
-        first, last = off == 0, not pieces
+        first = off == (meta.get("first_off", 0) if meta else 0)
+        last = not pieces
         lc = meta.get("last_col") if (meta and last) else None
-        if lc is None:
-            self.pool = self._prefill_fn(p_ids.shape[1], first, last)(
-                self.params, p_ids, p_mask, p_pos, self.pool,
-                np.int32(slot), np.int32(off), n_prompt,
-            )
-        else:
-            self.pool = self._prefill_fn(
-                p_ids.shape[1], first, last, True
-            )(
-                self.params, p_ids, p_mask, p_pos, self.pool,
-                np.int32(slot), np.int32(off), n_prompt,
-                np.int32(lc),
-            )
+        with tracing.region("pw.decode.prefill", tokens=int(p_ids.shape[1]),
+                            piece=int(off // p_ids.shape[1])):
+            if lc is None:
+                self.pool = self._prefill_fn(p_ids.shape[1], first, last)(
+                    self.params, p_ids, p_mask, p_pos, self.pool,
+                    np.int32(slot), np.int32(off), n_prompt,
+                )
+            else:
+                self.pool = self._prefill_fn(
+                    p_ids.shape[1], first, last, True
+                )(
+                    self.params, p_ids, p_mask, p_pos, self.pool,
+                    np.int32(slot), np.int32(off), n_prompt,
+                    np.int32(lc),
+                )
         self.stats["prefill_chunks"] += 1
         self._record_attn("chunk", int(p_ids.shape[1]), self.cache_len,
                           cached_kv=True)
@@ -2542,8 +2732,8 @@ class _ContinuousServer:
                 if req_p is not None:
                     req_p.span.event("migrate", blocks=int(nb))
             if meta and meta.get("insert") is not None:
-                req_i, e_i, base_i = meta["insert"]
-                self._prefix_insert(slot, req_i, e_i, base_i)
+                req_i, e_i, base_i, wrote_i = meta["insert"]
+                self._prefix_insert(slot, req_i, e_i, base_i, wrote_i)
 
     def _loop(self):
         import time as time_mod
@@ -2551,7 +2741,7 @@ class _ContinuousServer:
         import jax
         import numpy as np
 
-        from pathway_tpu.engine import probes
+        from pathway_tpu.engine import probes, tracing
         from pathway_tpu.engine.probes import record_spec, record_spec_many
 
         active = np.zeros(self.n_slots, dtype=bool)
@@ -2595,9 +2785,11 @@ class _ContinuousServer:
                 # and the autotuner account in CYCLES here
                 n_cycles = max(1, steps // (self.spec_k + 1))
                 self._last_dispatch_steps = n_cycles
-                self.pool, toks_dev, emit_dev = self._spec_fn_for(
-                    n_cycles
-                )(self.params, self.pool, lanes)
+                with tracing.region("pw.decode.chunk", steps=n_cycles,
+                                    lanes=int(lanes.sum()), spec=1):
+                    self.pool, toks_dev, emit_dev, counts_dev = \
+                        self._spec_fn_for(n_cycles)(
+                            self.params, self.pool, lanes)
                 payload = (toks_dev, emit_dev)
                 lane_steps = n_cycles
                 self.stats["spec_dispatches"] += 1
@@ -2605,9 +2797,10 @@ class _ContinuousServer:
             else:
                 self._last_dispatch_steps = steps
                 key = jax.random.fold_in(self._key, self._ticks)
-                self.pool, toks_dev = self._chunk_fn_for(steps)(
-                    self.params, self.pool, lanes, key
-                )
+                with tracing.region("pw.decode.chunk", steps=steps,
+                                    lanes=int(lanes.sum()), spec=0):
+                    self.pool, toks_dev, counts_dev = self._chunk_fn_for(
+                        steps)(self.params, self.pool, lanes, key)
                 payload = toks_dev
                 emit_dev = None
                 lane_steps = steps
@@ -2618,6 +2811,8 @@ class _ContinuousServer:
                 toks_dev.copy_to_host_async()
                 if emit_dev is not None:
                     emit_dev.copy_to_host_async()
+                if counts_dev is not None:
+                    counts_dev.copy_to_host_async()
             except Exception:  # noqa: BLE001 - platform-optional
                 pass
             self.stats["chunks"] += 1
@@ -2648,7 +2843,7 @@ class _ContinuousServer:
             # snapshot WHICH request each lane served: by the time
             # these tokens drain the slot may have been freed and
             # re-admitted to a different request
-            inflight.append((payload, lanes, list(self.slots)))
+            inflight.append((payload, lanes, list(self.slots), counts_dev))
             for slot in np.nonzero(active)[0]:
                 req = self.slots[slot]
                 if req is None:
@@ -2802,7 +2997,9 @@ class _ContinuousServer:
                         # bookkeeping is torn, so supervision rewinds the
                         # one slot instead of restarting the loop
                         self._chaos_admit.maybe_fail()
-                    self._admit_one(slot, req, direct, direct_inserts)
+                    with tracing.region("pw.decode.admit", slot=int(slot),
+                                        tokens=len(req.ids)):
+                        self._admit_one(slot, req, direct, direct_inserts)
                 except Exception as exc:  # noqa: BLE001 - isolation gate
                     if not self._supervised:
                         raise
@@ -2812,10 +3009,10 @@ class _ContinuousServer:
                 req_d = self.slots[slot]
                 if req_d is not None:
                     req_d.span.event("prefill", tokens=int(mask_d.sum()))
-            for slot, (req_i, e_i, base_i) in direct_inserts:
+            for slot, (req_i, e_i, base_i, wrote_i) in direct_inserts:
                 # after the admit dispatch: the slot's KV now holds the
                 # prompt's blocks — publish the new ones into the arena
-                self._prefix_insert(slot, req_i, e_i, base_i)
+                self._prefix_insert(slot, req_i, e_i, base_i, wrote_i)
             pend = list(self._pending_prefill)
             if (self.disagg and active.any()
                     and len(pend) > self._prefill_budget):
@@ -2864,115 +3061,128 @@ class _ContinuousServer:
                 self.wake.wait(timeout=0.05)
                 continue
             prev = inflight.popleft()
-            payload, was_active, snap_slots = prev
-            spec_rec = isinstance(payload, tuple)
-            if spec_rec:
-                # (n_cycles, n_slots, spec_k+1) proposed tokens and the
-                # (n_cycles, n_slots) per-cycle accepted counts: a
-                # lane's stream is each cycle's first n_emit tokens
-                toks = np.asarray(payload[0])
-                emit = np.asarray(payload[1])
-                lanes = np.nonzero(was_active)[0]
-                cyc, kk = toks.shape[0], toks.shape[2] - 1
-                n_act = len(lanes)
-                drafted = cyc * n_act * kk
-                emitted = int(emit[:, lanes].sum()) if n_act else 0
-                accepted = emitted - cyc * n_act
-                # accumulate locally, flush to the registry at request
-                # completions (and loop idle): one registry call per
-                # request instead of six per spec drain
-                acc = self._spec_accum
-                for k, v in (
-                    ("dispatches", 1), ("verify_steps", cyc * n_act),
-                    ("draft_steps", drafted), ("drafted", drafted),
-                    ("accepted", accepted), ("emitted", emitted),
-                ):
-                    acc[k] = acc.get(k, 0) + v
-                self.stats["spec_verify_steps"] += cyc * n_act
-                self.stats["spec_drafted"] += drafted
-                self.stats["spec_accepted"] += accepted
-                self.stats["spec_emitted"] += emitted
-                if drafted:
-                    rate = accepted / drafted
-                    self._accept_ema = (
-                        rate if self._accept_ema is None
-                        else 0.7 * self._accept_ema + 0.3 * rate
-                    )
-                    self._spec_drains += 1
-                    # below ~1/(k+1) acceptance the drafts are noise:
-                    # latch back to plain chunks (identical tokens,
-                    # none of the draft cost)
-                    if (self._spec_drains >= 4
-                            and self._accept_ema < 0.25):
-                        self._spec_off = True
-            else:
-                toks = np.asarray(payload)
-            for slot in np.nonzero(was_active)[0]:
-                req = snap_slots[slot]
-                if req is None or req.done.is_set():
-                    continue  # freed by an earlier chunk's tail
-                if (self._deadline_s > 0.0 and req.deadline is not None
-                        and req.deadline <= time_mod.monotonic()):
-                    # in-flight enforcement: an admitted-then-stalled
-                    # request can't burn its slot past its deadline —
-                    # free it NOW instead of decoding an answer the
-                    # caller already abandoned
-                    if self.slots[slot] is req:
-                        self.slots[slot] = None
-                        active[slot] = False
-                        self._release_slot_kv(slot)
-                        with self.lock:
-                            self.free.append(int(slot))
-                    self._prefix_release(req)
-                    self._discard_parked(req)
-                    self._tenant_credit(req)
-                    self._shed_request(req, "deadline_inflight")
-                    continue
-                if spec_rec:
-                    stream = [
-                        int(t) for c in range(toks.shape[0])
-                        for t in toks[c, slot, : emit[c, slot]]
-                    ]
-                    req.span.event(
-                        "spec_cycles", cycles=int(cyc),
-                        emitted=len(stream), accepted=len(stream) - int(cyc),
-                    )
-                else:
-                    stream = toks[:, slot].tolist()
-                    req.span.event("decode_chunk", steps=len(stream))
-                for t in stream:
-                    if self.eos_id is not None and t == self.eos_id:
-                        req.max_new = 0  # stream closed
-                        break
-                    if not req.tokens:
-                        req.first_token_at = time_mod.perf_counter()
-                        req.span.event("first_token")
-                    req.tokens.append(int(t))
-                    if len(req.tokens) >= req.max_new:
-                        break
-                if req.max_new == 0 or len(req.tokens) >= req.max_new:
-                    import time as time_mod
+            with tracing.region("pw.decode.drain"):
+                self._drain(prev, active)
 
-                    req.text = self.tokenizer.decode(req.tokens)
-                    req.finished_at = time_mod.perf_counter()
-                    # eager refill may have freed (and even re-admitted)
-                    # this slot chunks ago — only release it if it still
-                    # belongs to the request we just completed
-                    if self.slots[slot] is req:
-                        self.slots[slot] = None
-                        active[slot] = False
-                        self._release_slot_kv(slot)
-                        with self.lock:
-                            self.free.append(int(slot))
-                    self._prefix_release(req)
-                    self._tenant_credit(req)
-                    # flush + finish BEFORE done.set(): a waiter that
-                    # wakes on done must find the spec counters and the
-                    # span already recorded
-                    self._spec_flush()
-                    req.span.event("drain")
-                    req.span.finish(tokens=len(req.tokens))
-                    req.done.set()
+    def _drain(self, prev, active) -> None:
+        """Read one dispatched chunk's tokens (their copy to the host began
+        at dispatch) and hand them to the requests its lanes served."""
+        import time as time_mod
+
+        import numpy as np
+
+        payload, was_active, snap_slots, counts_dev = prev
+        if counts_dev is not None:
+            self._moe_account(np.asarray(counts_dev))
+        spec_rec = isinstance(payload, tuple)
+        if spec_rec:
+            # (n_cycles, n_slots, spec_k+1) proposed tokens and the
+            # (n_cycles, n_slots) per-cycle accepted counts: a
+            # lane's stream is each cycle's first n_emit tokens
+            toks = np.asarray(payload[0])
+            emit = np.asarray(payload[1])
+            lanes = np.nonzero(was_active)[0]
+            cyc, kk = toks.shape[0], toks.shape[2] - 1
+            n_act = len(lanes)
+            drafted = cyc * n_act * kk
+            emitted = int(emit[:, lanes].sum()) if n_act else 0
+            accepted = emitted - cyc * n_act
+            # accumulate locally, flush to the registry at request
+            # completions (and loop idle): one registry call per
+            # request instead of six per spec drain
+            acc = self._spec_accum
+            for k, v in (
+                ("dispatches", 1), ("verify_steps", cyc * n_act),
+                ("draft_steps", drafted), ("drafted", drafted),
+                ("accepted", accepted), ("emitted", emitted),
+            ):
+                acc[k] = acc.get(k, 0) + v
+            self.stats["spec_verify_steps"] += cyc * n_act
+            self.stats["spec_drafted"] += drafted
+            self.stats["spec_accepted"] += accepted
+            self.stats["spec_emitted"] += emitted
+            if drafted:
+                rate = accepted / drafted
+                self._accept_ema = (
+                    rate if self._accept_ema is None
+                    else 0.7 * self._accept_ema + 0.3 * rate
+                )
+                self._spec_drains += 1
+                # below ~1/(k+1) acceptance the drafts are noise:
+                # latch back to plain chunks (identical tokens,
+                # none of the draft cost)
+                if (self._spec_drains >= 4
+                        and self._accept_ema < 0.25):
+                    self._spec_off = True
+        else:
+            toks = np.asarray(payload)
+        for slot in np.nonzero(was_active)[0]:
+            req = snap_slots[slot]
+            if req is None or req.done.is_set():
+                continue  # freed by an earlier chunk's tail
+            if (self._deadline_s > 0.0 and req.deadline is not None
+                    and req.deadline <= time_mod.monotonic()):
+                # in-flight enforcement: an admitted-then-stalled
+                # request can't burn its slot past its deadline —
+                # free it NOW instead of decoding an answer the
+                # caller already abandoned
+                if self.slots[slot] is req:
+                    self.slots[slot] = None
+                    active[slot] = False
+                    self._release_slot_kv(slot)
+                    with self.lock:
+                        self.free.append(int(slot))
+                self._prefix_release(req)
+                self._discard_parked(req)
+                self._tenant_credit(req)
+                self._shed_request(req, "deadline_inflight")
+                continue
+            if spec_rec:
+                stream = [
+                    int(t) for c in range(toks.shape[0])
+                    for t in toks[c, slot, : emit[c, slot]]
+                ]
+                req.span.event(
+                    "spec_cycles", cycles=int(cyc),
+                    emitted=len(stream), accepted=len(stream) - int(cyc),
+                )
+            else:
+                stream = toks[:, slot].tolist()
+                req.span.event("decode_chunk", steps=len(stream))
+            for t in stream:
+                if self.eos_id is not None and t == self.eos_id:
+                    req.max_new = 0  # stream closed
+                    break
+                if not req.tokens:
+                    req.first_token_at = time_mod.perf_counter()
+                    req.span.event("first_token")
+                req.tokens.append(int(t))
+                if len(req.tokens) >= req.max_new:
+                    break
+            if req.max_new == 0 or len(req.tokens) >= req.max_new:
+                import time as time_mod
+
+                req.text = self.tokenizer.decode(req.tokens)
+                req.finished_at = time_mod.perf_counter()
+                # eager refill may have freed (and even re-admitted)
+                # this slot chunks ago — only release it if it still
+                # belongs to the request we just completed
+                if self.slots[slot] is req:
+                    self.slots[slot] = None
+                    active[slot] = False
+                    self._release_slot_kv(slot)
+                    with self.lock:
+                        self.free.append(int(slot))
+                self._prefix_release(req)
+                self._tenant_credit(req)
+                # flush + finish BEFORE done.set(): a waiter that
+                # wakes on done must find the spec counters and the
+                # span already recorded
+                self._spec_flush()
+                req.span.event("drain")
+                req.span.event("done")
+                req.span.finish(tokens=len(req.tokens))
+                req.done.set()
 
     def _spec_flush(self):
         """Flush locally-accumulated spec counters to the registry.
@@ -2985,6 +3195,25 @@ class _ContinuousServer:
             from pathway_tpu.engine.probes import record_spec_many
 
             record_spec_many(**acc)
+
+    def _moe_account(self, totals) -> None:
+        """``moe_assignments{held=0|1, phase=}`` from the device's running
+        (phase, held | all) totals, which wrap mod 2**32: the difference
+        between two drains is small."""
+        from pathway_tpu.engine import probes
+
+        totals = totals.astype("uint32")
+        seen = self._moe_seen if self._moe_seen is not None \
+            else totals * 0
+        delta = (totals - seen).astype("int64")     # uint32: wraps right
+        self._moe_seen = totals
+        for row, phase in enumerate(("prefill", "decode")):
+            held, every = int(delta[row, 0]), int(delta[row, 1])
+            if every:
+                probes.REGISTRY.counter_add(
+                    "moe_assignments", held, held=1, phase=phase)
+                probes.REGISTRY.counter_add(
+                    "moe_assignments", every - held, held=0, phase=phase)
 
     def shutdown(self, timeout: float = 10.0):
         self._stop = True

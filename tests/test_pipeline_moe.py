@@ -1,5 +1,8 @@
-"""Pipeline-parallel encoder and expert-parallel MoE — exactness against
-the sequential encoder / unsharded block on the virtual 8-device mesh."""
+"""Pipeline-parallel encoder and the decoder's routed experts — exactness
+against the sequential encoder / the uncut, unsharded layer on the virtual
+8-device mesh. (The two MoE tests were the encoder top-1 layer's, ported one
+for one to the layer that took its place: shapes-and-routing, and
+sharded-matches-unsharded; the third ties a chip's SHARE to the model.)"""
 
 from __future__ import annotations
 
@@ -16,8 +19,9 @@ from pathway_tpu.models import MINILM_L6, init_params
 from pathway_tpu.models.moe import (
     MoEConfig,
     init_moe_params,
-    moe_ffn,
+    moe_mlp,
     moe_partition_specs,
+    route,
 )
 from pathway_tpu.models.pipeline import encode_pipelined
 from pathway_tpu.models.transformer import encode
@@ -56,28 +60,57 @@ def test_pipeline_validates_divisibility(tiny):
         encode_pipelined(params, ids, mask, cfg, mesh2, n_microbatches=3)
 
 
-def test_moe_shapes_routing_and_aux(tiny):
+def _plain_moe(x, mp, moe, held=None):
+    """The layer written out per expert in float32: every expert held
+    weighted by the tokens that picked it, plus the shared expert."""
+    tokens = x.reshape(-1, x.shape[-1])
+    idx, w, _s = route(tokens, mp, moe)
+    first, count = held or (0, moe.experts)
+    y = jnp.zeros_like(tokens)
+    for e in range(count):
+        we = jnp.sum(jnp.where(idx == first + e, w, 0.0), axis=-1)
+        a = jax.nn.silu(tokens @ mp["moe_in_w"][e]) * (
+            tokens @ mp["moe_up_w"][e])
+        y = y + we[:, None] * (a @ mp["moe_out_w"][e])
+    if moe.shared:
+        y = y + (jax.nn.silu(tokens @ mp["shared_in_w"]) * (
+            tokens @ mp["shared_up_w"])) @ mp["shared_out_w"]
+    return y.reshape(x.shape)
+
+
+def test_moe_shapes_routing_and_no_token_dropped(tiny):
     cfg, _params, _ids, _mask = tiny
-    moe = MoEConfig(n_experts=4, capacity_factor=2.0)
-    mp = init_moe_params(jax.random.PRNGKey(2), cfg, moe)
+    moe = MoEConfig(experts=4, per_token=2, width=48, shared=1,
+                    route_scale=2.448)
+    mp = init_moe_params(jax.random.PRNGKey(2), cfg.hidden, moe, scale=0.2)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 16, cfg.hidden))
-    y, aux = moe_ffn(x, mp, cfg, moe)
+    y, counts = moe_mlp(x, mp, moe, jnp.float32)
     assert y.shape == x.shape
-    assert float(aux) > 0
-    # tight capacity drops tokens (outputs become exactly zero for dropped)
-    tight = MoEConfig(n_experts=4, capacity_factor=0.25)
-    y2, _ = moe_ffn(x, mp, cfg, tight)
-    zeros2 = int(jnp.sum(jnp.all(y2 == 0, axis=-1)))
-    zeros1 = int(jnp.sum(jnp.all(y == 0, axis=-1)))
-    assert zeros2 > zeros1
+    # every token's every pick is computed: no capacity, nothing dropped,
+    # however uneven the routing (all 32 tokens x 2 picks are held here)
+    assert counts.tolist() == [64, 64]
+    idx, w, s = route(x.reshape(32, -1), mp, moe)
+    assert idx.shape == (32, 2) and s.shape == (32, 4)
+    assert bool(jnp.all(idx[:, 0] != idx[:, 1]))
+    assert np.allclose(np.asarray(w.sum(-1)), 2.448, atol=1e-5)
+    assert float(jnp.max(jnp.abs(y - _plain_moe(x, mp, moe)))) < 1e-5
+    # the balance bias moves the CHOICE and never the weights
+    biased = dict(mp, router_bias=mp["router_bias"].at[3].add(10.0))
+    idx_b, w_b, _ = route(x.reshape(32, -1), biased, moe)
+    assert bool(jnp.all(jnp.any(idx_b == 3, axis=-1)))
+    assert np.allclose(np.asarray(w_b.sum(-1)), 2.448, atol=1e-5)
+    # uneven on purpose: one expert takes every token, none is lost
+    y_b, counts_b = moe_mlp(x, biased, moe, jnp.float32)
+    assert counts_b.tolist() == [64, 64]
+    assert float(jnp.max(jnp.abs(y_b - _plain_moe(x, biased, moe)))) < 1e-5
 
 
 def test_moe_ep_sharded_matches_unsharded(tiny):
     cfg, _params, _ids, _mask = tiny
-    moe = MoEConfig(n_experts=8, capacity_factor=2.0)
-    mp = init_moe_params(jax.random.PRNGKey(4), cfg, moe)
+    moe = MoEConfig(experts=8, per_token=2, width=48, shared=1)
+    mp = init_moe_params(jax.random.PRNGKey(4), cfg.hidden, moe, scale=0.2)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 16, cfg.hidden))
-    ref, _ = moe_ffn(x, mp, cfg, moe)
+    ref, _ = moe_mlp(x, mp, moe, jnp.float32)
     mesh = Mesh(np.array(jax.devices()[:8]), ("ep",))
     specs = moe_partition_specs(moe)
     mp_sharded = {
@@ -85,5 +118,34 @@ def test_moe_ep_sharded_matches_unsharded(tiny):
         for k, v in mp.items()
     }
     with mesh:
-        out, _ = jax.jit(lambda x, mp: moe_ffn(x, mp, cfg, moe))(x, mp_sharded)
+        out, _ = jax.jit(lambda x, mp: moe_mlp(x, mp, moe, jnp.float32))(
+            x, mp_sharded)
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+
+
+def test_the_eight_shares_sum_to_the_uncut_layer(tiny):
+    """The guide's share test: 16 experts over 8 chips, 2 held each. Every
+    share routes over all 16, computes its own experts' part and the shared
+    expert; the parts of all 8, the shared expert counted ONCE, add up to
+    the uncut layer, and each share's held assignments to all of them."""
+    cfg, _params, _ids, _mask = tiny
+    whole = MoEConfig(experts=16, per_token=4, width=48, shared=1,
+                      route_scale=2.448)
+    mp = init_moe_params(jax.random.PRNGKey(6), cfg.hidden, whole, scale=0.2)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 16, cfg.hidden))
+    uncut, counts = moe_mlp(x, mp, whole, jnp.float32)
+    assert counts.tolist() == [128, 128]
+    shared_only = _plain_moe(x, mp, whole, held=(0, 0))
+    total, held_sum = jnp.zeros_like(uncut), 0
+    for chip in range(8):
+        share = dataclasses.replace(whole, held=(2 * chip, 2))
+        mine = {**mp, **{k: mp[k][2 * chip:2 * chip + 2]
+                         for k in ("moe_in_w", "moe_up_w", "moe_out_w")}}
+        part, c = moe_mlp(x, mine, share, jnp.float32)
+        assert float(jnp.max(jnp.abs(
+            part - _plain_moe(x, mine, share, held=(2 * chip, 2))))) < 1e-5
+        total = total + (part - shared_only)
+        held_sum += int(c[0])
+        assert int(c[1]) == 128
+    assert held_sum == 128
+    assert float(jnp.max(jnp.abs(total + shared_only - uncut))) < 2e-5
