@@ -10,6 +10,7 @@ XLA kernels without conversion; irregular columns stay on host.
 
 from __future__ import annotations
 
+import struct
 from typing import Any, Iterable, Mapping
 
 import numpy as np
@@ -183,17 +184,130 @@ def concat_batches(batches: list[Batch]) -> Batch | None:
     return Batch(keys, cols, diffs)
 
 
-def row_hashes(batch: Batch) -> np.ndarray:
-    """Per-row content hash over value columns (for consolidation grouping)."""
-    return value_mod.keys_for_value_columns(
-        [batch.cols[n] for n in batch.column_names], len(batch)
+def _canonical(value: Any) -> bytes:
+    out = bytearray()
+    value_mod.serialize_value(value, out)
+    return bytes(out)
+
+
+_PACK_DOUBLE = struct.Struct("<d").pack
+# exact types whose ``==`` is the serialiser's equality
+_PLAIN = frozenset((str, int, bytes, bool, value_mod.Pointer))
+
+
+def same_value(a: Any, b: Any) -> bool:
+    """Whether two values are the same value to the engine: their canonical
+    serialisations (``value.serialize_value``) are equal. Decided by the
+    cheapest evidence first — identity, then (for two values of one exact
+    type) a tuple's length and its elements in turn, a plain ``==`` where
+    that IS the serialiser's equality — and by the serialisation itself
+    only for what is still undecided (a ``Json``, an ndarray, a datetime,
+    values of unlike types: ``1`` and ``np.int64(1)`` are one value, ``1``,
+    ``1.0`` and ``True`` three). So two tuples of unequal length cost a
+    length check, a tuple with one changed element a walk of identity
+    checks and one leaf, and only a true match walks the whole value."""
+    if a is b:
+        return True
+    kind = type(a)
+    if kind is type(b):
+        if kind is tuple or kind is list:
+            if len(a) != len(b):
+                return False
+            for x, y in zip(a, b):
+                if x is not y and not same_value(x, y):
+                    return False
+            return True
+        if kind in _PLAIN:
+            return a == b
+        if kind is float:  # by bits, as packed: 0.0 is not -0.0, nan is nan
+            return _PACK_DOUBLE(a) == _PACK_DOUBLE(b)
+    return _canonical(a) == _canonical(b)
+
+
+def _same_row(batch: Batch, i: int, j: int) -> bool:
+    """Are rows ``i`` and ``j`` of ``batch`` the same row?"""
+    for col in batch.cols.values():
+        x, y = col[i], col[j]
+        if col.dtype != object:  # as ``astype(object)`` would hand them over
+            x, y = x.item(), y.item()
+        if x is not y and not same_value(x, y):
+            return False
+    return True
+
+
+def _same_pairs(batch: Batch, left: np.ndarray, right: np.ndarray) -> np.ndarray:
+    """For rows ``left[i]`` and ``right[i]`` of ``batch``: are they the same
+    row? Column by column, and in a later column only for the pairs that
+    the earlier ones left equal."""
+    same = np.ones(len(left), dtype=bool)
+    for col in batch.cols.values():
+        if col.dtype.kind in "biuf" and col.dtype.itemsize <= 8:
+            # a typed column holds one type: equal bits, equal serialisation
+            bits = col.view(f"u{col.dtype.itemsize}")
+            same &= bits[left] == bits[right]
+            continue
+        live = same.nonzero()[0]
+        if len(live) == 0:
+            break
+        if col.dtype != object:
+            col = col.astype(object)
+        pairs = zip(col[left[live]].tolist(), col[right[live]].tolist())
+        for i, (x, y) in zip(live.tolist(), pairs):
+            if x is not y and not same_value(x, y):
+                same[i] = False
+    return same
+
+
+def _sum_by_content(batch: Batch, rows: np.ndarray):
+    """Rows of ``batch`` under keys that three or more of them share: the
+    first row of each distinct (key, content) and the sum of its diffs,
+    grouped by a hash of the canonical serialisation of these rows alone."""
+    content = value_mod.keys_for_value_columns(
+        [col[rows] for col in batch.cols.values()], len(rows)
     )
+    keys, diffs = batch.keys[rows], batch.diffs[rows]
+    native = _get_native_consolidate()
+    if native is not None:
+        first, summed = native(keys, content, diffs)
+        return rows[first.astype(np.int64)], summed
+    combo = np.empty(len(rows), dtype=[("k", np.uint64), ("r", np.uint64)])
+    combo["k"] = keys
+    combo["r"] = content
+    _uniq, first, inverse = np.unique(
+        combo, return_index=True, return_inverse=True
+    )
+    summed = np.zeros(len(first), dtype=np.int64)
+    np.add.at(summed, inverse.ravel(), diffs)
+    return rows[first], summed
 
 
-def consolidate(batch: Batch | None) -> Batch | None:
-    """Sum diffs of identical (key, row) pairs; drop zero-diff rows."""
+def _distinct_keys(batch: Batch) -> tuple[Batch | None, int]:
+    """No two rows share a key: identical (key, row) pairs are impossible —
+    the common shape of every bulk-ingest commit, where reading wide object
+    columns (e.g. embedding vectors) would dominate the epoch."""
+    diffs = batch.diffs
+    if diffs.min() > 0 or diffs.max() < 0:
+        batch._consolidated = True
+        return batch, 0
+    if diffs.all():
+        return batch, 0
+    live = diffs.nonzero()[0]
+    return (batch.take(live) if len(live) else None), 0
+
+
+def consolidate_counted(batch: Batch | None) -> tuple[Batch | None, int]:
+    """Sum diffs of identical (key, row) pairs; drop zero-diff rows. Also
+    returns how many rows had their CONTENT examined to decide that.
+
+    Two rows can only cancel or sum if they share a key, so the keys decide
+    first: a row whose key is alone in the batch is kept as it is, its
+    content never read — what a commit pays follows the rows it changes,
+    not the size of the values they hold (a standing ``reducers.tuple`` of
+    every row ever seen is one value). Two rows under one key — the (-old,
+    +new) of every update — are compared by :func:`same_value`; three or
+    more by a content hash of those rows alone."""
     if batch is None or len(batch) == 0:
-        return None
+        return None, 0
     # a producer already proved this batch single-sign with distinct keys
     # (the invariant column transforms preserve) — skip even the sort-based
     # uniqueness re-check, which otherwise repeats at EVERY node of the
@@ -202,45 +316,66 @@ def consolidate(batch: Batch | None) -> Batch | None:
         from pathway_tpu.internals import config as config_mod
 
         if config_mod.pathway_config.epoch_closeout:
-            return batch
-    # insert-only (or retract-only) batch with all-distinct keys: identical
-    # (key, row) pairs are impossible, so skip the per-row content hashing —
-    # the common shape of every bulk-ingest commit, where hashing wide
-    # object columns (e.g. embedding vectors) would dominate the epoch
-    diffs = batch.diffs
-    if (diffs.min() > 0 or diffs.max() < 0) and len(
-        np.unique(batch.keys)
-    ) == len(batch):
-        batch._consolidated = True
-        return batch
-    rh = row_hashes(batch)
-    native = _get_native_consolidate()
-    if native is not None:
-        idx, summed = native(batch.keys, rh, batch.diffs)
-        if len(idx) == 0:
-            return None
-        if len(idx) == len(batch) and np.array_equal(summed, batch.diffs):
-            return batch
-        out = batch.take(idx.astype(np.int64))
-        out.diffs = summed.copy()
-        return out
-    combo = np.empty(len(batch), dtype=[("k", np.uint64), ("r", np.uint64)])
-    combo["k"] = batch.keys
-    combo["r"] = rh
-    uniq, first_idx, inverse = np.unique(
-        combo, return_index=True, return_inverse=True
-    )
-    if len(uniq) == len(batch) and np.all(batch.diffs != 0):
-        return batch
-    summed = np.zeros(len(uniq), dtype=np.int64)
-    np.add.at(summed, inverse, batch.diffs)
-    keep = summed != 0
-    if not np.any(keep):
-        return None
-    idx = first_idx[keep]
-    out = batch.take(idx)
-    out.diffs = summed[keep]
-    return out
+            return batch, 0
+    n = len(batch)
+    keys, diffs = batch.keys, batch.diffs
+    if n <= 2:
+        # one group's update: a row, or its (-old, +new) — no sort needed
+        if n == 1 or keys[0] != keys[1]:
+            return _distinct_keys(batch)
+        if not _same_row(batch, 0, 1):
+            return batch, 2
+        total = diffs[0] + diffs[1]
+        if total == 0:
+            return None, 2
+        out = batch.take(np.zeros(1, dtype=np.int64))
+        out.diffs = np.array([total], dtype=np.int64)
+        return out, 2
+    order = keys.argsort(kind="stable")
+    sorted_keys = keys[order]
+    new_key = sorted_keys[1:] != sorted_keys[:-1]
+    if new_key.all():
+        return _distinct_keys(batch)
+    # runs of rows under one key, in sorted order. The stable sort keeps
+    # the rows of a run in batch order, so a run's first row is its first
+    # occurrence: the one that stays for the group
+    starts = np.empty(int(new_key.sum()) + 1, dtype=np.int64)
+    starts[0] = 0
+    starts[1:] = new_key.nonzero()[0] + 1
+    sizes = np.empty_like(starts)
+    sizes[:-1] = starts[1:] - starts[:-1]
+    sizes[-1] = n - starts[-1]
+    summed = diffs.copy()
+    keep = np.ones(n, dtype=bool)
+    pairs = starts[sizes == 2]
+    compared = 2 * len(pairs)
+    if len(pairs):
+        left, right = order[pairs], order[pairs + 1]
+        same = _same_pairs(batch, left, right)
+        summed[left[same]] += diffs[right[same]]
+        keep[right[same]] = False
+    crowd = sizes > 2
+    if crowd.any():
+        rows = np.sort(order[np.repeat(crowd, sizes)])
+        compared += len(rows)
+        first, total = _sum_by_content(batch, rows)
+        keep[rows] = False
+        keep[first] = True
+        summed[first] = total
+    keep &= summed != 0
+    if keep.all():
+        return batch, compared
+    live = keep.nonzero()[0]
+    if len(live) == 0:
+        return None, compared
+    out = batch.take(live)
+    out.diffs = summed[live]
+    return out, compared
+
+
+def consolidate(batch: Batch | None) -> Batch | None:
+    """Sum diffs of identical (key, row) pairs; drop zero-diff rows."""
+    return consolidate_counted(batch)[0]
 
 
 _native_consolidate = False

@@ -355,10 +355,13 @@ class RowwiseNode(Node):
 
         # plan in row order against simulated cache membership, so a
         # same-batch insert-then-delete replays the insert's fresh value and
-        # a delete-then-insert recomputes after eviction
-        membership = {
-            ck: entry[0] for ck, entry in self._replay_cache.items()
-        }
+        # a delete-then-insert recomputes after eviction. Only this batch's
+        # rows are looked up: the cache holds every row ever inserted
+        membership = {}
+        for ck in ckeys:
+            entry = self._replay_cache.get(ck)
+            if entry is not None:
+                membership[ck] = entry[0]
         live = np.zeros(n, dtype=bool)
         for i in range(n):
             ck = ckeys[i]

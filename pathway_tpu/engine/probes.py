@@ -100,6 +100,10 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
         "counter", "padded", "Queries per brute-force search dispatch: "
         "as asked (padded=0) and as searched, rounded up to the pow2 "
         "bucket (padded=1); over device_dispatch{kind=knn_search}"),
+    "consolidate_rows": (
+        "counter", "content", "Rows consolidated after an operator's step: "
+        "decided by their keys alone (content=0) or by examining their "
+        "content, because they share a key (content=1)"),
     "compiles": (
         "counter", None, "XLA backend compilations in this process"),
     "compile_seconds": (
@@ -592,6 +596,17 @@ def record_op_step(
     REGISTRY.observe_op_step(operator, seconds, rows_in, rows_out)
 
 
+def record_consolidate(rows: int, compared: int) -> None:
+    """One node's consolidation: the rows it was decided for by their keys
+    alone (``content=0``) and those whose content had to be examined
+    (``content=1``: they share a key with another row of the batch). A
+    standing aggregation costs what a commit brings while ``content=1``
+    stays flat as its state grows."""
+    REGISTRY.counter_add_many(
+        "consolidate_rows", "content", {0: rows - compared, 1: compared}
+    )
+
+
 def record_backlog(queue: str, depth: int) -> None:
     """Backlog depth gauge (``queue`` = pending_epochs / async_inflight /
     drain_group). Throttled by callers — gauges only need freshness, not
@@ -692,6 +707,7 @@ def reset_engine_stats() -> None:
     REGISTRY.remove(
         "op_step_seconds", "op_rows", "op_held_rows", "watermark_lag",
         "engine_backlog", "engine_frontier_lag", "exchange_rows",
+        "consolidate_rows",
     )
 
 

@@ -9,6 +9,7 @@ the reference.
 
 from __future__ import annotations
 
+import bisect
 from collections import Counter
 from typing import Any, Callable
 
@@ -227,36 +228,76 @@ class SortedTupleAcc(_MultisetAcc):
 class TupleAcc(_MultisetAcc):
     """Ordered tuple: by (time, key) of arrival, or by the user's
     ``groupby(sort_by=...)`` key first (time as tie-break) when
-    ``user_order`` is set; args = (value, order_key)."""
+    ``user_order`` is set; args = (value, order_key).
 
-    __slots__ = ("skip_nones", "user_order", "_times")
+    The output is KEPT in order as rows arrive, one slot per output element
+    (``_keys[i]`` sorts ``_out[i]``), so ``compute`` copies a list and an
+    update costs what it changes, not what the group holds: a row that
+    sorts last — the standing aggregation's every commit — is an append,
+    any other a bisection and the list's own shift. A slot's key ends in
+    its entry's arrival number: entries with equal sort keys stay in the
+    order they were first seen, as a stable sort over the entries leaves
+    them. Keys that do not order (``TypeError``) end the bookkeeping for
+    this group: from then on ``compute`` sorts all entries, by ``repr``
+    where it must."""
+
+    __slots__ = ("skip_nones", "user_order", "_arrivals", "_keys", "_out")
 
     def __init__(self, skip_nones: bool = False, user_order: bool = False):
-        super().__init__()
+        super().__init__()  # hkey -> [args, count, time, arrival]
         self.skip_nones = skip_nones
         self.user_order = user_order
-        self._times: dict[Any, int] = {}
+        self._arrivals = 0
+        self._keys: list | None = []  # None: the keys do not order
+        self._out: list = []
 
     def add(self, args, diff, time):
         hk = _hashable(args)
-        if hk not in self._times:
-            self._times[hk] = time
-        entry = self._entries.get(hk)
-        if entry is None:
-            entry = [args, 0]
-            self._entries[hk] = entry
+        # an entry's time is that of its first row since it was last
+        # empty: a retraction followed by a re-add arrives anew
+        fresh = [args, 0, time, self._arrivals + 1]
+        entry = self._entries.setdefault(hk, fresh)  # one hash of the row
+        if entry is fresh:
+            self._arrivals += 1
+        shown = max(entry[1], 0)
         entry[1] += diff
         if entry[1] == 0:
             del self._entries[hk]
-            self._times.pop(hk, None)
+        if self._keys is not None and not (args[0] is None and self.skip_nones):
+            try:
+                self._show(entry, shown, max(entry[1], 0))
+            except TypeError:
+                self._keys = self._out = None
+
+    def _show(self, entry, shown: int, wanted: int) -> None:
+        """Bring the entry's slots in the output from ``shown`` to
+        ``wanted`` copies of its value."""
+        if shown == wanted:
+            return
+        keys, out = self._keys, self._out
+        args, _count, time, arrival = entry
+        order = args[1] if len(args) > 1 else None
+        key = (order, time, arrival) if self.user_order else (time, order, arrival)
+        if wanted < shown:
+            at = bisect.bisect_left(keys, key)
+            del keys[at:at + shown - wanted]
+            del out[at:at + shown - wanted]
+        elif not keys or not key < keys[-1]:
+            keys.extend([key] * (wanted - shown))
+            out.extend([args[0]] * (wanted - shown))
+        else:
+            at = bisect.bisect_right(keys, key)
+            keys[at:at] = [key] * (wanted - shown)
+            out[at:at] = [args[0]] * (wanted - shown)
 
     def compute(self):
+        if self._keys is not None:
+            return tuple(self._out)
         items = []
-        for hk, (args, c) in self._entries.items():
+        for args, c, t, _arrival in self._entries.values():
             v, order = args[0], args[1] if len(args) > 1 else None
             if v is None and self.skip_nones:
                 continue
-            t = self._times.get(hk, 0)
             sort_key = (order, t) if self.user_order else (t, order)
             items.extend([(sort_key, v)] * max(c, 0))
         try:

@@ -15,7 +15,7 @@ import time
 from collections import defaultdict
 from typing import Any, Callable
 
-from pathway_tpu.engine.batch import Batch, concat_batches, consolidate
+from pathway_tpu.engine.batch import Batch, concat_batches, consolidate_counted
 from pathway_tpu.engine.graph import EngineGraph, Node, fuse_chains
 from pathway_tpu.engine import probes, tracing
 from pathway_tpu.engine.probes import SchedulerStats, _current_op
@@ -385,8 +385,12 @@ class Scheduler:
                 out = concat_batches([out] + extra) if out is not None else concat_batches(extra)
             result = None
             if out is not None:
-                with tracing.region("pw.engine.consolidate", rows=len(out)):
-                    result = consolidate(out)
+                with tracing.region("pw.engine.consolidate",
+                                    rows=len(out)) as region:
+                    result, compared = consolidate_counted(out)
+                    region.set_metadata(compared=compared)
+                if self.op_metrics:
+                    probes.record_consolidate(len(out), compared)
         outputs[node.id] = result
         if rows_in or result is not None:
             rows_out = len(result) if result is not None else 0

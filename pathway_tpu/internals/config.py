@@ -811,8 +811,9 @@ FLAG_REGISTRY: list[Flag] = [
         attr="op_metrics", group="observability",
         doc="Per-operator dataflow telemetry (registry "
             "`op_step_seconds` / `op_rows` / `op_held_rows` / "
-            "`watermark_lag` / `engine_backlog` / `exchange_rows` "
-            "families): `0` drops the engine-side registry writes while "
+            "`watermark_lag` / `engine_backlog` / `exchange_rows` / "
+            "`consolidate_rows` families): `0` drops the engine-side "
+            "registry writes while "
             "`SchedulerStats` accounting stays on. Read once per "
             "scheduler construction so the per-step hot path never "
             "touches the environment; pipeline outputs are "

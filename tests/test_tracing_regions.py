@@ -93,8 +93,13 @@ def test_a_session_around_a_toy_pipeline_finds_the_engines_regions(tmp_path):
         assert {"op", "rows_in"} <= set(ops[0][4])
         assert all(o[4]["rows_in"] == 4 for o in ops)
         for op in ops:
-            assert [c for c in by_name["pw.engine.consolidate"]
-                    if _inside(c, op)], op
+            inside = [c for c in by_name["pw.engine.consolidate"]
+                      if _inside(c, op)]
+            assert inside, op
+            # every row of these batches is alone under its key: decided
+            # without a look at its content
+            assert all(c[4]["rows"] == 4 and c[4]["compared"] == 0
+                       for c in inside), inside
         ends = [s for s in by_name["pw.engine.on_time_end"]
                 if _inside(s, epoch)]
         assert [s[4]["op"].rsplit(":", 1)[0] for s in ends] == ["Subscribe"]
@@ -310,4 +315,8 @@ def test_compilations_are_counted_and_exposed_on_metrics():
     assert "pathway_tpu_compiles_total " in text
     assert "pathway_tpu_compile_seconds_total " in text
     assert 'pathway_tpu_knn_search_queries_total{padded="0"}' in text
+    probes.record_consolidate(10, 2)
+    text = registry_text()
+    assert 'pathway_tpu_consolidate_rows_total{content="0"}' in text
+    assert 'pathway_tpu_consolidate_rows_total{content="1"}' in text
     assert 'pathway_tpu_e2e_seconds_count{phase="epoch"}' in text
