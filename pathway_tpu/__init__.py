@@ -89,7 +89,7 @@ from pathway_tpu.internals import config as _config
 from pathway_tpu.internals.config import set_license_key, set_monitoring_config
 
 # persistent XLA compilation cache for the whole package (engine runs,
-# tests, bench): $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
+# tests, benchmark): $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
 _config.enable_compile_cache()
 # every compilation counted on /metrics (compiles_total)
 from pathway_tpu.engine import probes as _probes  # noqa: E402

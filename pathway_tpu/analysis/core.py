@@ -85,7 +85,7 @@ RULES: dict[str, Rule] = {
         Rule(
             "GL203", "flag-dead",
             "`FLAG_REGISTRY` entry read nowhere (attr never accessed, "
-            "env never referenced by package/bench/tests) — delete the "
+            "env never referenced by package/tests) — delete the "
             "flag or wire it up.",
         ),
         Rule(
@@ -211,7 +211,7 @@ class ModuleSource:
 @dataclasses.dataclass
 class PackageCtx:
     """Everything a pass may look at: the parsed package, plus the repo
-    root for cross-referencing bench.py and tests/."""
+    root for cross-referencing tests/."""
 
     repo_root: str
     modules: list[ModuleSource]

@@ -17,7 +17,7 @@ in ``internals/config.py``'s ``FLAG_REGISTRY`` and read through
   stays a one-file question.
 * **GL203** — a ``FLAG_REGISTRY`` entry nobody reads: its ``attr`` is
   never accessed in the package (outside config.py) and its env name
-  never appears in package/bench/tests sources. Dead flags are lies in
+  never appears in package/tests sources. Dead flags are lies in
   the docs; delete them or wire them up.
 * **GL204** — a flag carrying a ``tunable`` search spec whose space is
   broken: missing/non-finite bounds, an inverted range, a non-positive
@@ -175,24 +175,20 @@ def check_dead_flags(flags, texts) -> list[tuple[str, str | None]]:
     """Registry entries with no reader. ``flags`` is an iterable with
     ``.env`` / ``.attr``; ``texts`` is ``[(path, source_text), ...]`` of
     everything that may legitimately read a flag (package minus
-    config.py, bench.py, tests/). Returns ``[(env, attr), ...]`` dead."""
+    config.py, tests/). Returns ``[(env, attr), ...]`` dead."""
     dead: list[tuple[str, str | None]] = []
     for flag in flags:
-        attr_re = (
-            re.compile(r"\." + re.escape(flag.attr) + r"\b")
-            if getattr(flag, "attr", None)
-            else None
-        )
+        attr_re = re.compile(r"\." + re.escape(flag.attr) + r"\b")
         live = False
         for _path, text in texts:
             if flag.env in text:
                 live = True
                 break
-            if attr_re is not None and attr_re.search(text):
+            if attr_re.search(text):
                 live = True
                 break
         if not live:
-            dead.append((flag.env, getattr(flag, "attr", None)))
+            dead.append((flag.env, flag.attr))
     return dead
 
 
@@ -212,11 +208,6 @@ def _dead_flags_on_repo(
     texts: list[tuple[str, str]] = [
         (m.path, m.text) for m in ctx.modules if m.path != CONFIG_PATH
     ]
-    for extra in ("bench.py",):
-        full = os.path.join(ctx.repo_root, extra)
-        if os.path.exists(full):
-            with open(full, encoding="utf-8") as f:
-                texts.append((extra, f.read()))
     tests_dir = os.path.join(ctx.repo_root, "tests")
     if os.path.isdir(tests_dir):
         for fn in sorted(os.listdir(tests_dir)):
@@ -231,8 +222,8 @@ def _dead_flags_on_repo(
         node.lineno = line
         config.emit(
             findings, "GL203", node,
-            f"flag `{env}` (attr `{attr}`) is never read by package, bench, "
-            "or tests — delete it or wire it up",
+            f"flag `{env}` (attr `{attr}`) is never read by package or "
+            "tests — delete it or wire it up",
             env,
         )
     return findings

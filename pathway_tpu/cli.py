@@ -296,10 +296,7 @@ def stats(url, as_json):
     section("prefix", serving.get("prefix") or {})
     section("spec", serving.get("spec") or {})
     section("cascade", serving.get("cascade") or {})
-    attn = serving.get("attn") or {}
-    section("attn", attn if attn.get("total_bytes") else {})
     section("dispatch", serving.get("dispatch") or {})
-    section("stage_seconds", serving.get("stage_seconds") or {})
     section("occupancy", serving.get("occupancy") or {})
     section("lanes", serving.get("lanes") or {})
     section("tenants", serving.get("tenants") or {})
@@ -325,9 +322,8 @@ def stats(url, as_json):
             if k in sched
         })
     if not any((latency, serving.get("prefix"), serving.get("spec"),
-                serving.get("cascade"), attn.get("total_bytes"),
-                serving.get("dispatch"),
-                serving.get("stage_seconds"), serving.get("occupancy"),
+                serving.get("cascade"), serving.get("dispatch"),
+                serving.get("occupancy"),
                 hbm.get("current_bytes"), sched)):
         click.echo("no metrics recorded yet")
 

@@ -106,9 +106,7 @@ class FusedChainNode(Node):
     operators as ONE step per epoch.
 
     The scheduler's epoch pump pays, per operator per epoch, a Python
-    dispatch, a ``Batch`` rematerialization and a consolidate pass — the
-    "engine tax" that put the engine-level ingest path at 0.76x of the
-    kernel-level headline. A chain of stateless per-row operators
+    dispatch, a ``Batch`` rematerialization and a consolidate pass. A chain of stateless per-row operators
     (select / filter / remove_errors / column projection) needs none of
     that: the composed column program can run over the raw
     ``(keys, cols, diffs)`` arrays once per batch. Filter masks apply

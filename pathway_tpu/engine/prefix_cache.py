@@ -407,7 +407,7 @@ class PrefixCache:
     def reset(self) -> None:
         """Drop the whole tree. ADOPTED mode unpins every cached block
         back into the global allocator — only call with no live refs
-        (e.g. the bench's between-arm reset); arena mode returns every
+        (e.g. a reset between two measured traces); arena mode returns every
         block to the private free list."""
         blocks, stack = [], list(self._root.children.values())
         while stack:

@@ -1,5 +1,5 @@
-"""Operator probes — per-node runtime statistics, device-dispatch counters
-and a roofline model.
+"""Operator probes — per-node runtime statistics and device-dispatch
+counters.
 
 The analog of the reference's prober machinery (`src/engine/graph.rs:533`
 ``ProberStats``/``OperatorStats``, ``src/engine/progress_reporter.rs:17-90``):
@@ -7,29 +7,19 @@ the scheduler times every operator step and counts rows; snapshots feed the
 console dashboard (``internals/monitoring.py``), the Prometheus endpoint
 (``internals/http_server.py``) and ``pw.run``'s final summary.
 
-Three additions beyond the reference:
+One addition beyond the reference:
 
 * **device-dispatch counters** — kernels (``models/embedder.py``,
   ``ops/knn.py``) call :func:`record_device_dispatch` on every accelerator
   round trip; counts accumulate globally per kind and, when the dispatch
-  happens inside an operator ``step``, per operator. The per-doc engine tax
-  is ``wall - dispatch`` made visible instead of guessed.
-* **roofline model** — :class:`RooflineModel` accumulates (seconds, FLOPs,
-  bytes moved) per pipeline phase and reports MFU, memory-bandwidth
-  utilisation and the arithmetic-intensity-implied bound, so the bench's
-  "ingest MFU" line is derived from accounting, not vibes.
-* **pipeline-stage ledger** — :func:`record_stage` accumulates host busy
-  seconds per ingest stage (tokenize / h2d / dispatch / drain) and
-  :func:`bubble_attribution` splits a window's wall time across them with
-  device compute as the residual, so the non-MFU fraction is attributed
-  instead of unexplained.
+  happens inside an operator ``step``, per operator.
 
 Since the observability PR every ledger is a thin shim over ONE
 :class:`MetricsRegistry` (``REGISTRY``): a thread-safe store of named
 counters, gauges and log-bucketed histograms with label sets. The shims
 keep the historical ``record_*`` / ``*_stats`` / ``reset_*`` signatures
-and return shapes byte-for-byte, so every existing call site (bench.py,
-kernels, tests) keeps working, while the registry adds what the ledgers
+and return shapes byte-for-byte, so every existing call site (kernels,
+tests) keeps working, while the registry adds what the ledgers
 never had: per-request latency histograms (TTFT / TPOT / queue-wait /
 e2e, fed by ``engine/tracing.py`` spans), one consistent
 :meth:`MetricsRegistry.snapshot` dict, and an OpenMetrics export path
@@ -46,41 +36,6 @@ import time
 
 from pathway_tpu.analysis.annotations import guarded_by
 from pathway_tpu.analysis.runtime import make_lock
-
-# What a utilization reads when the device selected no peak to divide by
-NOT_MEASURED = "not measured"
-
-
-@dataclasses.dataclass(frozen=True)
-class DevicePeaks:
-    """Published peaks of ONE chip: the denominators of every utilization."""
-
-    bf16_flops: float       # FLOP/s
-    hbm_bytes_per_s: float  # bytes/s
-    source: str
-
-
-# keyed by ``jax.devices()[0].device_kind``; a device that is not in the
-# table has no utilization — never another device's peaks
-DEVICE_PEAKS: dict[str, DevicePeaks] = {
-    "TPU v5 lite": DevicePeaks(
-        bf16_flops=197e12, hbm_bytes_per_s=819e9,
-        source='Google Cloud documentation, "TPU v5e"',
-    ),
-}
-
-
-def device_peaks(device_kind: str | None = None) -> DevicePeaks | None:
-    """The peaks row for ``device_kind`` (default: this process's first
-    device, which initialises the backend), or ``None`` for a device the
-    table does not hold — the roofline functions below then report
-    :data:`NOT_MEASURED` in every peak-derived field."""
-    if device_kind is None:
-        import jax
-
-        device_kind = jax.devices()[0].device_kind
-    return DEVICE_PEAKS.get(device_kind)
-
 
 # --------------------------------------------------------------------- #
 # the unified metrics registry
@@ -121,10 +76,6 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
     "spec_events": (
         "counter", "kind", "Speculative-decode events (drafted/accepted/"
         "emitted tokens, verify/draft steps)"),
-    "stage_seconds": (
-        "counter", "stage", "Host busy seconds per pipeline stage"),
-    "stage_items": (
-        "counter", "stage", "Items processed per pipeline stage"),
     "serving_occupancy": (
         "gauge", "server", "Useful slot-steps / total slot-steps of a "
         "continuous decode server"),
@@ -508,17 +459,13 @@ def reset_latency_metrics() -> None:
 
 def serving_snapshot() -> dict:
     """The serving-side view every consumer shares — ``/v1/statistics``,
-    the rich dashboard panel and bench.py all read THIS, so bench keys
-    and scraped metrics cannot drift."""
+    the rich dashboard panel and ``cli stats`` all read THIS, so their keys
+    and the scraped metrics cannot drift."""
     return {
         "prefix": prefix_stats(),
         "spec": spec_stats(),
         "cascade": cascade_stats(),
-        "attn": attn_stats(),
         "dispatch": dispatch_counts(),
-        "stage_seconds": {
-            k: round(v, 6) for k, v in sorted(stage_seconds().items())
-        },
         "occupancy": {
             k: round(v, 4)
             for k, v in REGISTRY.labelled(
@@ -927,7 +874,7 @@ def watch_compiles() -> None:
 #
 # Which index answered retrieval queries: ``dense`` (single-device
 # brute force / IVF) or ``sharded_ivf`` (mesh-resident, one shard per
-# device). Tests and the bench assert that mesh serving actually routed
+# device). Tests assert that mesh serving actually routed
 # queries through the sharded index rather than silently falling back.
 
 _retrieval_backends: dict[str, int] = {}  # backend -> queries served
@@ -996,50 +943,6 @@ def cascade_stats() -> dict:
 
 def reset_cascade_stats() -> None:
     REGISTRY.remove("cascade_pairs", "cascade_flops")
-
-
-# --------------------------------------------------------------------- #
-# attention HBM-traffic ledger (flash prefill)
-#
-# An ACCOUNTING MODEL, not a hardware counter: each attention dispatch is
-# charged the bytes its arm's graph materializes per layer — the dense
-# path's f32 score/prob/mask tensors (quadratic in sequence), or the
-# flash kernels' streamed q/k/v/o tiles (linear; see
-# ``models/flash_attention.attn_bytes_dense`` / ``attn_bytes_flash``).
-# ``attn_bytes_saved`` is the dense-score accounting minus what the
-# flash arm paid — what PATHWAY_TPU_FLASH_PREFILL kept out of HBM.
-
-def record_attn(path: str, nbytes: float, saved: float = 0.0) -> None:
-    """Account ``nbytes`` of modeled attention traffic on ``path``
-    (``prefill`` = whole-prompt admits, ``chunk`` = chunked-prefill
-    pieces, ``encoder`` = embedder/cross-encoder stacks); ``saved`` is
-    the dense-vs-flash delta when the flash arm ran. Thread-safe;
-    called host-side at each dispatch site."""
-    REGISTRY.counter_add("attn_bytes", nbytes, path=path)
-    if saved:
-        REGISTRY.counter_add("attn_bytes_saved", saved, path=path)
-
-
-def attn_stats() -> dict:
-    """Snapshot: per-path modeled attention bytes, bytes saved vs the
-    dense-score accounting, and their totals."""
-    bytes_ = {
-        k: int(v) for k, v in REGISTRY.labelled("attn_bytes", "path").items()
-    }
-    saved = {
-        k: int(v)
-        for k, v in REGISTRY.labelled("attn_bytes_saved", "path").items()
-    }
-    return {
-        "bytes": bytes_,
-        "bytes_saved": saved,
-        "total_bytes": sum(bytes_.values()),
-        "total_saved": sum(saved.values()),
-    }
-
-
-def reset_attn_stats() -> None:
-    REGISTRY.remove("attn_bytes", "attn_bytes_saved")
 
 
 # --------------------------------------------------------------------- #
@@ -1158,193 +1061,6 @@ def reset_spec_stats() -> None:
     REGISTRY.remove("spec_events")
 
 
-# --------------------------------------------------------------------- #
-# pipeline-stage ledger (bubble attribution)
-#
-# The roofline says HOW FAR the device is from peak; this ledger says
-# WHERE the missing time went. Host-measurable pipeline stages (tokenize,
-# h2d staging, dispatch enqueue, drain) record their busy seconds here;
-# :func:`bubble_attribution` turns a window's ledger into a percentage
-# breakdown of wall time, with device compute as the residual (under
-# JAX's async dispatch the host never observes compute directly).
-
-def record_stage(stage: str, seconds: float, items: int = 1) -> None:
-    """Accumulate ``seconds`` of host busy time for pipeline ``stage``
-    (e.g. ``tokenize``, ``h2d``, ``dispatch``, ``drain``). Thread-safe;
-    called by stage workers, so overlapped stages can legitimately sum to
-    more than wall time — that excess IS the overlap evidence."""
-    REGISTRY.counter_add("stage_seconds", seconds, stage=stage)
-    REGISTRY.counter_add("stage_items", items, stage=stage)
-
-
-def stage_seconds() -> dict[str, float]:
-    return REGISTRY.labelled("stage_seconds", "stage")
-
-
-def reset_stage_seconds() -> None:
-    REGISTRY.remove("stage_seconds", "stage_items")
-
-
-def bubble_attribution(wall_s: float, stages: dict[str, float] | None = None) -> dict:
-    """Split a window's wall time across pipeline stages.
-
-    ``stages`` defaults to the global ledger. Host stages are reported as
-    measured; ``compute`` is the residual ``wall - sum(host stages)``
-    clipped at zero — the time the host spent neither tokenizing, staging
-    nor draining, i.e. waiting on (or overlapped with) device compute.
-    ``pct`` values therefore sum to ~100 of wall when stages run serially
-    on one thread; ``sum_host_pct`` above 100 means background workers
-    overlapped host stages with each other or with compute."""
-    stages = dict(stages if stages is not None else stage_seconds())
-    wall = max(wall_s, 1e-12)
-    host_total = sum(stages.values())
-    compute = max(0.0, wall_s - host_total)
-    out: dict = {
-        "wall_s": round(wall_s, 6),
-        "stages_s": {k: round(v, 6) for k, v in sorted(stages.items())},
-        "compute_residual_s": round(compute, 6),
-        "pct": {
-            k: round(100.0 * v / wall, 2) for k, v in sorted(stages.items())
-        },
-        "sum_host_pct": round(100.0 * host_total / wall, 2),
-    }
-    out["pct"]["compute"] = round(100.0 * compute / wall, 2)
-    return out
-
-
-# --------------------------------------------------------------------- #
-# roofline model
-
-
-def roofline_ceiling(
-    flops: float,
-    bytes_moved: float,
-    peaks: DevicePeaks | None,
-    *,
-    wall_s: float | None = None,
-) -> dict:
-    """The roofline-implied CEILING for a workload, not just its score.
-
-    ``max(flops/peak_flops, bytes/peak_bw)`` is the hard lower bound on
-    device time; ``ceiling_mfu_pct`` is the best MFU the workload can post
-    even at 100% hardware efficiency — below 100 exactly when the shape is
-    bandwidth-bound (arithmetic intensity under the ridge point). Pass the
-    observed ``wall_s`` to also get the attainment split: how much of the
-    wall is the unavoidable bound vs overhead above it. This turns "MFU is
-    34%" into either "the ceiling itself is 41% — we are at 83% of
-    attainable" or "the ceiling is 95% — the other 60% is ours to close".
-    ``peaks=None`` (a device outside :data:`DEVICE_PEAKS`) leaves only
-    the shape-derived intensity; the rest reads :data:`NOT_MEASURED`.
-    """
-    if peaks is None:
-        return {
-            "arith_intensity": round(flops / max(bytes_moved, 1.0), 2),
-            **dict.fromkeys(
-                ("bound", "ridge_intensity", "ceiling_mfu_pct",
-                 "ceiling_hbm_pct", "attained_of_ceiling_pct"),
-                NOT_MEASURED,
-            ),
-        }
-    peak_flops, peak_bytes = peaks.bf16_flops, peaks.hbm_bytes_per_s
-    t_compute = flops / peak_flops
-    t_memory = bytes_moved / peak_bytes
-    t_lb = max(t_compute, t_memory, 1e-12)
-    out: dict = {
-        "flops_time_s": round(t_compute, 6),
-        "memory_time_s": round(t_memory, 6),
-        "bound_time_s": round(t_lb, 6),
-        "bound": "compute" if t_compute >= t_memory else "memory",
-        "arith_intensity": round(flops / max(bytes_moved, 1.0), 2),
-        "ridge_intensity": round(peak_flops / peak_bytes, 2),
-        "ceiling_mfu_pct": round(100.0 * t_compute / t_lb, 2),
-        "ceiling_hbm_pct": round(100.0 * t_memory / t_lb, 2),
-    }
-    if wall_s is not None:
-        wall = max(wall_s, 1e-12)
-        out["wall_s"] = round(wall_s, 6)
-        out["attained_of_ceiling_pct"] = round(100.0 * t_lb / wall, 2)
-        out["overhead_above_bound_s"] = round(max(0.0, wall_s - t_lb), 6)
-    return out
-
-
-@dataclasses.dataclass
-class PhaseRoofline:
-    """Accumulated work of one pipeline phase (e.g. ``ingest``, ``query``)."""
-
-    name: str
-    seconds: float = 0.0
-    flops: float = 0.0
-    bytes_moved: float = 0.0
-    dispatches: int = 0
-
-    def summary(self, peaks: DevicePeaks | None) -> dict:
-        ai = self.flops / max(self.bytes_moved, 1.0)
-        out = {
-            "phase": self.name,
-            "seconds": round(self.seconds, 6),
-            "gflops": round(self.flops / 1e9, 3),
-            "gbytes": round(self.bytes_moved / 1e9, 3),
-            "dispatches": self.dispatches,
-            "mfu_pct": NOT_MEASURED,
-            "hbm_util_pct": NOT_MEASURED,
-            "arith_intensity": round(ai, 2),
-            "bound": NOT_MEASURED,
-        }
-        if peaks is None:
-            return out
-        s = max(self.seconds, 1e-12)
-        mfu = self.flops / (s * peaks.bf16_flops)
-        bw_util = self.bytes_moved / (s * peaks.hbm_bytes_per_s)
-        # arithmetic intensity vs the machine's ridge point decides which
-        # ceiling the phase is under; the far-from-both case is overhead
-        ridge = peaks.bf16_flops / peaks.hbm_bytes_per_s
-        bound = "compute" if ai >= ridge else "memory"
-        if max(mfu, bw_util) < 0.05:
-            bound = "overhead"
-        out.update(
-            mfu_pct=round(100.0 * mfu, 2),
-            hbm_util_pct=round(100.0 * bw_util, 2),
-            bound=bound,
-        )
-        return out
-
-
-@guarded_by(phases="_lock")
-class RooflineModel:
-    """Per-phase (seconds, FLOPs, bytes) ledger -> MFU / bandwidth report
-    against ``peaks`` (:func:`device_peaks` of the device that ran it)."""
-
-    def __init__(self, peaks: DevicePeaks | None):
-        self.peaks = peaks
-        self._lock = make_lock("probes.roofline")
-        self.phases: dict[str, PhaseRoofline] = {}
-
-    def add(
-        self,
-        phase: str,
-        *,
-        seconds: float = 0.0,
-        flops: float = 0.0,
-        bytes_moved: float = 0.0,
-        dispatches: int = 0,
-    ) -> None:
-        with self._lock:
-            p = self.phases.get(phase)
-            if p is None:
-                p = self.phases[phase] = PhaseRoofline(name=phase)
-            p.seconds += seconds
-            p.flops += flops
-            p.bytes_moved += bytes_moved
-            p.dispatches += dispatches
-
-    def summary(self) -> dict:
-        with self._lock:
-            return {
-                name: p.summary(self.peaks)
-                for name, p in self.phases.items()
-            }
-
-
 @dataclasses.dataclass
 class OperatorStats:
     name: str
@@ -1445,22 +1161,4 @@ class SchedulerStats:
                 "steps_skipped": self.steps_skipped,
                 "operators": [dataclasses.asdict(s) for s in self.operators.values()],
                 "connectors": [dataclasses.asdict(s) for s in self.connectors.values()],
-            }
-
-    def engine_tax(self) -> dict:
-        """Aggregate engine-overhead view: total operator wall seconds split
-        into dispatch-bearing vs pure-Python steps. ``wall_s`` is the sum of
-        per-operator step time; with the device-dispatch counters this
-        separates 'the chip was working' from 'the engine was shuffling'."""
-        with self._lock:
-            wall = sum(s.total_time_s for s in self.operators.values())
-            steps = sum(s.epochs for s in self.operators.values())
-            dispatches = sum(s.dispatches for s in self.operators.values())
-            return {
-                "wall_s": round(wall, 6),
-                "steps": steps,
-                "steps_skipped": self.steps_skipped,
-                "operator_dispatches": dispatches,
-                "fused_chains": self.fused_chains,
-                "fused_nodes": self.fused_nodes,
             }

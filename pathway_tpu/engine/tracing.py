@@ -257,47 +257,15 @@ def _record(span_dict: dict) -> None:
 # one open across an ``await`` (a TraceMe belongs to its thread), which
 # is why REST requests are spans and not regions.
 
-_STAGES = ("tokenize", "h2d", "dispatch", "drain", "append")
 
-
-class _StageRegion:
-    """A region that also feeds ``probes.record_stage`` its own duration
-    on exit: the five ingest stages only."""
-
-    __slots__ = ("_stage", "_items", "_annotation", "_t0")
-
-    def __init__(self, annotation, stage: str, items: int):
-        self._annotation = annotation
-        self._stage = stage
-        self._items = items
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        self._annotation.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        self._annotation.__exit__(*exc)
-        probes.record_stage(
-            self._stage, time.perf_counter() - self._t0, self._items
-        )
-        return False
-
-
-def region(name: str, stage: str | None = None, items: int = 1, **ids):
+def region(name: str, **ids):
     """``with region("pw.engine.epoch", t=t, rows=n): ...`` — a nested,
     thread-local interval named ``pw.<layer>.<what>`` with its ids as
     stats. The profiler session is the only switch: with none running
     (every production minute) the TraceMe records nothing; inside one the
     region lands in the ``.xplane.pb`` host plane beside the device's
-    ops. ``stage=`` (one of the five ingest stages) also accumulates the
-    region's duration, over ``items``, into ``probes.stage_seconds()``."""
-    annotation = TraceAnnotation(name, **ids)
-    if stage is None:
-        return annotation
-    if stage not in _STAGES:
-        raise ValueError(f"unknown ingest stage {stage!r}")
-    return _StageRegion(annotation, stage, items)
+    ops."""
+    return TraceAnnotation(name, **ids)
 
 
 # full collections hold the interpreter for a quarter of a second and

@@ -102,9 +102,8 @@ class Tunable:
 @dataclass(frozen=True)
 class Flag:
     """One runtime knob: its env var, how to read it, and its one-line
-    doc. ``attr`` is the ``PathwayConfig`` property name (None for knobs
-    read elsewhere, e.g. by bench.py, that are registered only so the
-    README table includes them); ``group`` places the flag in a README
+    doc. ``attr`` is the ``PathwayConfig`` property name; ``group``
+    places the flag in a README
     table (``pipeline`` / ``query`` / ``observability``); ``minimum``
     clamps explicit
     values (defaults are trusted as-is, matching the historical
@@ -134,7 +133,7 @@ class Flag:
     kind: str  # "bool" | "int" | "float" | "str"
     default: Any
     doc: str
-    attr: str | None = None
+    attr: str
     group: str | None = None
     minimum: float | None = None
     parse: Any = None
@@ -278,10 +277,7 @@ FLAG_REGISTRY: list[Flag] = [
         doc="Brute-force KNN scoring with f32 *operands* (not just f32 "
             "accumulation). Recovers the bf16-operand recall loss at "
             "~2× the gemm cost; flip it when recall@k matters more than "
-            "ingest throughput. The bench config-2 phase now reports "
-            "recall BOTH ways (`knn_recall_at_10` bf16, "
-            "`knn_recall_at_10_f32` with this flag) so the trade is in "
-            "the record.",
+            "ingest throughput.",
     ),
     Flag(
         env="PATHWAY_TPU_FUSED_H2D", kind="bool", default=True,
@@ -626,16 +622,7 @@ FLAG_REGISTRY: list[Flag] = [
             "`SentenceTransformerEmbedder`: byte-identical texts "
             "(re-ingested unchanged chunks) serve from a bounded LRU "
             "instead of re-dispatching; an all-hit microbatch never "
-            "touches the device. The ingest bench reports the hit "
-            "ledger under `detail.embed_dedup`.",
-    ),
-    Flag(
-        env="PATHWAY_BENCH_SHARD_ROWS", kind="int", default=1048576,
-        group="pipeline", minimum=1,
-        doc="Rows PER SHARD for the bench config-5 sharded-IVF phase (8 "
-            "virtual-mesh shards); the phase walks a ladder down from "
-            "this target and records `bound_by` when host CPU memory, "
-            "not the design point, set the ceiling.",
+            "touches the device.",
     ),
     Flag(
         env="PATHWAY_TPU_MESH", kind="bool", default=False,
@@ -1373,8 +1360,6 @@ def _install_flag_properties() -> None:
     """Attach one read-per-use property per registry flag. Declared once
     in :data:`FLAG_REGISTRY`; the property is just ``Flag.read``."""
     for f in FLAG_REGISTRY:
-        if f.attr is None:
-            continue
         if hasattr(PathwayConfig, f.attr):  # never shadow a manual attr
             raise RuntimeError(f"duplicate config attr: {f.attr}")
 
@@ -1391,7 +1376,7 @@ pathway_config = PathwayConfig()
 
 def enable_compile_cache() -> str:
     """The ONE place the persistent XLA compilation cache is configured,
-    for library use, tests and the bench alike. Where
+    for library use, tests and the benchmark alike. Where
     ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own handling of it stands
     and no directory is set here; where it is not, the cache lives at the
     fixed ``<checkout>/.jax_cache`` (the directory is part of the cache

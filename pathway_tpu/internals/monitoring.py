@@ -7,7 +7,7 @@ per-operator row/latency stats from the scheduler's ``SchedulerStats``
 dataflow. ``MonitoringLevel`` mirrors the reference enum surface.
 
 The dashboard reads ``probes.unified_snapshot`` — the same payload that
-``/v1/statistics`` serves and bench.py summarizes — so a serving panel
+``/v1/statistics`` serves and ``cli stats`` prints — so a serving panel
 (slot occupancy, prefix hit rate, speculative acceptance, TTFT p50/p95)
 appears under the operator table whenever serving metrics exist.
 """

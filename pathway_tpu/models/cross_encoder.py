@@ -39,25 +39,6 @@ def score_fn(params, head, input_ids, attention_mask, cfg: TransformerConfig,
     return (pooled @ head["w"] + head["b"])[:, 0]
 
 
-def _record_rerank_attn(cfg, batch, seq, flash):
-    """Charge the attention-bytes ledger for one rerank batch (accounting
-    model — see probes.record_attn)."""
-    from pathway_tpu.engine.probes import record_attn
-    from pathway_tpu.models.flash_attention import (
-        attn_bytes_dense,
-        attn_bytes_flash,
-    )
-
-    batch, seq = int(batch), int(seq)
-    dense = cfg.layers * attn_bytes_dense(seq, seq, cfg.heads, batch=batch)
-    if flash:
-        fl = cfg.layers * attn_bytes_flash(
-            seq, seq, cfg.heads, cfg.hidden // cfg.heads, batch=batch)
-        record_attn("encoder", fl, saved=dense - fl)
-    else:
-        record_attn("encoder", dense)
-
-
 class CrossEncoderModel:
     """Host-facing reranker: [(query, doc)] -> np.ndarray scores."""
 
@@ -153,8 +134,6 @@ class CrossEncoderModel:
             out = score_fn(self.params, self.head, jnp.asarray(ids),
                            jnp.asarray(mask), self.cfg, jnp.asarray(types),
                            flash=self.flash_prefill)
-        _record_rerank_attn(self.cfg, ids.shape[0], ids.shape[1],
-                            self.flash_prefill)
         return (out, len(pairs))
 
     def score_resolve(self, handles) -> list[np.ndarray]:

@@ -432,8 +432,8 @@ def _split_heads(x, nh, hd):
 # ---- int8 KV quantization (PATHWAY_TPU_KV_QUANT=int8) ---------------------
 #
 # Decode streams the whole KV cache from HBM every step, so halving its
-# bytes is a direct decode-throughput lever (the phase runs at ~63.5% HBM
-# util, BENCH_r05). Storage is symmetric per-(layer, slot, head, token)
+# bytes is a direct decode-throughput lever (not measured on the
+# chip). Storage is symmetric per-(layer, slot, head, token)
 # int8: one f32 scale per head-token (max|x| / 127 over the head dim)
 # rides next to the payload, so a head-token costs hd + 4 bytes instead
 # of 2*hd bf16 bytes — 1.88x the slots per HBM byte at hd=64. Writes

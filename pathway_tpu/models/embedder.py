@@ -126,23 +126,6 @@ def _token_states_packed(params, packed, proj, cfg: TransformerConfig,
     return _quant_tokens(_project_tokens(hidden, packed[1], proj))
 
 
-def _record_encoder_attn(cfg: TransformerConfig, batch: int, seq: int,
-                         flash: bool) -> None:
-    """Charge one encoder dispatch to the attention ledger (accounting
-    model, per layer x batch; see ``engine/probes.record_attn``)."""
-    from pathway_tpu.engine.probes import record_attn
-    from pathway_tpu.models import flash_attention as _fa
-
-    dense = cfg.layers * _fa.attn_bytes_dense(seq, seq, cfg.heads,
-                                              batch=batch)
-    if flash:
-        paid = cfg.layers * _fa.attn_bytes_flash(seq, seq, cfg.heads,
-                                                 cfg.head_dim, batch=batch)
-        record_attn("encoder", paid, saved=max(0, dense - paid))
-    else:
-        record_attn("encoder", dense)
-
-
 class _PendingEmbed:
     """Handle returned by the pipelined ``embed_submit``: tokenize and
     dispatch run on background stage workers; :meth:`wait` blocks until
@@ -222,8 +205,7 @@ class _IngestPipeline:
         try:
             model = self._model
             handle.span.event("admit")
-            with region("pw.embed.tokenize", stage="tokenize",
-                        rows=len(texts)):
+            with region("pw.embed.tokenize", rows=len(texts)):
                 ids, mask = model.tokenizer(
                     texts, max_length=model.max_length)
                 ids, mask = pad_to_buckets(ids, mask)
@@ -268,7 +250,7 @@ class _IngestPipeline:
             self._chaos_h2d.maybe_fail()
         model = self._model
         fused = pathway_config.fused_h2d
-        with region("pw.embed.h2d", stage="h2d", rows=n):
+        with region("pw.embed.h2d", rows=n):
             if fused:
                 # one contiguous transfer instead of two (ids and mask are
                 # both int32, so the stack is a cheap host-side copy)
@@ -278,7 +260,7 @@ class _IngestPipeline:
                 dev_mask = jax.device_put(mask)
         handle.span.event("h2d")
         flash = model.flash_prefill
-        with region("pw.embed.dispatch", stage="dispatch", rows=n):
+        with region("pw.embed.dispatch", rows=n):
             if kind == "tokens":
                 proj = model.late_projection_matrix(dc)
                 if fused:
@@ -307,8 +289,6 @@ class _IngestPipeline:
                     )
                 record_device_dispatch("embed_dispatch")
                 out = out.astype(jnp.float16)
-            _record_encoder_attn(model.cfg, int(ids.shape[0]),
-                                 int(ids.shape[1]), flash)
             for leaf in jax.tree.leaves(out):
                 try:
                     leaf.copy_to_host_async()
@@ -500,8 +480,6 @@ class SentenceEmbedderModel:
         out = embed_fn(self.params, jnp.asarray(ids), jnp.asarray(mask),
                        self.cfg, flash=self.flash_prefill)
         record_device_dispatch("embed_dispatch")
-        _record_encoder_attn(self.cfg, int(ids.shape[0]),
-                             int(ids.shape[1]), self.flash_prefill)
         return (out, len(texts))
 
     def embed_resolve(self, handles) -> list[np.ndarray]:
@@ -515,8 +493,7 @@ class SentenceEmbedderModel:
             h.wait() if isinstance(h, _PendingEmbed) else h for h in handles
         ]
         # the host WAITING for the device, and named so
-        with region("pw.embed.drain", stage="drain",
-                    rows=sum(n for _, n in resolved)):
+        with region("pw.embed.drain", rows=sum(n for _, n in resolved)):
             fetched = jax.device_get([out for out, _ in resolved])
         record_device_dispatch("embed_drain")
         for h in handles:
@@ -562,8 +539,6 @@ class SentenceEmbedderModel:
             flash=self.flash_prefill,
         )
         record_device_dispatch("token_bank_dispatch")
-        _record_encoder_attn(self.cfg, int(ids.shape[0]),
-                             int(ids.shape[1]), self.flash_prefill)
         for leaf in jax.tree.leaves(out):
             try:
                 leaf.copy_to_host_async()
@@ -579,8 +554,7 @@ class SentenceEmbedderModel:
         resolved = [
             h.wait() if isinstance(h, _PendingEmbed) else h for h in handles
         ]
-        with region("pw.embed.drain", stage="drain",
-                    rows=sum(n for _, n in resolved)):
+        with region("pw.embed.drain", rows=sum(n for _, n in resolved)):
             fetched = jax.device_get([out for out, _ in resolved])
         record_device_dispatch("token_bank_drain")
         for h in handles:

@@ -450,29 +450,3 @@ def flash_chunk_attn_paged(q, kb, vb, kb_scale, vb_scale, tbl_row,
         sm_scale=sm_scale, block_t=T, block_k=Bk, n_kt=M,
         quant=quant, interpret=interpret, nh=nh, hd=hd,
     )
-
-
-# --------------------------------------------------------------------------
-# HBM-traffic accounting model (probes: attn_bytes / attn_bytes_saved)
-# --------------------------------------------------------------------------
-
-def attn_bytes_dense(n_q, n_k, heads, batch=1):
-    """Bytes the DENSE path materializes per attention call, per layer:
-    f32 scores + probs (B, nh, Sq, Sk) and the additive mask bias
-    (B, 1, Sq, Sk) — the quadratic objects flash eliminates. This is an
-    accounting model of tensors the dense graph instantiates, not a
-    hardware counter measurement."""
-    return 4 * batch * n_q * n_k * (2 * heads + 1)
-
-
-def attn_bytes_flash(n_q, n_k, heads, head_dim, batch=1, itemsize=4):
-    """Bytes the flash kernel streams per attention call, per layer:
-    q and o once, k and v once each, plus the (max, denom) running
-    stats — linear in sequence length. ``itemsize`` is the KV element
-    size (1 for int8 cached KV, whose scales add one f32 per token)."""
-    qo = 4 * batch * heads * 2 * n_q * head_dim
-    kv = itemsize * batch * heads * 2 * n_k * head_dim
-    if itemsize == 1:
-        kv += 4 * batch * heads * 2 * n_k
-    stats = 4 * batch * heads * 2 * n_q
-    return qo + kv + stats

@@ -47,8 +47,8 @@ _NEG_INF = -1e30
 def _encoder_flops(cfg: TransformerConfig, seq: int, n_layers: int,
                    pairs: int) -> float:
     """Model FLOPs of ``pairs`` sequences of length ``seq`` through
-    ``n_layers`` encoder layers (same accounting as bench.py's
-    ``flops_per_doc``: qkv+attn-out+mlp gemms + 2 S^2 attention gemms)."""
+    ``n_layers`` encoder layers (qkv+attn-out+mlp gemms + 2 S^2 attention
+    gemms)."""
     h, i = cfg.hidden, cfg.intermediate
     per_layer = 2 * seq * h * (3 * h + h + 2 * i) + 4 * seq * seq * h
     return float(pairs) * n_layers * per_layer

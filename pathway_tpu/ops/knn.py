@@ -341,7 +341,7 @@ class BruteForceKnnIndex:
         keep the whole batch in C — this sits on the per-batch ingest path."""
         # "append" = the host-side index bookkeeping share of the ingest
         # wall; the vector write itself rides the fused device dispatch
-        with region("pw.index.append", stage="append", rows=len(keys)):
+        with region("pw.index.append", rows=len(keys)):
             self._slot_of.update(zip(keys, range(start, start + len(keys))))
             self._keys.extend(keys)
             self.n += len(keys)

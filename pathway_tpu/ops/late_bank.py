@@ -19,8 +19,8 @@ independent of encoder depth. At ``dc``=32 a bank token costs
 
 This module holds the pure/jitted pieces — projection, quantized
 token-state encoding, dequant + MaxSim — shared by the fused query
-kernel (``ops/fused_query.py``), the embedder token-level submit path
-(``models/embedder.py``) and the bench. Bank LIFECYCLE (append /
+kernel (``ops/fused_query.py``) and the embedder token-level submit path
+(``models/embedder.py``). Bank LIFECYCLE (append /
 retraction / compaction mirroring the IVF row lifecycle) lives with the
 row owners: :class:`~pathway_tpu.ops.fused_query.FusedRAGPipeline`.
 """

@@ -16,8 +16,7 @@ failure sweep) and the ingest ``StageWorker`` contract in
   device dispatch per ``(kind, k)`` group — homogeneous load is exactly
   one dispatch per tick.
 * results resolve back per request; ``stats()`` reports ticks, the
-  batch-size histogram and coalescing rate the bench's Poisson phase
-  plots.
+  batch-size histogram and the coalescing rate.
 
 The server is opt-in: code that never constructs one keeps today's
 per-call query path byte-for-byte.
@@ -36,11 +35,12 @@ from pathway_tpu.internals.config import pathway_config
 
 class QueryRequest:
     """One in-flight query. ``done`` fires once ``result`` / ``error`` is
-    set; timestamps are ``time.monotonic()`` for latency accounting."""
+    set; ``submitted_at`` (``time.monotonic()``) opens the tick's window,
+    and the request's span carries its latency."""
 
     __slots__ = (
         "kind", "text", "k", "done", "result", "error",
-        "submitted_at", "finished_at", "span",
+        "submitted_at", "span",
     )
 
     def __init__(self, kind: str, text: str, k: int):
@@ -53,7 +53,6 @@ class QueryRequest:
         self.result = None
         self.error: BaseException | None = None
         self.submitted_at = time.monotonic()
-        self.finished_at = 0.0
         self.span = tracing.NULL_SPAN  # replaced by QueryServer.submit
 
     def wait(self, timeout: float | None = None):
@@ -62,10 +61,6 @@ class QueryRequest:
         if self.error is not None:
             raise self.error
         return self.result
-
-    @property
-    def latency_s(self) -> float:
-        return max(0.0, self.finished_at - self.submitted_at)
 
 
 @guarded_by(
@@ -190,10 +185,8 @@ class QueryServer:
             try:
                 self._serve(batch)
             except BaseException as exc:  # noqa: BLE001 - sweep to callers
-                now = time.monotonic()
                 for req in batch:
                     req.error = exc
-                    req.finished_at = now
                     req.span.finish(error=True)
                     req.done.set()
                 if self._supervised and self._restarts_left > 0:
@@ -224,7 +217,6 @@ class QueryServer:
                     self._cond.notify_all()
                 for req in pending:
                     req.error = exc
-                    req.finished_at = now
                     req.span.finish(error=True)
                     req.done.set()
                 return
@@ -255,10 +247,8 @@ class QueryServer:
                 # everything queued — keep serving
                 from pathway_tpu.engine import probes
 
-                now = time.monotonic()
                 for req in reqs:
                     req.error = exc
-                    req.finished_at = now
                     req.span.finish(error=True)
                     req.done.set()
                 probes.REGISTRY.counter_add(
@@ -267,10 +257,8 @@ class QueryServer:
                 )
                 failed_groups += 1
                 continue
-            now = time.monotonic()
             for req, res in zip(reqs, results):
                 req.result = res
-                req.finished_at = now
                 req.span.event("drain", group=len(reqs))
                 req.span.finish()
                 req.done.set()
@@ -321,7 +309,6 @@ class QueryServer:
         for req in pending:
             if not req.done.is_set():
                 req.error = RuntimeError("query server shut down")
-                req.finished_at = time.monotonic()
                 req.span.finish(error=True)
                 req.done.set()
 

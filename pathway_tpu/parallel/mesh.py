@@ -54,7 +54,7 @@ class MeshRef:
 
 
 def local_mesh() -> Mesh:
-    """1-chip degenerate mesh (bench path: one real TPU)."""
+    """1-chip degenerate mesh (one real TPU)."""
     return make_mesh(jax.devices()[:1], dp=1, tp=1)
 
 
@@ -70,7 +70,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 # ---- serving mesh (PATHWAY_TPU_MESH) --------------------------------------
 #
-# The (dp, tp) mesh above serves the bench-ladder index kernels. The
+# The (dp, tp) mesh above serves the sharded index kernels. The
 # PRODUCT serving path (continuous decoder server, embedder, in-query
 # retrieval) runs on a three-axis ``(data, fsdp, tp)`` mesh instead:
 # ``tp`` carries Megatron tensor parallelism (attention heads / ffn

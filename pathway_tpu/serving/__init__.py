@@ -11,7 +11,7 @@ replica.  This package adds the horizontal layer:
   shared RAG prefixes keep landing on the replica whose cache already
   holds them.
 * :mod:`~pathway_tpu.serving.replica` — replica handles: in-process
-  (a ``TPUDecoderChat`` continuous server, used by bench/tests) and
+  (a ``TPUDecoderChat`` continuous server, used by tests) and
   subprocess-over-HTTP (spawned via the ``parallel/distributed.py``
   env contract, health-checked through ``/healthz`` + ``/readyz``).
 * :mod:`~pathway_tpu.serving.router` — :class:`FleetRouter` picks the

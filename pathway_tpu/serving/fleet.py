@@ -24,7 +24,7 @@
   per cooldown so the fleet never flaps.
 
 The manager is clock/sleep-injectable so the whole policy is testable
-without wall time, and usable tick-by-tick (no thread) from bench.
+without wall time, and usable tick-by-tick (no thread).
 """
 
 from __future__ import annotations

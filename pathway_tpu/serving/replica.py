@@ -4,8 +4,8 @@ Two shapes behind one duck type (``replica_id``, ``healthy()``,
 ``scrape()``, ``stop()``):
 
 * :class:`InProcessReplica` — wraps a continuous-mode
-  ``TPUDecoderChat`` living in this process.  This is what the bench
-  fleet arm and the tier-1 tests use: real decode, real prefix cache,
+  ``TPUDecoderChat`` living in this process.  This is what the tier-1
+  tests use: real decode, real prefix cache,
   no subprocess startup tax.  Supports :meth:`InProcessReplica.submit`
   (the PR-10 two-phase completion protocol).
 * :class:`HttpReplica` — a subprocess replica reached over HTTP,

@@ -397,15 +397,14 @@ class IvfKnnFactory(KnnIndexFactory):
     inverted lists, so per-query HBM traffic (the large-corpus bottleneck)
     drops ~``n_cells/nprobe`` vs a full scan, with recall governed by
     ``nprobe``. Rule of thumb: ``n_cells ≈ 2*sqrt(N)``, then raise
-    ``nprobe`` until recall@10 clears your bar (bench config5 measures
-    0.9+ recall at several-x exact-scan throughput on a 1M corpus)."""
+    ``nprobe`` until recall@10 clears your bar."""
 
     n_cells: int = 64
     nprobe: int = 8
     metric: DistanceMetric | str = DistanceMetric.COS
     train_after: int | None = None
     # jnp.int8 = quantized cell storage (half the HBM per probed row,
-    # int8-MXU scoring; bench config-5 reports the recall delta per run)
+    # int8-MXU scoring)
     dtype: Any = None
 
     def build_inner_index(self, data_column, metadata_column=None) -> InnerIndex:

@@ -1,9 +1,8 @@
 """Workload profiles for the autotuner.
 
-A :class:`WorkloadProfile` is a reusable, seeded trace shape — factored
-out of the ``bench.py`` serving/ingest traces — plus the slice of the
-flag surface worth searching for it and the SLO objectives a winning
-config must hold. ``run_trial`` plays one profile against the REAL
+A :class:`WorkloadProfile` is a reusable, seeded trace shape plus the
+slice of the flag surface worth searching for it and the SLO objectives a
+winning config must hold. ``run_trial`` plays one profile against the REAL
 serving/ingest stack in-process (a continuous ``TPUDecoderChat`` server
 or a pipelined ``SentenceEmbedderModel``), with the candidate flags
 applied through :func:`pathway_tpu.internals.config.flag_overrides`
@@ -193,7 +192,7 @@ _DECODER_RES = None
 def decoder_resources():
     """(params, cfg, tokenizer) for the serving profiles: a tiny seeded
     decoder, shared process-wide. ``run_trial(..., resources=)`` lets
-    bench.py substitute its own checkpoint."""
+    a caller substitute its own checkpoint."""
     global _DECODER_RES
     if _DECODER_RES is None:
         import jax

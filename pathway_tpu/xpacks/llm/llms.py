@@ -376,8 +376,7 @@ class TPUDecoderChat(BaseChat):
             # (no-op for f32 configs)
             self.params = jax.device_put(cast_params_for_inference(params, cfg))
         # HBM ledger: the decoder's physical param footprint (int8
-        # payloads + scales when quantized) at placement — the bench
-        # quant arm reads its bytes-saved headline from this gauge. The
+        # payloads + scales when quantized) at placement. The
         # continuous server re-records after mesh sharding with the
         # real per-device split.
         from pathway_tpu.engine.probes import record_hbm
@@ -1152,7 +1151,7 @@ class _ContinuousServer:
         self.kv_bytes_saved = 0
         if self.kv_quant:
             # ledger the HBM the int8 pool did NOT allocate vs the same
-            # pool at full precision (recorded once; bench surfaces it)
+            # pool at full precision (recorded once)
             from pathway_tpu.engine.probes import record_spec
 
             it = _np_mod.dtype(cfg.dtype).itemsize
@@ -1473,8 +1472,8 @@ class _ContinuousServer:
         """Refresh the ``kv_fragmentation`` gauge: 1 - reachable/allocated
         KV bytes over the active slots. A dense slot always allocates the
         full ``cache_len`` row; a paged slot allocates only its table's
-        blocks, so the gauge is the direct HBM-stranding comparison the
-        bench surfaces (``serving.kv_fragmentation``)."""
+        blocks, so the gauge is the direct HBM-stranding comparison
+        (``kv_fragmentation``)."""
         from pathway_tpu.engine.probes import record_kv_fragmentation
 
         covers = self._slot_cover
@@ -1749,34 +1748,6 @@ class _ContinuousServer:
         served live lanes (1.0 = every lane of every chunk was busy)."""
         return self.stats["steps"] / max(self.stats["slot_steps_total"], 1)
 
-    def _record_attn(self, path: str, n_q: int, n_k: int,
-                     batch: int = 1, cached_kv: bool = False) -> None:
-        """Charge the attention-bytes ledger for one prefill dispatch
-        (accounting model, not a hardware counter — see
-        probes.record_attn). ``cached_kv=True`` bills KV reads at the
-        pool's storage width (int8 under kv_quant)."""
-        import numpy as np
-
-        from pathway_tpu.engine.probes import record_attn
-        from pathway_tpu.models.flash_attention import (
-            attn_bytes_dense,
-            attn_bytes_flash,
-        )
-
-        cfg = self.cfg
-        dense = cfg.layers * attn_bytes_dense(n_q, n_k, cfg.heads,
-                                              batch=batch)
-        if self.flash_prefill:
-            item = 1 if (cached_kv and self.kv_quant) else (
-                np.dtype(cfg.dtype).itemsize)
-            fl = cfg.layers * attn_bytes_flash(
-                n_q, n_k, cfg.heads, cfg.hidden // cfg.heads,
-                batch=batch, itemsize=item,
-            )
-            record_attn(path, fl, saved=dense - fl)
-        else:
-            record_attn(path, dense)
-
     def _admit_fn(self, s: int):
         fn = self._admit_fns.get(s)
         if fn is None:
@@ -2016,7 +1987,7 @@ class _ContinuousServer:
 
     def prefix_reset(self, *, unpin: bool = True) -> None:
         """Drop every cached prefix and zero the per-server prefix
-        counters (bench: warm up the executables, then measure a clean
+        counters (warm up the executables, then measure a clean
         trace). Only call while no requests are in flight. In paged
         mode the tree's adopted blocks unpin back into the allocator;
         the supervised restart path passes ``unpin=False`` because its
@@ -2104,8 +2075,7 @@ class _ContinuousServer:
 
         tokens, j, keys, blobs = item
         try:
-            with tracing.region("pw.decode.h2d", stage="h2d",
-                                items=len(keys), blocks=len(keys)):
+            with tracing.region("pw.decode.h2d", blocks=len(keys)):
                 staged = {c: jax.device_put(v) for c, v in blobs.items()}
                 for v in staged.values():
                     v.block_until_ready()
@@ -2700,8 +2670,6 @@ class _ContinuousServer:
                     np.int32(lc),
                 )
         self.stats["prefill_chunks"] += 1
-        self._record_attn("chunk", int(p_ids.shape[1]), self.cache_len,
-                          cached_kv=True)
         req_p = self.slots[slot]
         if req_p is not None:
             req_p.span.event(
@@ -2903,7 +2871,6 @@ class _ContinuousServer:
                             self.pool = self._admit_batch_fn(m, s)(
                                 self.params, ids, mask, self.pool, slots
                             )
-                        self._record_attn("prefill", s, s, batch=m)
                         self.stats["admit_dispatches"] += 1
                         for p in part:
                             active[p[0]] = True
@@ -2912,7 +2879,6 @@ class _ContinuousServer:
                     self.pool = self._admit_fn(s)(
                         self.params, ids, mask, self.pool, np.int32(slot)
                     )
-                    self._record_attn("prefill", s, s)
                     self.stats["admit_dispatches"] += 1
                     active[slot] = True
 

@@ -183,7 +183,7 @@ def test_kill_restart_cycles_exactly_once(tmp_path):
 
 @pytest.mark.timeout(360)
 def test_recovery_torture_at_scale(tmp_path):
-    """Reference-scale recovery torture (VERDICT r5 item 6, mirroring
+    """Reference-scale recovery torture (mirroring
     ``integration_tests/wordcount/base.py`` which replays a multi-million
     line wordcount through kill/restart cycles): millions of jsonlines
     rows streamed through ``pw.run()`` with persistence, >= 3 SIGKILLs at

@@ -36,14 +36,13 @@ def test_readme_tables_are_generated_output(group):
 def test_registry_env_and_attr_unique():
     envs = [f.env for f in C.FLAG_REGISTRY]
     assert len(envs) == len(set(envs))
-    attrs = [f.attr for f in C.FLAG_REGISTRY if f.attr]
+    attrs = [f.attr for f in C.FLAG_REGISTRY]
     assert len(attrs) == len(set(attrs))
 
 
 def test_every_attr_resolves_on_live_config():
     for f in C.FLAG_REGISTRY:
-        if f.attr:
-            assert hasattr(C.pathway_config, f.attr), f.attr
+        assert hasattr(C.pathway_config, f.attr), f.attr
 
 
 def test_defaults_when_env_unset(monkeypatch):

@@ -388,34 +388,6 @@ def test_serving_mesh_tokens_equal(tiny_params):
     assert a == b
 
 
-def test_serving_records_attn_bytes(tiny_params):
-    from pathway_tpu.engine.probes import attn_stats, reset_attn_stats
-
-    reset_attn_stats()
-    _serve(tiny_params, PROMPTS[:2], flash_prefill=True,
-           chunked_prefill=True)
-    st = attn_stats()
-    assert st["bytes"].get("chunk", 0) > 0
-    assert st["bytes_saved"].get("chunk", 0) > 0
-    reset_attn_stats()
-
-
-# -- accounting model --------------------------------------------------------
-
-
-def test_attn_bytes_flash_is_linear_dense_is_quadratic():
-    h, hd = 4, 8
-    d = [FA.attn_bytes_dense(s, s, h) for s in (256, 512, 1024)]
-    f = [FA.attn_bytes_flash(s, s, h, hd) for s in (256, 512, 1024)]
-    assert d[1] / d[0] == pytest.approx(4.0) and d[2] / d[1] == \
-        pytest.approx(4.0)
-    assert f[1] / f[0] == pytest.approx(2.0, rel=0.1)
-    assert f[2] / f[1] == pytest.approx(2.0, rel=0.1)
-    # int8 cached KV reads are billed at 1 byte + scale planes
-    assert FA.attn_bytes_flash(8, 1024, h, hd, itemsize=1) < \
-        FA.attn_bytes_flash(8, 1024, h, hd, itemsize=4)
-
-
 # -- perf guard --------------------------------------------------------------
 
 

@@ -168,11 +168,7 @@ def test_fused_chain_reported_in_stats(monkeypatch):
     snap = stats.snapshot()
     assert snap["fused_chains"] >= 1
     assert snap["fused_nodes"] >= 2
-    tax = stats.engine_tax()
-    assert set(tax) >= {
-        "wall_s", "steps", "steps_skipped", "operator_dispatches",
-        "fused_chains", "fused_nodes",
-    }
+    assert set(snap) >= {"steps_skipped", "operators", "connectors"}
 
 
 # ------------------------------------------------------- persistence

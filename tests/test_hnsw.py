@@ -2,8 +2,8 @@
 
 Reference parity: ``src/external_integration/usearch_integration.rs``
 (connectivity / expansion knobs, mask-style deletion). Scale-recall is
-covered here at test size; the TPU-native ANN story (IVF) is benched in
-``bench.py`` config 5.
+covered here at test size; the TPU-native ANN index (IVF) has its own
+recall tests (``tests/test_indexing.py``).
 """
 
 import numpy as np

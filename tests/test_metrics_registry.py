@@ -103,7 +103,6 @@ def test_ledger_shims_keep_shapes():
     probes.reset_cascade_stats()
     probes.reset_prefix_stats()
     probes.reset_spec_stats()
-    probes.reset_stage_seconds()
 
     probes.record_device_dispatch("embed_submit", 3)
     counts = probes.dispatch_counts()
@@ -136,14 +135,10 @@ def test_ledger_shims_keep_shapes():
     assert ss["acceptance_rate"] == 0.75
     assert ss["tokens_per_dispatch"] == 3.25
 
-    probes.record_stage("tokenize", 0.25, items=10)
-    assert probes.stage_seconds()["tokenize"] == pytest.approx(0.25)
-
     probes.reset_dispatch_counts()
     probes.reset_cascade_stats()
     probes.reset_prefix_stats()
     probes.reset_spec_stats()
-    probes.reset_stage_seconds()
     assert probes.dispatch_counts() == {}
     assert probes.prefix_stats()["hit_rate"] == 0.0
     assert probes.spec_stats()["acceptance_rate"] == 0.0
@@ -176,9 +171,9 @@ def test_serving_and_unified_snapshot_shapes():
     probes.observe_latency("ttft_seconds", 0.05, "decode")
     serving = probes.serving_snapshot()
     assert set(serving) == {
-        "prefix", "spec", "cascade", "dispatch", "stage_seconds",
+        "prefix", "spec", "cascade", "dispatch",
         "occupancy", "latency", "lanes", "tenants", "kv_parked_bytes",
-        "retrieval", "attn",
+        "retrieval",
     }
     assert serving["prefix"]["hit_rate"] == 0.5
     assert serving["latency"]["ttft_seconds"]["count"] == 1

@@ -7,23 +7,51 @@ from __future__ import annotations
 
 import json
 import os
+import random
 import socket
 import subprocess
 import sys
 import textwrap
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+def _free_ports(n: int) -> int:
+    """The first of ``n`` consecutive ports nobody holds, BELOW the range
+    the kernel hands out by itself. Process ``pid`` of a cluster listens on
+    ``PATHWAY_FIRST_PORT + pid``, so every one of them has to be free, not
+    the first alone. ``bind(0)`` cannot find them: Linux gives it odd ports
+    only and gives every outgoing connection an even one, so the port after
+    a ``bind(0)`` port is where all the clients of all the tests running
+    beside this one draw their source ports, and where those stay in
+    TIME_WAIT for a minute: a listener cannot bind there (``SO_REUSEADDR``
+    or not), process 1 dies on ``EADDRINUSE`` and process 0 waits its 60 s
+    for a peer that never comes."""
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            ephemeral = int(f.read().split()[0])
+    except OSError:
+        ephemeral = 32768
+    # above the per-process MetricsServer's 20000 + process_id
+    for _ in range(256):
+        first = random.randrange(20100, ephemeral - n)
+        held = []
+        try:
+            for port in range(first, first + n):
+                s = socket.socket()
+                held.append(s)
+                # without SO_REUSEADDR, so that any holder refuses it
+                s.bind(("127.0.0.1", port))
+            return first
+        except OSError:
+            continue
+        finally:
+            for s in held:
+                s.close()
+    raise RuntimeError(f"no {n} consecutive free ports below {ephemeral}")
 
 
 def _spawn(script: str, tmp_path, processes: int):
     procs = []
-    port = _free_port()
+    port = _free_ports(processes)
     for pid in range(processes):
         repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env = dict(

@@ -184,7 +184,11 @@ def test_ring_attention_core_vs_softmax():
     assert np.abs(np.asarray(out) - np.asarray(ref)).max() < 1e-5
 
 
-def test_sharded_ivf_full_probe_is_exact():
+# ``add``: row by row (argmin shard, one cell at a time); ``add_bulk``: the
+# build for millions of rows (water-filled shards, one centroid gemm a chunk,
+# build-time k-means), which the four-chip bring-up loads its shards with
+@pytest.mark.parametrize("build", ["add", "add_bulk"])
+def test_sharded_ivf_full_probe_is_exact(build):
     # nprobe == n_cells scans every cell: results must match numpy exact
     from pathway_tpu.parallel import ShardedIvfIndex
 
@@ -194,7 +198,8 @@ def test_sharded_ivf_full_probe_is_exact():
                           cell_capacity=32)
     rng = np.random.default_rng(1)
     vecs = rng.normal(size=(n, dim))
-    idx.add([f"k{i}" for i in range(n)], vecs)
+    getattr(idx, build)([f"k{i}" for i in range(n)], vecs)
+    assert len(idx) == n
     q = rng.normal(size=(3, dim))
     res = idx.search(q, k=5)
     vn = vecs / np.linalg.norm(vecs, axis=1, keepdims=True)
@@ -206,7 +211,8 @@ def test_sharded_ivf_full_probe_is_exact():
         assert got == expect
 
 
-def test_sharded_ivf_pruned_recall_reasonable():
+@pytest.mark.parametrize("build", ["add", "add_bulk"])
+def test_sharded_ivf_pruned_recall_reasonable(build):
     # nprobe < n_cells prunes; trained clustering must keep recall@10 high
     from pathway_tpu.parallel import ShardedIvfIndex
 
@@ -218,7 +224,7 @@ def test_sharded_ivf_pruned_recall_reasonable():
     vecs = centers[rng.integers(0, 32, n)] + rng.normal(size=(n, dim))
     idx = ShardedIvfIndex(mesh, dimensions=dim, n_cells=8, nprobe=4,
                           cell_capacity=64, train_after=32)
-    idx.add([f"k{i}" for i in range(n)], vecs)
+    getattr(idx, build)([f"k{i}" for i in range(n)], vecs)
     assert idx._trained
     nq = 16
     q = centers[rng.integers(0, 32, nq)] + rng.normal(size=(nq, dim))

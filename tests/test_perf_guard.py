@@ -3,8 +3,7 @@ least 0.8x the throughput of a direct Python loop over the same kernel —
 the host-side engine tax (operator dispatch, batch plumbing, consolidate)
 may cost at most ~25% on top of the actual compute.
 
-This is the CPU analog of the bench's config4-vs-headline contract
-(``bench.py``); it runs with a numpy kernel so it guards the engine's
+It runs with a numpy kernel so it guards the engine's
 overhead on any machine, independent of the accelerator. Marked slow: it
 needs multi-second measurement windows to be stable, and tier-1 excludes
 it (-m 'not slow').
@@ -78,7 +77,7 @@ def test_engine_stream_vs_direct_kernel_loop():
     ratio = direct_s / engine_s
     detail = (
         f"direct={direct_s:.3f}s engine={engine_s:.3f}s ratio={ratio:.3f} "
-        f"stats={stats.engine_tax() if stats else None}"
+        f"stats={stats.snapshot() if stats else None}"
     )
     assert ratio >= 0.8, f"engine tax exceeded 25% of kernel cost: {detail}"
 

@@ -1,64 +1,10 @@
-"""Roofline model + device-dispatch counters (``engine/probes.py``) and the
-ragged-tail blocked top-k (``ops/knn.py``)."""
+"""Device-dispatch counters and the cascade ledger (``engine/probes.py``)
+and the ragged-tail blocked top-k (``ops/knn.py``)."""
 
 import numpy as np
 import pytest
 
 from pathway_tpu.engine import probes
-
-V5E = probes.DEVICE_PEAKS["TPU v5 lite"]
-
-
-# ------------------------------------------------------------- roofline
-
-
-def test_phase_roofline_compute_bound():
-    # 1s at half of peak FLOPs, tiny byte traffic -> compute bound
-    ph = probes.PhaseRoofline(
-        name="x", seconds=1.0, flops=V5E.bf16_flops * 0.5,
-        bytes_moved=1e9, dispatches=4,
-    )
-    s = ph.summary(V5E)
-    assert s["mfu_pct"] == pytest.approx(50.0, abs=0.1)
-    assert s["bound"] == "compute"
-    assert s["dispatches"] == 4
-
-
-def test_phase_roofline_memory_bound():
-    # saturate HBM, negligible FLOPs -> memory bound
-    ph = probes.PhaseRoofline(
-        name="x", seconds=1.0, flops=1e12,
-        bytes_moved=V5E.hbm_bytes_per_s * 0.8, dispatches=1,
-    )
-    s = ph.summary(V5E)
-    assert s["bound"] == "memory"
-    assert s["hbm_util_pct"] == pytest.approx(80.0, abs=0.5)
-
-
-def test_phase_roofline_overhead_bound():
-    # neither resource above 5% utilisation -> dispatch/host overhead
-    ph = probes.PhaseRoofline(
-        name="x", seconds=1.0, flops=1e12, bytes_moved=1e9, dispatches=999,
-    )
-    s = ph.summary(V5E)
-    assert s["bound"] == "overhead"
-    # a device outside the peaks table has no utilization, never v5e's
-    assert probes.device_peaks("cpu") is None
-    s = ph.summary(None)
-    assert s["mfu_pct"] == s["hbm_util_pct"] == s["bound"] == "not measured"
-
-
-def test_roofline_model_ledger():
-    m = probes.RooflineModel(V5E)
-    m.add("ingest", seconds=2.0, flops=4e12, bytes_moved=8e9, dispatches=10)
-    m.add("drain", seconds=0.5, flops=0.0, bytes_moved=1e9, dispatches=1)
-    out = m.summary()
-    assert set(out) == {"ingest", "drain"}
-    assert out["ingest"]["gflops"] == pytest.approx(4000.0, rel=1e-3)
-    assert out["ingest"]["arith_intensity"] == pytest.approx(500.0, rel=1e-3)
-    for row in out.values():
-        assert {"mfu_pct", "hbm_util_pct", "bound", "seconds"} <= set(row)
-
 
 # ----------------------------------------------------- dispatch counters
 
@@ -168,16 +114,6 @@ def test_fused_rerank_one_dispatch_per_cascade_tick():
                 os.environ.pop(var, None)
             else:
                 os.environ[var] = val
-
-
-def test_scheduler_stats_engine_tax_keys():
-    st = probes.SchedulerStats()
-    st.record_skip()
-    st.record_skip()
-    tax = st.engine_tax()
-    assert tax["steps_skipped"] == 2
-    assert {"wall_s", "steps", "operator_dispatches", "fused_chains",
-            "fused_nodes"} <= set(tax)
 
 
 # ------------------------------------------------- blocked top-k ragged

@@ -1,0 +1,259 @@
+"""What the benchmark reads from the program, by name.
+
+``benchmarks/`` finds the program's device modules by a pattern over
+``"jit_" + f.__name__``, its counters by family and label, its request spans
+by kind and metric, and the decoder server's work by ``stats`` field. A
+rename on the program's side breaks none of the program's own tests: it
+shows a PR later, as a ``null`` on the ledger. The cases here are read FROM
+the benchmark's own files (never edited here), and each runs the program
+at toy size and then the benchmark's own reader: CPU only, names and
+counts, never a time."""
+
+import ast
+import glob
+import json
+import os
+import re
+import threading
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import pathway_tpu as pw
+from pathway_tpu.engine import probes, tracing
+from tests.benchmark.bench_paths import BENCH, REPO
+from harness import manifest, program_trace
+
+def _load(path):
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+METRICS = {
+    os.path.basename(path)[:-len(".json")]: _load(path)
+    for path in sorted(glob.glob(os.path.join(BENCH, "metrics", "*.json")))
+}
+
+
+def _params(key):
+    return sorted((name, m["params"]) for name, m in METRICS.items()
+                  if key in m.get("params", {}))
+
+
+def _ids(cases):
+    return [name for name, _ in cases]
+
+
+# ------------------------------------------------------- device modules
+
+
+def _jitted_names():
+    """``__name__`` of every function the package hands to ``jax.jit``:
+    decorated with it (bare or through ``functools.partial``), or passed to
+    it by name."""
+
+    def is_jit(node):
+        return (isinstance(node, ast.Attribute) and node.attr == "jit") or (
+            isinstance(node, ast.Name) and node.id == "jit")
+
+    names = set()
+    for path in glob.glob(os.path.join(REPO, "pathway_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path, encoding="utf-8") as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.FunctionDef):
+                for dec in node.decorator_list:
+                    call = dec.func if isinstance(dec, ast.Call) else dec
+                    args = dec.args if isinstance(dec, ast.Call) else []
+                    if is_jit(call) or any(is_jit(a) for a in args):
+                        names.add(node.name)
+            elif (isinstance(node, ast.Call) and is_jit(node.func)
+                  and node.args and isinstance(node.args[0], ast.Name)):
+                names.add(node.args[0].id)
+    return names
+
+
+MODULE_PATTERNS = _params("modules")
+# device ops that PERF.md's breakdowns and the `host_attribution` line quote
+# by name, beside the three a roofline metric matches by pattern
+QUOTED_MODULES = ("jit__append_kernel", "jit_score_fn", "jit_chunk")
+
+
+@pytest.fixture(scope="module")
+def jitted():
+    return {"jit_" + name for name in _jitted_names()}
+
+
+@pytest.mark.parametrize("name,params", MODULE_PATTERNS,
+                         ids=_ids(MODULE_PATTERNS))
+def test_a_roofline_metrics_pattern_finds_a_jitted_function(
+        name, params, jitted):
+    assert any(re.search(params["modules"], module) for module in jitted)
+
+
+@pytest.mark.parametrize("module", QUOTED_MODULES)
+def test_a_quoted_device_module_is_a_jitted_function(module, jitted):
+    assert module in jitted
+
+
+# ------------------------------------------------- the decoder's toy run
+
+
+class _Ids:
+    def encode(self, text):
+        return [int(t) for t in text.split()]
+
+    def decode(self, ids):
+        return " ".join(str(i) for i in ids)
+
+
+@pytest.fixture(scope="module")
+def decoder_run():
+    """One request through a continuous server whose block routes experts
+    and holds half of them; the server's ``stats`` once it is answered."""
+    from pathway_tpu.models import decoder as D
+    from pathway_tpu.models.moe import MoEConfig
+    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
+
+    cfg = D.DecoderConfig(
+        vocab_size=64, hidden=16, layers=1, heads=2, intermediate=32,
+        max_position=64, dtype=jnp.float32, mlp="swiglu", bias=False,
+        moe=MoEConfig(experts=4, per_token=2, width=8, held=(0, 2)))
+    tracing.reset_traces()
+    probes.REGISTRY.remove("moe_assignments")
+    chat = TPUDecoderChat(
+        params=D.init_params(jax.random.PRNGKey(0), cfg), cfg=cfg,
+        tokenizer=_Ids(), max_new_tokens=4, temperature=0.0,
+        max_prompt_tokens=16, continuous=True, n_slots=2, chunk_steps=4,
+        prefill_chunk=8)
+    try:
+        request = chat._server.submit(list(range(1, 12)), 4)
+        assert request.done.wait(timeout=300)
+        return dict(chat._server.stats)
+    finally:
+        chat.close()
+
+
+STATS_FIELDS = sorted({
+    value[len("decoder_"):]
+    for m in METRICS.values() for value in m.get("params", {}).values()
+    if isinstance(value, str) and value.startswith("decoder_")})
+
+
+@pytest.mark.parametrize("field", STATS_FIELDS)
+def test_the_decoder_server_counts_a_stats_field_a_metric_names(
+        field, decoder_run):
+    assert decoder_run[field] > 0
+
+
+# ----------------------------------------------------------- counters
+
+
+def _series(params):
+    """``[family, label, value]`` of every registry series a metric's
+    ``params`` name."""
+    out = [params[k] for k in ("numerator", "denominator")
+           if isinstance(params.get(k), list)]
+    if "family" in params:
+        out.append([params["family"], params["label"], params["value"]])
+    return out
+
+
+COUNTER_METRICS = sorted(
+    (name, m["params"]) for name, m in METRICS.items()
+    if _series(m.get("params", {})))
+
+
+@pytest.fixture(scope="module")
+def search_run():
+    """One search of a toy exact index."""
+    import numpy as np
+
+    from pathway_tpu.ops.knn import BruteForceKnnIndex
+
+    probes.REGISTRY.remove("knn_search_queries", "device_dispatch")
+    index = BruteForceKnnIndex(8, reserved_space=16)
+    vectors = np.eye(8, dtype=np.float32)
+    index.add(list(range(8)), vectors)
+    index.search(vectors[:3], 2)
+
+
+@pytest.mark.parametrize("name,params", COUNTER_METRICS,
+                         ids=_ids(COUNTER_METRICS))
+def test_a_counter_metric_reads_what_the_program_registered(
+        name, params, decoder_run, search_run):
+    for family, label, value in _series(params):
+        assert probes.METRIC_FAMILIES[family][1] == label
+        assert probes.REGISTRY.labelled(family, label).get(str(value))
+    assert _reader(name)({}, params) is not None
+
+
+def _reader(name):
+    """The metric's own reader, as the harness loads it."""
+    return manifest.load_reader_module(manifest.load_manifest(), name).read
+
+
+# -------------------------------------------------------------- spans
+
+
+class _Query(pw.Schema):
+    q: str
+
+
+@pytest.fixture(scope="module")
+def rest_run(decoder_run):
+    """Two requests through ``rest_connector`` (after the decoder's run,
+    whose spans it leaves in the ring)."""
+    from pathway_tpu.io.http import _RestConnector
+
+    pw.clear_graph()
+    queries, writer = pw.io.http.rest_connector(
+        port=0, schema=_Query, delete_completed_queries=True)
+    writer(queries.select(ans=queries.q + "!"))
+    conns = list(pw.G.connectors)
+    rest = next(c for c in conns if isinstance(c, _RestConnector))
+    answers = []
+
+    def client():
+        rest.webserver._started.wait(timeout=20)
+        try:
+            for q in ("hi", "ho"):
+                req = urllib.request.Request(
+                    f"http://127.0.0.1:{rest.webserver.port}/",
+                    data=json.dumps({"q": q}).encode(),
+                    headers={"Content-Type": "application/json"})
+                answers.append(json.loads(
+                    urllib.request.urlopen(req, timeout=15).read()))
+        finally:
+            for c in conns:
+                c._stop.set()
+                c.close()
+
+    thread = threading.Thread(target=client, daemon=True)
+    thread.start()
+    pw.run()
+    thread.join(timeout=20)
+    pw.clear_graph()
+    assert [a["ans"] for a in answers] == ["hi!", "ho!"]
+
+
+SPAN_METRICS = _params("kind")
+
+
+@pytest.mark.parametrize("name,params", SPAN_METRICS, ids=_ids(SPAN_METRICS))
+def test_a_span_metric_finds_its_kind_and_its_events(
+        name, params, decoder_run, rest_run):
+    spans = program_trace.program_spans(params["kind"])
+    assert spans
+    for metric in [params["metric"]] + [params[k] for k in ("minus",)
+                                        if k in params]:
+        assert all(metric in s["metrics"] for s in spans)
+    counters = {params["last"]: len(spans)} if "last" in params else {}
+    assert _reader(name)({"counters": counters}, params) is not None
+
+
+def test_an_epoch_span_lists_the_requests_it_carried(rest_run):
+    assert _reader("retrieve.requests_per_epoch")({}, {}) >= 1.0

@@ -394,7 +394,7 @@ def _tflag(env="PATHWAY_TPU_T", default=4, **spec):
     from pathway_tpu.internals.config import Flag, Tunable
 
     return Flag(
-        env=env, attr=None, kind="int" if isinstance(default, int) else
+        env=env, attr="t", kind="int" if isinstance(default, int) else
         "float", default=default, doc="x", group="pipeline",
         tunable=Tunable(**spec),
     )

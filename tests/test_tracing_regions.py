@@ -127,44 +127,40 @@ def test_a_full_collection_is_a_region_and_a_young_one_is_not(tmp_path):
     assert names.count("pw.gc") == 1
 
 
-def test_a_stage_region_feeds_stage_seconds_what_the_clock_pair_fed_it():
-    probes.reset_stage_seconds()
-    t0 = time.perf_counter()
-    with tracing.region("pw.embed.tokenize", stage="tokenize", rows=7):
-        time.sleep(0.02)
-    pair = time.perf_counter() - t0
-    with tracing.region("pw.embed.drain", stage="drain", items=3):
+def test_a_region_is_the_annotation_and_touches_no_registry():
+    before = probes.REGISTRY.snapshot()
+    region = tracing.region("pw.embed.tokenize", rows=7)
+    assert type(region) is jax.profiler.TraceAnnotation
+    with region:
         pass
-    seconds = probes.stage_seconds()
-    assert set(seconds) == {"tokenize", "drain"}
-    assert 0.02 <= seconds["tokenize"] <= pair
-    items = probes.REGISTRY.labelled("stage_items", "stage")
-    assert items == {"tokenize": 1.0, "drain": 3.0}
-    # a plain region touches no registry; an unknown stage is refused
-    with tracing.region("pw.engine.epoch", t=3):
-        pass
-    assert set(probes.stage_seconds()) == {"tokenize", "drain"}
-    with pytest.raises(ValueError):
-        tracing.region("pw.embed.tokenize", stage="tokenise")
+    assert probes.REGISTRY.snapshot() == before
 
 
-def test_an_ingest_run_leaves_the_same_stage_keys_as_before():
+def test_an_ingest_run_leaves_the_five_ingest_regions(tmp_path):
     from pathway_tpu.models.embedder import SentenceEmbedderModel
     from pathway_tpu.models.transformer import TransformerConfig
     from pathway_tpu.ops.knn import BruteForceKnnIndex
 
-    probes.reset_stage_seconds()
     cfg = TransformerConfig(vocab_size=64, hidden=16, layers=1, heads=2,
                             intermediate=32, max_position=32)
-    model = SentenceEmbedderModel(cfg=cfg, max_length=16)
-    try:
-        vectors = model.embed_batch(["a b c", "d e"])
-    finally:
-        model.close()
-    index = BruteForceKnnIndex(16, reserved_space=16)
-    index.add([1, 2], vectors)
-    assert set(probes.stage_seconds()) == {
-        "tokenize", "h2d", "dispatch", "drain", "append"}
+
+    def body():
+        model = SentenceEmbedderModel(cfg=cfg, max_length=16)
+        try:
+            vectors = model.embed_batch(["a b c", "d e"])
+        finally:
+            model.close()
+        index = BruteForceKnnIndex(16, reserved_space=16)
+        index.add([1, 2], vectors)
+
+    rows = {}
+    for _thread, name, _start, _dur, stats in _session(tmp_path, body):
+        if name.startswith(("pw.embed.", "pw.index.")):
+            rows.setdefault(name, []).append(stats["rows"])
+    assert rows == {
+        "pw.embed.tokenize": [2], "pw.embed.h2d": [2],
+        "pw.embed.dispatch": [2], "pw.embed.drain": [2],
+        "pw.index.append": [2]}
 
 
 def test_epoch_spans_order_wait_and_no_span_for_an_empty_epoch():
