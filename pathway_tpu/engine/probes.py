@@ -159,6 +159,11 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
         "counter", "held", "Token-to-expert assignments the routers made, "
         "by whether the expert is held here (held=1) and by phase; summed "
         "on the device, read with the tokens at drain"),
+    "prefill_attn_blocks": (
+        "counter", "layer", "Key blocks of a slot's row that the blockwise "
+        "attention read of a prefill piece visited (visited=1) or skipped "
+        "because no query of the piece can see a key in them (visited=0), "
+        "by kind of layer (full | window), summed over its layers"),
     "kv_migrated_blocks": (
         "counter", "server", "KV blocks handed from the prefill lane to "
         "the decode lane at prompt completion (PATHWAY_TPU_DISAGG)"),
@@ -224,17 +229,20 @@ class MetricsRegistry:
             series = self._counters.setdefault(name, {})
             series[key] = series.get(key, 0.0) + value
 
-    def counter_add_many(self, name: str, label: str,
-                         counts: dict) -> None:
-        """Batched :meth:`counter_add` over one label dimension: a single
-        enabled check + lock acquisition for a whole group of updates —
-        what serving hot loops (one spec cycle = six counters) call."""
+    def counter_add_many(self, name: str, label, counts: dict) -> None:
+        """Batched :meth:`counter_add` over one label dimension (or, with
+        a tuple of label names and tuples as ``counts``' keys, several): a
+        single enabled check + lock acquisition for a whole group of
+        updates — what serving hot loops (one spec cycle = six counters)
+        call."""
         if not self.enabled:
             return
         with self._lock:
             series = self._counters.setdefault(name, {})
+            several = isinstance(label, tuple)
             for lv, v in counts.items():
-                key = ((label, str(lv)),)
+                key = (tuple(sorted(zip(label, map(str, lv)))) if several
+                       else ((label, str(lv)),))
                 series[key] = series.get(key, 0.0) + v
 
     def gauge_set(self, name: str, value: float, **labels) -> None:
@@ -552,6 +560,14 @@ def record_consolidate(rows: int, compared: int) -> None:
     REGISTRY.counter_add_many(
         "consolidate_rows", "content", {0: rows - compared, 1: compared}
     )
+
+
+def record_prefill_attn_blocks(counts: dict) -> None:
+    """One prefill piece's ``{(layer, visited): blocks}``
+    (``models.decoder.prefill_blocks_visited``: host arithmetic, no sync):
+    how much of a long row the blockwise read touches."""
+    REGISTRY.counter_add_many(
+        "prefill_attn_blocks", ("layer", "visited"), counts)
 
 
 def record_backlog(queue: str, depth: int) -> None:

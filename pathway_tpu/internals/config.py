@@ -536,7 +536,10 @@ FLAG_REGISTRY: list[Flag] = [
             "`(B, 1, S, S)` score/mask tensors, O(S) attention memory. "
             "Online softmax is allclose-not-bitwise vs the dense path, "
             "so `0` (default) keeps today's dense attention "
-            "byte-identically (`tests/test_flash_prefill.py`).",
+            "byte-identically (`tests/test_flash_prefill.py`). A "
+            "chunked-prefill piece over a LONG row takes the chunk kernel "
+            "whatever this says: by its shapes "
+            "(`models.decoder.blockwise_chunk_read`).",
     ),
     Flag(
         env="PATHWAY_TPU_FLASH_BLOCK_Q", kind="int", default=0,

@@ -775,7 +775,25 @@ def _flash_self_attn_fn(mesh):
     )
 
 
-def _flash_chunk_attn_fn(mesh, quant):
+# The dense read of a prefill piece writes ``heads x T x columns`` float32
+# scores to HBM, and reads and writes them again for each pass of the softmax
+# and for the probabilities; the blockwise read keeps them in VMEM and skips
+# the blocks no query can see. Past this many bytes of scores the dense read's
+# trips through HBM cost more than the blockwise read's fixed costs (a kernel
+# call a layer, a grid step a block): the serving defaults of a GPT-2-sized
+# model stay under it by a factor of ten (16 heads x 64 x 656: 2.7 MB), a
+# long-context row is over it by as much (48 x 512 x 8,304: 816 MB).
+_DENSE_SCORE_BYTES = 32 << 20
+
+
+def blockwise_chunk_read(heads: int, T: int, columns: int) -> bool:
+    """THE rule that chooses a prefill piece's attention read, from the
+    shapes in hand: ``T`` queries of ``heads`` heads against a row of
+    ``columns`` keys (:func:`pool_prefill_chunk`)."""
+    return heads * T * columns * 4 > _DENSE_SCORE_BYTES
+
+
+def _flash_chunk_attn_fn(mesh, quant, window=0):
     """Chunk-vs-cache flash entry for :func:`pool_prefill_chunk`,
     adapting ``_block``'s (1, nh, ...) operands to the batchless kernel
     layout. Quantized pools get a separate wrapper because ``shard_map``
@@ -783,9 +801,9 @@ def _flash_chunk_attn_fn(mesh, quant):
     full-precision layout (same split as :func:`_paged_attn_fn`)."""
     from pathway_tpu.models import flash_attention as _fa
 
-    def plain(q, k_row, v_row, ks_row, vs_row, row_mask, start):
+    def plain(q, k_row, v_row, ks_row, vs_row, kcol, start):
         return _fa.flash_chunk_attn(
-            q[0], k_row[0], v_row[0], row_mask[0], start,
+            q[0], k_row[0], v_row[0], kcol[0], start, window=window,
             k_scale=None if ks_row is None else ks_row[0],
             v_scale=None if vs_row is None else vs_row[0],
         )[None]
@@ -798,7 +816,7 @@ def _flash_chunk_attn_fn(mesh, quant):
         return plain
     t = SERVE_TP_AXIS
     head = P(None, t, None, None)  # q / rows / scales: (1, nh, ., .)
-    rep = P(None, None)            # row mask: (1, C)
+    rep = P(None, None)            # key columns: (1, C)
     if quant:
         return jax.shard_map(
             plain, mesh=mesh,
@@ -806,15 +824,15 @@ def _flash_chunk_attn_fn(mesh, quant):
             out_specs=head, check_vma=False,
         )
 
-    def unquant(q, k_row, v_row, row_mask, start):
-        return plain(q, k_row, v_row, None, None, row_mask, start)
+    def unquant(q, k_row, v_row, kcol, start):
+        return plain(q, k_row, v_row, None, None, kcol, start)
 
     mapped = jax.shard_map(
         unquant, mesh=mesh, in_specs=(head, head, head, rep, P()),
         out_specs=head, check_vma=False,
     )
-    return lambda q, k_row, v_row, _ks, _vs, row_mask, start: \
-        mapped(q, k_row, v_row, row_mask, start)
+    return lambda q, k_row, v_row, _ks, _vs, kcol, start: \
+        mapped(q, k_row, v_row, kcol, start)
 
 
 def _logits(params, x, cfg):
@@ -1837,7 +1855,15 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
     RIGHT-padded (token i must sit at cache column i for arena blocks
     to be layout-exact), so its final piece may end on pad columns and
     the next-token logits live mid-piece. Paged pools gather-run-
-    scatter (see :func:`pool_admit`)."""
+    scatter (see :func:`pool_admit`).
+
+    Each kind of layer reads its row DENSE (:func:`_attn_ctx` under a mask
+    bias) or BLOCKWISE (``flash_attention.flash_chunk_attn``: online
+    softmax over the key blocks some query of the piece can see, scores
+    never in HBM) by :func:`blockwise_chunk_read` on the shapes in hand;
+    ``flash`` forces the kernel. A query row with no visible key (left
+    padding) is zeros blockwise and a uniform average dense: no real
+    position ever reads it."""
     if pool_paged(pool):
         return _paged_scatter(
             pool, pool_prefill_chunk(
@@ -1863,16 +1889,19 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         pool["slot_mask"], row_mask, (slot, 0)
     )
     quant = pool_quantized(pool)
-    ctx_fn = mask_bias = ring_bias = None
+    # each kind of layer reads blockwise or dense by ONE rule on its shapes
+    # (``flash`` forces the kernel, as it always has): the dense read stays
+    # the small rows' and the decode step's, and the tests' reference
+    full_fn = window_fn = mask_bias = ring_bias = None
     qcol = (start + jnp.arange(T))[None, :]             # (1, T)
-    if flash:
-        require_gpt2_block(cfg, "flash_prefill")
-        # the kernel rebuilds the same live-&-causal predicate from
-        # row_mask and start internally, with int8 dequant fused into
-        # the cache tile read — no (1, 1, T, C) bias, no f32 KV row
+    if flash or blockwise_chunk_read(cfg.heads, T, C):
+        # the kernel builds the live-&-causal predicate from the column
+        # each key row holds, with int8 dequant fused into the tile read:
+        # no (1, 1, T, C) bias, no f32 KV row, no scores in HBM
         attn_c = _flash_chunk_attn_fn(mesh, quant)
-        ctx_fn = lambda q, kr, vr, ksr, vsr: \
-            attn_c(q, kr, vr, ksr, vsr, row_mask, start)
+        kcol = jnp.where(row_mask > 0, jnp.arange(C, dtype=jnp.int32), -1)
+        full_fn = lambda q, kr, vr, ksr, vsr: \
+            attn_c(q, kr, vr, ksr, vsr, kcol, start)
     else:
         # a piece query at cache index start+j attends every LIVE index
         # of this row <= start+j (earlier pieces + its own causal
@@ -1890,15 +1919,24 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
             raise ValueError(f"a prefill piece of {T} columns does not "
                              f"fit a window layer's ring of {R}")
         cols = _ring_cols(jnp.reshape(start - 1, (1,)), R)      # (1, R)
-        live = jnp.broadcast_to(_live_at(row_mask, cols)[:, None, :],
-                                (1, T, R))
-        old = _ring_bias(cols, live, qcol, W)               # (1, 1, T, R)
-        j = jnp.arange(T)
-        own = (mask[:, None, :] > 0) & (j[None, None, :] <= j[None, :, None]) \
-            & (j[None, :, None] - j[None, None, :] < W)
-        ring_bias = jnp.concatenate(
-            [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]],
-            axis=-1)
+        ring_live = _live_at(row_mask, cols)                    # (1, R)
+        if flash or blockwise_chunk_read(cfg.heads, T, R + T):
+            attn_w = _flash_chunk_attn_fn(mesh, quant, W)
+            kcol_w = jnp.concatenate([
+                jnp.where(ring_live, cols, -1),
+                jnp.where(mask > 0, qcol, -1)], axis=1).astype(jnp.int32)
+            window_fn = lambda q, kr, vr, ksr, vsr: \
+                attn_w(q, kr, vr, ksr, vsr, kcol_w, start)
+        else:
+            live = jnp.broadcast_to(ring_live[:, None, :], (1, T, R))
+            old = _ring_bias(cols, live, qcol, W)           # (1, 1, T, R)
+            j = jnp.arange(T)
+            own = (mask[:, None, :] > 0) \
+                & (j[None, None, :] <= j[None, :, None]) \
+                & (j[None, :, None] - j[None, None, :] < W)
+            ring_bias = jnp.concatenate(
+                [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]],
+                axis=-1)
         ring_idx = jnp.mod(start + jnp.arange(T), R)
 
     def layer(x, lp, kvl, kind):
@@ -1910,7 +1948,7 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
             x, cnt = _block(
                 x, lp, jnp.concatenate([k_old, k_new.astype(kl.dtype)], 2),
                 jnp.concatenate([v_old, v_new.astype(vl.dtype)], 2),
-                ring_bias, cfg, kind=kind, pos=p)
+                ring_bias, cfg, ctx_fn=window_fn, kind=kind, pos=p)
             # only REAL tokens enter the ring: a pad column's index still
             # holds an earlier column that a later query may read
             real = (mask[0] > 0)[:, None, None]
@@ -1942,7 +1980,7 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         k_row = jax.lax.dynamic_slice(kl, (slot, 0, 0, 0), (1, nh, C, hd))
         v_row = jax.lax.dynamic_slice(vl, (slot, 0, 0, 0), (1, nh, C, hd))
         x, cnt = _block(x, lp, k_row, v_row, mask_bias, cfg,
-                        k_scale=ks_row, v_scale=vs_row, ctx_fn=ctx_fn,
+                        k_scale=ks_row, v_scale=vs_row, ctx_fn=full_fn,
                         kind=kind, pos=p)
         return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
 
@@ -1968,6 +2006,48 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         out["write"] = jax.lax.dynamic_update_slice(
             pool["write"], write_end, (slot,)
         )
+    return out
+
+
+def prefill_blocks_visited(cfg: DecoderConfig, T: int, C: int, R: int,
+                           start: int, lo: int, hi: int,
+                           flash: bool = False) -> dict:
+    """What one prefill piece at ``start`` adds to the counter
+    ``prefill_attn_blocks{layer, visited}``: for each kind of layer whose
+    read is blockwise (:func:`blockwise_chunk_read`), the key blocks its
+    kernel visits (``visited=1``) and skips, times the layers of that kind.
+    On the HOST, in numpy, from the piece's offset and the row's live
+    columns ``[lo, hi]`` (the prompt's first live column; this piece's last)
+    — ``flash_attention.blocks_seen``, the kernel's own predicate, without
+    the device: no sync. ``{}`` where every layer reads dense."""
+    kinds = [(kind, rows, window, cfg.n_layers_of(kind))
+             for kind, rows, window in (("full", C, 0),
+                                        ("window", R + T, cfg.sliding_window))
+             if cfg.n_layers_of(kind)
+             and (flash or blockwise_chunk_read(cfg.heads, T, rows))]
+    if not kinds:
+        return {}       # before the kernel's module is ever imported
+    import numpy as np
+
+    from pathway_tpu.models.flash_attention import blocks_seen, chunk_block
+
+    group, it = cfg.heads // cfg.n_kv, jnp.dtype(cfg.dtype).itemsize
+
+    def live(cols):
+        return np.where((cols >= lo) & (cols <= hi), cols, -1)
+
+    out = {}
+    for kind, rows, window, layers in kinds:
+        if kind == "full":
+            kcol = live(np.arange(C))
+        else:
+            ring = (start - 1) - np.mod(start - 1 - np.arange(R), R)
+            kcol = np.concatenate([live(ring), live(start + np.arange(T))])
+        _kcol, seen = blocks_seen(
+            np, kcol, start, T, window,
+            chunk_block(rows, T, group, cfg.head_dim, it))
+        out[(kind, 1)] = int(seen.sum()) * layers
+        out[(kind, 0)] = int((~seen).sum()) * layers
     return out
 
 

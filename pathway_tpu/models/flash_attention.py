@@ -11,10 +11,18 @@ tensors through the dense ``_attn_ctx`` funnel:
   rerank cascade get the same O(S) memory profile.
 * ``flash_chunk_attn`` — chunk-vs-cache cross attention for
   ``pool_prefill_chunk``: a T-token query piece at offset ``start``
-  attends cache columns ``[0, start + t]``. int8 dequantization of the
-  cached KV is FUSED into the tile read (the per-token f32 scales
-  multiply the int8 payload inside the kernel), so cached KV never
-  round-trips through HBM at f32.
+  attends the keys of one slot's row. The kernel is told the absolute
+  cache COLUMN each key row holds (-1: dead), so one predicate serves a
+  full layer's row, a window layer's ``[ring | own]`` and the paged
+  planes; key-value heads are a grid axis with the query heads that share
+  one folded into the query rows (grouped query; multi-head is a group of
+  one); the blocks that hold no key any query of the piece can see are
+  found on the device beforehand and neither computed nor DMA'd; the
+  row's last tile may be ragged. int8 dequantization of the cached KV is
+  FUSED into the tile read (the per-token f32 scales multiply the int8
+  payload inside the kernel), so cached KV never round-trips through HBM
+  at f32. ``pool_prefill_chunk`` takes this read by a rule on its shapes
+  (``decoder.blockwise_chunk_read``), not by the kill switch below.
 * ``flash_chunk_attn_paged`` — the same chunk read over the paged pool's
   physical block planes, walking one slot's block-table row via
   ``PrefetchScalarGridSpec`` exactly like the decode kernel.
@@ -25,9 +33,11 @@ tensor is ever materialized.
 
 Numerics: online softmax is mathematically identical to the dense
 softmax but associates the reductions differently, so flash output is
-allclose-not-bitwise vs the dense path — which is why everything rides
-the ``PATHWAY_TPU_FLASH_PREFILL`` kill switch (off = today's dense path,
-byte-identical, pinned by ``tests/test_flash_prefill.py``). One visible
+allclose-not-bitwise vs the dense path — which is why the whole-prompt
+and encoder paths ride the ``PATHWAY_TPU_FLASH_PREFILL`` kill switch (off
+= the dense path, byte-identical, pinned by
+``tests/test_flash_prefill.py``), and why the chunk read engages only for
+rows far longer than any test's. One visible
 divergence is DEFINED behavior: a query row with no attendable column
 (left-padding before the first real token) is exact zeros here, where
 dense softmax yields a uniform average over masked columns. Those rows'
@@ -40,8 +50,9 @@ runs the same kernel bodies through the Pallas interpreter; on a TPU the
 same calls compile natively, and nothing falls back to the interpreter
 there. Tile row counts are kept 8-aligned (or the whole axis) and every
 block's last dim is the array's, which the TPU lowering accepts at
-head_dim 64 and 32 alike (``tests/test_tpu_compile.py`` compiles all
-three kernels for a v5e). Tile sizes tune via
+head_dim 128, 64 and 32 alike (``tests/test_tpu_compile.py`` compiles all
+three kernels for a v5e, the chunk kernel at the answer cell's widths
+too). Tile sizes tune via
 ``PATHWAY_TPU_FLASH_BLOCK_Q`` / ``PATHWAY_TPU_FLASH_BLOCK_K``
 (``configure_blocks`` installs them at construction time).
 """
@@ -87,18 +98,6 @@ def configure_blocks(block_q=0, block_k=0):
 
 def _round8(n):
     return -(-int(n) // 8) * 8
-
-
-def _pick_block(n, want):
-    """Largest divisor of ``n`` that is <= ``want`` and a multiple of 8
-    (cache rows cannot be padded without copying the whole row, so the
-    tile must divide C, and the TPU lowering wants the tile's row count
-    8-aligned); a row with no such divisor rides as ONE tile, which is
-    legal at any length because it spans the whole axis."""
-    for b in range(min(int(want), int(n)) // 8 * 8, 0, -8):
-        if n % b == 0:
-            return b
-    return int(n)
 
 
 # --------------------------------------------------------------------------
@@ -250,185 +249,283 @@ def flash_attn(q, k, v, mask, *, causal=True, sm_scale=None,
 # (b): chunk-vs-cache cross attention for pool_prefill_chunk
 # --------------------------------------------------------------------------
 
-# Chunk index maps take (k_tile, meta) — meta is the scalar-prefetched
-# int32 vector [start] (dense rows) or [start, *block_table_row] (paged).
-def _chunk_q_map(i, meta):
-    return (0, 0, 0)
+# Scalar-prefetched, all int32: ``meta`` = [start, n_live]; ``blk`` (k_tiles,)
+# the key blocks that hold a key some query of the piece may see, in order,
+# the last of them repeated to the end (a repeated index is not DMA'd again);
+# ``src`` (k_tiles,) where each of those blocks' K/V lie: ``blk`` itself for a
+# dense row, the slot's physical blocks for the paged planes.
+def _chunk_q_map(h, j, meta, blk, src):
+    return (h, 0, 0)
 
 
-def _chunk_kv_map(i, meta):
-    return (0, 0, i, 0)
+def _chunk_kv_map(h, j, meta, blk, src):
+    return (0, h, src[j], 0)
 
 
-def _chunk_mask_map(i, meta):
-    return (i, 0, 0)
+def _paged_chunk_kv_map(h, j, meta, blk, src):
+    return (src[j], h, 0, 0)
 
 
-def _paged_chunk_kv_map(i, meta):
-    return (meta[i + 1], 0, 0, 0)
+def _chunk_kcol_map(h, j, meta, blk, src):
+    return (blk[j], 0, 0)
 
 
-def _chunk_kernel(meta_ref, *refs, sm_scale, block_t, block_k, n_kt, quant):
-    """Grid (k_tiles,): the whole T-token query piece stays resident in
-    VMEM while cache column tiles stream past; ``meta_ref[0]`` is the
-    piece's absolute ``start`` offset, so query row t attends logical
-    columns ``live & (col <= start + t)``. Shared by the dense-row and
-    block-table variants — only the index maps differ."""
+def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
+                  block_k, n_kt, n_rows, window, quant):
+    """Grid (kv_heads, k_tiles), the key axis innermost. One grid step is
+    ONE key-value head against one block of its keys: the ``group`` query
+    heads that share it lie folded into the query rows (``group * T``,
+    resident in VMEM across the head's blocks) and walk the block one after
+    another against one predicate tile, so K and V are read once a group.
+
+    ``kcol`` is the absolute cache column each key row holds (-1: dead), so
+    one predicate serves a dense row, a paged row and a window layer's
+    ``[ring | own]``: query row t, at column ``start + t``, reads a key when
+    ``0 <= kcol <= start + t`` and, with a window, ``start + t - kcol <
+    window``. Only the first ``meta[1]`` steps of a head hold a block some
+    query can see; the rest neither compute nor move anything. Operands go
+    into both dots as they come (bfloat16 on the chip), scores, statistics
+    and the accumulator (the resident output block) are float32, and the
+    probabilities are cast to the operands' type before the value dot: the
+    precision of ``decoder._attn_ctx``."""
     if quant:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, o_ref = refs[:7]
+        q_ref, k_ref, v_ref, ks_ref, vs_ref, kcol_ref, o_ref = refs[:7]
     else:
-        q_ref, k_ref, v_ref, mask_ref, o_ref = refs[:5]
+        q_ref, k_ref, v_ref, kcol_ref, o_ref = refs[:5]
         ks_ref = vs_ref = None
-    m_ref, l_ref, acc_ref = refs[-3:]
-    i = pl.program_id(0)
+    m_ref, l_ref = refs[-2:]
+    j = pl.program_id(1)
     start = meta_ref[0]
 
-    @pl.when(i == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
+    @pl.when(j < meta_ref[1])
     def _tile():
-        q = q_ref[...].astype(jnp.float32)          # (nh, T, hd)
-        k = k_ref[0].astype(jnp.float32)            # (nh, Bk, hd)
-        v = v_ref[0].astype(jnp.float32)
+        k, v = k_ref[0, 0], v_ref[0, 0]             # (Bk, hd)
         if quant:
-            # fused int8 dequant: (nh, Bk, 1) f32 scales broadcast over hd
-            k = k * ks_ref[0].astype(jnp.float32)
-            v = v * vs_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                                # (nh, T, Bk)
-        rows = start + jax.lax.broadcasted_iota(
+            # fused int8 dequant: (Bk, 1) f32 scales broadcast over hd
+            k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(q_ref.dtype)
+            v = (v.astype(jnp.float32) * vs_ref[0, 0]).astype(q_ref.dtype)
+        if n_rows % block_k:
+            # the row's last tile reaches past its end: what was read there
+            # is not data, and 0 x NaN is NaN, so it is zeroed before it
+            # meets its zero probability (its scores are masked by kcol -1)
+            rows = blk_ref[j] * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            v = jnp.where(rows < n_rows, v, jnp.zeros_like(v))
+        kcol = kcol_ref[0]                          # (1, Bk)
+        qcol = start + jax.lax.broadcasted_iota(
             jnp.int32, (block_t, block_k), 0)
-        cols = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_t, block_k), 1)
-        live = jnp.broadcast_to(mask_ref[0] > 0,
-                                (block_t, block_k)) & (cols <= rows)
-        s = jnp.where(live[None, :, :], s, _NEG)
+        live = (kcol >= 0) & (kcol <= qcol)
+        if window:
+            live = live & (qcol - kcol < window)
+        for g in range(group):
+            rows = pl.ds(g * block_t, block_t)
+            s = jax.lax.dot_general(
+                q_ref[0, rows, :], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale                            # (T, Bk)
+            s = jnp.where(live, s, _NEG)
+            m_prev = m_ref[rows, :]                 # (T, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                       # (T, hd)
+            o_ref[0, rows, :] = o_ref[0, rows, :] * alpha + pv
+            m_ref[rows, :] = m_new
 
-        m_prev = m_ref[...]                         # (nh, T)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(live[None, :, :],
-                      jnp.exp(s - m_new[..., None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
-        m_ref[...] = m_new
-
-    # tiles entirely past the piece's last written column are dead (the
-    # tile is still DMA'd by the BlockSpec schedule; only compute skips)
-    pl.when(i * block_k <= start + (block_t - 1))(_tile)
-
-    @pl.when(i == n_kt - 1)
+    @pl.when(j == n_kt - 1)
     def _finish():
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] /
-                      jnp.where(l == 0.0, 1.0, l)[..., None]
-                      ).astype(o_ref.dtype)
+        # a row with no attendable column divides by 1 instead of 0 and
+        # emits exact zeros; see the module docstring
+        o_ref[0] = o_ref[0] / jnp.where(l == 0.0, 1.0, l)
 
 
-def _chunk_call(meta, q, kv_operands, kv_specs, row_mask, *,
-                sm_scale, block_t, block_k, n_kt, quant, interpret, nh, hd):
+# What one kernel instance should need in VMEM by :func:`_chunk_vmem_bytes`
+# (a rough count: the compiler is given twice it as its limit, 32 MiB here,
+# well inside the smallest core this runs on: 64 MiB; a v5e's has 128).
+_CHUNK_VMEM_BUDGET = 16 << 20
+
+
+def _chunk_vmem_bytes(T, group, hd, bk, itemsize):
+    """VMEM one grid step of :func:`_chunk_kernel` needs: the group's
+    queries and float32 output (double-buffered), its two statistics
+    (a lane-padded column each), K and V tiles, and one head's scores,
+    probabilities and predicate."""
+    rows = group * T
+    resident = 2 * rows * hd * (itemsize + 4) + 2 * rows * 128 * 4
+    tiles = 4 * bk * (hd * itemsize + 128 * 4) + 2 * 8 * bk * 4
+    return resident + tiles + T * bk * (4 + 4 + 4 + itemsize)
+
+
+def chunk_block(columns, T, group, hd, itemsize):
+    """Key rows a block of the chunk read, from the shapes: the largest of
+    512, 256 and 128 that fits the VMEM budget (a larger block amortises a
+    grid step's fixed cost; a smaller one skips dead columns more finely),
+    or the whole 8-rounded row when that is shorter. The row's length need
+    not be a multiple: its last tile is ragged. ``flash_block_k`` overrides
+    (``configure_blocks``)."""
+    want = _BLOCK_K
+    if not want:
+        want = 512
+        while want > 128 and _chunk_vmem_bytes(
+                T, group, hd, want, itemsize) > _CHUNK_VMEM_BUDGET:
+            want //= 2
+    return min(_round8(want), _round8(columns))
+
+
+def blocks_seen(xp, kcol, start, T, window, bk):
+    """``kcol`` (rows,) padded with -1 to whole blocks of ``bk`` as
+    ``(n, bk)``, and for each block whether it holds a key that SOME query
+    of the piece ``[start, start + T)`` may see. ``xp`` is ``jnp`` (the
+    kernel's prefetch) or ``numpy`` (the host's counter): one predicate."""
+    n = -(-kcol.shape[0] // bk)
+    kcol = xp.pad(kcol, (0, n * bk - kcol.shape[0]),
+                  constant_values=-1).reshape(n, bk)
+    seen = (kcol >= 0) & (kcol < start + T)
+    if window:
+        seen = seen & (kcol > start - window)
+    return kcol, seen.any(axis=1)
+
+
+def chunk_live_blocks(kcol, start, T, window, bk):
+    """What the kernel prefetches: ``(kcol as (n, 1, bk), n_live, blk)``,
+    ``blk`` (n,) the blocks :func:`blocks_seen` marks, in order, the last
+    one repeated to the end. On the device, before the kernel: a reduction
+    over a few thousand ints."""
+    kcol, seen = blocks_seen(jnp, kcol.astype(jnp.int32), start, T, window,
+                             bk)
+    n = seen.shape[0]
+    n_live = seen.sum().astype(jnp.int32)
+    order = jnp.argsort(~seen, stable=True).astype(jnp.int32)
+    blk = order[jnp.minimum(jnp.arange(n), jnp.maximum(n_live - 1, 0))]
+    return kcol.reshape(n, 1, bk), n_live, blk
+
+
+def _chunk_call(q, kv_operands, kv_specs, kcol, start, tbl, *, window,
+                sm_scale, block_k, n_rows, quant, interpret):
+    """``q`` (heads, T, hd) against K/V given as 4-D operands: the keys of
+    logical block ``b`` lie in block ``b`` of a dense row (``tbl`` None),
+    in block ``tbl[b]`` of the paged planes."""
+    nq, T, hd = q.shape
+    nkv = kv_operands[0].shape[1]
+    group = nq // nkv
+    kcol, n_live, blk = chunk_live_blocks(kcol, start, T, window, block_k)
+    n_kt = blk.shape[0]
+    meta = jnp.stack([jnp.asarray(start, jnp.int32), n_live])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_kt,),
-        in_specs=[pl.BlockSpec((nh, block_t, hd), _chunk_q_map)] + kv_specs
-        # row mask as (k_tiles, 1, block_k), see flash_attn's mask spec
-        + [pl.BlockSpec((1, 1, block_k), _chunk_mask_map)],
-        out_specs=pl.BlockSpec((nh, block_t, hd), _chunk_q_map),
+        num_scalar_prefetch=3,
+        grid=(nkv, n_kt),
+        in_specs=[pl.BlockSpec((1, group * T, hd), _chunk_q_map)] + kv_specs
+        # key columns as (k_tiles, 1, block_k), see flash_attn's mask spec
+        + [pl.BlockSpec((1, 1, block_k), _chunk_kcol_map)],
+        out_specs=pl.BlockSpec((1, group * T, hd), _chunk_q_map),
         scratch_shapes=[
-            pltpu.VMEM((nh, block_t), jnp.float32),
-            pltpu.VMEM((nh, block_t), jnp.float32),
-            pltpu.VMEM((nh, block_t, hd), jnp.float32),
+            pltpu.VMEM((group * T, 1), jnp.float32),    # running max
+            pltpu.VMEM((group * T, 1), jnp.float32),    # running denom
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _chunk_kernel, sm_scale=sm_scale, block_t=block_t,
-            block_k=block_k, n_kt=n_kt, quant=quant,
+            _chunk_kernel, sm_scale=sm_scale, group=group, block_t=T,
+            block_k=block_k, n_kt=n_kt, n_rows=n_rows, window=int(window),
+            quant=quant,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nh, block_t, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nkv, group * T, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=2 * max(
+                _CHUNK_VMEM_BUDGET, _chunk_vmem_bytes(
+                    T, group, hd, block_k, q.dtype.itemsize)),
+        ),
         interpret=interpret,
-    )(meta, q, *kv_operands, row_mask.reshape(n_kt, 1, block_k))
+    )(meta, blk, blk if tbl is None else tbl[blk],
+      q.reshape(nkv, group * T, hd), *kv_operands, kcol)
+    return out.reshape(nq, T, hd)
 
 
-def flash_chunk_attn(q, k_row, v_row, row_mask, start, *,
+def flash_chunk_attn(q, k_row, v_row, kcol, start, *, window=0,
                      k_scale=None, v_scale=None, sm_scale=None,
                      block_k=None, interpret=None):
     """Chunk-vs-cache attention over one slot's DENSE cache row.
 
     Args:
       q: (heads, T, head_dim) query piece in compute dtype.
-      k_row/v_row: (heads, cache_len, head_dim) full cache row (int8
-        when quantized, else compute dtype).
-      row_mask: (cache_len,) attendable-column mask (>0 = live).
-      start: absolute offset of the piece (scalar, may be traced); query
-        row t attends columns ``live & (col <= start + t)``.
-      k_scale/v_scale: (heads, cache_len, 1) f32 per-token scales, or
-        None when the cache is unquantized.
-      block_k: cache tile size; defaults to the construction-time value,
-        else the largest divisor of cache_len that is <= 128.
+      k_row/v_row: (kv_heads, rows, head_dim) the slot's keys and values
+        (int8 when quantized, else compute dtype); ``heads // kv_heads``
+        query heads share each (grouped query; 1: multi-head).
+      kcol: (rows,) the absolute cache column each key row holds, -1 where
+        it holds none that may be read: ``arange`` under the row's mask for
+        a full layer, the ring's columns then the piece's own for a window
+        layer.
+      start: absolute column of the piece's first query (scalar, may be
+        traced); query row t reads ``0 <= kcol <= start + t``.
+      window: > 0 also asks ``start + t - kcol < window`` (static).
+      k_scale/v_scale: (kv_heads, rows, 1) f32 per-token scales, or None
+        when the cache is unquantized.
+      block_k: key rows a tile; defaults to :func:`chunk_block`.
       interpret: run the Pallas interpreter; defaults to True off-TPU.
 
     Returns (heads, T, head_dim) float32 context.
     """
-    nh, T, hd = q.shape
-    C = k_row.shape[1]
+    nq, T, hd = q.shape
+    nkv, C, _ = k_row.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    bk = _pick_block(C, block_k or _BLOCK_K or _AUTO_BLOCK)
-    n_kt = C // bk
+    bk = int(block_k or chunk_block(C, T, nq // nkv, hd, q.dtype.itemsize))
     quant = k_scale is not None
-    meta = jnp.full((1,), start, jnp.int32)
 
     kv_operands = [k_row[None], v_row[None]]
-    kv_specs = [pl.BlockSpec((1, nh, bk, hd), _chunk_kv_map)] * 2
+    kv_specs = [pl.BlockSpec((1, 1, bk, hd), _chunk_kv_map)] * 2
     if quant:
         kv_operands += [k_scale[None], v_scale[None]]
-        kv_specs += [pl.BlockSpec((1, nh, bk, 1), _chunk_kv_map)] * 2
+        kv_specs += [pl.BlockSpec((1, 1, bk, 1), _chunk_kv_map)] * 2
     return _chunk_call(
-        meta, q, kv_operands, kv_specs, row_mask.astype(jnp.int32),
-        sm_scale=sm_scale, block_t=T, block_k=bk, n_kt=n_kt,
-        quant=quant, interpret=interpret, nh=nh, hd=hd,
+        q, kv_operands, kv_specs, kcol, start, None,
+        window=window, sm_scale=sm_scale, block_k=bk, n_rows=C,
+        quant=quant, interpret=interpret,
     )
 
 
 def flash_chunk_attn_paged(q, kb, vb, kb_scale, vb_scale, tbl_row,
-                           row_mask, start, *, sm_scale=None,
+                           kcol, start, *, window=0, sm_scale=None,
                            interpret=None):
     """Chunk-vs-cache attention straight over the PAGED pool's physical
-    block planes — no gather of the slot's row. The scalar-prefetched
-    vector packs ``[start, *tbl_row]`` so each grid step DMAs exactly
-    the physical block the slot's table references, mirroring
-    ``paged_attention.paged_attn_decode``.
+    block planes — no gather of the slot's row. Each live grid step DMAs
+    exactly the physical block the slot's table references (scalar-
+    prefetched), mirroring ``paged_attention.paged_attn_decode``.
 
     Args:
       q: (heads, T, head_dim) query piece.
-      kb/vb: (n_blocks, heads, block, head_dim) physical KV block planes
+      kb/vb: (n_blocks, kv_heads, block, head_dim) physical KV block planes
         (int8 when quantized).
-      kb_scale/vb_scale: (n_blocks, heads, block, 1) f32 scales or None.
+      kb_scale/vb_scale: (n_blocks, kv_heads, block, 1) f32 scales or None.
       tbl_row: (cache_len // block,) int32 — ONE slot's block-table row.
-      row_mask: (cache_len,) attendable-column mask in logical order.
+      kcol: (cache_len,) the column each key row holds in logical order,
+        -1 where dead (:func:`flash_chunk_attn`).
       start: absolute offset of the piece (scalar, may be traced).
 
     Returns (heads, T, head_dim) float32 context.
     """
-    nh, T, hd = q.shape
+    hd = q.shape[2]
     Bk = kb.shape[2]
     M = tbl_row.shape[0]
-    if row_mask.shape[0] != M * Bk:
+    if kcol.shape[0] != M * Bk:
         raise ValueError(
-            f"row_mask width {row_mask.shape[0]} != table blocks "
+            f"kcol width {kcol.shape[0]} != table blocks "
             f"{M} x block {Bk}"
         )
     if sm_scale is None:
@@ -436,17 +533,14 @@ def flash_chunk_attn_paged(q, kb, vb, kb_scale, vb_scale, tbl_row,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     quant = kb_scale is not None
-    meta = jnp.concatenate([
-        jnp.full((1,), start, jnp.int32), tbl_row.astype(jnp.int32),
-    ])
 
     kv_operands = [kb, vb]
-    kv_specs = [pl.BlockSpec((1, nh, Bk, hd), _paged_chunk_kv_map)] * 2
+    kv_specs = [pl.BlockSpec((1, 1, Bk, hd), _paged_chunk_kv_map)] * 2
     if quant:
         kv_operands += [kb_scale, vb_scale]
-        kv_specs += [pl.BlockSpec((1, nh, Bk, 1), _paged_chunk_kv_map)] * 2
+        kv_specs += [pl.BlockSpec((1, 1, Bk, 1), _paged_chunk_kv_map)] * 2
     return _chunk_call(
-        meta, q, kv_operands, kv_specs, row_mask.astype(jnp.int32),
-        sm_scale=sm_scale, block_t=T, block_k=Bk, n_kt=M,
-        quant=quant, interpret=interpret, nh=nh, hd=hd,
+        q, kv_operands, kv_specs, kcol, start, tbl_row.astype(jnp.int32),
+        window=window, sm_scale=sm_scale, block_k=Bk, n_rows=M * Bk,
+        quant=quant, interpret=interpret,
     )

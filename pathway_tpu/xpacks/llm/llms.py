@@ -2466,7 +2466,8 @@ class _ContinuousServer:
                 (ids[:, o:o + P], mask[:, o:o + P], pos[:, o:o + P], o)
                 for o in range(0, s, P) if mask[0, o:o + P].any()
             ]
-            meta = {"first_off": pieces[0][3]}
+            meta = {"first_off": pieces[0][3],
+                    "first_col": s - int(n_prompt[0])}
             if ins is not None:
                 meta["insert"] = ins
             self._pending_prefill[slot] = (pieces, n_prompt, meta)
@@ -2670,6 +2671,19 @@ class _ContinuousServer:
                     np.int32(lc),
                 )
         self.stats["prefill_chunks"] += 1
+        live = np.flatnonzero(p_mask[0])
+        if live.size:
+            # how much of the row this piece's attention touched: host
+            # arithmetic on the piece's offset and the row's live columns
+            from pathway_tpu.engine import probes
+
+            blocks = self._D.prefill_blocks_visited(
+                self.cfg, int(p_ids.shape[1]), self.cache_len,
+                self._D.pool_ring(self.pool), int(off),
+                int(meta.get("first_col", 0)) if meta else 0,
+                int(off) + int(live[-1]), flash=self.flash_prefill)
+            if blocks:
+                probes.record_prefill_attn_blocks(blocks)
         req_p = self.slots[slot]
         if req_p is not None:
             req_p.span.event(

@@ -97,32 +97,127 @@ def test_flash_attn_fully_masked_rows_are_zero():
     assert np.all(np.isfinite(np.asarray(out)))
 
 
-@pytest.mark.parametrize("start", [0, 7, 88])
-@pytest.mark.parametrize("quant", [False, True])
-def test_flash_chunk_attn_matches_dense(start, quant):
-    nh, t, c, hd = 4, 8, 96, 8
+def _full_keys(rows, t, start, lo):
+    """A full layer's row as ``pool_prefill_chunk`` reads it: live columns
+    ``[lo, start + t)``; ``(kcol, dense mask bias (1, 1, t, rows))``."""
+    cols = jnp.arange(rows)
+    row_mask = (cols >= lo) & (cols < start + t)
+    qcol = start + jnp.arange(t)
+    allowed = row_mask[None, :] & (cols[None, :] <= qcol[:, None])
+    return (jnp.where(row_mask, cols, -1),
+            jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)[None, None])
+
+
+def _window_keys(ring, window, t, start, lo):
+    """A window layer's ``[ring | own]`` as ``pool_prefill_chunk`` reads
+    it, the bias from the decoder's own ``_ring_bias`` and ``own``."""
+    cache = 128
+    cols_c = jnp.arange(cache)
+    row_mask = ((cols_c >= lo) & (cols_c < start + t)).astype(jnp.int32)[None]
+    mask = row_mask[:, start:start + t]
+    qcol = (start + jnp.arange(t))[None, :]
+    cols = D._ring_cols(jnp.reshape(jnp.int32(start) - 1, (1,)), ring)
+    ring_live = D._live_at(row_mask, cols)
+    old = D._ring_bias(cols, jnp.broadcast_to(
+        ring_live[:, None, :], (1, t, ring)), qcol, window)
+    j = jnp.arange(t)
+    own = (mask[:, None, :] > 0) & (j[None, None, :] <= j[None, :, None]) \
+        & (j[None, :, None] - j[None, None, :] < window)
+    bias = jnp.concatenate(
+        [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]], -1)
+    kcol = jnp.concatenate([jnp.where(ring_live, cols, -1),
+                            jnp.where(mask > 0, qcol, -1)], axis=1)[0]
+    return kcol, bias
+
+
+# (heads, kv_heads, window, rows or ring, start, first live column, int8,
+# block_k): multi-head as it always was (the first six); then grouped query
+# (6 heads a key-value head), a window layer's [ring | own] before, at and
+# after the ring (24) wraps, a piece whose first rows lie wholly in the left
+# padding, row lengths whose last tile is ragged, and int8 under a group
+_CHUNK_CASES = [
+    pytest.param(4, 4, 0, 96, st, 0, qn, None, id=f"{qn}-{st}")
+    for qn in (False, True) for st in (0, 7, 88)
+] + [
+    pytest.param(12, 2, 0, 96, 40, 0, False, None, id="group6"),
+    pytest.param(12, 2, 0, 100, 88, 0, False, 32, id="group6-ragged"),
+    pytest.param(4, 4, 0, 203, 150, 30, False, 64, id="ragged-203"),
+    pytest.param(12, 2, 0, 96, 16, 20, False, 32, id="group6-leftpad-rows"),
+    pytest.param(12, 2, 0, 96, 40, 0, True, 32, id="group6-int8"),
+    pytest.param(4, 4, 16, 24, 8, 0, False, None, id="window-before-wrap"),
+    pytest.param(4, 4, 16, 24, 24, 0, False, None, id="window-at-wrap"),
+    pytest.param(4, 4, 16, 24, 40, 0, False, None, id="window-after-wrap"),
+    pytest.param(12, 2, 16, 24, 8, 0, False, 8, id="window-group6-before"),
+    pytest.param(12, 2, 16, 24, 24, 0, False, 8, id="window-group6-at"),
+    pytest.param(12, 2, 16, 24, 56, 0, False, 8, id="window-group6-after"),
+    pytest.param(12, 2, 16, 24, 32, 35, False, 8,
+                 id="window-group6-leftpad-rows"),
+]
+
+
+@pytest.mark.parametrize(
+    "nq,nkv,window,rows,start,lo,quant,block_k", _CHUNK_CASES)
+def test_flash_chunk_attn_matches_dense(nq, nkv, window, rows, start, lo,
+                                        quant, block_k):
+    """The chunk kernel against ``decoder._attn_ctx`` under the bias the
+    dense read builds today, on every query row that sees a key; a row
+    that sees none (left padding) is exact zeros."""
+    t, hd = 8, 8
+    cfg = D.DecoderConfig(heads=nq, kv_heads=nkv, head_size=hd,
+                          hidden=nq * hd, dtype=jnp.float32)
+    if window:
+        kcol, bias = _window_keys(rows, window, t, start, lo)
+    else:
+        kcol, bias = _full_keys(rows, t, start, lo)
+    c = kcol.shape[0]
     key = jax.random.PRNGKey(3)
-    q = jax.random.normal(jax.random.fold_in(key, 0), (nh, t, hd))
+    q = jax.random.normal(jax.random.fold_in(key, 0), (nq, t, hd))
     if quant:
-        kq, vq = (jax.random.randint(jax.random.fold_in(key, i), (nh, c, hd),
+        kr, vr = (jax.random.randint(jax.random.fold_in(key, i), (nkv, c, hd),
                                      -127, 128, jnp.int32).astype(jnp.int8)
                   for i in (1, 2))
-        ks, vs = (jax.random.uniform(jax.random.fold_in(key, i), (nh, c, 1),
-                                     minval=0.01, maxval=0.05)
-                  for i in (3, 4))
-        k = (kq.astype(jnp.float32) * ks)
-        v = (vq.astype(jnp.float32) * vs)
-        kr, vr, krs, vrs = kq, vq, ks, vs
+        krs, vrs = (jax.random.uniform(jax.random.fold_in(key, i),
+                                       (nkv, c, 1), minval=0.01, maxval=0.05)
+                    for i in (3, 4))
     else:
-        k = jax.random.normal(jax.random.fold_in(key, 1), (nh, c, hd))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (nh, c, hd))
-        kr, vr, krs, vrs = k, v, None, None
-    row_mask = (jnp.arange(c) < start + t).astype(jnp.int32)
-    out = FA.flash_chunk_attn(q, kr, vr, row_mask, jnp.int32(start),
-                              k_scale=krs, v_scale=vrs)
-    ref = _dense_ref(q[None], k[None], v[None], row_mask[None],
-                     causal=False, start=start)[0]
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
+        kr = jax.random.normal(jax.random.fold_in(key, 1), (nkv, c, hd))
+        vr = jax.random.normal(jax.random.fold_in(key, 2), (nkv, c, hd))
+        krs = vrs = None
+    out = np.asarray(FA.flash_chunk_attn(
+        q, kr, vr, kcol, jnp.int32(start), window=window, k_scale=krs,
+        v_scale=vrs, block_k=block_k))
+    ref = np.asarray(D._attn_ctx(
+        q[None], kr[None], vr[None], bias, cfg,
+        None if krs is None else krs[None],
+        None if vrs is None else vrs[None]))[0]
+    sees = np.asarray(bias[0, 0] == 0.0).any(axis=-1)        # (t,)
+    assert sees.any() and (lo <= start or not sees.all())
+    assert np.all(out[:, ~sees] == 0.0)
+    np.testing.assert_allclose(out[:, sees], ref[:, sees],
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_chunk_live_blocks_are_exactly_the_blocks_with_a_visible_key():
+    """Dead blocks cost nothing only if no live one is among them: the
+    prefetched list holds every block with a key some query sees, in
+    order, then the last of them again."""
+    kcol, _bias = _window_keys(24, 16, 8, 40, 0)
+    for bk, window in ((8, 16), (8, 0), (16, 16)):
+        tiles, n_live, blk = FA.chunk_live_blocks(
+            kcol, jnp.int32(40), 8, window, bk)
+        kc = np.asarray(tiles)[:, 0, :]
+        seen = (kc >= 0) & (kc < 48)
+        if window:
+            seen &= kc > 40 - window
+        want = np.flatnonzero(seen.any(axis=1))
+        assert int(n_live) == len(want) > 0
+        got = np.asarray(blk)
+        assert list(got[:len(want)]) == list(want)
+        assert np.all(got[len(want):] == want[-1])
+    # nothing to see: no step computes, and the indices stay in range
+    _t, n_live, blk = FA.chunk_live_blocks(
+        jnp.full((24,), -1), jnp.int32(0), 8, 0, 8)
+    assert int(n_live) == 0 and np.all(np.asarray(blk) == 0)
 
 
 def test_flash_chunk_attn_paged_matches_dense():
@@ -135,7 +230,8 @@ def test_flash_chunk_attn_paged_matches_dense():
     tbl = jnp.arange(1, m + 1, dtype=jnp.int32)
     start = 21
     row_mask = (jnp.arange(m * blk) < start + t).astype(jnp.int32)
-    out = FA.flash_chunk_attn_paged(q, kb, vb, None, None, tbl, row_mask,
+    kcol = jnp.where(row_mask > 0, jnp.arange(m * blk), -1)
+    out = FA.flash_chunk_attn_paged(q, kb, vb, None, None, tbl, kcol,
                                     jnp.int32(start))
     k = kb[1:].transpose(1, 0, 2, 3).reshape(nh, m * blk, hd)
     v = vb[1:].transpose(1, 0, 2, 3).reshape(nh, m * blk, hd)

@@ -192,12 +192,78 @@ def test_flash_chunk_attn_kernel(shape):
     row = shape((HEADS, CACHE_LEN, HEAD_DIM), I8)
     scale = shape((HEADS, CACHE_LEN, 1), F32)
     c = _compile(
-        lambda q, k, v, m, s, ks, vs: flash_chunk_attn(
-            q, k, v, m, s, k_scale=ks, v_scale=vs, interpret=False),
+        lambda q, k, v, kc, s, ks, vs: flash_chunk_attn(
+            q, k, v, kc, s, k_scale=ks, v_scale=vs, interpret=False),
         shape((HEADS, PREFILL_CHUNK, HEAD_DIM), BF16), row, row,
         shape((CACHE_LEN,), I32), shape((), I32), scale, scale,
     )
     assert _is_mosaic(c)
+
+
+@pytest.mark.parametrize(
+    "rows,window", [(8304, 0), (4352 + 512, 4096)],
+    ids=["full-row-8304", "window-ring-4352+512"],
+)
+def test_flash_chunk_attn_kernel_at_the_answer_cells_widths(
+        shape, rows, window):
+    """Trinity's attention as ``trinity_rag_answer_closed16`` runs it: 48
+    query heads over 8 key-value heads of 128, a piece of 512, against a
+    full layer's row (8,304: no MXU-sized divisor, so its last tile is
+    ragged) and a window layer's ``[ring | own]``, bfloat16."""
+    from pathway_tpu.models.flash_attention import flash_chunk_attn
+
+    row = shape((8, rows, 128), BF16)
+    c = _compile(
+        lambda q, k, v, kc, s: flash_chunk_attn(
+            q, k, v, kc, s, window=window, interpret=False),
+        shape((48, 512, 128), BF16), row, row, shape((rows,), I32),
+        shape((), I32),
+    )
+    assert _is_mosaic(c)
+
+
+def test_trinity_width_prefill_piece(shape, monkeypatch):
+    """The whole prefill piece of the answer cell (the configuration the
+    benchmark runs, 16 slots of 8,304 columns, a piece of 512) with the
+    blockwise read its shapes choose: the kernel is in the program, and the
+    piece's temporaries are under the dense read's 1.84 GB (``PERF.md``
+    section 4 has both)."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import manifest as M
+
+    from pathway_tpu.models import decoder as D
+
+    # this process's default backend is the CPU: steer the kernel's own
+    # choice of the interpreter here, in the test
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(bench, "configs",
+                           "trinity-large-ep8-rag.json")) as f:
+        model = json.load(f)["models"]["decoder"]
+    cfg = M.resolve(M.load_manifest(), "layouts", "afmoe").program_config(
+        model)
+    params = jax.eval_shape(lambda: D.cast_params_for_inference(
+        D.init_params(jax.random.PRNGKey(0), cfg), cfg))
+    pool = jax.eval_shape(lambda: D.pool_init(None, cfg, 16, 8304))
+    assert D.pool_ring(pool) == 4352
+    piece = shape((1, 512), I32)
+    c = _compile(
+        lambda p, i, m, ps, pl, s, st, n: D.pool_prefill_chunk(
+            p, i, m, ps, pl, s, st, n, cfg, first=False, last=False),
+        _placed(shape, params), piece, piece, piece, _placed(shape, pool),
+        shape((), I32), shape((), I32), shape((1,), I32),
+        donate_argnums=(4,),
+    )
+    assert _is_mosaic(c)
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 1_400_000_000
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16 << 30
 
 
 def test_flash_chunk_attn_paged_kernel(shape):
@@ -206,8 +272,8 @@ def test_flash_chunk_attn_paged_kernel(shape):
     m = PAGED_CACHE_LEN // PAGED_BLOCK
     plane = shape((N_SLOTS * m + 1, HEADS, PAGED_BLOCK, HEAD_DIM), BF16)
     c = _compile(
-        lambda q, kb, vb, t, msk, s: flash_chunk_attn_paged(
-            q, kb, vb, None, None, t, msk, s, interpret=False),
+        lambda q, kb, vb, t, kc, s: flash_chunk_attn_paged(
+            q, kb, vb, None, None, t, kc, s, interpret=False),
         shape((HEADS, PREFILL_CHUNK, HEAD_DIM), BF16), plane, plane,
         shape((m,), I32), shape((PAGED_CACHE_LEN,), I32), shape((), I32),
     )

@@ -183,7 +183,9 @@ def _pieces(prompt, bucket, piece, left):
             for o in range(0, bucket, piece)]
 
 
-def _prefill(p32, cfg32, pool, slot, prompt, left=True):
+def _prefill(p32, cfg32, pool, slot, prompt, left=True, blockwise=False):
+    """``blockwise``: every layer's read through the chunk kernel (the
+    ``flash`` argument forces what the shape rule chooses for long rows)."""
     pieces = _pieces(prompt, 48, 16, left)
     n_prompt = np.asarray([len(prompt)], np.int32)
     for ids, mask, pos, o in pieces:
@@ -191,12 +193,14 @@ def _prefill(p32, cfg32, pool, slot, prompt, left=True):
         if last and not left:
             pool = jax.jit(lambda p, i, m, ps, pl, lc: D.pool_prefill_chunk(
                 p, i, m, ps, pl, np.int32(slot), np.int32(o), n_prompt,
-                cfg32, first=first, last=True, last_col=lc))(
+                cfg32, first=first, last=True, last_col=lc,
+                flash=blockwise))(
                     p32, ids, mask, pos, pool, np.int32(len(prompt) - 33))
         else:
             pool = jax.jit(lambda p, i, m, ps, pl: D.pool_prefill_chunk(
                 p, i, m, ps, pl, np.int32(slot), np.int32(o), n_prompt,
-                cfg32, first=first, last=last))(p32, ids, mask, pos, pool)
+                cfg32, first=first, last=last, flash=blockwise))(
+                    p32, ids, mask, pos, pool)
     return pool
 
 
@@ -247,8 +251,37 @@ def test_pool_prefill_then_decode_matches_the_full_forward(
     assert 0 < held < every and every % 4 == 0
 
 
+@pytest.mark.parametrize("left", [True, False], ids=["left", "right"])
+def test_chunked_prefill_through_the_blockwise_read_matches_the_reference(
+        layout, net, prompt, left):
+    """The pieces of a prompt longer than the window and the ring, every
+    layer's attention through the chunk kernel (grouped query 8 / 2, window
+    16, the ring wrapping between pieces, the first piece's leading rows
+    wholly left padding): the next-token logits are the reference's inside
+    the same tolerance the dense read is held to, and the decode steps that
+    follow read what those pieces wrote."""
+    params, p32, _cfg, cfg32 = net
+    pool = _prefill(p32, cfg32,
+                    D.pool_init(p32, cfg32, 2, 96, window_slack=8), 1,
+                    prompt, left=left, blockwise=True)
+    seq, judged = list(prompt), 0
+    step = jax.jit(lambda p, pl: D.pool_decode_chunk(
+        p, pl, np.asarray([False, True]), jax.random.PRNGKey(0), cfg32, 1))
+    for _ in range(4):
+        want, sound = ref_rows(layout, params, seq, len(seq) - 1)
+        if sound[0]:
+            judged += 1
+            assert np.abs(np.asarray(pool["logits"][1]) - want[0]).max() \
+                < F32_TOL, len(seq)
+        pool, toks = step(p32, pool)
+        seq.append(int(toks[0, 1]))
+    assert judged >= 3
+
+
+@pytest.mark.parametrize("blockwise", [False, True],
+                         ids=["dense", "blockwise"])
 def test_one_shot_admission_lays_the_ring_out_as_the_pieces_do(
-        net, prompt):
+        net, prompt, blockwise):
     """``pool_admit`` (one dispatch) and chunked prefill leave the same
     logits, and the window layers' rings hold the same last columns."""
     _params, p32, _cfg, cfg32 = net
@@ -259,7 +292,7 @@ def test_one_shot_admission_lays_the_ring_out_as_the_pieces_do(
         p, ids, mask, pl, np.int32(1), cfg32))(
             p32, D.pool_init(p32, cfg32, 2, 96, window_slack=8))
     b = _prefill(p32, cfg32, D.pool_init(p32, cfg32, 2, 96, window_slack=8),
-                 1, prompt)
+                 1, prompt, blockwise=blockwise)
     assert np.abs(np.asarray(a["logits"][1]) - np.asarray(b["logits"][1])
                   ).max() < F32_TOL
     for name in ("kw0", "vw1", "kw3"):
@@ -271,6 +304,110 @@ def test_one_shot_admission_lays_the_ring_out_as_the_pieces_do(
             p32, D.pool_init(p32, cfg32, 2, 96, window_slack=8))
     assert np.abs(np.asarray(batch["logits"]) - np.asarray(a["logits"][1])
                   ).max() < F32_TOL
+
+
+# the toy's 8 heads and pieces of 16 against a slot's row: a full layer's
+# scores pass 32 MiB at 65,536 columns; a window layer's ring of 24 never does
+@pytest.mark.parametrize("cache_len,kernels", [
+    (96, 0),          # every tier-1 row: the dense read, as it always was
+    (65_536, 0),      # 8 x 16 x 65,536 x 4 B = 32 MiB exactly: still dense
+    (65_552, 1),      # past it: the one full layer reads blockwise, and the
+])                    # window layers' [ring | own] of 40 columns stay dense
+def test_the_shape_rule_chooses_the_read_of_each_kind_of_layer(
+        net, cache_len, kernels):
+    """One expression on shapes (``blockwise_chunk_read``) decides, for the
+    row each kind of layer has in hand; nothing else does: no flag, no
+    model's name."""
+    _params, p32, _cfg, cfg32 = net
+    assert D.blockwise_chunk_read(cfg32.heads, 16, cache_len) == bool(kernels)
+    assert not D.blockwise_chunk_read(cfg32.heads, 16, 24 + 16)
+    # the answer cell's rows, and the serving defaults of GPT-2 medium
+    assert D.blockwise_chunk_read(48, 512, 8304)
+    assert D.blockwise_chunk_read(48, 512, 4352 + 512)
+    assert not D.blockwise_chunk_read(16, 64, 656)
+    pool = jax.eval_shape(
+        lambda: D.pool_init(None, cfg32, 2, cache_len, window_slack=8))
+    piece = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    traced = jax.make_jaxpr(lambda p, i, m, ps, pl: D.pool_prefill_chunk(
+        p, i, m, ps, pl, np.int32(1), np.int32(16),
+        np.asarray([40], np.int32), cfg32, first=False, last=False))(
+            p32, piece, piece, piece, pool)
+    assert str(traced).count("pallas_call") == kernels
+
+
+def _blocks_by_definition(T, rows, ring, window, start, lo, hi, bk):
+    """Key blocks with a key some query of the piece sees, by brute force
+    over every (query, key row): the definition, not the arithmetic."""
+    if ring:
+        held = [max((c for c in range(start) if c % ring == r), default=-1)
+                for r in range(ring)] + list(range(start, start + T))
+    else:
+        held = list(range(rows))
+    seen = set()
+    for row, c in enumerate(held):
+        if not lo <= c <= hi:
+            continue
+        if any(c <= q and (not window or q - c < window)
+               for q in range(start, start + T)):
+            seen.add(row // bk)
+    return len(seen), -(-len(held) // bk)
+
+
+def test_the_server_counts_the_blocks_its_pieces_visit(
+        layout, net, prompt, monkeypatch):
+    """``prefill_attn_blocks{layer, visited}``: with the rule's threshold
+    at zero (and blocks of 16 key rows, ``flash_block_k``'s own hook) the
+    toy's pieces read blockwise through the server's defaults;
+    the greedy tokens stay the reference's, and the counter holds, piece by
+    piece, what the definition gives (and what the device's own reduction
+    gives for the same piece)."""
+    from pathway_tpu.engine import probes
+    from pathway_tpu.internals.http_server import registry_text
+    from pathway_tpu.models import flash_attention as FA
+
+    params, p32, _cfg, cfg32 = net
+    monkeypatch.setattr(D, "_DENSE_SCORE_BYTES", 0)
+    monkeypatch.setattr(FA, "_BLOCK_K", 16)     # a toy row is one block else
+    probes.REGISTRY.remove("prefill_attn_blocks")
+    streams, stats, chat = _serve(p32, cfg32, [prompt], prefix_cache=False)
+    want, sure = _greedy_by_reference(layout, params, prompt, 8)
+    assert sure and streams[0] == want
+    C, W, T = chat._server.cache_len, 16, 16
+    R = D.pool_ring(chat._server.pool)
+    lo, bucket = 64 - len(prompt), 64
+    expect = {("full", 1): 0, ("full", 0): 0, ("window", 1): 0,
+              ("window", 0): 0}
+    layers = {"full": cfg32.n_layers_of("full"),
+              "window": cfg32.n_layers_of("window")}
+    pieces = [o for o in range(0, bucket, T) if o + T > lo]
+    assert stats["prefill_chunks"] == len(pieces) == 3
+    for start in pieces:
+        hi = start + T - 1
+        for kind, rows, ring, window in (("full", C, 0, 0),
+                                         ("window", R + T, R, W)):
+            bk = FA.chunk_block(rows, T, 4, 16, 4)
+            seen, n = _blocks_by_definition(
+                T, rows, ring, window, start, lo, hi, bk)
+            expect[(kind, 1)] += seen * layers[kind]
+            expect[(kind, 0)] += (n - seen) * layers[kind]
+        # the host's arithmetic is the device's reduction
+        cols = np.arange(C)
+        kcol = np.where((cols >= lo) & (cols <= hi), cols, -1)
+        _t, n_live, _b = FA.chunk_live_blocks(
+            jnp.asarray(kcol), jnp.int32(start), T, 0,
+            FA.chunk_block(C, T, 4, 16, 4))
+        assert int(n_live) * layers["full"] == D.prefill_blocks_visited(
+            cfg32, T, C, R, start, lo, hi)[("full", 1)]
+    got = {(s["labels"]["layer"], int(s["labels"]["visited"])): s["value"]
+           for s in probes.REGISTRY.snapshot()["counters"][
+               "prefill_attn_blocks"]["series"]}
+    assert got == expect and expect[("window", 1)] > 0 < expect[("full", 0)]
+    text = registry_text()
+    assert "# TYPE pathway_tpu_prefill_attn_blocks counter" in text
+    assert 'prefill_attn_blocks_total{layer="full",visited="1"}' in text
+    # a dense read counts nothing: the family says how often the kernel runs
+    monkeypatch.undo()
+    assert D.prefill_blocks_visited(cfg32, T, C, R, 16, lo, 31) == {}
 
 
 class WordIds:
