@@ -163,7 +163,12 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
         "counter", "layer", "Key blocks of a slot's row that the blockwise "
         "attention read of a prefill piece visited (visited=1) or skipped "
         "because no query of the piece can see a key in them (visited=0), "
-        "by kind of layer (full | window), summed over its layers"),
+        "by kind of layer (full | window | latent), summed over its layers"),
+    "latent_rows_expanded": (
+        "counter", "phase", "Latent cache rows turned into per-head keys "
+        "and values for the time of one attention read (phase=prefill: the "
+        "rows of the key blocks a piece's blockwise read visited), summed "
+        "over the latent layers; host arithmetic, no sync"),
     "kv_migrated_blocks": (
         "counter", "server", "KV blocks handed from the prefill lane to "
         "the decode lane at prompt completion (PATHWAY_TPU_DISAGG)"),
@@ -568,6 +573,13 @@ def record_prefill_attn_blocks(counts: dict) -> None:
     how much of a long row the blockwise read touches."""
     REGISTRY.counter_add_many(
         "prefill_attn_blocks", ("layer", "visited"), counts)
+
+
+def record_latent_rows_expanded(rows: int, phase: str = "prefill") -> None:
+    """Latent rows one dispatch expanded into per-head keys and values,
+    over its latent layers (``models.decoder.prefill_blocks_visited`` times
+    the block's rows: host arithmetic, no sync)."""
+    REGISTRY.counter_add("latent_rows_expanded", rows, phase=phase)
 
 
 def record_backlog(queue: str, depth: int) -> None:

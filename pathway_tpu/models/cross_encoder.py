@@ -18,7 +18,11 @@ import jax
 import jax.numpy as jnp
 
 from pathway_tpu.engine.tracing import region
-from pathway_tpu.models.tokenizer import HashTokenizer, pad_to_buckets
+from pathway_tpu.models.tokenizer import (
+    HashTokenizer,
+    bucket_pow2,
+    pad_to_buckets,
+)
 from pathway_tpu.models.transformer import (
     TransformerConfig,
     MINILM_L6,
@@ -26,6 +30,13 @@ from pathway_tpu.models.transformer import (
     init_params,
     _dense_init,
 )
+
+
+# The dense attention of one dispatch keeps rows x heads x S x S float32
+# scores a layer: a batch that would pass this many bytes goes as several
+# dispatches (512 pairs of 256 tokens at 12 heads are 1.6 GB and go as one;
+# 384 pairs of 512 tokens as three of 128).
+_MAX_SCORE_BYTES = 2 << 30
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "flash"))
@@ -130,16 +141,35 @@ class CrossEncoderModel:
             ids, mask, types = self.tokenizer.encode_pairs(
                 pairs, max_length=self.max_length, return_types=True
             )
-            ids, mask, types = pad_to_buckets(ids, mask, types)
-            out = score_fn(self.params, self.head, jnp.asarray(ids),
-                           jnp.asarray(mask), self.cfg, jnp.asarray(types),
-                           flash=self.flash_prefill)
-        return (out, len(pairs))
+            outs = []
+            for rows in self._dispatch_rows(*ids.shape):
+                i, m, t = pad_to_buckets(ids[rows], mask[rows], types[rows])
+                outs.append(score_fn(
+                    self.params, self.head, jnp.asarray(i), jnp.asarray(m),
+                    self.cfg, jnp.asarray(t), flash=self.flash_prefill))
+        return (outs[0] if len(outs) == 1 else tuple(outs), len(pairs))
+
+    def _dispatch_rows(self, n: int, seq: int) -> list[np.ndarray]:
+        """The rows of a batch of ``n`` pairs of ``seq`` tokens by dispatch:
+        evenly, as few dispatches as keep each one's dense scores under
+        ``_MAX_SCORE_BYTES`` (the tiled read keeps none: one dispatch)."""
+        per_row = self.cfg.heads * bucket_pow2(seq, 16) ** 2 * 4
+        # rows are padded to a power of two: the largest that fits
+        cap = max(8, 1 << (_MAX_SCORE_BYTES // per_row).bit_length() - 1)
+        parts = 1 if self.flash_prefill else -(-n // cap)
+        return np.array_split(np.arange(n), parts)
 
     def score_resolve(self, handles) -> list[np.ndarray]:
         with region("pw.rerank.score", pairs=sum(n for _, n in handles)):
             fetched = jax.device_get([h for h, _ in handles])
-        return [np.asarray(o)[:n] for o, (_, n) in zip(fetched, handles)]
+        out = []
+        for o, (_, n) in zip(fetched, handles):
+            if isinstance(o, tuple):    # several dispatches, rows in order
+                rows = np.array_split(np.arange(n), len(o))
+                o = np.concatenate(
+                    [np.asarray(c)[:len(r)] for c, r in zip(o, rows)])
+            out.append(np.asarray(o)[:n])
+        return out
 
     def __call__(self, pairs: list[tuple[str, str]]) -> np.ndarray:
         return self.score_batch(pairs)

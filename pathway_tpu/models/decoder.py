@@ -72,19 +72,58 @@ class DecoderConfig:
     sliding_window: int = 0
     dense_layers: int = 0           # leading dense-MLP layers before `moe`
     moe: Any = None                 # models.moe.MoEConfig: routed experts
+    # latent attention (kv_rank > 0): queries and key-values through low-rank
+    # projections with an inner RMSNorm each; a head's query and key are
+    # `nope_size` values without positions beside `rope_size` rotary ones
+    # (the rotary key ONE a token, shared by all heads), its value
+    # `v_size`. What is cached is the normed latent and the rotated key:
+    # kv_rank + rope_size values a token a layer, nothing per head
+    q_rank: int = 0                 # 0: queries straight from the hidden
+    kv_rank: int = 0
+    nope_size: int = 0
+    rope_size: int = 0
+    v_size: int = 0
+    # YaRN (rope_factor > 1): frequencies blended between theta's and the
+    # same over `rope_factor` by a ramp between the dimensions at which
+    # `rope_original` positions make `rope_beta_fast` and `rope_beta_slow`
+    # rotations; `rope_mscale_all_dim` scales the softmax (as its square)
+    rope_factor: float = 1.0
+    rope_original: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_rank > 0
 
     @property
     def head_dim(self) -> int:
+        """A head's query and key size."""
+        if self.latent:
+            return self.nope_size + self.rope_size
         return self.head_size or self.hidden // self.heads
+
+    @property
+    def v_dim(self) -> int:
+        """A head's value size (latent attention's differs from its key's)."""
+        return self.v_size if self.latent else self.head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a token: latent and rotary key."""
+        return self.kv_rank + self.rope_size
 
     @property
     def n_kv(self) -> int:
         return self.kv_heads or self.heads
 
     def layer_kind(self, i: int) -> tuple:
-        """``(window | full, learned | rotary | none, dense | moe)`` of
-        layer ``i``."""
-        attn = self.layer_types[i] if self.layer_types else "full"
+        """``(window | full | latent, learned | rotary | none, dense |
+        moe)`` of layer ``i``."""
+        attn = "latent" if self.latent else (
+            self.layer_types[i] if self.layer_types else "full")
         pos = self.positions if isinstance(self.positions, str) \
             else self.positions[i]
         mlp = "moe" if self.moe is not None and i >= self.dense_layers \
@@ -139,7 +178,7 @@ def gpt2_block(cfg: DecoderConfig) -> bool:
     kernels, int8 KV and weights, the lane migration and the serving mesh
     have been written for)."""
     return (cfg.n_kv == cfg.heads and cfg.norm == "layernorm"
-            and not cfg.sandwich_norm and not cfg.qk_norm
+            and not cfg.latent and not cfg.sandwich_norm and not cfg.qk_norm
             and not cfg.attn_gate and cfg.positions == "learned"
             and cfg.mlp == "gelu" and cfg.bias and cfg.tied_head
             and cfg.embed_scale == 1.0 and cfg.moe is None
@@ -153,7 +192,9 @@ def require_gpt2_block(cfg: DecoderConfig, mechanism: str) -> None:
         raise UnsupportedForLayout(
             mechanism, "it is written for full multi-head layers with "
             "learned positions, LayerNorm, gelu and a tied head; this "
-            "configuration's layers differ")
+            "configuration's layers differ"
+            + (" (latent attention: one compressed row a token a layer, "
+               "no per-head keys and values)" if cfg.latent else ""))
 
 
 def _init(key, shape, dtype, scale=0.02):
@@ -176,15 +217,27 @@ def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
             out[name + "_bias"] = ((width,), "zero", None)
 
     norm("ln1")
-    out["qkv_w"] = ((h, (nq + 2 * nkv) * hd), 2, 1)
-    if cfg.bias:
-        out["qkv_b"] = (((nq + 2 * nkv) * hd,), "zero", 0)
-    if cfg.qk_norm:
-        out["q_norm_scale"] = ((hd,), "one", None)
-        out["k_norm_scale"] = ((hd,), "one", None)
+    if _attn == "latent":
+        # W_DQ, W_UQ (a head's columns: nope | rope), W_DKV (latent | the
+        # shared rotary key), W_UKV (a head's columns: key nope | value)
+        if cfg.q_rank:
+            out["q_a_w"] = ((h, cfg.q_rank), 16, None)
+            out["q_a_norm_scale"] = ((cfg.q_rank,), "one", None)
+        out["q_b_w"] = ((cfg.q_rank or h, nq * hd), 17, 1)
+        out["kv_a_w"] = ((h, cfg.latent_width), 18, None)
+        out["kv_a_norm_scale"] = ((cfg.kv_rank,), "one", None)
+        out["kv_b_w"] = ((cfg.kv_rank, nq * (cfg.nope_size + cfg.v_dim)),
+                         19, 1)
+    else:
+        out["qkv_w"] = ((h, (nq + 2 * nkv) * hd), 2, 1)
+        if cfg.bias:
+            out["qkv_b"] = (((nq + 2 * nkv) * hd,), "zero", 0)
+        if cfg.qk_norm:
+            out["q_norm_scale"] = ((hd,), "one", None)
+            out["k_norm_scale"] = ((hd,), "one", None)
     if cfg.attn_gate:
-        out["gate_w"] = ((h, nq * hd), 6, 1)
-    out["attn_out_w"] = ((nq * hd, h), 3, 0)
+        out["gate_w"] = ((h, nq * cfg.v_dim), 6, 1)
+    out["attn_out_w"] = ((nq * cfg.v_dim, h), 3, 0)
     if cfg.bias:
         out["attn_out_b"] = ((h,), "zero", None)
     if cfg.sandwich_norm:
@@ -204,7 +257,8 @@ def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
         moe = cfg.moe
         count, w = moe.held_range[1], moe.width
         out["router_w"] = ((h, moe.experts), 8, None)
-        out["router_bias"] = ((moe.experts,), "zero", None)
+        if moe.bias:
+            out["router_bias"] = ((moe.experts,), "zero", None)
         out["moe_in_w"] = ((count, h, w), 10, None)
         out["moe_up_w"] = ((count, h, w), 11, None)
         out["moe_out_w"] = ((count, w, h), 12, None)
@@ -221,7 +275,7 @@ def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
 # gains and biases, the router (float32 as published)
 _F32_LEAVES = frozenset(
     [f"{ln}_{leaf}" for ln in ("ln1", "ln2", "ln_f", "ln1p", "ln2p",
-                               "q_norm", "k_norm")
+                               "q_norm", "k_norm", "q_a_norm", "kv_a_norm")
      for leaf in ("scale", "bias")] + ["router_w", "router_bias"])
 
 
@@ -455,6 +509,17 @@ def _kv_quant(x):
     return jnp.round(xf / scale).astype(jnp.int8), scale
 
 
+def kv_token_bytes(cfg: DecoderConfig, itemsize: int,
+                   quant: bool = False) -> int:
+    """Cache bytes one token costs over all layers: K and V of every
+    key-value head (int8 with a float32 scale a head-token where ``quant``),
+    or a latent layer's one row."""
+    if cfg.latent:
+        return cfg.layers * cfg.latent_width * itemsize
+    per_head = cfg.head_dim + 4 if quant else cfg.head_dim * itemsize
+    return 2 * cfg.layers * cfg.n_kv * per_head
+
+
 def pool_quantized(pool: dict) -> bool:
     """True when the pool stores int8 KV (``pool_init(kv_quant=True)`` /
     ``paged_pool_init(kv_quant=True)``)."""
@@ -587,13 +652,55 @@ def _norm(x, lp, name: str, cfg: DecoderConfig):
     return _rms(x, lp[name + "_scale"], cfg.layer_norm_eps)
 
 
-def _rope(t, pos, theta: float):
+def yarn_inv_freq(cfg: DecoderConfig, dim: int):
+    """YaRN's frequencies for a rotary head of ``dim`` values (numpy, at
+    trace time): ``theta``'s own where ``rope_original`` positions make more
+    than ``rope_beta_fast`` rotations, the same over ``rope_factor`` where
+    they make fewer than ``rope_beta_slow``, a linear ramp between."""
+    import numpy as np
+
+    half = dim // 2
+    extra = cfg.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(cfg.rope_original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 0.001), 0, 1)
+    return (extra / cfg.rope_factor * ramp + extra * (1 - ramp)
+            ).astype(np.float32)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def attn_scale(cfg: DecoderConfig) -> float:
+    """What a latent layer's scores are multiplied by: the head's
+    ``size^-1/2`` times YaRN's ``m(mscale_all_dim)`` squared."""
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return m * m / math.sqrt(cfg.head_dim)
+
+
+def _rope(t, pos, theta: float, cfg: DecoderConfig | None = None):
     """Rotary positions on ``t`` (B, n, S, hd) at ``pos`` (B, S), halves
-    rotated against each other (the released code's ``rotate_half``)."""
+    rotated against each other (the released code's ``rotate_half``). With
+    ``cfg`` scaled by YaRN, the frequencies are :func:`yarn_inv_freq`'s and
+    cos and sin carry ``m(mscale) / m(mscale_all_dim)``."""
     half = t.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if cfg is not None and cfg.rope_factor > 1:
+        inv = jnp.asarray(yarn_inv_freq(cfg, t.shape[-1]))
+        amp = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
+            / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    else:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        amp = 1.0
     ang = pos.astype(jnp.float32)[:, None, :, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     t = t.astype(jnp.float32)
     a, b = t[..., :half], t[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -606,9 +713,12 @@ def _project(x, lp, cfg: DecoderConfig, kind: tuple, pos, want_q: bool):
     """The block's first norm and fused QKV projection, head-split, with
     what the configuration puts on q and k (per-head RMSNorm, rotary
     positions on a rotary layer): ``(q | None, k, v)``, q (B, nq, S, hd),
-    k and v (B, nkv, S, hd)."""
+    k and v (B, nkv, S, hd). A latent layer gives ``(q, c, None)``
+    (:func:`_project_latent`)."""
     nq, nkv, hd = cfg.heads, cfg.n_kv, cfg.head_dim
     h1 = _norm(x, lp, "ln1", cfg)
+    if kind[0] == "latent":
+        return _project_latent(h1.astype(cfg.dtype), lp, cfg, pos, want_q)
     qkv = _wq_matmul("bsh,hk->bsk", h1.astype(cfg.dtype), lp, "qkv_w", cfg)
     if cfg.bias:
         qkv = qkv + lp["qkv_b"].astype(cfg.dtype)
@@ -628,6 +738,95 @@ def _project(x, lp, cfg: DecoderConfig, kind: tuple, pos, want_q: bool):
     return q, k.astype(cfg.dtype), v.astype(cfg.dtype)
 
 
+def _project_latent(h1, lp, cfg: DecoderConfig, pos, want_q: bool):
+    """A latent layer's projections of the normed ``h1`` (B, S, H):
+    ``(q | None, c, None)``. ``q`` (B, nq, S, nope + rope), its last
+    ``rope`` values rotated; ``c`` (B, 1, S, kv_rank + rope) what the layer
+    CACHES of a token: the latent after its norm, then the one rotary key
+    all heads share, after its rotation."""
+    B, S, _H = h1.shape
+    nq, rank, rd = cfg.heads, cfg.kv_rank, cfg.rope_size
+    eps = cfg.layer_norm_eps
+    q = None
+    if want_q:
+        cq = h1
+        if cfg.q_rank:
+            cq = _wq_matmul("bsh,hk->bsk", h1, lp, "q_a_w", cfg)
+            cq = _rms(cq, lp["q_a_norm_scale"], eps).astype(cfg.dtype)
+        q = _split_heads(_wq_matmul("bsh,hk->bsk", cq, lp, "q_b_w", cfg),
+                         nq, cfg.head_dim)
+        q = jnp.concatenate(
+            [q[..., :cfg.nope_size],
+             _rope(q[..., cfg.nope_size:], pos, cfg.rope_theta, cfg
+                   ).astype(cfg.dtype)], axis=-1)
+    ckv = _wq_matmul("bsh,hk->bsk", h1, lp, "kv_a_w", cfg)
+    c = jnp.concatenate(
+        [_rms(ckv[..., :rank], lp["kv_a_norm_scale"], eps),
+         _rope(ckv[:, None, :, rank:], pos, cfg.rope_theta, cfg)[:, 0]],
+        axis=-1).astype(cfg.dtype)
+    return q, c.reshape(B, 1, S, rank + rd), None
+
+
+def latent_absorbed(cfg: DecoderConfig, n_queries: int) -> bool:
+    """THE rule that chooses a latent layer's read, from the shapes in hand:
+    ABSORBED (``W_UK`` folded into the queries, ``W_UV`` applied to the
+    context; every head reads the latent row as it lies) where that is fewer
+    operations a key row than EXPANDING the row into per-head keys and
+    values first. A decode step's handful of queries read absorbed; a
+    prefill piece's hundreds read expanded."""
+    nq, rank = cfg.heads, cfg.kv_rank
+    expanded = 2 * rank * nq * (cfg.nope_size + cfg.v_dim) \
+        + 2 * n_queries * nq * (cfg.head_dim + cfg.v_dim)
+    absorbed = 2 * n_queries * nq * (2 * rank + cfg.rope_size)
+    return absorbed <= expanded
+
+
+def _w_ukv(lp, cfg: DecoderConfig):
+    """``W_UKV`` by head, (kv_rank, nq, nope + v): ``[..., :nope]`` is
+    ``W_UK`` and ``[..., nope:]`` ``W_UV``, views of the one leaf."""
+    return lp["kv_b_w"].astype(cfg.dtype).reshape(
+        cfg.kv_rank, cfg.heads, cfg.nope_size + cfg.v_dim)
+
+
+def latent_expand(c, lp, cfg: DecoderConfig):
+    """Latent rows ``c`` (B, 1, C, kv_rank + rope) as per-head keys
+    (B, nq, C, nope + rope) and values (B, nq, C, v): for the time of one
+    read, never kept."""
+    with jax.named_scope("mla.expand"):
+        rank, nope = cfg.kv_rank, cfg.nope_size
+        kv = jnp.einsum("bcr,rnd->bncd", c[:, 0, :, :rank], _w_ukv(lp, cfg),
+                        preferred_element_type=cfg.dtype)
+        k_pe = jnp.broadcast_to(c[:, :, :, rank:],
+                                (*kv.shape[:3], cfg.rope_size))
+        return (jnp.concatenate([kv[..., :nope], k_pe], axis=-1),
+                kv[..., nope:])
+
+
+def _latent_ctx(q, c, lp, mask_bias, cfg: DecoderConfig,
+                absorbed: bool | None = None):
+    """A latent layer's attention read of rows ``c`` (B, 1, C, kv_rank +
+    rope) by queries ``q`` (B, nq, Sq, nope + rope): (B, nq, Sq, v). Both
+    forms are the same mathematics (:func:`latent_absorbed` chooses)."""
+    if absorbed is None:
+        absorbed = latent_absorbed(cfg, q.shape[2])
+    scale = attn_scale(cfg)
+    if not absorbed:
+        k, v = latent_expand(c, lp, cfg)
+        return _attn_ctx(q, k, v, mask_bias, cfg, scale=scale)
+    with jax.named_scope("mla.absorb"):
+        rank, nope = cfg.kv_rank, cfg.nope_size
+        w = _w_ukv(lp, cfg)
+        # q' = q_nope W_UK^T: a head's query against the latent itself
+        q_lat = jnp.einsum("bnsd,rnd->bnsr", q[..., :nope], w[..., :nope],
+                           preferred_element_type=cfg.dtype)
+        q_abs = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+        # 128 query heads over ONE key row, whose first kv_rank values are
+        # the value row too: the grouped-query read with one key-value head
+        ctx = _attn_ctx(q_abs, c, c, mask_bias, cfg, scale=scale)
+        return jnp.einsum("bnsr,rnd->bnsd", ctx[..., :rank], w[..., nope:],
+                          preferred_element_type=cfg.dtype)
+
+
 def _block_qkv(x, lp, cfg: DecoderConfig, kind: tuple = _GPT2_KIND,
                pos=None):
     """First norm + fused QKV projection, head-split: ``(q, k_new,
@@ -637,7 +836,7 @@ def _block_qkv(x, lp, cfg: DecoderConfig, kind: tuple = _GPT2_KIND,
 
 
 def _attn_ctx(q, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
-              v_scale=None):
+              v_scale=None, scale: float | None = None):
     """Attention read over ALREADY-PROJECTED k/v: scores in f32, softmax,
     f32-accumulated probs@v. With ``k_scale``/``v_scale`` given, k/v
     arrive as int8 payloads and dequantize here, on read — the one place
@@ -645,7 +844,9 @@ def _attn_ctx(q, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
     serving cannot fork the numerics. The Pallas paged kernel
     (``models/paged_attention.py``) is the block-table counterpart of
     exactly this function. With fewer key-value heads than query heads
-    each is shared by ``heads // kv_heads`` query heads (grouped query)."""
+    each is shared by ``heads // kv_heads`` query heads (grouped query).
+    ``scale`` multiplies the scores in place of ``head_dim^-1/2``; the
+    context has the values' size."""
     if k_scale is not None:
         k = (k.astype(jnp.float32) * k_scale).astype(cfg.dtype)
         v = (v.astype(jnp.float32) * v_scale).astype(cfg.dtype)
@@ -655,14 +856,16 @@ def _attn_ctx(q, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
         qg = q.reshape(B, nkv, nq // nkv, Sq, hd)
         scores = jnp.einsum("bngqd,bnkd->bngqk", qg, k.astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(cfg.head_dim) + mask_bias[:, :, None]
+        scores = (scores / math.sqrt(cfg.head_dim) if scale is None
+                  else scores * scale) + mask_bias[:, :, None]
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         return jnp.einsum("bngqk,bnkd->bngqd", probs, v.astype(cfg.dtype),
                           preferred_element_type=jnp.float32
-                          ).astype(cfg.dtype).reshape(B, nq, Sq, hd)
+                          ).astype(cfg.dtype).reshape(B, nq, Sq, v.shape[-1])
     scores = jnp.einsum("bnqd,bnkd->bnqk", q, k.astype(cfg.dtype),
                         preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(cfg.head_dim) + mask_bias
+    scores = (scores / math.sqrt(cfg.head_dim) if scale is None
+              else scores * scale) + mask_bias
     probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
     # the weighted-sum over up to cache_len values keeps GUARANTEED f32
     # accumulation (same as the encoder's explicit-softmax path) — with a
@@ -678,7 +881,7 @@ def _block_finish(x, lp, ctx, cfg: DecoderConfig,
     is the attention read (B, nh, S, hd). Returns ``(x, counts)``:
     ``counts`` the expert layer's (held, all) assignments, else None."""
     B, S, _H = x.shape
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, cfg.heads * cfg.head_dim)
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, cfg.heads * cfg.v_dim)
     if cfg.attn_gate:
         h1 = _norm(x, lp, "ln1", cfg).astype(cfg.dtype)
         gate = _wq_matmul("bsh,hk->bsk", h1, lp, "gate_w", cfg)
@@ -722,7 +925,9 @@ def _block_finish(x, lp, ctx, cfg: DecoderConfig,
 
 def _block(x, lp, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
            v_scale=None, ctx_fn=None, kind: tuple = _GPT2_KIND, pos=None):
-    """One decoder block over ALREADY-PROJECTED k/v (B, nkv, Skv, hd).
+    """One decoder block over ALREADY-PROJECTED k/v (B, nkv, Skv, hd); a
+    latent layer's ``k`` is its cached rows (B, 1, Skv, kv_rank + rope) and
+    its ``v`` None (:func:`_latent_ctx`).
 
     The caller owns the KV source — the in-sequence keys for prefill, the
     cache for decode — so prefill and decode share one block body and
@@ -741,10 +946,12 @@ def _block(x, lp, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
     byte-identical. Returns ``(x, counts)`` (:func:`_block_finish`)."""
     q, _k_new, _v_new = _block_qkv(x, lp, cfg, kind, pos)
     with jax.named_scope("decoder.attn." + kind[0]):
-        if ctx_fn is None:
-            ctx = _attn_ctx(q, k, v, mask_bias, cfg, k_scale, v_scale)
-        else:
+        if ctx_fn is not None:
             ctx = ctx_fn(q, k, v, k_scale, v_scale).astype(cfg.dtype)
+        elif kind[0] == "latent":
+            ctx = _latent_ctx(q, k, lp, mask_bias, cfg)
+        else:
+            ctx = _attn_ctx(q, k, v, mask_bias, cfg, k_scale, v_scale)
     return _block_finish(x, lp, ctx, cfg, kind)
 
 
@@ -878,6 +1085,11 @@ def _embed(params, ids, pos, cfg: DecoderConfig):
 def _kv_names(cfg: DecoderConfig, r: int, kind: tuple) -> tuple:
     """Names of run ``r``'s (k, v, k_scale, v_scale) arrays in a pool or a
     prefill cache."""
+    if kind[0] == "latent":
+        # ONE array a run, (layers, slots, 1, columns, kv_rank + rope): the
+        # key row of the absorbed read, whose unit axis is the one key-value
+        # head every query head shares; there is no second array
+        return (f"cl{r}", None, None, None)
     if cfg.uniform:
         return ("k", "v", "k_scale", "v_scale")
     t = "w" if kind[0] == "window" else "f"
@@ -885,11 +1097,11 @@ def _kv_names(cfg: DecoderConfig, r: int, kind: tuple) -> tuple:
 
 
 def _is_kv(name: str, window: bool | None = None) -> bool:
-    """``name`` is a run's KV array (of a window run / of a full run where
-    ``window`` says which)."""
+    """``name`` is a run's KV array (of a window run / of a run whose rows
+    are whole, full or latent, where ``window`` says which)."""
     if name in ("k", "v"):
         return not window
-    ok = (len(name) > 2 and name[0] in "kv" and name[1] in "fw"
+    ok = (len(name) > 2 and name[:2] in ("kf", "vf", "kw", "vw", "cl")
           and name[2:].isdigit())
     return ok and (window is None or (name[1] == "w") == window)
 
@@ -1020,13 +1232,13 @@ def _self_attend(params, input_ids, attention_mask, cfg: DecoderConfig,
     pos = jnp.clip(jnp.cumsum(attention_mask, axis=1) - 1, 0)
     x = _embed(params, input_ids, pos, cfg)
     ctx_fn = None
-    bias = {"full": None, "window": None}
+    bias = {"full": None, "window": None, "latent": None}
     if flash:
         require_gpt2_block(cfg, "flash_prefill")
         attn = _flash_self_attn_fn(mesh)
         ctx_fn = lambda q, k, v, ks, vs: attn(q, k, v, attention_mask)
     else:
-        bias["full"] = _causal_bias(attention_mask, S)
+        bias["full"] = bias["latent"] = _causal_bias(attention_mask, S)
         if cfg.n_layers_of("window"):
             bias["window"] = _causal_bias(attention_mask, S,
                                           cfg.sliding_window)
@@ -1110,6 +1322,7 @@ def decode_step(params: dict, token: jax.Array, step_pos: jax.Array,
     x = _embed(params, token[:, None], pos, cfg)
     live = slot_mask[:, None, None, :] > 0
     bias = {"full": jnp.where(live, 0.0, -1e9).astype(jnp.float32)}
+    bias["latent"] = bias["full"]
     if cfg.n_layers_of("window"):
         idxs = jnp.arange(slot_mask.shape[1])[None, None, None, :]
         bias["window"] = jnp.where(
@@ -1119,7 +1332,8 @@ def decode_step(params: dict, token: jax.Array, step_pos: jax.Array,
     def body(x, lp, kvl, kind):
         k_new, v_new = _prefill_kv(x, lp, cfg, kind, pos)  # (B, nkv, 1, hd)
         kl = jax.lax.dynamic_update_slice(kvl["k"], k_new, (0, 0, slot, 0))
-        vl = jax.lax.dynamic_update_slice(kvl["v"], v_new, (0, 0, slot, 0))
+        vl = None if v_new is None else jax.lax.dynamic_update_slice(
+            kvl["v"], v_new, (0, 0, slot, 0))
         x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg, kind=kind,
                         pos=pos)
         return x, {**kvl, "k": kl, "v": vl}, cnt
@@ -1316,6 +1530,16 @@ def pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
     pool = {}
     for r, (kind, _first, n) in enumerate(cfg.runs()):
         kn, vn, ksn, vsn = _kv_names(cfg, r, kind)
+        if kind[0] == "latent":
+            # the normed latent and the rotated shared key of every column:
+            # kv_rank + rope values a token a layer, nothing per head
+            pool[kn] = jnp.zeros(
+                (n, n_slots, 1, cache_len, cfg.latent_width), kv_dtype)
+            if arena_blocks > 0:
+                pool["arena_" + kn] = jnp.zeros(
+                    (arena_blocks, n, 1, arena_block, cfg.latent_width),
+                    kv_dtype)
+            continue
         rows = ring if kind[0] == "window" else cache_len
         pool[kn] = jnp.zeros((n, n_slots, nh, rows, hd), kv_dtype)
         pool[vn] = jnp.zeros((n, n_slots, nh, rows, hd), kv_dtype)
@@ -1390,16 +1614,18 @@ def _component_keys(pool: dict) -> dict:
     """ledger component -> the pool's keys it accounts: the fixed names
     above, and a several-run model's per-run arrays (``slot_pool``: the
     full-attention runs' rows of ``cache_len``; ``slot_pool_window``: the
-    window runs' rings; their arena blocks under ``prefix_arena``)."""
+    window runs' rings; ``slot_pool_latent``: the latent runs' one array
+    each; their arena blocks under ``prefix_arena``)."""
     out = {c: [k for k in keys if k in pool]
            for c, keys in _HBM_COMPONENT_KEYS.items()}
     out["slot_pool_window"] = []
+    out["slot_pool_latent"] = []
     for name in pool:
         if name in ("k", "v"):
             continue
         if _is_kv(name):
-            out["slot_pool_window" if name[1] == "w" else "slot_pool"
-                ].append(name)
+            out[{"w": "slot_pool_window", "l": "slot_pool_latent"}.get(
+                name[1], "slot_pool")].append(name)
         elif name.startswith("arena_") and _is_kv(name[6:]) \
                 and name[6:] not in ("k", "v"):
             out["prefix_arena"].append(name)
@@ -1892,16 +2118,30 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
     # each kind of layer reads blockwise or dense by ONE rule on its shapes
     # (``flash`` forces the kernel, as it always has): the dense read stays
     # the small rows' and the decode step's, and the tests' reference
-    full_fn = window_fn = mask_bias = ring_bias = None
+    full_fn = latent_fn = window_fn = mask_bias = ring_bias = None
     qcol = (start + jnp.arange(T))[None, :]             # (1, T)
     if flash or blockwise_chunk_read(cfg.heads, T, C):
         # the kernel builds the live-&-causal predicate from the column
         # each key row holds, with int8 dequant fused into the tile read:
         # no (1, 1, T, C) bias, no f32 KV row, no scores in HBM
-        attn_c = _flash_chunk_attn_fn(mesh, quant)
         kcol = jnp.where(row_mask > 0, jnp.arange(C, dtype=jnp.int32), -1)
-        full_fn = lambda q, kr, vr, ksr, vsr: \
-            attn_c(q, kr, vr, ksr, vsr, kcol, start)
+        if not cfg.latent:
+            attn_c = _flash_chunk_attn_fn(mesh, quant)
+            full_fn = lambda q, kr, vr, ksr, vsr: \
+                attn_c(q, kr, vr, ksr, vsr, kcol, start)
+        else:
+            from pathway_tpu.models import flash_attention as _fa
+
+            # the same walk over the slot's LATENT row: a block becomes a
+            # head's keys and values inside the kernel, for that step only.
+            # The kernel takes the row with its COLUMNS minor, (width,
+            # columns): keys transposed are what a score multiplies, and it
+            # is how the chip's compiler keeps an array whose rows are 4.5
+            # lane tiles wide, so the row goes in as it lies
+            latent_fn = lambda q, c_row, w_ukv: \
+                _fa.flash_chunk_attn_latent(
+                    q[0], c_row[0, 0].T, w_ukv, kcol[0], start,
+                    nope=cfg.nope_size, sm_scale=attn_scale(cfg))[None]
     else:
         # a piece query at cache index start+j attends every LIVE index
         # of this row <= start+j (earlier pieces + its own causal
@@ -1942,6 +2182,18 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
     def layer(x, lp, kvl, kind):
         kl, vl, ksl, vsl = kvl["k"], kvl["v"], kvl["k_scale"], kvl["v_scale"]
         k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (1, nh, T, hd)
+        if kind[0] == "latent":
+            # the piece's latent rows go in, then its queries read the row:
+            # per-head keys and values exist only inside that read
+            kl = jax.lax.dynamic_update_slice(
+                kl, k_new.astype(kl.dtype), (slot, 0, start, 0))
+            c_row = jax.lax.dynamic_slice(
+                kl, (slot, 0, 0, 0), (1, 1, C, cfg.latent_width))
+            read = latent_fn and (lambda q, c, _v, _ks, _vs:
+                                  latent_fn(q, c, _w_ukv(lp, cfg)))
+            x, cnt = _block(x, lp, c_row, None, mask_bias, cfg,
+                            ctx_fn=read, kind=kind, pos=p)
+            return x, {**kvl, "k": kl}, cnt
         if kind[0] == "window":
             k_old = jax.lax.dynamic_slice(kl, (slot, 0, 0, 0), (1, nh, R, hd))
             v_old = jax.lax.dynamic_slice(vl, (slot, 0, 0, 0), (1, nh, R, hd))
@@ -2009,19 +2261,34 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
     return out
 
 
+def chunk_rows(cfg: DecoderConfig, T: int, rows: int,
+               itemsize: int) -> int:
+    """Key rows a block of the blockwise read of a piece of ``T`` queries
+    over ``rows`` key rows: the kernel's own choice, from the shapes."""
+    from pathway_tpu.models import flash_attention as _fa
+
+    if cfg.latent:
+        return _fa.latent_chunk_block(
+            rows, T, cfg.head_dim, cfg.latent_width, cfg.kv_rank,
+            cfg.nope_size + cfg.v_dim, itemsize)
+    return _fa.chunk_block(rows, T, cfg.heads // cfg.n_kv, cfg.head_dim,
+                           itemsize)
+
+
 def prefill_blocks_visited(cfg: DecoderConfig, T: int, C: int, R: int,
                            start: int, lo: int, hi: int,
                            flash: bool = False) -> dict:
     """What one prefill piece at ``start`` adds to the counter
-    ``prefill_attn_blocks{layer, visited}``: for each kind of layer whose
-    read is blockwise (:func:`blockwise_chunk_read`), the key blocks its
+    ``prefill_attn_blocks{layer, visited}`` (a latent layer expands exactly
+    the blocks it visits, so ``latent_rows_expanded`` is their rows): for
+    each kind of layer whose read is blockwise (:func:`blockwise_chunk_read`), the key blocks its
     kernel visits (``visited=1``) and skips, times the layers of that kind.
     On the HOST, in numpy, from the piece's offset and the row's live
     columns ``[lo, hi]`` (the prompt's first live column; this piece's last)
     — ``flash_attention.blocks_seen``, the kernel's own predicate, without
     the device: no sync. ``{}`` where every layer reads dense."""
     kinds = [(kind, rows, window, cfg.n_layers_of(kind))
-             for kind, rows, window in (("full", C, 0),
+             for kind, rows, window in (("full", C, 0), ("latent", C, 0),
                                         ("window", R + T, cfg.sliding_window))
              if cfg.n_layers_of(kind)
              and (flash or blockwise_chunk_read(cfg.heads, T, rows))]
@@ -2029,23 +2296,22 @@ def prefill_blocks_visited(cfg: DecoderConfig, T: int, C: int, R: int,
         return {}       # before the kernel's module is ever imported
     import numpy as np
 
-    from pathway_tpu.models.flash_attention import blocks_seen, chunk_block
+    from pathway_tpu.models.flash_attention import blocks_seen
 
-    group, it = cfg.heads // cfg.n_kv, jnp.dtype(cfg.dtype).itemsize
+    it = jnp.dtype(cfg.dtype).itemsize
 
     def live(cols):
         return np.where((cols >= lo) & (cols <= hi), cols, -1)
 
     out = {}
     for kind, rows, window, layers in kinds:
-        if kind == "full":
+        if kind != "window":
             kcol = live(np.arange(C))
         else:
             ring = (start - 1) - np.mod(start - 1 - np.arange(R), R)
             kcol = np.concatenate([live(ring), live(start + np.arange(T))])
-        _kcol, seen = blocks_seen(
-            np, kcol, start, T, window,
-            chunk_block(rows, T, group, cfg.head_dim, it))
+        _kcol, seen = blocks_seen(np, kcol, start, T, window,
+                                  chunk_rows(cfg, T, rows, it))
         out[(kind, 1)] = int(seen.sum()) * layers
         out[(kind, 0)] = int((~seen).sum()) * layers
     return out
@@ -2265,7 +2531,8 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
         bias = {"full": jnp.where(
             slot_mask[:, None, None, :] > 0, 0.0, -1e9
         ).astype(jnp.float32)}
-        col = {"full": w}
+        bias["latent"] = bias["full"]
+        col = {"full": w, "latent": w}
         if R:
             cols = _ring_cols(w, R)
             bias["window"] = _ring_bias(cols, _live_at(slot_mask, cols), w, W)
@@ -2285,13 +2552,16 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
                 vsl = vsl.at[b_idx, :, c, :].set(
                     jnp.where(act_b, sv[:, :, 0, :], vsl[b_idx, :, c, :])
                 )
-            # per-ROW write position (each lane is at its own slot)
+            # per-ROW write position (each lane is at its own slot); a
+            # latent layer has one row a lane to write and no V: the step
+            # reads every slot's rows ABSORBED, as they lie (_latent_ctx)
             kl = kl.at[b_idx, :, c, :].set(
                 jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, c, :])
             )
-            vl = vl.at[b_idx, :, c, :].set(
-                jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
-            )
+            if v_new is not None:
+                vl = vl.at[b_idx, :, c, :].set(
+                    jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
+                )
             x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
                             k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
             return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
@@ -2499,7 +2769,8 @@ def _draft_scan(params, cfg: DecoderConfig, kv: dict, slot_mask,
                                      & (idxs <= col[:, None]))
         bias = {"full": jnp.where(allowed, 0.0, -1e9
                                   ).astype(jnp.float32)[:, None, None, :]}
-        at = {"full": col}
+        bias["latent"] = bias["full"]
+        at = {"full": col, "latent": col}
         if R:
             cols = _ring_cols(col, R)
             live = _live_at(slot_mask, cols) | (cols >= w[:, None])
@@ -2525,9 +2796,10 @@ def _draft_scan(params, cfg: DecoderConfig, kv: dict, slot_mask,
             kl = kl.at[b_idx, :, c, :].set(
                 jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, c, :])
             )
-            vl = vl.at[b_idx, :, c, :].set(
-                jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
-            )
+            if v_new is not None:       # a latent layer writes one row
+                vl = vl.at[b_idx, :, c, :].set(
+                    jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
+                )
             x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
                             k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
             return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
@@ -2650,7 +2922,8 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
         )
         bias = {"full": jnp.where(allowed, 0.0, -1e9
                                   ).astype(jnp.float32)[:, None, :, :]}
-        at = {"full": qcol}
+        bias["latent"] = bias["full"]
+        at = {"full": qcol, "latent": qcol}
         if R:
             cols = _ring_cols(w + k, R)                     # (B, R)
             live = (_live_at(slot_mask, cols) | (cols >= w[:, None])
@@ -2665,7 +2938,7 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
             c = at[kind[0]]
             k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B,nh,k+1,hd)
             kt = k_new.transpose(0, 2, 1, 3)  # (B, k+1, nh, hd)
-            vt = v_new.transpose(0, 2, 1, 3)
+            vt = None if v_new is None else v_new.transpose(0, 2, 1, 3)
             if quant:
                 kt, skt = _kv_quant(kt)
                 vt, svt = _kv_quant(vt)
@@ -2683,10 +2956,11 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
                 jnp.where(act_bt, kt.astype(kl.dtype),
                           kl[b_idx[:, None], :, c, :])
             )
-            vl = vl.at[b_idx[:, None], :, c, :].set(
-                jnp.where(act_bt, vt.astype(vl.dtype),
-                          vl[b_idx[:, None], :, c, :])
-            )
+            if vt is not None:          # a latent layer writes one row
+                vl = vl.at[b_idx[:, None], :, c, :].set(
+                    jnp.where(act_bt, vt.astype(vl.dtype),
+                              vl[b_idx[:, None], :, c, :])
+                )
             x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
                             k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
             return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt

@@ -266,12 +266,20 @@ def _paged_chunk_kv_map(h, j, meta, blk, src):
     return (src[j], h, 0, 0)
 
 
+def _chunk_latent_map(h, j, meta, blk, src):
+    return (0, 0, 0, src[j])
+
+
+def _chunk_w_map(h, j, meta, blk, src):
+    return (h, 0, 0)
+
+
 def _chunk_kcol_map(h, j, meta, blk, src):
     return (blk[j], 0, 0)
 
 
 def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
-                  block_k, n_kt, n_rows, window, quant):
+                  block_k, n_kt, n_rows, window, quant, latent=None):
     """Grid (kv_heads, k_tiles), the key axis innermost. One grid step is
     ONE key-value head against one block of its keys: the ``group`` query
     heads that share it lie folded into the query rows (``group * T``,
@@ -287,8 +295,21 @@ def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
     into both dots as they come (bfloat16 on the chip), scores, statistics
     and the accumulator (the resident output block) are float32, and the
     probabilities are cast to the operands' type before the value dot: the
-    precision of ``decoder._attn_ctx``."""
-    if quant:
+    precision of ``decoder._attn_ctx``.
+
+    ``latent = (rank, nope)``: the head axis of the grid is a QUERY head and
+    what it walks is the one latent row all heads share, TRANSPOSED: ``(rank
+    + rope, Bk)`` a block, beside the head's own ``W_UKV`` transposed
+    ``(nope + v, rank)``. The block becomes the head's keys and values HERE,
+    for this step only (``kv^T = W_UKV^T @ latent^T``: keys^T its first
+    ``nope`` rows, values^T the rest), and the score is two dots, the
+    queries' first ``nope`` values against those keys and their last
+    ``rope`` against the block's shared rotary key: a key of 192 = 128 + 64
+    beside a value of 128, no per-head key or value ever in HBM."""
+    if latent:
+        q_ref, k_ref, v_ref, kcol_ref, o_ref = refs[:5]     # q, rows, W_UKV
+        ks_ref = vs_ref = None
+    elif quant:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, kcol_ref, o_ref = refs[:7]
     else:
         q_ref, k_ref, v_ref, kcol_ref, o_ref = refs[:5]
@@ -305,7 +326,8 @@ def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
 
     @pl.when(j < meta_ref[1])
     def _tile():
-        k, v = k_ref[0, 0], v_ref[0, 0]             # (Bk, hd)
+        k = k_ref[0, 0]                             # (Bk, hd)
+        v = None if latent else v_ref[0, 0]         # latent: k (width, Bk)
         if quant:
             # fused int8 dequant: (Bk, 1) f32 scales broadcast over hd
             k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(q_ref.dtype)
@@ -314,9 +336,25 @@ def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
             # the row's last tile reaches past its end: what was read there
             # is not data, and 0 x NaN is NaN, so it is zeroed before it
             # meets its zero probability (its scores are masked by kcol -1)
-            rows = blk_ref[j] * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, 1), 0)
-            v = jnp.where(rows < n_rows, v, jnp.zeros_like(v))
+            if latent:
+                cols = blk_ref[j] * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_k), 1)
+                k = jnp.where(cols < n_rows, k, jnp.zeros_like(k))
+            else:
+                rows = blk_ref[j] * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, 1), 0)
+                v = jnp.where(rows < n_rows, v, jnp.zeros_like(v))
+        # the key in parts, each against its columns of the queries; a part
+        # contracts its ``k_dim`` (1: keys by row; 0: keys transposed)
+        keys, k_dim = ((k, slice(None)),), 1
+        if latent:
+            rank, nope = latent
+            kv = jax.lax.dot_general(
+                v_ref[0], k[:rank, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(k.dtype)
+            keys, k_dim = ((kv[:nope, :], slice(0, nope)),
+                           (k[rank:, :], slice(nope, None))), 0
+            v = kv[nope:, :]                        # (v, Bk): transposed too
         kcol = kcol_ref[0]                          # (1, Bk)
         qcol = start + jax.lax.broadcasted_iota(
             jnp.int32, (block_t, block_k), 0)
@@ -325,10 +363,13 @@ def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
             live = live & (qcol - kcol < window)
         for g in range(group):
             rows = pl.ds(g * block_t, block_t)
-            s = jax.lax.dot_general(
-                q_ref[0, rows, :], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale                            # (T, Bk)
+            s = None
+            for part, cols in keys:
+                dot = jax.lax.dot_general(
+                    q_ref[0, rows, cols], part, (((1,), (k_dim,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                s = dot if s is None else s + dot
+            s = s * sm_scale                        # (T, Bk)
             s = jnp.where(live, s, _NEG)
             m_prev = m_ref[rows, :]                 # (T, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -337,7 +378,7 @@ def _chunk_kernel(meta_ref, blk_ref, src_ref, *refs, sm_scale, group, block_t,
             l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
                 p, axis=-1, keepdims=True)
             pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v, (((1,), (1 - k_dim,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )                                       # (T, hd)
             o_ref[0, rows, :] = o_ref[0, rows, :] * alpha + pv
@@ -413,13 +454,19 @@ def chunk_live_blocks(kcol, start, T, window, bk):
 
 
 def _chunk_call(q, kv_operands, kv_specs, kcol, start, tbl, *, window,
-                sm_scale, block_k, n_rows, quant, interpret):
+                sm_scale, block_k, n_rows, quant, interpret, latent=None,
+                out_dim=None, vmem=None):
     """``q`` (heads, T, hd) against K/V given as 4-D operands: the keys of
     logical block ``b`` lie in block ``b`` of a dense row (``tbl`` None),
-    in block ``tbl[b]`` of the paged planes."""
+    in block ``tbl[b]`` of the paged planes. ``latent``: the operands are
+    the one latent row and ``W_UKV`` by head, every query head a grid row
+    of its own, the context ``out_dim`` wide (:func:`_chunk_kernel`)."""
     nq, T, hd = q.shape
-    nkv = kv_operands[0].shape[1]
+    nkv = nq if latent else kv_operands[0].shape[1]
     group = nq // nkv
+    out_dim = out_dim or hd
+    if vmem is None:
+        vmem = _chunk_vmem_bytes(T, group, hd, block_k, q.dtype.itemsize)
     kcol, n_live, blk = chunk_live_blocks(kcol, start, T, window, block_k)
     n_kt = blk.shape[0]
     meta = jnp.stack([jnp.asarray(start, jnp.int32), n_live])
@@ -429,7 +476,7 @@ def _chunk_call(q, kv_operands, kv_specs, kcol, start, tbl, *, window,
         in_specs=[pl.BlockSpec((1, group * T, hd), _chunk_q_map)] + kv_specs
         # key columns as (k_tiles, 1, block_k), see flash_attn's mask spec
         + [pl.BlockSpec((1, 1, block_k), _chunk_kcol_map)],
-        out_specs=pl.BlockSpec((1, group * T, hd), _chunk_q_map),
+        out_specs=pl.BlockSpec((1, group * T, out_dim), _chunk_q_map),
         scratch_shapes=[
             pltpu.VMEM((group * T, 1), jnp.float32),    # running max
             pltpu.VMEM((group * T, 1), jnp.float32),    # running denom
@@ -439,20 +486,19 @@ def _chunk_call(q, kv_operands, kv_specs, kcol, start, tbl, *, window,
         functools.partial(
             _chunk_kernel, sm_scale=sm_scale, group=group, block_t=T,
             block_k=block_k, n_kt=n_kt, n_rows=n_rows, window=int(window),
-            quant=quant,
+            quant=quant, latent=latent,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nkv, group * T, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nkv, group * T, out_dim),
+                                       jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=2 * max(
-                _CHUNK_VMEM_BUDGET, _chunk_vmem_bytes(
-                    T, group, hd, block_k, q.dtype.itemsize)),
+            vmem_limit_bytes=2 * max(_CHUNK_VMEM_BUDGET, vmem),
         ),
         interpret=interpret,
     )(meta, blk, blk if tbl is None else tbl[blk],
       q.reshape(nkv, group * T, hd), *kv_operands, kcol)
-    return out.reshape(nq, T, hd)
+    return out.reshape(nq, T, out_dim)
 
 
 def flash_chunk_attn(q, k_row, v_row, kcol, start, *, window=0,
@@ -497,6 +543,66 @@ def flash_chunk_attn(q, k_row, v_row, kcol, start, *, window=0,
         q, kv_operands, kv_specs, kcol, start, None,
         window=window, sm_scale=sm_scale, block_k=bk, n_rows=C,
         quant=quant, interpret=interpret,
+    )
+
+
+def _latent_vmem_bytes(T, hd, width, rank, d, bk, itemsize):
+    """:func:`_chunk_vmem_bytes` for the latent read: one head's queries,
+    float32 output and statistics and its ``W_UKV`` (double-buffered), the
+    latent tile, its expansion in float32 and cast, and the scores."""
+    out = d - hd + (width - rank)       # the value's size: d - nope
+    resident = 2 * T * (hd * itemsize + out * 4) + 2 * T * 128 * 4 \
+        + 2 * rank * d * itemsize
+    tiles = 2 * bk * width * itemsize + 2 * 8 * bk * 4 \
+        + bk * d * (4 + itemsize)
+    return resident + tiles + T * bk * (4 + 4 + 4 + itemsize)
+
+
+def latent_chunk_block(columns, T, hd, width, rank, d, itemsize):
+    """:func:`chunk_block` for the latent read (``hd`` a query's size,
+    ``width`` a latent row's, ``d`` a head's columns of ``W_UKV``)."""
+    want = _BLOCK_K
+    if not want:
+        want = 512
+        while want > 128 and _latent_vmem_bytes(
+                T, hd, width, rank, d, want, itemsize) > _CHUNK_VMEM_BUDGET:
+            want //= 2
+    return min(_round8(want), _round8(columns))
+
+
+def flash_chunk_attn_latent(q, c_row, w_ukv, kcol, start, *, nope, sm_scale,
+                            block_k=None, interpret=None):
+    """Chunk-vs-cache attention over one slot's LATENT row: every block of
+    rows some query can see is expanded into the head's keys and values
+    inside the kernel's walk, read, and dropped.
+
+    Args:
+      q: (heads, T, nope + rope) query piece, the rotary part rotated.
+      c_row: (rank + rope, rows) the slot's cached rows TRANSPOSED: of each
+        column the normed latent, then the rotated key all heads share.
+      w_ukv: (rank, heads, nope + v) the up-projection by head: a head's
+        keys' ``nope`` columns, then its values'.
+      kcol, start: as :func:`flash_chunk_attn`.
+      nope: a key's size without positions (static); sm_scale: the scores'
+        multiplier (static: YaRN's is not ``size^-1/2``).
+
+    Returns (heads, T, v) float32 context.
+    """
+    nq, T, hd = q.shape
+    width, C = c_row.shape
+    rank, _nq, d = w_ukv.shape
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    it = q.dtype.itemsize
+    bk = int(block_k or latent_chunk_block(C, T, hd, width, rank, d, it))
+    return _chunk_call(
+        q, [c_row[None, None], w_ukv.transpose(1, 2, 0)],
+        [pl.BlockSpec((1, 1, width, bk), _chunk_latent_map),
+         pl.BlockSpec((1, d, rank), _chunk_w_map)],
+        kcol, start, None, window=0, sm_scale=sm_scale, block_k=bk,
+        n_rows=C, quant=False, interpret=interpret, latent=(rank, nope),
+        out_dim=d - nope,
+        vmem=_latent_vmem_bytes(T, hd, width, rank, d, bk, it),
     )
 
 

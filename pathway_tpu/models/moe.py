@@ -10,9 +10,14 @@ PARTIAL result on. No capacity, no dropped token, and nothing that stands in
 for the absent chips or their exchange: the parts of all shares, the shared
 expert counted once, add up to the uncut layer (``tests/test_pipeline_moe.py``).
 
-Routing is float32 as published: ``s = sigmoid(x Wr)`` over all experts; the
-top ``per_token`` of ``s + b`` (``b`` the per-expert balance bias, used for
-the CHOICE only); ``w = s[top] / sum s[top] * route_scale``.
+Routing is float32 as published: ``s = sigmoid(x Wr)`` (or ``softmax``:
+``MoEConfig.score``) over all experts; the top ``per_token`` of ``s + b``
+(``b`` the per-expert balance bias, used for the CHOICE only; a router may
+have none); ``w = s[top] / sum s[top] * route_scale``, or unnormalised.
+With ``groups`` the experts lie in that many equal device groups and a
+token may use ``groups_per_token`` of them: a group's score is its best
+expert's, the best groups stay, the others' scores become 0 before the top
+``per_token`` are taken (group-limited routing).
 
 The grouped matmul sorts the assignments by expert and walks (row tile,
 expert) pairs in one ``while_loop``: each visit reads ONE expert's three
@@ -38,6 +43,15 @@ class MoEConfig:
     held: tuple | None = None   # (first, count) held here; None: all
     route_norm: bool = True
     route_scale: float = 1.0
+    score: str = "sigmoid"      # sigmoid | softmax
+    groups: int = 1             # device groups the experts lie in
+    groups_per_token: int = 1   # of which a token may use this many
+
+    @property
+    def bias(self) -> bool:
+        """A sigmoid router carries the per-expert balance bias on its
+        choice (leaf ``router_bias``); a softmax router has none."""
+        return self.score == "sigmoid"
 
     @property
     def held_range(self) -> tuple:
@@ -96,9 +110,18 @@ def route(tokens: jax.Array, mp: dict, moe: MoEConfig):
     logits = jnp.dot(tokens.astype(jnp.float32),
                      mp["router_w"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
-    _top, idx = jax.lax.top_k(s + mp["router_bias"].astype(jnp.float32),
-                              moe.per_token)
+    s = jax.nn.softmax(logits, axis=-1) if moe.score == "softmax" \
+        else jax.nn.sigmoid(logits)
+    choice = s + mp["router_bias"].astype(jnp.float32) if moe.bias else s
+    if moe.groups > 1:
+        T, E = choice.shape
+        best = choice.reshape(T, moe.groups, E // moe.groups).max(axis=-1)
+        _g, gi = jax.lax.top_k(best, moe.groups_per_token)
+        kept = jnp.zeros((T, moe.groups), jnp.bool_).at[
+            jnp.arange(T)[:, None], gi].set(True)
+        choice = jnp.where(jnp.repeat(kept, E // moe.groups, axis=1),
+                           choice, 0.0)
+    _top, idx = jax.lax.top_k(choice, moe.per_token)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if moe.route_norm:
         w = w / jnp.sum(w, axis=-1, keepdims=True)

@@ -1038,15 +1038,12 @@ class _ContinuousServer:
             blk = next_pow2(max(blk, self.prefill_chunk), self.prefill_chunk)
             if self.paged_kv:
                 blk = self.paged_block
-            itemsize = _np_mod.dtype(cfg.dtype).itemsize
             # int8 KV: each cached head-token costs head_dim int8 bytes
             # plus one f32 scale instead of head_dim full-precision
-            # bytes, so the same MB budget holds ~2x the blocks
-            per_tok = (
-                cfg.head_dim + 4 if self.kv_quant
-                else cfg.head_dim * itemsize
-            )
-            block_bytes = 2 * cfg.layers * cfg.n_kv * blk * per_tok
+            # bytes, so the same MB budget holds ~2x the blocks; a latent
+            # layer caches one row a token, nothing per head
+            block_bytes = blk * decoder_mod.kv_token_bytes(
+                cfg, _np_mod.dtype(cfg.dtype).itemsize, bool(self.kv_quant))
             n_blocks = int(mb * (1 << 20) // block_bytes)
             if n_blocks >= 1:
                 self.prefix_block = blk
@@ -1089,12 +1086,9 @@ class _ContinuousServer:
         # per-block KV device footprint (the kv_parked_bytes gauge's
         # multiplier; paged mode only — dense preemption has no blocks
         # to park)
-        per_tok_kv = (
-            cfg.head_dim + 4 if self.kv_quant
-            else cfg.head_dim * _np_mod.dtype(cfg.dtype).itemsize
-        )
         self._block_kv_bytes = (
-            2 * cfg.layers * cfg.n_kv * self.paged_block * per_tok_kv
+            self.paged_block * decoder_mod.kv_token_bytes(
+                cfg, _np_mod.dtype(cfg.dtype).itemsize, bool(self.kv_quant))
             if self.paged_kv else 0
         )
         # autotune candidates: halvings of the constructor's chunk_steps
@@ -2684,6 +2678,12 @@ class _ContinuousServer:
                 int(off) + int(live[-1]), flash=self.flash_prefill)
             if blocks:
                 probes.record_prefill_attn_blocks(blocks)
+            if blocks.get(("latent", 1)):
+                # a latent layer expands exactly the key blocks it visits
+                probes.record_latent_rows_expanded(
+                    blocks[("latent", 1)] * self._D.chunk_rows(
+                        self.cfg, int(p_ids.shape[1]), self.cache_len,
+                        np.dtype(self.cfg.dtype).itemsize))
         req_p = self.slots[slot]
         if req_p is not None:
             req_p.span.event(

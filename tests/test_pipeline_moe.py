@@ -149,3 +149,53 @@ def test_the_eight_shares_sum_to_the_uncut_layer(tiny):
         assert int(c[1]) == 128
     assert held_sum == 128
     assert float(jnp.max(jnp.abs(total + shared_only - uncut))) < 2e-5
+
+
+def test_the_eight_groups_shares_sum_to_the_uncut_layer(tiny):
+    """The same for the router that is limited to device groups: 32
+    experts in 8 groups of 4, a token may use 3 groups and 6 experts,
+    softmax scores times 16, not renormalised, no bias; every chip holds
+    ONE group. The parts of all 8, the two shared experts counted ONCE, add
+    up to the uncut layer, which is the plain reference's of the
+    ``deepseek_v2`` layout; a token reaches a chip's group 3 times in 8."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import manifest as M
+
+    cfg, _params, _ids, _mask = tiny
+    whole = MoEConfig(experts=32, per_token=6, width=24, shared=2,
+                      route_norm=False, route_scale=16.0, score="softmax",
+                      groups=8, groups_per_token=3)
+    mp = init_moe_params(jax.random.PRNGKey(8), cfg.hidden, whole, scale=0.2)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 16, cfg.hidden))
+    uncut, counts = moe_mlp(x, mp, whole, jnp.float32)
+    assert counts.tolist() == [192, 192]
+    ref = M.load_named_module(M.load_manifest(), "layouts", "deepseek_v2")
+    d = {"groups": 8, "topk_group": 3, "k": 6, "first": 0, "held": 32,
+         "norm": False, "scale": 16.0, "shared": 2}
+    with jax.default_matmul_precision("highest"):
+        want, margin = ref._experts(x.reshape(32, -1), mp, d, False)
+    assert float(margin.min()) > 1e-6       # no near-tie decides this
+    assert float(jnp.max(jnp.abs(uncut.reshape(32, -1) - want))) < 2e-5
+    shared_only = _plain_moe(x, mp, whole, held=(0, 0))
+    total, held_sum, reached = jnp.zeros_like(uncut), 0, 0
+    idx, _w, _s = route(x.reshape(32, -1), mp, whole)
+    for chip in range(8):
+        share = dataclasses.replace(whole, held=(4 * chip, 4))
+        mine = {**mp, **{k: mp[k][4 * chip:4 * chip + 4]
+                         for k in ("moe_in_w", "moe_up_w", "moe_out_w")}}
+        part, c = moe_mlp(x, mine, share, jnp.float32)
+        assert float(jnp.max(jnp.abs(
+            part - _plain_moe(x, mine, share, held=(4 * chip, 4))))) < 1e-5
+        total = total + (part - shared_only)
+        held_sum += int(c[0])
+        reached += int(jnp.any(idx // 4 == chip, axis=-1).sum())
+        assert int(c[1]) == 192
+    assert held_sum == 192
+    assert reached <= 3 * 32        # a token's picks lie in at most 3 groups
+    assert float(jnp.max(jnp.abs(total + shared_only - uncut))) < 2e-5

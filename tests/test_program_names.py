@@ -137,6 +137,94 @@ def decoder_run():
         chat.close()
 
 
+LATENT_METRIC = "answer.latent_rows_expanded_per_token"
+
+
+def _latent_cfg():
+    from pathway_tpu.models import decoder as D
+
+    return D.DecoderConfig(
+        vocab_size=64, hidden=16, layers=2, heads=2, intermediate=32,
+        max_position=64, dtype=jnp.float32, norm="rmsnorm",
+        positions="rotary", mlp="swiglu", bias=False, tied_head=False,
+        q_rank=8, kv_rank=8, nope_size=6, rope_size=2, v_size=6,
+        rope_factor=40.0, rope_original=16, rope_mscale=0.707,
+        rope_mscale_all_dim=0.707)
+
+
+@pytest.fixture(scope="module")
+def latent_run():
+    """One request through a continuous server whose block is latent
+    attention, the shape rule's threshold at zero so that its prefill
+    pieces read blockwise as a long row's do: the server's ``stats`` once
+    it is answered, and the context the new metric's reader is given."""
+    from pathway_tpu.models import decoder as D
+    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
+
+    cfg = _latent_cfg()
+    probes.REGISTRY.remove("latent_rows_expanded", "prefill_attn_blocks")
+    was, D._DENSE_SCORE_BYTES = D._DENSE_SCORE_BYTES, 0
+    chat = TPUDecoderChat(
+        params=D.init_params(jax.random.PRNGKey(0), cfg), cfg=cfg,
+        tokenizer=_Ids(), max_new_tokens=4, temperature=0.0,
+        max_prompt_tokens=16, continuous=True, n_slots=2, chunk_steps=4,
+        prefill_chunk=8)
+    try:
+        request = chat._server.submit(list(range(1, 12)), 4)
+        assert request.done.wait(timeout=300)
+        stats = dict(chat._server.stats)
+    finally:
+        chat.close()
+        D._DENSE_SCORE_BYTES = was
+    ctx = {"lifetime_counters": {"decoder_" + k: v
+                                 for k, v in stats.items()
+                                 if isinstance(v, (int, float))},
+           "config": {"models": {"decoder": {"num_hidden_layers": 2}},
+                      "deployment": {"decoder_server": {
+                          "prefill_chunk": 8}}}}
+    return stats, ctx
+
+
+def test_the_latent_blocks_scopes_and_counters_are_what_the_metric_reads(
+        latent_run):
+    """The named scopes are in the executables' text, the counter series
+    under the names the benchmark's files give, and the new metric is their
+    ratio: two pieces of 8 over a row that is one block, so every column
+    prefilled costs the whole row's expansion twice over."""
+    from pathway_tpu.models import decoder as D
+
+    stats, ctx = latent_run
+    assert stats["prefill_chunks"] == 2
+    params = METRICS[LATENT_METRIC]["params"]
+    assert (params["family"], params["label"], params["value"],
+            params["pieces"]) == ("latent_rows_expanded", "phase",
+                                  "prefill", "decoder_prefill_chunks")
+    blocks = probes.REGISTRY.labelled("prefill_attn_blocks", "layer")
+    assert set(blocks) == {"latent"} and blocks["latent"] == 2 * 2
+    rows = probes.REGISTRY.labelled("latent_rows_expanded", "phase")
+    cache_len = 16 + 4 + 5 * 4
+    assert rows == {"prefill": float(2 * 2 * cache_len)}
+    assert _reader(LATENT_METRIC)(ctx, params) == cache_len / 8
+    assert _reader(LATENT_METRIC)({"lifetime_counters": {}}, params) is None
+    cfg = _latent_cfg()
+    p = jax.eval_shape(lambda: D.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: D.pool_init(None, cfg, 2, cache_len))
+    ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    one = jax.ShapeDtypeStruct((), jnp.int32)
+    piece = jax.jit(lambda p, i, pl, s: D.pool_prefill_chunk(
+        p, i, i, i, pl, s, s, s[None], cfg, first=False, last=False)
+    ).lower(p, ids, jax.eval_shape(
+        lambda: D.pool_init(None, cfg, 2, 512)), one).as_text(
+            debug_info=True)
+    step = jax.jit(lambda p, pl, a, k: D.pool_decode_chunk(
+        p, pl, a, k, cfg, 2)).lower(
+            p, pool, jax.ShapeDtypeStruct((2,), jnp.bool_),
+            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
+    assert "decoder.attn.latent" in piece and "mla.expand" in piece
+    assert "decoder.attn.latent" in step and "mla.absorb" in step
+    assert "mla.expand" not in step      # a step never expands a row
+
+
 STATS_FIELDS = sorted({
     value[len("decoder_"):]
     for m in METRICS.values() for value in m.get("params", {}).values()
@@ -184,11 +272,12 @@ def search_run():
 @pytest.mark.parametrize("name,params", COUNTER_METRICS,
                          ids=_ids(COUNTER_METRICS))
 def test_a_counter_metric_reads_what_the_program_registered(
-        name, params, decoder_run, search_run):
+        name, params, decoder_run, search_run, latent_run):
     for family, label, value in _series(params):
         assert probes.METRIC_FAMILIES[family][1] == label
         assert probes.REGISTRY.labelled(family, label).get(str(value))
-    assert _reader(name)({}, params) is not None
+    ctx = latent_run[1] if name == LATENT_METRIC else {}
+    assert _reader(name)(ctx, params) is not None
 
 
 def _reader(name):

@@ -138,6 +138,32 @@ def test_minilm_embed_fn(shape):
              _placed(shape, params), ids, ids)
 
 
+def test_minilm_rerank_batch_of_the_long_cell(shape):
+    """384 pairs of 423 tokens, the long-context cell's rerank batch, in
+    their bucket of 512 tokens: the reranker bounds its own dispatch
+    (``cross_encoder._MAX_SCORE_BYTES``) and sends three of 128 pairs,
+    each dense: one layer's float32 scores are 1.6 GB, as the accepted
+    cells' 512 pairs of 256 tokens are, where 512 x 512 would be 6.4 GB
+    beside 9 GB of decoder."""
+    import dataclasses
+
+    from pathway_tpu.models import MINILM_L6
+    from pathway_tpu.models import transformer as T
+    from pathway_tpu.models.cross_encoder import CrossEncoderModel
+
+    cfg = dataclasses.replace(MINILM_L6, dtype=BF16)
+    m = CrossEncoderModel.__new__(CrossEncoderModel)
+    m.cfg, m.flash_prefill = cfg, False
+    assert [len(r) for r in m._dispatch_rows(384, 423)] == [128] * 3
+    params = jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    ids = shape((128, 512), I32)
+    c = _compile(lambda p, i, m, t: T.encode(p, i, m, cfg, t),
+                 _placed(shape, params), ids, ids, ids)
+    assert not _is_mosaic(c)
+    assert c.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 def test_knn_search_1m_rows(shape):
     from pathway_tpu.ops import knn as K
 
@@ -264,6 +290,115 @@ def test_trinity_width_prefill_piece(shape, monkeypatch):
     m = c.memory_analysis()
     assert m.temp_size_in_bytes < 1_400_000_000
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16 << 30
+
+
+LATENT_COLUMNS = 16384 + 64 + 5 * 16     # the long-context cell's slot row
+
+
+@pytest.mark.parametrize("piece", [512, 1024, 2048])
+def test_flash_chunk_attn_latent_kernel_at_the_long_cells_widths(
+        shape, piece):
+    """Latent attention as ``deepseek_v2_rag_long_closed8`` runs it: 128
+    query heads of 128 + 64 over ONE row of 16,528 x 576 latent values, a
+    head's 512 x 256 of ``W_UKV`` beside it: a key of 192 (two dots) and a
+    value of 128 made inside the kernel's walk from blocks of the row
+    TRANSPOSED (576 x 512 columns), the last tile ragged."""
+    from pathway_tpu.models.flash_attention import flash_chunk_attn_latent
+
+    c = _compile(
+        lambda q, row, w, kc, s: flash_chunk_attn_latent(
+            q, row, w, kc, s, nope=128, sm_scale=0.1147, interpret=False),
+        shape((128, piece, 192), BF16), shape((576, LATENT_COLUMNS), BF16),
+        shape((512, 128, 256), BF16), shape((LATENT_COLUMNS,), I32),
+        shape((), I32),
+    )
+    assert _is_mosaic(c)
+
+
+@pytest.fixture(scope="module")
+def deepseek(shape):
+    """(cfg, params, pool) of the long-context cell: the configuration the
+    benchmark runs, 8 slots of 16,528 columns, the prefix arena of the
+    server's default 64 MB."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import manifest as M
+
+    from pathway_tpu.models import decoder as D
+
+    with open(os.path.join(bench, "configs",
+                           "deepseek-v2-ep8-rag.json")) as f:
+        model = json.load(f)["models"]["decoder"]
+    cfg = M.resolve(M.load_manifest(), "layouts",
+                    "deepseek_v2").program_config(model)
+    params = jax.eval_shape(lambda: D.cast_params_for_inference(
+        D.init_params(jax.random.PRNGKey(0), cfg), cfg))
+    pool = jax.eval_shape(lambda: D.pool_init(
+        None, cfg, 8, LATENT_COLUMNS, arena_blocks=18, arena_block=512))
+    return cfg, _placed(shape, params), _placed(shape, pool)
+
+
+@pytest.mark.parametrize("piece", [512, 2048])
+def test_deepseek_width_prefill_piece(shape, deepseek, monkeypatch, piece):
+    """The whole prefill piece of the long-context cell with the blockwise
+    read its shapes choose: the kernel is in the program, no per-head key
+    or value of the slot's row among its temporaries (expanded, 16,528 x
+    128 x 320 x 2 B would be 1.35 GB a layer), and weights, pool and
+    temporaries inside the chip (``PERF.md`` section 4 has the numbers)."""
+    from pathway_tpu.models import decoder as D
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, pool = deepseek
+    assert D.blockwise_chunk_read(cfg.heads, piece, LATENT_COLUMNS)
+    ids = shape((1, piece), I32)
+    c = _compile(
+        lambda p, i, m, ps, pl, s, st, n: D.pool_prefill_chunk(
+            p, i, m, ps, pl, s, st, n, cfg, first=False, last=False),
+        params, ids, ids, ids, pool, shape((), I32), shape((), I32),
+        shape((1,), I32), donate_argnums=(4,),
+    )
+    assert _is_mosaic(c)
+    m = c.memory_analysis()
+    # 1.18 GB and 2.04 GB here: 0.76 GB of it one copy of the expert
+    # layers' latent stack at the loop's exit (PERF.md section 7)
+    assert m.temp_size_in_bytes < (1_400_000_000 if piece == 512
+                                   else 2_400_000_000)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 11 << 30
+
+
+def test_deepseek_width_decode_chunk(shape, deepseek):
+    """The decode chunk of the long-context cell, 16 steps over 8 slots:
+    every slot's latent rows read ABSORBED (scores of 8 x 128 x 16,528 in
+    float32 are 68 MB); no per-head key or value of the pool among its
+    temporaries (one layer's would be 10.8 GB)."""
+    from pathway_tpu.models import decoder as D
+
+    cfg, params, pool = deepseek
+    c = _compile(
+        lambda p, pl, a, k: D.pool_decode_chunk(p, pl, a, k, cfg,
+                                                CHUNK_STEPS),
+        params, pool, shape((8,), jnp.bool_), shape((2,), jnp.uint32),
+        donate_argnums=(1,),
+    )
+    m = c.memory_analysis()
+    # 3.34 GB here: the pool's two stacks once in the layout the loop
+    # prefers and once back, and one layer's rows cut out and put back
+    assert m.temp_size_in_bytes < 3_700_000_000
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12 << 30
+    # ... and the step a default (greedy) server dispatches first
+    c = _compile(
+        lambda p, pl, a: D.pool_decode_spec(
+            p, pl, a, cfg, CHUNK_STEPS // 4, draft_layers=1, n_spec=3),
+        params, pool, shape((8,), jnp.bool_), donate_argnums=(1,),
+    )
+    m = c.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13 << 30
 
 
 def test_flash_chunk_attn_paged_kernel(shape):
