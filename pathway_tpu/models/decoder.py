@@ -1074,13 +1074,22 @@ def _embed(params, ids, pos, cfg: DecoderConfig):
 # ---- stacks of like layers -------------------------------------------------
 #
 # Every pass over the layers goes through :func:`_scan_layers`, which runs
-# one ``lax.scan`` per run of like layers (:meth:`DecoderConfig.runs`) over
-# that run's own KV arrays. GPT-2 is one run: its KV is ``k``/``v`` (and the
-# int8 pool's ``k_scale``/``v_scale``), one scan over the whole stack, as it
-# always was. A model of several runs keeps ONE pair of arrays per run,
-# named for the run's kind and number (``kf2``/``vf2``: run 2, full
-# attention, rows of ``cache_len``; ``kw3``/``vw3``: run 3, window layers,
-# rings): no pass ever slices or reassembles a stack.
+# one ``lax.scan`` per run of like layers (:meth:`DecoderConfig.runs`). GPT-2
+# is one run: its KV is ``k``/``v`` (and the int8 pool's ``k_scale``/
+# ``v_scale``), one scan over the whole stack. A model of several runs keeps
+# ONE pair of arrays per run, named for the run's kind and number
+# (``kf2``/``vf2``: run 2, full attention, rows of ``cache_len``;
+# ``kw3``/``vw3``: run 3, window layers, rings; ``cl1``: run 1, latent rows).
+#
+# A run's arrays ride the scan's CARRY, whole, beside ``x``; what is scanned
+# is the layer's own small leaves and the layer INDEX. A scan cannot alias a
+# scanned input with a scanned output, so a stack handed over as one is cut
+# out of, a layer's rows of every slot at a time, and put back; a carried
+# buffer is written where it lies. So a layer's body writes its new rows at
+# ``(layer, slot, 0, column, 0)`` of the stack (:func:`_kv_put`,
+# :func:`_kv_put_lanes`, :func:`_ring_put`) and reads what it needs of it
+# (:func:`_kv_rows`): the pool is donated to every dispatch, and no dispatch
+# copies one of its arrays.
 
 def _kv_names(cfg: DecoderConfig, r: int, kind: tuple) -> tuple:
     """Names of run ``r``'s (k, v, k_scale, v_scale) arrays in a pool or a
@@ -1124,61 +1133,148 @@ def _run_stacks(params: dict, cfg: DecoderConfig) -> list:
 _EXPERT_LEAVES = ("moe_in_w", "moe_up_w", "moe_out_w")
 
 
-def _scan_run(step, x, lp: dict, kvl, n: int):
-    """``lax.scan`` of ``step(x, (lp_l, kvl_l))`` over one run's ``n``
-    stacked layers. A run of expert layers scans a layer INDEX beside its
-    other leaves and reads its experts out of the whole stack in place
+_KV_SHORT = ("k", "v", "k_scale", "v_scale")
+
+
+def _kv_put(stack, new, layer, slot, col):
+    """``new`` (b, heads, t, d) written at ``(layer, slot, 0, col, 0)`` of a
+    run's ``stack`` (layers, slots, heads, columns, d), where it lies."""
+    return jax.lax.dynamic_update_slice(
+        stack, new[None].astype(stack.dtype), (layer, slot, 0, col, 0))
+
+
+def _kv_rows(stack, layer, slot=None, col=0, n: int | None = None):
+    """What a layer reads of its run's ``stack``: ``n`` rows from ``col``
+    (all by default) of one ``slot``, (1, heads, n, d); without a slot the
+    layer's rows of EVERY slot, (slots, heads, columns, d)."""
+    if slot is None:
+        return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    _L, _S, nh, C, d = stack.shape
+    return jax.lax.dynamic_slice(
+        stack, (layer, slot, 0, col, 0),
+        (1, 1, nh, C if n is None else n, d))[0]
+
+
+def _kv_put_lanes(stack, new, layer, cols, active):
+    """Every lane at its own columns: ``new`` (B, heads, t, d) written at
+    columns ``cols`` (B, t) of lane b's row of ``layer``; a lane that is not
+    ``active`` (B,) keeps its bytes."""
+    b = jnp.arange(new.shape[0])[:, None]
+    old = stack[layer, b, :, cols, :]                   # (B, t, heads, d)
+    return stack.at[layer, b, :, cols, :].set(jnp.where(
+        active[:, None, None, None],
+        new.transpose(0, 2, 1, 3).astype(stack.dtype), old))
+
+
+def _put_lanes(st: dict, k_new, v_new, layer, cols, active, quant: bool):
+    """A step's new keys and values (B, heads, t, d) — a latent layer's one
+    row and no V — into the run's stacks ``st``, every lane at its own
+    ``cols`` (B, t): payloads and scales where the pool is int8."""
+    ks, vs, kss, vss = (st[n] for n in _KV_SHORT)
+    if quant:
+        k_new, sk = _kv_quant(k_new)
+        v_new, sv = _kv_quant(v_new)
+        kss = _kv_put_lanes(kss, sk, layer, cols, active)
+        vss = _kv_put_lanes(vss, sv, layer, cols, active)
+    ks = _kv_put_lanes(ks, k_new, layer, cols, active)
+    if v_new is not None:
+        vs = _kv_put_lanes(vs, v_new, layer, cols, active)
+    return {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}
+
+
+def _block_lanes(x, lp, st: dict, layer, mask_bias, cfg, kind, pos):
+    """:func:`_block` over ``layer``'s rows of every slot, read out of the
+    run's stacks ``st`` as they lie."""
+    kl, vl, ksl, vsl = (None if st[n] is None else _kv_rows(st[n], layer)
+                        for n in _KV_SHORT)
+    return _block(x, lp, kl, vl, mask_bias, cfg, k_scale=ksl, v_scale=vsl,
+                  kind=kind, pos=pos)
+
+
+def _ring_put(stack, new, layer, slot, start, real):
+    """A piece's rows into a window layer's ring: ``new`` (1, heads, T, d)
+    holds cache columns ``start .. start + T - 1``, column c goes to ring
+    row ``c mod R``, and only ``real`` (T,) columns go in. That is at most
+    TWO stretches of contiguous rows — up to the ring's end, then from its
+    start — and each is written as ONE fixed window of T rows on the row
+    axis: the window's old rows read, kept where no real column of the
+    stretch lands, and written back where they lie. (A gather and a scatter
+    over slot and row ask for the array rows-outside-heads, and every
+    dispatch then copies it into that layout and back, all slots of it.)"""
+    R, T = stack.shape[3], new.shape[2]
+    s0 = jnp.mod(start, R)
+    n0 = jnp.minimum(T, R - s0)         # columns before the ring's end
+    at = jnp.minimum(s0, R - T)         # the first window ends inside it
+    new = new.astype(stack.dtype)
+    # (the window's first row, how far the piece is rolled under it): row i
+    # of a window takes piece column i - shift, if the piece has one
+    for row0, shift in ((at, s0 - at), (0, -n0)):
+        j = jnp.arange(T) - shift
+        take = (j >= 0) & (j < T) & jnp.roll(real, shift)
+        old = _kv_rows(stack, layer, slot, row0, T)
+        stack = _kv_put(
+            stack, jnp.where(take[:, None], jnp.roll(new, shift, axis=2), old),
+            layer, slot, row0)
+    return stack
+
+
+def _scan_run(body, x, lp: dict, stacks: dict, n: int, kind: tuple):
+    """``lax.scan`` of ``body(x, lp_l, stacks, layer, kind) -> (x, stacks |
+    kvl, counts)`` over the first ``n`` layers of one run. SCANNED: the
+    run's stacked leaves but its experts, and the layer index. CARRIED, whole:
+    ``x`` and the run's KV ``stacks`` — ``body`` writes a layer's rows into
+    them in place and hands them on. The experts are neither: they ride the
+    closure whole and are read in place by ``moe_layer``
     (``models/moe.py:_expert``): as scanned leaves, one layer's experts —
-    nine tenths of its bytes — would be copied out at every step."""
-    if "moe_in_w" not in lp:
-        return jax.lax.scan(step, x, (lp, kvl))
-    stacked = {k: lp[k] for k in _EXPERT_LEAVES}
-    rest = {k: v for k, v in lp.items() if k not in _EXPERT_LEAVES}
+    nine tenths of its bytes — would be copied out at every step, as a
+    scanned stack's rows of every slot would be. Where ``stacks`` holds
+    nothing (a pass over whole sequences) what ``body`` returns beside ``x``
+    is the layer's own keys and values, stacked as scanned outputs."""
+    experts = {k: lp[k] for k in _EXPERT_LEAVES if k in lp}
+    scanned = {k: a for k, a in lp.items() if k not in experts}
+    if jax.tree_util.tree_leaves(scanned)[0].shape[0] != n:
+        # a depth prefix ends inside this run
+        scanned = jax.tree.map(lambda a: a[:n], scanned)
+    carried = stacks["k"] is not None
 
-    def indexed(x, inp):
-        lp_l, kvl_l, layer = inp
-        return step(x, ({**lp_l, **stacked, "moe_layer": layer}, kvl_l))
+    def step(carry, inp):
+        x, st = carry
+        lp_l, layer = inp
+        if experts:
+            lp_l = {**lp_l, **experts, "moe_layer": layer}
+        x, new, cnt = body(x, lp_l, st, layer, kind)
+        return ((x, new), (None, cnt)) if carried else ((x, st), (new, cnt))
 
-    return jax.lax.scan(indexed, x,
-                        (rest, kvl, jnp.arange(n, dtype=jnp.int32)))
+    (x, st), (ys, cnt) = jax.lax.scan(
+        step, (x, stacks), (scanned, jnp.arange(n, dtype=jnp.int32)))
+    return x, (st if carried else ys), cnt
 
 
 def _scan_layers(cfg: DecoderConfig, params: dict, x, kv: dict, body,
                  n_layers: int | None = None):
-    """Run ``body(x, lp, kvl, kind) -> (x, kvl, counts | None)`` over the
-    first ``n_layers`` layers (all by default). ``kvl`` holds the layer's
-    ``k``, ``v``, ``k_scale``, ``v_scale`` (None where ``kv`` has none: a
-    pass over whole sequences starts from ``{}`` and gets its keys and
-    values back). Returns ``(x, kv_out, counts)``: ``kv_out`` is ``kv``
-    with what ``body`` returned for the visited runs, ``counts`` the expert
-    layers' summed (held, all) or None."""
+    """Run ``body(x, lp, stacks, layer, kind) -> (x, stacks, counts | None)``
+    over the first ``n_layers`` layers (all by default). ``stacks`` holds the
+    layer's RUN's ``k``, ``v``, ``k_scale``, ``v_scale`` arrays as ``kv`` has
+    them, whole, and ``layer`` is the layer's index in them: they ride the
+    scan's carry (:func:`_scan_run`), ``body`` writes its rows in place and
+    returns them. A depth prefix that ends inside a run visits the run's
+    first layers and leaves the others' rows alone. A pass over whole
+    sequences starts from ``{}``: ``stacks`` holds None, and ``body`` returns
+    the layer's keys and values in their place. Returns ``(x, kv_out,
+    counts)``: ``kv_out`` is ``kv`` with what ``body`` returned for the
+    visited runs, ``counts`` the expert layers' summed (held, all) or
+    None."""
     layers = _run_stacks(params, cfg)
     out = dict(kv)
     counts = None
     for r, (kind, _first, n) in enumerate(cfg.runs(n_layers)):
-        lp = layers[r]
         names = _kv_names(cfg, r, kind)
-        whole = {short: (kv.get(name) if name else None)
-                 for short, name in zip(("k", "v", "k_scale", "v_scale"),
-                                        names)}
-        cut = jax.tree_util.tree_leaves(lp)[0].shape[0] != n
-        if cut:     # a depth-prefix ends inside this run
-            lp = jax.tree.map(lambda a: a[:n], lp)
-        kvl = {short: (a[:n] if cut and a is not None else a)
-               for short, a in whole.items()}
-
-        def step(x, inp, kind=kind):
-            x, kvl, cnt = body(x, inp[0], inp[1], kind)
-            return x, (kvl, cnt)
-
-        x, (kv_run, cnt) = _scan_run(step, x, lp, kvl, n)
-        for short, name in zip(("k", "v", "k_scale", "v_scale"), names):
-            new = kv_run[short]
-            if name is None or new is None:
-                continue
-            if cut and whole[short] is not None:
-                new = jnp.concatenate([new, whole[short][n:]], axis=0)
-            out[name] = new
+        stacks = {short: (kv.get(name) if name else None)
+                  for short, name in zip(_KV_SHORT, names)}
+        x, kv_run, cnt = _scan_run(body, x, layers[r], stacks, n, kind)
+        for short, name in zip(_KV_SHORT, names):
+            if name is not None and kv_run[short] is not None:
+                out[name] = kv_run[short]
         if cnt is not None:
             cnt = cnt.sum(axis=0)
             counts = cnt if counts is None else counts + cnt
@@ -1243,11 +1339,12 @@ def _self_attend(params, input_ids, attention_mask, cfg: DecoderConfig,
             bias["window"] = _causal_bias(attention_mask, S,
                                           cfg.sliding_window)
 
-    def body(carry, lp, kvl, kind):
-        k, v = _prefill_kv(carry, lp, cfg, kind, pos)
-        x, cnt = _block(carry, lp, k, v, bias[kind[0]], cfg, ctx_fn=ctx_fn,
+    def body(x, lp, none, _layer, kind):
+        # nothing is carried: the layer's keys and values are its outputs
+        k, v = _prefill_kv(x, lp, cfg, kind, pos)
+        x, cnt = _block(x, lp, k, v, bias[kind[0]], cfg, ctx_fn=ctx_fn,
                         kind=kind, pos=pos)
-        return x, ({**kvl, "k": k, "v": v} if keep_kv else kvl), cnt
+        return x, ({**none, "k": k, "v": v} if keep_kv else none), cnt
 
     return _scan_layers(cfg, params, x, {}, body)
 
@@ -1329,14 +1426,15 @@ def decode_step(params: dict, token: jax.Array, step_pos: jax.Array,
             live & (idxs > slot - cfg.sliding_window), 0.0, -1e9
         ).astype(jnp.float32)
 
-    def body(x, lp, kvl, kind):
+    def body(x, lp, st, layer, kind):
         k_new, v_new = _prefill_kv(x, lp, cfg, kind, pos)  # (B, nkv, 1, hd)
-        kl = jax.lax.dynamic_update_slice(kvl["k"], k_new, (0, 0, slot, 0))
-        vl = None if v_new is None else jax.lax.dynamic_update_slice(
-            kvl["v"], v_new, (0, 0, slot, 0))
-        x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg, kind=kind,
-                        pos=pos)
-        return x, {**kvl, "k": kl, "v": vl}, cnt
+        ks = _kv_put(st["k"], k_new, layer, 0, slot)
+        vs = None if v_new is None else _kv_put(st["v"], v_new, layer, 0,
+                                                slot)
+        x, cnt = _block(x, lp, _kv_rows(ks, layer),
+                        None if vs is None else _kv_rows(vs, layer),
+                        bias[kind[0]], cfg, kind=kind, pos=pos)
+        return x, {**st, "k": ks, "v": vs}, cnt
 
     x, out, _counts = _scan_layers(cfg, params, x, cache, body, n_layers)
     return _logits(params, x, cfg)[:, 0, :], out
@@ -2083,6 +2181,16 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
     the next-token logits live mid-piece. Paged pools gather-run-
     scatter (see :func:`pool_admit`).
 
+    The pool is donated and every write of it is IN PLACE: each run's
+    stacks ride the layer loop's carry whole (:func:`_scan_layers`; what is
+    scanned is the layer's leaves and its index), a full or latent layer's
+    rows go in by one ``dynamic_update_slice`` at ``(layer, slot, 0, start,
+    0)``, a window layer's by two on the row axis of the slot's ring
+    (:func:`_ring_put`), and what the piece reads back is ONE slot's row.
+    No instruction of the compiled piece produces a whole run array or a
+    layer's rows of all slots (``tests/test_tpu_compile.py`` holds it to
+    that at both answer cells' widths).
+
     Each kind of layer reads its row DENSE (:func:`_attn_ctx` under a mask
     bias) or BLOCKWISE (``flash_attention.flash_chunk_attn``: online
     softmax over the key blocks some query of the piece can see, scores
@@ -2100,7 +2208,6 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         )
     C = pool["slot_mask"].shape[1]
     T = ids.shape[1]
-    nh, hd = cfg.n_kv, cfg.head_dim
     R, W = pool_ring(pool), cfg.sliding_window
     p = jnp.clip(pos, 0, cfg.max_position - 1)
     x = _embed(params, ids, p, cfg)
@@ -2177,64 +2284,47 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
             ring_bias = jnp.concatenate(
                 [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]],
                 axis=-1)
-        ring_idx = jnp.mod(start + jnp.arange(T), R)
 
-    def layer(x, lp, kvl, kind):
-        kl, vl, ksl, vsl = kvl["k"], kvl["v"], kvl["k_scale"], kvl["v_scale"]
+    def layer(x, lp, st, li, kind):
+        # ``st``: the run's stacks, carried whole; this layer's rows of
+        # ``slot`` are written where they lie and read back as ONE row
+        ks, vs, kss, vss = (st[n] for n in _KV_SHORT)
         k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (1, nh, T, hd)
         if kind[0] == "latent":
             # the piece's latent rows go in, then its queries read the row:
             # per-head keys and values exist only inside that read
-            kl = jax.lax.dynamic_update_slice(
-                kl, k_new.astype(kl.dtype), (slot, 0, start, 0))
-            c_row = jax.lax.dynamic_slice(
-                kl, (slot, 0, 0, 0), (1, 1, C, cfg.latent_width))
+            ks = _kv_put(ks, k_new, li, slot, start)
             read = latent_fn and (lambda q, c, _v, _ks, _vs:
                                   latent_fn(q, c, _w_ukv(lp, cfg)))
-            x, cnt = _block(x, lp, c_row, None, mask_bias, cfg,
-                            ctx_fn=read, kind=kind, pos=p)
-            return x, {**kvl, "k": kl}, cnt
+            x, cnt = _block(x, lp, _kv_rows(ks, li, slot), None, mask_bias,
+                            cfg, ctx_fn=read, kind=kind, pos=p)
+            return x, {**st, "k": ks}, cnt
         if kind[0] == "window":
-            k_old = jax.lax.dynamic_slice(kl, (slot, 0, 0, 0), (1, nh, R, hd))
-            v_old = jax.lax.dynamic_slice(vl, (slot, 0, 0, 0), (1, nh, R, hd))
             x, cnt = _block(
-                x, lp, jnp.concatenate([k_old, k_new.astype(kl.dtype)], 2),
-                jnp.concatenate([v_old, v_new.astype(vl.dtype)], 2),
+                x, lp,
+                jnp.concatenate([_kv_rows(ks, li, slot),
+                                 k_new.astype(ks.dtype)], 2),
+                jnp.concatenate([_kv_rows(vs, li, slot),
+                                 v_new.astype(vs.dtype)], 2),
                 ring_bias, cfg, ctx_fn=window_fn, kind=kind, pos=p)
             # only REAL tokens enter the ring: a pad column's index still
             # holds an earlier column that a later query may read
-            real = (mask[0] > 0)[:, None, None]
-            kl = kl.at[slot, :, ring_idx, :].set(jnp.where(
-                real, k_new[0].transpose(1, 0, 2).astype(kl.dtype),
-                kl[slot, :, ring_idx, :]))
-            vl = vl.at[slot, :, ring_idx, :].set(jnp.where(
-                real, v_new[0].transpose(1, 0, 2).astype(vl.dtype),
-                vl[slot, :, ring_idx, :]))
-            return x, {**kvl, "k": kl, "v": vl}, cnt
+            real = mask[0] > 0
+            return x, {**st, "k": _ring_put(ks, k_new, li, slot, start, real),
+                       "v": _ring_put(vs, v_new, li, slot, start, real)}, cnt
         ks_row = vs_row = None
         if quant:
             k_new, sk = _kv_quant(k_new)
             v_new, sv = _kv_quant(v_new)
-            ksl = jax.lax.dynamic_update_slice(ksl, sk, (slot, 0, start, 0))
-            vsl = jax.lax.dynamic_update_slice(vsl, sv, (slot, 0, start, 0))
-            ks_row = jax.lax.dynamic_slice(
-                ksl, (slot, 0, 0, 0), (1, nh, C, 1)
-            )
-            vs_row = jax.lax.dynamic_slice(
-                vsl, (slot, 0, 0, 0), (1, nh, C, 1)
-            )
-        kl = jax.lax.dynamic_update_slice(
-            kl, k_new.astype(kl.dtype), (slot, 0, start, 0)
-        )
-        vl = jax.lax.dynamic_update_slice(
-            vl, v_new.astype(vl.dtype), (slot, 0, start, 0)
-        )
-        k_row = jax.lax.dynamic_slice(kl, (slot, 0, 0, 0), (1, nh, C, hd))
-        v_row = jax.lax.dynamic_slice(vl, (slot, 0, 0, 0), (1, nh, C, hd))
-        x, cnt = _block(x, lp, k_row, v_row, mask_bias, cfg,
-                        k_scale=ks_row, v_scale=vs_row, ctx_fn=full_fn,
-                        kind=kind, pos=p)
-        return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
+            kss = _kv_put(kss, sk, li, slot, start)
+            vss = _kv_put(vss, sv, li, slot, start)
+            ks_row, vs_row = _kv_rows(kss, li, slot), _kv_rows(vss, li, slot)
+        ks = _kv_put(ks, k_new, li, slot, start)
+        vs = _kv_put(vs, v_new, li, slot, start)
+        x, cnt = _block(x, lp, _kv_rows(ks, li, slot), _kv_rows(vs, li, slot),
+                        mask_bias, cfg, k_scale=ks_row, v_scale=vs_row,
+                        ctx_fn=full_fn, kind=kind, pos=p)
+        return x, {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}, cnt
 
     x, kv, counts = _scan_layers(cfg, params, x, pool, layer)
     out = _add_counts(pool, {**kv, "slot_mask": slot_mask}, counts)
@@ -2506,12 +2596,9 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
             temperature, top_k, top_p,
         )
         return _paged_scatter(pool, view), toks
-    B = pool["logits"].shape[0]
     C = pool["slot_mask"].shape[1]
     R, W = pool_ring(pool), cfg.sliding_window
-    b_idx = jnp.arange(B)
     act_i = active.astype(jnp.int32)
-    act_b = active[:, None, None]
     quant = pool_quantized(pool)
     sample = _sample_fn(temperature, top_k, top_p)
     stacks = _kv_stacks(pool)
@@ -2532,39 +2619,20 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
             slot_mask[:, None, None, :] > 0, 0.0, -1e9
         ).astype(jnp.float32)}
         bias["latent"] = bias["full"]
-        col = {"full": w, "latent": w}
+        col = {"full": w[:, None], "latent": w[:, None]}
         if R:
             cols = _ring_cols(w, R)
             bias["window"] = _ring_bias(cols, _live_at(slot_mask, cols), w, W)
-            col["window"] = jnp.mod(w, R)
+            col["window"] = jnp.mod(w, R)[:, None]
 
-        def layer(x, lp, kvl, kind):
-            kl, vl, ksl, vsl = (kvl["k"], kvl["v"], kvl["k_scale"],
-                                kvl["v_scale"])
-            c = col[kind[0]]
+        def layer(x, lp, st, li, kind):
             k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B, nh, 1, hd)
-            if quant:
-                k_new, sk = _kv_quant(k_new)
-                v_new, sv = _kv_quant(v_new)
-                ksl = ksl.at[b_idx, :, c, :].set(
-                    jnp.where(act_b, sk[:, :, 0, :], ksl[b_idx, :, c, :])
-                )
-                vsl = vsl.at[b_idx, :, c, :].set(
-                    jnp.where(act_b, sv[:, :, 0, :], vsl[b_idx, :, c, :])
-                )
             # per-ROW write position (each lane is at its own slot); a
             # latent layer has one row a lane to write and no V: the step
             # reads every slot's rows ABSORBED, as they lie (_latent_ctx)
-            kl = kl.at[b_idx, :, c, :].set(
-                jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, c, :])
-            )
-            if v_new is not None:
-                vl = vl.at[b_idx, :, c, :].set(
-                    jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
-                )
-            x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
-                            k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
-            return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
+            st = _put_lanes(st, k_new, v_new, li, col[kind[0]], active, quant)
+            x, cnt = _block_lanes(x, lp, st, li, bias[kind[0]], cfg, kind, p)
+            return x, st, cnt
 
         x, kv, cnt = _scan_layers(cfg, params, x, kv, layer)
         if cnt is not None:
@@ -2744,17 +2812,18 @@ def _draft_scan(params, cfg: DecoderConfig, kv: dict, slot_mask,
     """``n_draft`` greedy draft steps with the first ``n_layers`` layers.
 
     ``kv`` carries the pool's KV stacks (``k``/``v``, scale planes,
-    ``kw``/``vw``); only the depth-prefix the draft runs is read and
-    written, in a LOCAL copy. Starting from certain token ``t0`` at cache
-    column ``w`` / position ``pos``, each step writes the fed token's
-    shallow KV at its column and predicts the next via the final norm +
-    the head over the truncated stack. Returns ``drafts (B, n_draft)``,
-    the drafted continuation d_1..d_k (the shallow KV is discarded: the
-    verify rewrites those columns for ALL layers)."""
-    B, C = t0.shape[0], slot_mask.shape[1]
+    ``kw``/``vw``), whole: the draft writes the first ``n_layers`` layers'
+    rows of them where they lie and never touches the rest. Starting from
+    certain token ``t0`` at cache column ``w`` / position ``pos``, each step
+    writes the fed token's shallow KV at its column and predicts the next
+    via the final norm + the head over the truncated stack. Returns
+    ``(drafts (B, n_draft), kv)``: the drafted continuation d_1..d_k, and
+    the stacks with the shallow rows in them — columns ``w .. w + n_draft -
+    1`` of active lanes, outside ``slot_mask``, which the cycle's verify
+    rewrites for ALL layers (a caller that only wants the drafts drops
+    them, and its pool is as it was)."""
+    C = slot_mask.shape[1]
     R, W = pool_ring(kv), cfg.sliding_window
-    b_idx = jnp.arange(B)
-    act_b = active[:, None, None]
     idxs = jnp.arange(C)[None, :]
     quant = kv.get("k_scale") is not None
 
@@ -2770,55 +2839,26 @@ def _draft_scan(params, cfg: DecoderConfig, kv: dict, slot_mask,
         bias = {"full": jnp.where(allowed, 0.0, -1e9
                                   ).astype(jnp.float32)[:, None, None, :]}
         bias["latent"] = bias["full"]
-        at = {"full": col, "latent": col}
+        at = {"full": col[:, None], "latent": col[:, None]}
         if R:
             cols = _ring_cols(col, R)
             live = _live_at(slot_mask, cols) | (cols >= w[:, None])
             bias["window"] = _ring_bias(cols, live, col, W)
-            at["window"] = jnp.mod(col, R)
+            at["window"] = jnp.mod(col, R)[:, None]
 
-        def layer(x, lp, kvl, kind):
-            kl, vl, ksl, vsl = (kvl["k"], kvl["v"], kvl["k_scale"],
-                                kvl["v_scale"])
-            c = at[kind[0]]
+        def layer(x, lp, st, li, kind):
             k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B, nh, 1, hd)
-            if quant:
-                k_new, sk = _kv_quant(k_new)
-                v_new, sv = _kv_quant(v_new)
-                ksl = ksl.at[b_idx, :, c, :].set(
-                    jnp.where(act_b, sk[:, :, 0, :],
-                              ksl[b_idx, :, c, :])
-                )
-                vsl = vsl.at[b_idx, :, c, :].set(
-                    jnp.where(act_b, sv[:, :, 0, :],
-                              vsl[b_idx, :, c, :])
-                )
-            kl = kl.at[b_idx, :, c, :].set(
-                jnp.where(act_b, k_new[:, :, 0, :], kl[b_idx, :, c, :])
-            )
-            if v_new is not None:       # a latent layer writes one row
-                vl = vl.at[b_idx, :, c, :].set(
-                    jnp.where(act_b, v_new[:, :, 0, :], vl[b_idx, :, c, :])
-                )
-            x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
-                            k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
-            return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
+            st = _put_lanes(st, k_new, v_new, li, at[kind[0]], active, quant)
+            x, cnt = _block_lanes(x, lp, st, li, bias[kind[0]], cfg, kind, p)
+            return x, st, cnt
 
         x, kv, _cnt = _scan_layers(cfg, params, x, kv, layer, n_layers)
         nxt = jnp.argmax(_logits(params, x, cfg)[:, 0, :], axis=-1
                          ).astype(jnp.int32)
         return (kv, nxt), nxt
 
-    # only the depth-prefix is carried: the draft never touches the rest
-    prefix = {}
-    for r, (kind, _first, n) in enumerate(cfg.runs(n_layers)):
-        for name in _kv_names(cfg, r, kind):
-            if name and kv.get(name) is not None:
-                prefix[name] = kv[name][:n]
-    (_kv, _), drafts = jax.lax.scan(
-        step, (prefix, t0), jnp.arange(n_draft)
-    )
-    return drafts.T  # (B, n_draft)
+    (kv, _), drafts = jax.lax.scan(step, (kv, t0), jnp.arange(n_draft))
+    return drafts.T, kv  # (B, n_draft)
 
 
 def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
@@ -2826,9 +2866,9 @@ def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
                       n_draft: int) -> jax.Array:
     """Draft ``n_draft`` greedy tokens per active lane with the first
     ``draft_layers`` layers of the stack. Pure with respect to the pool:
-    the shallow KV writes live in a local copy of the depth-prefix, so a
-    discarded draft costs nothing — :func:`pool_decode_spec`'s verify
-    pass owns every persistent write. Exposed standalone for tests and
+    the stacks with the shallow KV rows in them are dropped here —
+    :func:`pool_decode_spec`'s verify pass owns every write that a reader
+    sees. Exposed standalone for tests and
     draft-quality probing; the serving path uses the fused cycle.
     Paged pools gather-run-scatter (see :func:`pool_admit`); drafting
     never writes, so only the gather side is needed."""
@@ -2840,10 +2880,11 @@ def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
     C = pool["slot_mask"].shape[1]
     t0 = jnp.argmax(pool["logits"], axis=-1).astype(jnp.int32)
     w = jnp.minimum(pool["write"], C - n_draft)
-    return _draft_scan(
+    drafts, _kv = _draft_scan(
         params, cfg, _kv_stacks(pool), pool["slot_mask"], pool["pos"], w,
         t0, active, n_draft, draft_layers,
     )
+    return drafts
 
 
 def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
@@ -2894,10 +2935,8 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
             f"{n_spec} speculated columns past the window of {W}")
     D, k = draft_layers, n_spec
     quant = pool_quantized(pool)
-    b_idx = jnp.arange(B)
     idxs = jnp.arange(C)
     offs = jnp.arange(k + 1)
-    act_bt = active[:, None, None, None]
 
     def cycle(carry, _):
         kv, logits, slot_mask, pos, write, counts = carry
@@ -2906,7 +2945,8 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
         # the cache — the host sizes slack so live lanes never clamp
         w = jnp.minimum(write, C - 1 - k)
         t0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        drafts = _draft_scan(
+        # the draft's shallow rows land on columns the verify rewrites
+        drafts, kv = _draft_scan(
             params, cfg, kv, slot_mask, pos, w, t0, active, k, D,
         )
         u = jnp.concatenate([t0[:, None], drafts], axis=1)  # (B, k+1)
@@ -2932,38 +2972,13 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
                 cols, jnp.broadcast_to(live, (B, k + 1, R)), qcol, W)
             at["window"] = jnp.mod(qcol, R)
 
-        def vlayer(x, lp, kvl, kind):
-            kl, vl, ksl, vsl = (kvl["k"], kvl["v"], kvl["k_scale"],
-                                kvl["v_scale"])
-            c = at[kind[0]]
+        def vlayer(x, lp, st, li, kind):
             k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B,nh,k+1,hd)
-            kt = k_new.transpose(0, 2, 1, 3)  # (B, k+1, nh, hd)
-            vt = None if v_new is None else v_new.transpose(0, 2, 1, 3)
-            if quant:
-                kt, skt = _kv_quant(kt)
-                vt, svt = _kv_quant(vt)
-                ksl = ksl.at[b_idx[:, None], :, c, :].set(
-                    jnp.where(act_bt, skt,
-                              ksl[b_idx[:, None], :, c, :])
-                )
-                vsl = vsl.at[b_idx[:, None], :, c, :].set(
-                    jnp.where(act_bt, svt,
-                              vsl[b_idx[:, None], :, c, :])
-                )
-            # advanced indexing (b, col) pairs land each row's k+1 new
-            # entries at ITS columns; inactive lanes keep their bytes
-            kl = kl.at[b_idx[:, None], :, c, :].set(
-                jnp.where(act_bt, kt.astype(kl.dtype),
-                          kl[b_idx[:, None], :, c, :])
-            )
-            if vt is not None:          # a latent layer writes one row
-                vl = vl.at[b_idx[:, None], :, c, :].set(
-                    jnp.where(act_bt, vt.astype(vl.dtype),
-                              vl[b_idx[:, None], :, c, :])
-                )
-            x, cnt = _block(x, lp, kl, vl, bias[kind[0]], cfg,
-                            k_scale=ksl, v_scale=vsl, kind=kind, pos=p)
-            return x, {"k": kl, "v": vl, "k_scale": ksl, "v_scale": vsl}, cnt
+            # each lane's k+1 new entries land at ITS columns; inactive
+            # lanes keep their bytes
+            st = _put_lanes(st, k_new, v_new, li, at[kind[0]], active, quant)
+            x, cnt = _block_lanes(x, lp, st, li, bias[kind[0]], cfg, kind, p)
+            return x, st, cnt
 
         x, kv, cnt = _scan_layers(cfg, params, x, kv, vlayer)
         if cnt is not None:

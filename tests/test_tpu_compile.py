@@ -248,12 +248,156 @@ def test_flash_chunk_attn_kernel_at_the_answer_cells_widths(
     assert _is_mosaic(c)
 
 
-def test_trinity_width_prefill_piece(shape, monkeypatch):
-    """The whole prefill piece of the answer cell (the configuration the
-    benchmark runs, 16 slots of 8,304 columns, a piece of 512) with the
-    blockwise read its shapes choose: the kernel is in the program, and the
-    piece's temporaries are under the dense read's 1.84 GB (``PERF.md``
-    section 4 has both)."""
+# ---- the slot pool is written where it lies (PR 33) ------------------------
+#
+# Read off the compiled text: every instruction OUTSIDE fused computations
+# (the entry, a loop's body) whose result is a whole run array of the pool or
+# one layer's rows of all slots. Such a result is 142 MB to 761 MB at the
+# cells' widths, so each is a pass over HBM; the only ones a dispatch may
+# hold are the writes of the carried buffer where it lies.
+
+_SKIP = ("parameter", "get-tuple-element", "tuple", "bitcast", "while",
+         "constant", "conditional", "call", "opt-barrier")
+
+
+def _computations(text: str) -> dict:
+    """name -> (is the entry, its instruction lines)."""
+    import re
+
+    out, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+        if m:
+            cur = m.group(2)
+            out[cur] = (bool(m.group(1)), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur:
+            out[cur][1].append(line)
+    return out
+
+
+def _dims(ty: str) -> list:
+    import re
+
+    return [tuple(int(x) for x in d.split(",") if x)
+            for d in re.findall(r"\w+\[([\d,]*)\]", ty)]
+
+
+def _whole_array_ops(text: str, stacks: list) -> list:
+    """``(where, name, kind)`` of every instruction outside fused
+    computations with a result as large as one of ``stacks`` (the pool's run
+    arrays' shapes) or as one layer of one. ``where`` is ``"entry"`` or
+    ``"loop"``; ``kind`` is ``"in place"`` for a ``dynamic-update-slice`` or
+    ``scatter`` (bare, or the root of a fusion) whose operand is the buffer
+    itself, else the opcode (a fusion's root's)."""
+    import re
+
+    whole = set()
+    for d in stacks:
+        for full in (tuple(d), tuple(d[1:])):
+            whole |= {full, (1,) + full, tuple(x for x in full if x != 1)}
+    comps = _computations(text)
+    fused = {c.lstrip("%") for c in re.findall(r"calls=(%?[\w.\-]+)", text)}
+    inst = re.compile(
+        r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\(")
+
+    def root_kind(comp: str) -> str:
+        """What a fused computation's root (each element, if a tuple) is."""
+        lines = comps[comp][1]
+        by_name = {}
+        for line in lines:
+            m = inst.match(line)
+            if m:
+                by_name[m.group(1)] = (m.group(3), line)
+        root = next(l for l in lines if l.lstrip().startswith("ROOT"))
+        m = inst.match(root)
+        roots = [m.group(1)]
+        if m.group(3) == "tuple":
+            roots = re.findall(r"%([\w.\-]+)", root.split("tuple(", 1)[1])
+        kinds = set()
+        for r in roots:
+            op, line = by_name[r]
+            if not any(d in whole for d in _dims(inst.match(line).group(2))):
+                continue
+            first = re.search(op + r"\(%([\w.\-]+)", line).group(1)
+            while by_name.get(first, ("",))[0] == "bitcast":
+                first = re.search(r"bitcast\(%([\w.\-]+)",
+                                  by_name[first][1]).group(1)
+            in_place = (op in ("dynamic-update-slice", "scatter")
+                        and by_name.get(first, ("",))[0] == "parameter")
+            kinds.add("in place" if in_place else op)
+        return kinds.pop() if len(kinds) == 1 else "/".join(sorted(kinds))
+
+    found = []
+    for name, (entry, lines) in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = inst.match(line)
+            if not m or m.group(3) in _SKIP:
+                continue
+            if not any(d in whole for d in _dims(m.group(2))):
+                continue
+            kind = m.group(3)
+            if kind in ("dynamic-update-slice", "scatter"):
+                kind = "in place"
+            elif kind == "fusion":
+                kind = root_kind(
+                    re.search(r"calls=%?([\w.\-]+)", line).group(1))
+            found.append(("entry" if entry else "loop", m.group(1), kind))
+    return found
+
+
+def _pool_stacks(pool) -> list:
+    from pathway_tpu.models import decoder as D
+
+    return [a.shape for a in D._kv_stacks(pool).values()]
+
+
+def test_whole_array_ops_reads_the_compiled_text():
+    """The guard's own reading, on a text with one of each: a copy and a
+    cut-out layer are found, the writes in place are told apart, a small
+    result and anything inside a fused computation are not listed."""
+    text = """HloModule m
+%fused_put (p0: bf16[2,4,1,64,8], p1: bf16[1,1,1,8,8]) -> bf16[2,4,1,64,8] {
+  %p0 = bf16[2,4,1,64,8]{4,3,2,1,0} parameter(0)
+  %p1 = bf16[1,1,1,8,8]{4,3,2,1,0} parameter(1)
+  %c = s32[] constant(0)
+  ROOT %dus = bf16[2,4,1,64,8]{4,3,2,1,0} dynamic-update-slice(%p0, %p1, %c, %c, %c, %c, %c)
+}
+%fused_cut (p0: bf16[2,4,1,64,8], p1: s32[]) -> bf16[4,64,8] {
+  %p0 = bf16[2,4,1,64,8]{4,3,2,1,0} parameter(0)
+  %p1 = s32[] parameter(1)
+  %c = s32[] constant(0)
+  %ds = bf16[1,4,1,64,8]{4,3,2,1,0} dynamic-slice(%p0, %p1, %c, %c, %c, %c), dynamic_slice_sizes={1,4,1,64,8}
+  ROOT %b = bf16[4,64,8]{2,1,0} bitcast(%ds)
+}
+%body (t: (bf16[2,4,1,64,8], s32[])) -> (bf16[2,4,1,64,8], s32[]) {
+  %t = (bf16[2,4,1,64,8]{4,3,2,1,0}, s32[]) parameter(0)
+  %kv = bf16[2,4,1,64,8]{4,3,2,1,0} get-tuple-element(%t), index=0
+  %i = s32[] get-tuple-element(%t), index=1
+  %new = bf16[1,1,1,8,8]{4,3,2,1,0} constant({...})
+  %put = bf16[2,4,1,64,8]{4,3,2,1,0} fusion(%kv, %new), kind=kLoop, calls=%fused_put
+  %cut = bf16[4,64,8]{2,1,0} fusion(%put, %i), kind=kLoop, calls=%fused_cut
+  %small = bf16[1,64,8]{2,1,0} copy(%new)
+  ROOT %out = (bf16[2,4,1,64,8]{4,3,2,1,0}, s32[]) tuple(%put, %i)
+}
+ENTRY %main (a: bf16[2,4,1,64,8]) -> bf16[2,4,1,64,8] {
+  %a = bf16[2,4,1,64,8]{4,3,2,1,0} parameter(0)
+  %there = bf16[2,4,1,64,8]{3,4,2,1,0} copy(%a)
+  ROOT %back = bf16[2,4,1,64,8]{4,3,2,1,0} copy(%there)
+}
+"""
+    assert _whole_array_ops(text, [(2, 4, 1, 64, 8)]) == [
+        ("loop", "put", "in place"), ("loop", "cut", "bitcast"),
+        ("entry", "there", "copy"), ("entry", "back", "copy")]
+
+
+def _answer_cell(shape, config: str, layout: str, slots: int, columns: int,
+                 **pool_kw):
+    """(cfg, params, pool) of an answer cell, as shapes on the described
+    chip: the configuration file the benchmark runs, through its layout."""
     import json
     import os
     import sys
@@ -266,33 +410,92 @@ def test_trinity_width_prefill_piece(shape, monkeypatch):
 
     from pathway_tpu.models import decoder as D
 
-    # this process's default backend is the CPU: steer the kernel's own
-    # choice of the interpreter here, in the test
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with open(os.path.join(bench, "configs",
-                           "trinity-large-ep8-rag.json")) as f:
+    with open(os.path.join(bench, "configs", config)) as f:
         model = json.load(f)["models"]["decoder"]
-    cfg = M.resolve(M.load_manifest(), "layouts", "afmoe").program_config(
+    cfg = M.resolve(M.load_manifest(), "layouts", layout).program_config(
         model)
     params = jax.eval_shape(lambda: D.cast_params_for_inference(
         D.init_params(jax.random.PRNGKey(0), cfg), cfg))
-    pool = jax.eval_shape(lambda: D.pool_init(None, cfg, 16, 8304))
-    assert D.pool_ring(pool) == 4352
+    pool = jax.eval_shape(
+        lambda: D.pool_init(None, cfg, slots, columns, **pool_kw))
+    return cfg, _placed(shape, params), _placed(shape, pool)
+
+
+@pytest.fixture(scope="module")
+def trinity(shape):
+    """The answer cell: 16 slots of 8,304 columns, window rings of 4,352."""
+    from pathway_tpu.models import decoder as D
+
+    cell = _answer_cell(shape, "trinity-large-ep8-rag.json", "afmoe", 16,
+                        8304)
+    assert D.pool_ring(cell[2]) == 4352
+    return cell
+
+
+def test_trinity_width_prefill_piece(shape, trinity, monkeypatch):
+    """The whole prefill piece of the answer cell (the configuration the
+    benchmark runs, 16 slots of 8,304 columns, a piece of 512) with the
+    blockwise read its shapes choose: the kernel is in the program, and the
+    piece holds NO whole key-value array among its temporaries (``PERF.md``
+    section 4: 1.16 GB of them before PR 33, when every window layer's ring
+    of all 16 slots was copied into the layout a scatter asks for and back,
+    and a run of two layers cut out of its scanned stack and put back): all
+    it does to a run array is write the piece's rows where they lie."""
+    from pathway_tpu.models import decoder as D
+
+    # this process's default backend is the CPU: steer the kernel's own
+    # choice of the interpreter here, in the test
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, pool = trinity
     piece = shape((1, 512), I32)
     c = _compile(
         lambda p, i, m, ps, pl, s, st, n: D.pool_prefill_chunk(
             p, i, m, ps, pl, s, st, n, cfg, first=False, last=False),
-        _placed(shape, params), piece, piece, piece, _placed(shape, pool),
+        params, piece, piece, piece, pool,
         shape((), I32), shape((), I32), shape((1,), I32),
         donate_argnums=(4,),
     )
     assert _is_mosaic(c)
+    ops = _whole_array_ops(c.as_text(), _pool_stacks(pool))
+    # the full layer's rows, each window layer's two stretches of its ring
+    assert ops and {kind for _w, _n, kind in ops} == {"in place"}, ops
     m = c.memory_analysis()
-    assert m.temp_size_in_bytes < 1_400_000_000
+    assert m.temp_size_in_bytes < 44_000_000      # 39,547,392 + a tenth
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16 << 30
+
+
+def test_trinity_width_decode_chunk(shape, trinity):
+    """The decode chunk of the answer cell, 16 steps over 16 slots. The
+    pool changes layout ONCE a chunk, at entry and at exit (a one-column
+    write wants the rows outside the heads, a piece the heads outside the
+    rows: 16 copies, pinned); inside a step every write is in place and the
+    only other whole-array operation is ONE read of a layer's rows of all
+    slots an array, in the run of two window layers (the loop's body holds
+    it once for keys, once for values; a run of one is indexed statically
+    and reads in place)."""
+    from pathway_tpu.models import decoder as D
+
+    cfg, params, pool = trinity
+    c = _compile(
+        lambda p, pl, a, k: D.pool_decode_chunk(p, pl, a, k, cfg,
+                                                CHUNK_STEPS),
+        params, pool, shape((16,), jnp.bool_), shape((2,), jnp.uint32),
+        donate_argnums=(1,),
+    )
+    ops = _whole_array_ops(c.as_text(), _pool_stacks(pool))
+    entry = [kind for where, _n, kind in ops if where == "entry"]
+    step = [kind for where, _n, kind in ops
+            if where == "loop" and kind != "in place"]
+    assert entry == ["copy"] * 16, ops
+    assert step == ["dynamic-slice"] * 2, ops
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 1_862_000_000   # 1,692,039,168 + a tenth
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16 << 30
 
 
 LATENT_COLUMNS = 16384 + 64 + 5 * 16     # the long-context cell's slot row
+# a piece's temporaries there: 97,279,488 and 486,489,088 bytes, + a tenth
+PIECE_TEMP = {512: 107_000_000, 2048: 535_000_000}
 
 
 @pytest.mark.parametrize("piece", [512, 1024, 2048])
@@ -317,31 +520,10 @@ def test_flash_chunk_attn_latent_kernel_at_the_long_cells_widths(
 
 @pytest.fixture(scope="module")
 def deepseek(shape):
-    """(cfg, params, pool) of the long-context cell: the configuration the
-    benchmark runs, 8 slots of 16,528 columns, the prefix arena of the
-    server's default 64 MB."""
-    import json
-    import os
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmarks")
-    if bench not in sys.path:
-        sys.path.insert(0, bench)
-    from harness import manifest as M
-
-    from pathway_tpu.models import decoder as D
-
-    with open(os.path.join(bench, "configs",
-                           "deepseek-v2-ep8-rag.json")) as f:
-        model = json.load(f)["models"]["decoder"]
-    cfg = M.resolve(M.load_manifest(), "layouts",
-                    "deepseek_v2").program_config(model)
-    params = jax.eval_shape(lambda: D.cast_params_for_inference(
-        D.init_params(jax.random.PRNGKey(0), cfg), cfg))
-    pool = jax.eval_shape(lambda: D.pool_init(
-        None, cfg, 8, LATENT_COLUMNS, arena_blocks=18, arena_block=512))
-    return cfg, _placed(shape, params), _placed(shape, pool)
+    """The long-context cell: 8 slots of 16,528 columns, the prefix arena of
+    the server's default 64 MB."""
+    return _answer_cell(shape, "deepseek-v2-ep8-rag.json", "deepseek_v2", 8,
+                        LATENT_COLUMNS, arena_blocks=18, arena_block=512)
 
 
 @pytest.mark.parametrize("piece", [512, 2048])
@@ -364,11 +546,14 @@ def test_deepseek_width_prefill_piece(shape, deepseek, monkeypatch, piece):
         shape((1,), I32), donate_argnums=(4,),
     )
     assert _is_mosaic(c)
+    # the latent stacks ride the layer loop's carry: the piece's rows go in
+    # where they lie and nothing else touches a whole stack (before PR 33:
+    # one layer's rows of all 8 slots cut out and put back a layer, and the
+    # 761 MB stack copied at the loop's exit)
+    ops = _whole_array_ops(c.as_text(), _pool_stacks(pool))
+    assert ops and {kind for _w, _n, kind in ops} == {"in place"}, ops
     m = c.memory_analysis()
-    # 1.18 GB and 2.04 GB here: 0.76 GB of it one copy of the expert
-    # layers' latent stack at the loop's exit (PERF.md section 7)
-    assert m.temp_size_in_bytes < (1_400_000_000 if piece == 512
-                                   else 2_400_000_000)
+    assert m.temp_size_in_bytes < PIECE_TEMP[piece]
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 11 << 30
 
 
@@ -386,10 +571,15 @@ def test_deepseek_width_decode_chunk(shape, deepseek):
         params, pool, shape((8,), jnp.bool_), shape((2,), jnp.uint32),
         donate_argnums=(1,),
     )
+    # the pool's two stacks once in the layout the loop prefers and once
+    # back (4 copies, pinned); inside a step nothing but the write in place
+    ops = _whole_array_ops(c.as_text(), _pool_stacks(pool))
+    assert [kind for where, _n, kind in ops if where == "entry"] \
+        == ["copy"] * 4, ops
+    assert {kind for where, _n, kind in ops if where == "loop"} \
+        == {"in place"}, ops
     m = c.memory_analysis()
-    # 3.34 GB here: the pool's two stacks once in the layout the loop
-    # prefers and once back, and one layer's rows cut out and put back
-    assert m.temp_size_in_bytes < 3_700_000_000
+    assert m.temp_size_in_bytes < 1_905_000_000   # 1,731,513,344 + a tenth
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12 << 30
     # ... and the step a default (greedy) server dispatches first
     c = _compile(

@@ -594,3 +594,105 @@ def test_the_server_compiles_nothing_under_traffic(net, prompt):
     finally:
         chat.close()
         jax.monitoring.unregister_event_duration_listener(on)
+
+
+# ---- the ring write (PR 33) -------------------------------------------------
+#
+# A piece enters a window layer's ring as at most two stretches of contiguous
+# rows (``decoder._ring_put``). The rows below carry their own cache column
+# as their value, so what the ring holds can be read back and compared with a
+# plain numpy ring: column mod R, pads skipped.
+
+RING, PIECE_T = 24, 16
+RING_CASES = {
+    # (start, the piece's mask) in turn
+    "ends at the ring's end": [(8, [1] * 16)],
+    "wraps": [(16, [1] * 16)],
+    "first columns are left padding": [(0, [0] * 5 + [1] * 11)],
+    "unaligned start, right padding": [(0, [1] * 16), (19, [1] * 9 + [0] * 7)],
+    "a second lap over a first lap's rows": [
+        (0, [0] * 3 + [1] * 13), (16, [1] * 16), (32, [1] * 16),
+        (48, [1] * 16)],
+    "a ring no longer than the piece": [(5, [1] * 16), (21, [1] * 16)],
+}
+
+
+def _column_rows(start, heads=2, d=4):
+    """(1, heads, T, d): every value of row j is its column, start + j."""
+    col = (start + np.arange(PIECE_T, dtype=np.float32))[None, None, :, None]
+    return np.broadcast_to(col, (1, heads, PIECE_T, d)).copy()
+
+
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_a_piece_enters_the_ring_as_a_plain_numpy_ring_takes_it(net, case):
+    """Elementwise, the whole stack: the rows of the layer and slot written
+    are the numpy ring's, every other row of every layer and slot is as it
+    was; and the ring the write leaves is the one the next piece's read is
+    told about — ``kcol_w``'s columns (``_ring_cols`` under ``_live_at``) and
+    ``prefill_blocks_visited``'s count."""
+    from pathway_tpu.models import flash_attention as FA
+
+    _params, _p32, _cfg, cfg32 = net
+    R = PIECE_T if case.startswith("a ring no longer") else RING
+    rng = np.random.default_rng(1)
+    stack = -1.0 - rng.random((2, 3, 2, R, 4)).astype(np.float32)
+    want = stack.copy()
+    layer, slot, C = 1, 2, 96
+    row_mask = np.zeros((1, C), np.int32)
+    put = jax.jit(D._ring_put)
+    got = jnp.asarray(stack)
+    for start, mask in RING_CASES[case]:
+        new, real = _column_rows(start), np.asarray(mask) > 0
+        got = put(got, new, np.int32(layer), np.int32(slot), np.int32(start),
+                  real)
+        for j in np.flatnonzero(real):                  # the numpy ring
+            want[layer, slot, :, (start + j) % R, :] = new[0, :, j, :]
+        row_mask[0, start:start + PIECE_T] = mask
+    assert np.array_equal(np.asarray(got), want)
+    # what the NEXT piece's read is told: ring row r holds column cols[r]
+    nxt = start + PIECE_T
+    cols = D._ring_cols(jnp.reshape(jnp.int32(nxt - 1), (1,)), R)
+    live = np.asarray(D._live_at(jnp.asarray(row_mask), cols))[0]
+    told = np.where(live, np.asarray(cols)[0], -1)
+    held = want[layer, slot, 0, :, 0]
+    assert (told >= 0).any() and np.array_equal(held[told >= 0],
+                                                told[told >= 0])
+    # ... and the counter's host arithmetic sees the ring the write left
+    real_cols = np.flatnonzero(row_mask[0])
+    lo, hi = int(real_cols.min()), nxt + PIECE_T - 1
+    if R != RING or real_cols.size != real_cols.max() - lo + 1:
+        return      # the server's live columns are one stretch [lo, hi]
+    bk = FA.chunk_block(R + PIECE_T, PIECE_T, 4, 16, 4)
+    kcol = np.concatenate([np.where(held >= lo, held, -1).astype(np.int64),
+                           nxt + np.arange(PIECE_T)])
+    _k, seen = FA.blocks_seen(np, kcol, nxt, PIECE_T, cfg32.sliding_window,
+                              bk)
+    counted = D.prefill_blocks_visited(cfg32, PIECE_T, C, R, nxt, lo, hi,
+                                       flash=True)
+    assert counted[("window", 1)] == int(seen.sum()) * cfg32.n_layers_of(
+        "window")
+
+
+def test_the_pieces_leave_the_ring_one_shot_prefill_would(net, prompt):
+    """Through ``pool_prefill_chunk``: a prompt of 40 in three pieces, left
+    padded (the first piece's first columns are padding; the ring of 24 wraps
+    in the third) and right padded from an unaligned start (the prefix-cache
+    admission), against ``prefill``'s cache, which keeps a window layer's
+    keys at full length: ring row r holds the last real column congruent to
+    r, to the element."""
+    _params, p32, _cfg, cfg32 = net
+    for left in (True, False):
+        pool = _prefill(p32, cfg32,
+                        D.pool_init(p32, cfg32, 2, 96, window_slack=8), 1,
+                        prompt, left=left)
+        ids, mask = np.zeros((1, 48), np.int32), np.zeros((1, 48), np.int32)
+        at = slice(8, 48) if left else slice(0, 40)
+        ids[0, at], mask[0, at] = prompt, 1
+        _logits, cache = jax.jit(lambda p: D.prefill(
+            p, ids, mask, cfg32, 48))(p32)
+        for name in ("kw0", "vw1", "kw3", "vw3"):
+            ring = np.asarray(pool[name][:, 1])         # (layers, nh, 24, hd)
+            full = np.asarray(cache[name][:, 0])        # (layers, nh, 48, hd)
+            for r in range(24):
+                c = max(c for c in np.flatnonzero(mask[0]) if c % 24 == r)
+                assert np.abs(ring[:, :, r] - full[:, :, c]).max() < F32_TOL
