@@ -169,6 +169,17 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
         "and values for the time of one attention read (phase=prefill: the "
         "rows of the key blocks a piece's blockwise read visited), summed "
         "over the latent layers; host arithmetic, no sync"),
+    "loop_passes": (
+        "counter", "phase", "Passes of a looped layer stack run for tokens "
+        "that were real, by phase (prefill: a piece's real columns; decode: "
+        "a chunk's useful slot-steps) and by pass (pass=1..loops: every "
+        "token runs pass 1, so the series' sum over pass=1's is the passes a "
+        "token); host arithmetic, no sync"),
+    "loop_exit_step": (
+        "counter", "step", "Emitted tokens by the pass (step=1..loops) the "
+        "exit rule of a looped stack took their logits from; all at the "
+        "last under a threshold of 1. Summed on the device, read with the "
+        "tokens at drain"),
     "kv_migrated_blocks": (
         "counter", "server", "KV blocks handed from the prefill lane to "
         "the decode lane at prompt completion (PATHWAY_TPU_DISAGG)"),
@@ -580,6 +591,19 @@ def record_latent_rows_expanded(rows: int, phase: str = "prefill") -> None:
     over its latent layers (``models.decoder.prefill_blocks_visited`` times
     the block's rows: host arithmetic, no sync)."""
     REGISTRY.counter_add("latent_rows_expanded", rows, phase=phase)
+
+
+def record_loop_passes(phase: str, tokens: int, loops: int) -> None:
+    """``tokens`` real tokens went through every one of ``loops`` passes
+    of a looped stack (``_ContinuousServer._loop_account``)."""
+    REGISTRY.counter_add_many(
+        "loop_passes", ("phase", "pass"),
+        {(phase, u): tokens for u in range(1, loops + 1)})
+
+
+def record_loop_exits(by_step: dict) -> None:
+    """``{step: tokens}``: emitted tokens by the exit rule's pass."""
+    REGISTRY.counter_add_many("loop_exit_step", "step", by_step)
 
 
 def record_backlog(queue: str, depth: int) -> None:

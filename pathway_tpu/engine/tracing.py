@@ -264,7 +264,9 @@ def region(name: str, **ids):
     stats. The profiler session is the only switch: with none running
     (every production minute) the TraceMe records nothing; inside one the
     region lands in the ``.xplane.pb`` host plane beside the device's
-    ops."""
+    ops. (The decoder server's ``pw.decode.prefill`` and
+    ``pw.decode.chunk`` carry ``passes``: how many times the dispatch runs
+    the layer stack for each of its tokens, 1 but for a looped stack.)"""
     return TraceAnnotation(name, **ids)
 
 

@@ -93,6 +93,17 @@ class DecoderConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # a LOOPED stack (loops > 1): the layers run `loops` times over the same
+    # weights, the final norm closing every pass and feeding the next; pass u
+    # of layer l keeps a cache of its own (cache layer u * layers + l: pass u
+    # of a later token attends what pass u of the earlier tokens wrote). With
+    # `exit_gate` a learned gate reads every pass's normed state and the
+    # logits come from the first pass whose cumulative exit probability
+    # reaches `exit_threshold`, else the last (1 or more: always the last).
+    # EVERY pass runs for every token whatever the gate says
+    loops: int = 1
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
 
     @property
     def latent(self) -> bool:
@@ -183,6 +194,7 @@ def gpt2_block(cfg: DecoderConfig) -> bool:
             and cfg.mlp == "gelu" and cfg.bias and cfg.tied_head
             and cfg.embed_scale == 1.0 and cfg.moe is None
             and not cfg.sliding_window and cfg.head_size is None
+            and cfg.loops == 1
             and (cfg.layer_types is None
                  or all(t == "full" for t in cfg.layer_types)))
 
@@ -194,7 +206,20 @@ def require_gpt2_block(cfg: DecoderConfig, mechanism: str) -> None:
             "learned positions, LayerNorm, gelu and a tied head; this "
             "configuration's layers differ"
             + (" (latent attention: one compressed row a token a layer, "
-               "no per-head keys and values)" if cfg.latent else ""))
+               "no per-head keys and values)" if cfg.latent else "")
+            + (f" (a looped stack: {cfg.loops} passes over the same weights, "
+               "a cache layer for every pass of every layer)"
+               if cfg.loops > 1 else ""))
+
+
+def require_single_pass(cfg: DecoderConfig, mechanism: str) -> None:
+    """A depth prefix of the layers is a prefix of the computation only where
+    the stack runs once: the self-speculative draft refuses a looped one."""
+    if cfg.loops > 1:
+        raise UnsupportedForLayout(
+            mechanism, "its draft is a depth prefix of the layers, and a "
+            f"stack run {cfg.loops} times has none (a looped stack: the first "
+            "layers of the first pass are not a shallower model)")
 
 
 def _init(key, shape, dtype, scale=0.02):
@@ -276,7 +301,8 @@ def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
 _F32_LEAVES = frozenset(
     [f"{ln}_{leaf}" for ln in ("ln1", "ln2", "ln_f", "ln1p", "ln2p",
                                "q_norm", "k_norm", "q_a_norm", "kv_a_norm")
-     for leaf in ("scale", "bias")] + ["router_w", "router_bias"])
+     for leaf in ("scale", "bias")]
+    + ["router_w", "router_bias", "exit_w", "exit_b"])
 
 
 def init_params(rng: jax.Array, cfg: DecoderConfig) -> dict:
@@ -314,6 +340,11 @@ def init_params(rng: jax.Array, cfg: DecoderConfig) -> dict:
         # GPT-2's head is weight-tied to wte; an untied one is its own leaf
         params["lm_head"] = _init(jax.random.fold_in(rng, 9),
                                   (cfg.vocab_size, h), pd)
+    if cfg.loops > 1 and cfg.exit_gate:
+        # the exit gate of a looped stack: Linear(hidden -> 1) with a bias
+        params["exit_w"] = _init(jax.random.fold_in(rng, 20), (h, 1),
+                                 jnp.float32)
+        params["exit_b"] = jnp.zeros((1,), jnp.float32)
     return params
 
 
@@ -344,6 +375,9 @@ def param_partition_specs(cfg: DecoderConfig, tp_axis: str = "tp") -> dict:
         specs["ln_f_bias"] = P(None)
     if not cfg.tied_head:
         specs["lm_head"] = P(t, None)
+    if cfg.loops > 1 and cfg.exit_gate:
+        specs["exit_w"] = P(None, None)
+        specs["exit_b"] = P(None)
     return specs
 
 
@@ -511,13 +545,15 @@ def _kv_quant(x):
 
 def kv_token_bytes(cfg: DecoderConfig, itemsize: int,
                    quant: bool = False) -> int:
-    """Cache bytes one token costs over all layers: K and V of every
-    key-value head (int8 with a float32 scale a head-token where ``quant``),
-    or a latent layer's one row."""
+    """Cache bytes one token costs over all cache layers (a looped stack
+    keeps one for every pass of every layer): K and V of every key-value head
+    (int8 with a float32 scale a head-token where ``quant``), or a latent
+    layer's one row."""
+    cache_layers = cfg.loops * cfg.layers
     if cfg.latent:
-        return cfg.layers * cfg.latent_width * itemsize
+        return cache_layers * cfg.latent_width * itemsize
     per_head = cfg.head_dim + 4 if quant else cfg.head_dim * itemsize
-    return 2 * cfg.layers * cfg.n_kv * per_head
+    return 2 * cache_layers * cfg.n_kv * per_head
 
 
 def pool_quantized(pool: dict) -> bool:
@@ -1042,12 +1078,17 @@ def _flash_chunk_attn_fn(mesh, quant, window=0):
         mapped(q, k_row, v_row, kcol, start)
 
 
-def _logits(params, x, cfg):
+def _final_norm(params, x, cfg):
     if cfg.norm == "layernorm":
-        h = _ln(x, params["ln_f_scale"], params["ln_f_bias"],
-                cfg.layer_norm_eps)
-    else:
-        h = _rms(x, params["ln_f_scale"], cfg.layer_norm_eps)
+        return _ln(x, params["ln_f_scale"], params["ln_f_bias"],
+                   cfg.layer_norm_eps)
+    return _rms(x, params["ln_f_scale"], cfg.layer_norm_eps)
+
+
+def _logits(params, x, cfg):
+    # a looped stack's state is normed already: the final norm closes every
+    # pass (:func:`_scan_layers`), the last like the others
+    h = x if cfg.loops > 1 else _final_norm(params, x, cfg)
     head = params["wte"] if cfg.tied_head else params["lm_head"]
     out = jnp.einsum("bsh,vh->bsv", h.astype(cfg.dtype),
                      head.astype(cfg.dtype),
@@ -1218,9 +1259,13 @@ def _ring_put(stack, new, layer, slot, start, real):
     return stack
 
 
-def _scan_run(body, x, lp: dict, stacks: dict, n: int, kind: tuple):
+def _scan_run(body, x, lp: dict, stacks: dict, n: int, kind: tuple,
+              base=None):
     """``lax.scan`` of ``body(x, lp_l, stacks, layer, kind) -> (x, stacks |
-    kvl, counts)`` over the first ``n`` layers of one run. SCANNED: the
+    kvl, counts)`` over the first ``n`` layers of one run; with ``base`` (a
+    looped stack's pass times the run's layers) ``body`` is handed ``base +
+    layer``, the index of this PASS of the layer in the run's KV stacks,
+    while the weights are the layer's own. SCANNED: the
     run's stacked leaves but its experts, and the layer index. CARRIED, whole:
     ``x`` and the run's KV ``stacks`` — ``body`` writes a layer's rows into
     them in place and hands them on. The experts are neither: they ride the
@@ -1242,12 +1287,57 @@ def _scan_run(body, x, lp: dict, stacks: dict, n: int, kind: tuple):
         lp_l, layer = inp
         if experts:
             lp_l = {**lp_l, **experts, "moe_layer": layer}
-        x, new, cnt = body(x, lp_l, st, layer, kind)
+        x, new, cnt = body(x, lp_l, st,
+                           layer if base is None else base + layer, kind)
         return ((x, new), (None, cnt)) if carried else ((x, st), (new, cnt))
 
     (x, st), (ys, cnt) = jax.lax.scan(
         step, (x, stacks), (scanned, jnp.arange(n, dtype=jnp.int32)))
     return x, (st if carried else ys), cnt
+
+
+def _one_pass(cfg: DecoderConfig, params: dict, x, kv: dict, body,
+              n_layers: int | None = None, u=None):
+    """ONE pass over the runs of like layers (pass ``u`` of a looped stack:
+    its layers' rows lie ``u`` times a run's layers further down the run's
+    stacks): ``(x, kv_out, counts)`` as :func:`_scan_layers` says."""
+    layers = _run_stacks(params, cfg)
+    out = dict(kv)
+    counts = None
+    for r, (kind, _first, n) in enumerate(cfg.runs(n_layers)):
+        names = _kv_names(cfg, r, kind)
+        stacks = {short: (kv.get(name) if name else None)
+                  for short, name in zip(_KV_SHORT, names)}
+        x, kv_run, cnt = _scan_run(body, x, layers[r], stacks, n, kind,
+                                   None if u is None else u * n)
+        for short, name in zip(_KV_SHORT, names):
+            if name is not None and kv_run[short] is not None:
+                out[name] = kv_run[short]
+        if cnt is not None:
+            cnt = cnt.sum(axis=0)
+            counts = cnt if counts is None else counts + cnt
+    return x, out, counts
+
+
+def loop_exit(z, threshold: float):
+    """The exit rule of a looped stack from its gate's logits ``z`` (loops,
+    ...), one a pass: ``lambda_u = sigmoid(z_u)``; the exit distribution
+    ``p_u = lambda_u prod_{j<u} (1 - lambda_j)`` for every pass but the
+    last, which takes the remainder; the exit step the first pass whose
+    cumulative ``p`` reaches ``threshold``, else the last. The cumulative is
+    read off what is LEFT (``prod_{j<=u} (1 - lambda_j) <= 1 - threshold``,
+    each factor ``sigmoid(-z_j)``), so a threshold of 1 is reached by no
+    pass before the last unless a factor underflows, and one above 1 by
+    none. Returns ``(p (loops, ...) float32, step (...) int32)``."""
+    z = z.astype(jnp.float32)
+    left = jnp.cumprod(jax.nn.sigmoid(-z), axis=0)      # after pass u
+    before = jnp.concatenate([jnp.ones_like(left[:1]), left[:-1]], axis=0)
+    p = jnp.concatenate([jax.nn.sigmoid(z[:-1]) * before[:-1], before[-1:]],
+                        axis=0)
+    reached = left[:-1] <= 1.0 - threshold
+    last = z.shape[0] - 1
+    step = jnp.where(reached.any(axis=0), jnp.argmax(reached, axis=0), last)
+    return p, step.astype(jnp.int32)
 
 
 def _scan_layers(cfg: DecoderConfig, params: dict, x, kv: dict, body,
@@ -1261,24 +1351,65 @@ def _scan_layers(cfg: DecoderConfig, params: dict, x, kv: dict, body,
     first layers and leaves the others' rows alone. A pass over whole
     sequences starts from ``{}``: ``stacks`` holds None, and ``body`` returns
     the layer's keys and values in their place. Returns ``(x, kv_out,
-    counts)``: ``kv_out`` is ``kv`` with what ``body`` returned for the
-    visited runs, ``counts`` the expert layers' summed (held, all) or
-    None."""
-    layers = _run_stacks(params, cfg)
-    out = dict(kv)
-    counts = None
-    for r, (kind, _first, n) in enumerate(cfg.runs(n_layers)):
-        names = _kv_names(cfg, r, kind)
-        stacks = {short: (kv.get(name) if name else None)
-                  for short, name in zip(_KV_SHORT, names)}
-        x, kv_run, cnt = _scan_run(body, x, layers[r], stacks, n, kind)
-        for short, name in zip(_KV_SHORT, names):
-            if name is not None and kv_run[short] is not None:
-                out[name] = kv_run[short]
-        if cnt is not None:
-            cnt = cnt.sum(axis=0)
-            counts = cnt if counts is None else counts + cnt
-    return x, out, counts
+    counts, exits)``: ``kv_out`` is ``kv`` with what ``body`` returned for
+    the visited runs, ``counts`` the expert layers' summed (held, all) or
+    None, ``exits`` None.
+
+    A LOOPED stack (``cfg.loops > 1``) runs the layers ``loops`` times in
+    ONE ``lax.scan`` whose body is one pass (the executable holds one pass
+    body, not ``loops`` copies): ``x`` and the KV stacks ride its carry
+    whole; ``body`` is handed the index of THIS pass of the layer, ``u *
+    layers + l`` within its run's stacks, which have ``loops * layers`` on
+    their leading axis; the final norm closes every pass and its output is
+    what the next starts from. Every pass's normed state is kept, the exit
+    gate reads each, and :func:`loop_exit` picks among them: ``x`` is then
+    the state of each position's exit pass, NORMED (:func:`_logits` does not
+    norm it again), and ``exits`` is ``(step (B, S) int32, p (loops, B, S))``.
+    No pass is skipped whatever the gate says."""
+    if cfg.loops == 1:
+        return (*_one_pass(cfg, params, x, kv, body, n_layers), None)
+    if n_layers is not None:
+        require_single_pass(cfg, "a depth prefix of the layers (n_layers)")
+    names = [name for r, (kind, _f, _n) in enumerate(cfg.runs())
+             for name in _kv_names(cfg, r, kind) if name and name in kv]
+    gate = cfg.exit_gate
+
+    def one(carry, u):
+        x, st = carry
+        with jax.named_scope("decoder.pass"):
+            x, new, cnt = _one_pass(cfg, params, x, st, body, None, u)
+        h = _final_norm(params, x, cfg)
+        z = None
+        if gate:
+            with jax.named_scope("decoder.exit_gate"):
+                z = jnp.einsum(
+                    "bsh,ho->bso", h, params["exit_w"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)[..., 0] \
+                    + params["exit_b"].astype(jnp.float32)[0]
+        x = h.astype(cfg.dtype)
+        if names:       # carried stacks, written in place
+            return (x, {n: new[n] for n in names}), (x, z, cnt, None)
+        return (x, st), (x, z, cnt, new)
+
+    (x, st), (states, z, cnt, made) = jax.lax.scan(
+        one, (x, {n: kv[n] for n in names}),
+        jnp.arange(cfg.loops, dtype=jnp.int32))
+    if names:
+        out = {**kv, **st}
+    else:
+        # a pass over whole sequences: each pass's keys and values were its
+        # outputs, (loops, layers, ...) -> the stacks' (loops * layers, ...)
+        out = {**kv, **{n: a.reshape(-1, *a.shape[2:])
+                        for n, a in made.items()}}
+    counts = None if cnt is None else cnt.sum(axis=0)
+    B, S = x.shape[:2]
+    if gate:
+        p, step = loop_exit(z, cfg.exit_threshold)
+        x = jnp.take_along_axis(states, step[None, :, :, None], axis=0)[0]
+    else:
+        step = jnp.full((B, S), cfg.loops - 1, jnp.int32)
+        p = jnp.zeros((cfg.loops, B, S), jnp.float32).at[-1].set(1.0)
+    return x, out, counts, (step, p)
 
 
 def _ring_cols(hi, R: int):
@@ -1322,8 +1453,9 @@ def _causal_bias(attention_mask, S: int, window: int = 0):
 def _self_attend(params, input_ids, attention_mask, cfg: DecoderConfig,
                  flash: bool, mesh, keep_kv: bool):
     """The causal forward over whole sequences that :func:`forward` and
-    :func:`prefill` share: ``(x, kv, counts)``, ``kv`` every layer's
-    in-sequence keys and values by stack (``keep_kv``), unpadded."""
+    :func:`prefill` share: ``(x, kv, counts, exits)``, ``kv`` every layer's
+    in-sequence keys and values by stack (``keep_kv``; every pass's, where
+    the stack is looped), unpadded."""
     B, S = input_ids.shape
     pos = jnp.clip(jnp.cumsum(attention_mask, axis=1) - 1, 0)
     x = _embed(params, input_ids, pos, cfg)
@@ -1366,9 +1498,17 @@ def forward(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
     tolerance; fully-masked query rows (left-padding) produce different
     hidden states (flash: zeros) that never reach live positions.
     ``mesh`` shard-maps the kernel over tp shards (heads split)."""
-    x, _kv, _counts = _self_attend(params, input_ids, attention_mask, cfg,
-                                   flash, mesh, False)
+    x, _kv, _counts, _exits = _self_attend(
+        params, input_ids, attention_mask, cfg, flash, mesh, False)
     return _logits(params, x, cfg)
+
+
+def exit_profile(params: dict, input_ids: jax.Array,
+                 attention_mask: jax.Array, cfg: DecoderConfig):
+    """What a looped stack's exit rule says of every position of whole
+    sequences: ``(step (B, S) int32, p (loops, B, S))`` (:func:`loop_exit`)."""
+    return _self_attend(params, input_ids, attention_mask, cfg, False, None,
+                        False)[3]
 
 
 def _prefill_kv(x, lp, cfg, kind: tuple = _GPT2_KIND, pos=None):
@@ -1394,8 +1534,8 @@ def prefill(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
     read, so decode streams see identical attention inputs."""
     B, S = input_ids.shape
     assert cache_len >= S
-    x, kv, _counts = _self_attend(params, input_ids, attention_mask, cfg,
-                                  flash, mesh, True)
+    x, kv, _counts, _exits = _self_attend(
+        params, input_ids, attention_mask, cfg, flash, mesh, True)
     pad = [(0, 0), (0, 0), (0, 0), (0, cache_len - S), (0, 0)]
     # (L, B, nkv, cache_len, hd)
     cache = {name: jnp.pad(a, pad) for name, a in kv.items()}
@@ -1436,7 +1576,8 @@ def decode_step(params: dict, token: jax.Array, step_pos: jax.Array,
                         bias[kind[0]], cfg, kind=kind, pos=pos)
         return x, {**st, "k": ks, "v": vs}, cnt
 
-    x, out, _counts = _scan_layers(cfg, params, x, cache, body, n_layers)
+    x, out, _counts, _exits = _scan_layers(cfg, params, x, cache, body,
+                                           n_layers)
     return _logits(params, x, cfg)[:, 0, :], out
 
 
@@ -1628,6 +1769,10 @@ def pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
     pool = {}
     for r, (kind, _first, n) in enumerate(cfg.runs()):
         kn, vn, ksn, vsn = _kv_names(cfg, r, kind)
+        # a looped stack keeps a cache layer for every PASS of every layer:
+        # pass u of the run's layer l at u * n + l (the arena's blocks too: a
+        # prefix hit restores every pass's rows)
+        n = cfg.loops * n
         if kind[0] == "latent":
             # the normed latent and the rotated shared key of every column:
             # kv_rank + rope values a token a layer, nothing per head
@@ -1663,6 +1808,12 @@ def pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
         # phase (row 0: prefill, row 1: decode), summed on the device by
         # every prefill and decode op; wraps mod 2**32
         pool["moe_counts"] = jnp.zeros((2, 2), jnp.uint32)
+    if cfg.loops > 1:
+        # the pass the exit rule took each slot's staged logits from, and
+        # the tokens sampled since the pool was built by that pass: summed
+        # on the device as each is sampled, wraps mod 2**32
+        pool["exit_step"] = jnp.zeros((n_slots,), jnp.int32)
+        pool["loop_exits"] = jnp.zeros((cfg.loops,), jnp.uint32)
     return pool
 
 
@@ -1678,6 +1829,20 @@ def _add_counts(pool: dict, out: dict, counts, decode: bool = False) -> dict:
     if counts is not None and "moe_counts" in pool:
         out["moe_counts"] = pool["moe_counts"].at[int(decode)].add(
             counts.astype(jnp.uint32))
+    return out
+
+
+def _stage_exits(pool: dict, out: dict, steps, slots) -> dict:
+    """Beside the logits a prefill stages for ``slots`` (a scalar, or (n,)
+    distinct ones): the pass the exit rule took each from, ``steps`` (n,)
+    (a looped stack's; None otherwise). The decode chunk counts it when the
+    token is sampled (``loop_exits``)."""
+    if steps is not None and "exit_step" in pool:
+        if jnp.ndim(slots) == 0:
+            out["exit_step"] = jax.lax.dynamic_update_slice(
+                pool["exit_step"], steps, (slots,))
+        else:
+            out["exit_step"] = pool["exit_step"].at[slots].set(steps)
     return out
 
 
@@ -2043,7 +2208,7 @@ def pool_admit(params: dict, ids: jax.Array, mask: jax.Array, pool: dict,
         )
     C = pool["slot_mask"].shape[1]
     S = ids.shape[1]
-    last_logits, cache, counts = _prefill_for_pool(
+    last_logits, cache, counts, exits = _prefill_for_pool(
         params, ids, mask, pool, cfg, flash, mesh)
     upd = {}
     if pool_quantized(pool):
@@ -2073,18 +2238,21 @@ def pool_admit(params: dict, ids: jax.Array, mask: jax.Array, pool: dict,
     write = jax.lax.dynamic_update_slice(
         pool["write"], jnp.full((1,), S, jnp.int32), (slot,)
     )
-    return _add_counts(pool, {
+    return _stage_exits(pool, _add_counts(pool, {
         **pool, **upd, "logits": logits,
-        "slot_mask": slot_mask, "pos": pos, "write": write}, counts)
+        "slot_mask": slot_mask, "pos": pos, "write": write}, counts),
+        exits, slot)
 
 
 def _prefill_for_pool(params, ids, mask, pool, cfg, flash, mesh):
     """One-shot prefill in the pool's own layout: ``(last_logits, cache,
-    counts)``; the full layers' keys and values padded to the slot row,
-    the window layers' LAST ring-length columns laid out as the ring holds
-    them (column ``c`` at index ``c`` mod its length)."""
+    counts, exits)``; the full layers' keys and values padded to the slot
+    row, the window layers' LAST ring-length columns laid out as the ring
+    holds them (column ``c`` at index ``c`` mod its length); ``exits`` the
+    exit pass of each prompt's first token (a looped stack's, else None)."""
     C, S = pool["slot_mask"].shape[1], ids.shape[1]
-    x, kv, counts = _self_attend(params, ids, mask, cfg, flash, mesh, True)
+    x, kv, counts, exits = _self_attend(params, ids, mask, cfg, flash, mesh,
+                                        True)
     R = pool_ring(pool)
     cache = {}
     for name, new in kv.items():
@@ -2097,7 +2265,8 @@ def _prefill_for_pool(params, ids, mask, pool, cfg, flash, mesh):
         else:
             cache[name] = jnp.roll(new[:, :, :, S - R:, :], (S - R) % R,
                                    axis=3)
-    return _logits(params, x[:, -1:, :], cfg)[:, 0, :], cache, counts
+    return (_logits(params, x[:, -1:, :], cfg)[:, 0, :], cache, counts,
+            None if exits is None else exits[0][:, -1])
 
 
 def pool_admit_batch(params: dict, ids: jax.Array, mask: jax.Array,
@@ -2122,7 +2291,7 @@ def pool_admit_batch(params: dict, ids: jax.Array, mask: jax.Array,
         )
     C = pool["slot_mask"].shape[1]
     M, S = ids.shape
-    last_logits, cache, counts = _prefill_for_pool(
+    last_logits, cache, counts, exits = _prefill_for_pool(
         params, ids, mask, pool, cfg, flash, mesh)
     upd = {}
     if pool_quantized(pool):
@@ -2140,9 +2309,10 @@ def pool_admit_batch(params: dict, ids: jax.Array, mask: jax.Array,
     n_prompt = jnp.sum(mask, axis=1).astype(jnp.int32)  # (M,)
     pos = pool["pos"].at[slots].set(n_prompt)
     write = pool["write"].at[slots].set(jnp.full((M,), S, jnp.int32))
-    return _add_counts(pool, {
+    return _stage_exits(pool, _add_counts(pool, {
         **pool, **upd, "logits": logits,
-        "slot_mask": slot_mask, "pos": pos, "write": write}, counts)
+        "slot_mask": slot_mask, "pos": pos, "write": write}, counts),
+        exits, slots)
 
 
 def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
@@ -2326,7 +2496,7 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
                         ctx_fn=full_fn, kind=kind, pos=p)
         return x, {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}, cnt
 
-    x, kv, counts = _scan_layers(cfg, params, x, pool, layer)
+    x, kv, counts, exits = _scan_layers(cfg, params, x, pool, layer)
     out = _add_counts(pool, {**kv, "slot_mask": slot_mask}, counts)
     if last:
         if last_col is None:
@@ -2334,6 +2504,11 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         else:
             H = x.shape[2]
             x_last = jax.lax.dynamic_slice(x, (0, last_col, 0), (1, 1, H))
+        if exits is not None:
+            # the exit pass of the prompt's first token
+            out = _stage_exits(pool, out, jax.lax.dynamic_slice_in_dim(
+                exits[0][0], T - 1 if last_col is None else last_col, 1),
+                slot)
         last_logits = _logits(params, x_last, cfg)[:, 0, :]
         out["logits"] = jax.lax.dynamic_update_slice(
             pool["logits"], last_logits, (slot, 0)
@@ -2604,9 +2779,15 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
     stacks = _kv_stacks(pool)
 
     def body(carry, _):
-        kv, logits, slot_mask, pos, write, counts, key = carry
+        kv, logits, slot_mask, pos, write, counts, key, looped = carry
         key, sub = jax.random.split(key)
         tok = sample(logits, sub)
+        if looped is not None:
+            # a token is sampled: count it under the pass its logits are of
+            exit_step, exited = looped
+            hit = (exit_step[:, None] == jnp.arange(cfg.loops)) \
+                & active[:, None]
+            exited = exited + hit.sum(axis=0).astype(jnp.uint32)
         w = jnp.minimum(write, C - 1)
         # the sampled token's own cache slot attends to itself
         slot_mask = jnp.where(
@@ -2634,24 +2815,30 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
             x, cnt = _block_lanes(x, lp, st, li, bias[kind[0]], cfg, kind, p)
             return x, st, cnt
 
-        x, kv, cnt = _scan_layers(cfg, params, x, kv, layer)
+        x, kv, cnt, exits = _scan_layers(cfg, params, x, kv, layer)
         if cnt is not None:
             counts = counts + cnt
+        if looped is not None:
+            looped = (jnp.where(active, exits[0][:, 0], exit_step), exited)
         new_logits = _logits(params, x, cfg)[:, 0, :]
         logits = jnp.where(active[:, None], new_logits, logits)
         return (kv, logits, slot_mask, pos + act_i, write + act_i, counts,
-                key), tok
+                key, looped), tok
 
-    (kv, logits, slot_mask, pos, write, counts, _), toks = \
+    (kv, logits, slot_mask, pos, write, counts, _, looped), toks = \
         jax.lax.scan(
             body,
             (stacks, pool["logits"], pool["slot_mask"], pool["pos"],
-             pool["write"], jnp.zeros((2,), jnp.uint32), key),
+             pool["write"], jnp.zeros((2,), jnp.uint32), key,
+             (pool["exit_step"], pool["loop_exits"]) if cfg.loops > 1
+             else None),
             None,
             length=n_steps,
         )
     out = {**pool, **kv, "logits": logits,
            "slot_mask": slot_mask, "pos": pos, "write": write}
+    if looped is not None:
+        out["exit_step"], out["loop_exits"] = looped
     return _add_counts(pool, out, counts, decode=True), toks
 
 
@@ -2852,7 +3039,8 @@ def _draft_scan(params, cfg: DecoderConfig, kv: dict, slot_mask,
             x, cnt = _block_lanes(x, lp, st, li, bias[kind[0]], cfg, kind, p)
             return x, st, cnt
 
-        x, kv, _cnt = _scan_layers(cfg, params, x, kv, layer, n_layers)
+        x, kv, _cnt, _exits = _scan_layers(cfg, params, x, kv, layer,
+                                           n_layers)
         nxt = jnp.argmax(_logits(params, x, cfg)[:, 0, :], axis=-1
                          ).astype(jnp.int32)
         return (kv, nxt), nxt
@@ -2872,6 +3060,7 @@ def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
     draft-quality probing; the serving path uses the fused cycle.
     Paged pools gather-run-scatter (see :func:`pool_admit`); drafting
     never writes, so only the gather side is needed."""
+    require_single_pass(cfg, "spec_decode")
     if pool_paged(pool):
         return pool_decode_draft(
             params, _paged_gather(pool), active, cfg,
@@ -2920,6 +3109,7 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
     Paged pools gather-run-scatter (see :func:`pool_admit`); the paged
     kernel does not apply to the spec path — verify scores ``n_spec+1``
     query positions, while the kernel is single-query decode."""
+    require_single_pass(cfg, "spec_decode")
     if pool_paged(pool):
         view, toks, n_emit = pool_decode_spec(
             params, _paged_gather(pool), active, cfg, n_cycles,
@@ -2980,7 +3170,7 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
             x, cnt = _block_lanes(x, lp, st, li, bias[kind[0]], cfg, kind, p)
             return x, st, cnt
 
-        x, kv, cnt = _scan_layers(cfg, params, x, kv, vlayer)
+        x, kv, cnt, _exits = _scan_layers(cfg, params, x, kv, vlayer)
         if cnt is not None:
             counts = counts + cnt
         out_logits = _logits(params, x, cfg)  # (B, k+1, V) f32

@@ -829,9 +829,16 @@ class _ContinuousServer:
             pathway_config.spec_decode
             if spec_decode is None else bool(spec_decode)
         )
+        if spec_decode and cfg.loops > 1:
+            # ASKED for: the draft is a depth prefix of the layers, which a
+            # stack run several times does not have. Refused by type, as
+            # the mechanisms below are; the flag's default resolves to
+            # plain chunks for such a stack, as it does for one layer
+            decoder_mod.require_single_pass(cfg, "spec_decode")
         self.spec_decode = bool(
             want_spec and float(temperature) == 0.0
             and top_k is None and top_p is None and cfg.layers >= 2
+            and cfg.loops == 1
         )
         d = (
             pathway_config.spec_draft_layers
@@ -1249,8 +1256,14 @@ class _ContinuousServer:
             "t2_hit_requests": 0, "t2_promoted_blocks": 0,
             "prefix_declined": 0,
         }
-        self._moe_seen = None       # the device's (phase, held|all) totals
-        self._has_moe = cfg.moe is not None
+        # the device's running totals as last drained, by pool counter
+        # ((phase, held|all) assignments; tokens by exit pass)
+        self._device_seen: dict = {}
+        logger.info(
+            "decoder server pool: %d slots x %d columns x %d cache layers "
+            "(%d layers x %d passes) = %d bytes",
+            n_slots, self.cache_len, cfg.loops * cfg.layers, cfg.layers,
+            cfg.loops, decoder_mod.pool_bytes(self.pool))
         # in-flight chunk records, oldest first; an attribute (not a loop
         # local) so the failure sweep can fail eagerly-freed requests
         # whose tokens never drained
@@ -1783,17 +1796,15 @@ class _ContinuousServer:
             temp, tk, tp = self._temperature, self._top_k, self._top_p
             pk, msh = self.paged_kernel, self.mesh
 
-            moe = self._has_moe
-
             def chunk(params_, pool, active, key):
                 pool, toks = D.pool_decode_chunk(
                     params_, pool, active, key, cfgc, steps,
                     temperature=temp, top_k=tk, top_p=tp,
                     paged_kernel=pk, mesh=msh,
                 )
-                # the expert counters ride out with the tokens (the pool
+                # the device's counters ride out with the tokens (the pool
                 # itself is donated to the next dispatch): no extra sync
-                return pool, toks, (pool["moe_counts"] + 0 if moe else None)
+                return pool, toks, self._device_counts(pool)
 
             fn = jax.jit(chunk, donate_argnums=(1,))
             self._chunk_fns[steps] = fn
@@ -1807,19 +1818,25 @@ class _ContinuousServer:
             D, cfgc = self._D, self.cfg
             dl, kk = self.spec_draft_layers, self.spec_k
 
-            moe = self._has_moe
-
             def spec(params_, pool, active):
                 pool, toks, n_emit = D.pool_decode_spec(
                     params_, pool, active, cfgc, n_cycles,
                     draft_layers=dl, n_spec=kk,
                 )
-                return pool, toks, n_emit, (
-                    pool["moe_counts"] + 0 if moe else None)
+                return pool, toks, n_emit, self._device_counts(pool)
 
             fn = jax.jit(spec, donate_argnums=(1,))
             self._spec_fns[n_cycles] = fn
         return fn
+
+    @staticmethod
+    def _device_counts(pool: dict):
+        """Copies of the pool's running counters (the routers' assignments,
+        a looped stack's tokens by exit pass) for a dispatch to hand out
+        beside its tokens; None where the pool keeps neither."""
+        out = {k: pool[k] + 0 for k in ("moe_counts", "loop_exits")
+               if k in pool}
+        return out or None
 
     def spec_acceptance(self) -> float:
         """Drained draft-token acceptance rate of this server (0.0 before
@@ -2637,6 +2654,14 @@ class _ContinuousServer:
         self.stats["admitted"] += 1
         self._update_fragmentation()
 
+    def _loop_account(self, phase: str, tokens: int) -> None:
+        """``loop_passes{phase, pass}``: every pass of a looped stack ran
+        for ``tokens`` tokens that were real (host arithmetic, no sync)."""
+        if self.cfg.loops > 1 and tokens:
+            from pathway_tpu.engine import probes
+
+            probes.record_loop_passes(phase, tokens, self.cfg.loops)
+
     def _prefill_piece(self, slot: int, active) -> None:
         """Dispatch one pending prefill piece for ``slot`` (a method so
         supervised serving can rewind just this slot on a fault)."""
@@ -2650,7 +2675,8 @@ class _ContinuousServer:
         last = not pieces
         lc = meta.get("last_col") if (meta and last) else None
         with tracing.region("pw.decode.prefill", tokens=int(p_ids.shape[1]),
-                            piece=int(off // p_ids.shape[1])):
+                            piece=int(off // p_ids.shape[1]),
+                            passes=self.cfg.loops):
             if lc is None:
                 self.pool = self._prefill_fn(p_ids.shape[1], first, last)(
                     self.params, p_ids, p_mask, p_pos, self.pool,
@@ -2671,6 +2697,7 @@ class _ContinuousServer:
             # arithmetic on the piece's offset and the row's live columns
             from pathway_tpu.engine import probes
 
+            self._loop_account("prefill", int(live.size))
             blocks = self._D.prefill_blocks_visited(
                 self.cfg, int(p_ids.shape[1]), self.cache_len,
                 self._D.pool_ring(self.pool), int(off),
@@ -2768,7 +2795,8 @@ class _ContinuousServer:
                 n_cycles = max(1, steps // (self.spec_k + 1))
                 self._last_dispatch_steps = n_cycles
                 with tracing.region("pw.decode.chunk", steps=n_cycles,
-                                    lanes=int(lanes.sum()), spec=1):
+                                    lanes=int(lanes.sum()), spec=1,
+                                    passes=self.cfg.loops):
                     self.pool, toks_dev, emit_dev, counts_dev = \
                         self._spec_fn_for(n_cycles)(
                             self.params, self.pool, lanes)
@@ -2780,7 +2808,8 @@ class _ContinuousServer:
                 self._last_dispatch_steps = steps
                 key = jax.random.fold_in(self._key, self._ticks)
                 with tracing.region("pw.decode.chunk", steps=steps,
-                                    lanes=int(lanes.sum()), spec=0):
+                                    lanes=int(lanes.sum()), spec=0,
+                                    passes=self.cfg.loops):
                     self.pool, toks_dev, counts_dev = self._chunk_fn_for(
                         steps)(self.params, self.pool, lanes, key)
                 payload = toks_dev
@@ -2793,8 +2822,8 @@ class _ContinuousServer:
                 toks_dev.copy_to_host_async()
                 if emit_dev is not None:
                     emit_dev.copy_to_host_async()
-                if counts_dev is not None:
-                    counts_dev.copy_to_host_async()
+                for dev in (counts_dev or {}).values():
+                    dev.copy_to_host_async()
             except Exception:  # noqa: BLE001 - platform-optional
                 pass
             self.stats["chunks"] += 1
@@ -2826,6 +2855,7 @@ class _ContinuousServer:
             # these tokens drain the slot may have been freed and
             # re-admitted to a different request
             inflight.append((payload, lanes, list(self.slots), counts_dev))
+            useful0 = self.stats["steps"]
             for slot in np.nonzero(active)[0]:
                 req = self.slots[slot]
                 if req is None:
@@ -2855,6 +2885,7 @@ class _ContinuousServer:
                     self._release_slot_kv(slot)
                     with self.lock:
                         self.free.append(int(slot))
+            self._loop_account("decode", self.stats["steps"] - useful0)
             return True
 
         def admit_direct(direct) -> None:
@@ -2986,6 +3017,7 @@ class _ContinuousServer:
                     self._isolate_admission_failure(slot, req, exc, active)
             admit_direct(direct)
             for slot, _ids_d, mask_d, _s_d in direct:
+                self._loop_account("prefill", int(mask_d.sum()))
                 req_d = self.slots[slot]
                 if req_d is not None:
                     req_d.span.event("prefill", tokens=int(mask_d.sum()))
@@ -3052,8 +3084,10 @@ class _ContinuousServer:
         import numpy as np
 
         payload, was_active, snap_slots, counts_dev = prev
-        if counts_dev is not None:
-            self._moe_account(np.asarray(counts_dev))
+        for name, account in (("moe_counts", self._moe_account),
+                              ("loop_exits", self._exit_account)):
+            if name in (counts_dev or {}):
+                account(self._device_delta(name, counts_dev[name]))
         spec_rec = isinstance(payload, tuple)
         if spec_rec:
             # (n_cycles, n_slots, spec_k+1) proposed tokens and the
@@ -3176,17 +3210,22 @@ class _ContinuousServer:
 
             record_spec_many(**acc)
 
-    def _moe_account(self, totals) -> None:
-        """``moe_assignments{held=0|1, phase=}`` from the device's running
-        (phase, held | all) totals, which wrap mod 2**32: the difference
-        between two drains is small."""
+    def _device_delta(self, name: str, totals):
+        """What one of the pool's running counters (which wrap mod 2**32)
+        gained since it was last drained: the difference between two drains
+        is small."""
+        import numpy as np
+
+        totals = np.asarray(totals).astype("uint32")
+        seen = self._device_seen.get(name, totals * 0)
+        self._device_seen[name] = totals
+        return (totals - seen).astype("int64")      # uint32: wraps right
+
+    def _moe_account(self, delta) -> None:
+        """``moe_assignments{held=0|1, phase=}`` from what the device's
+        (phase, held | all) totals gained."""
         from pathway_tpu.engine import probes
 
-        totals = totals.astype("uint32")
-        seen = self._moe_seen if self._moe_seen is not None \
-            else totals * 0
-        delta = (totals - seen).astype("int64")     # uint32: wraps right
-        self._moe_seen = totals
         for row, phase in enumerate(("prefill", "decode")):
             held, every = int(delta[row, 0]), int(delta[row, 1])
             if every:
@@ -3194,6 +3233,15 @@ class _ContinuousServer:
                     "moe_assignments", held, held=1, phase=phase)
                 probes.REGISTRY.counter_add(
                     "moe_assignments", every - held, held=0, phase=phase)
+
+    def _exit_account(self, delta) -> None:
+        """``loop_exit_step{step=1..loops}`` from what the device's totals
+        of sampled tokens, by the pass the exit rule took their logits
+        from, gained."""
+        from pathway_tpu.engine import probes
+
+        probes.record_loop_exits(
+            {u + 1: int(n) for u, n in enumerate(delta) if n})
 
     def shutdown(self, timeout: float = 10.0):
         self._stop = True

@@ -15,8 +15,10 @@ with ``_scan_layers`` — the same bodies, layer by layer, no ``lax.scan``:
 
 For a toy of each layout: GPT-2's single run (dense, int8, paged, on the CPU
 mesh), afmoe's four runs (one of them two window layers, rings that wrap),
-``deepseek_v2``'s two latent runs; through the prefill pieces, the decode chunk
-and the speculative cycle with a draft prefix that ends INSIDE a run.
+``deepseek_v2``'s two latent runs, ``ouro``'s stack run four times (PR 34: the
+loop over passes by plain loops too, tokens leaving at different passes);
+through the prefill pieces, the decode chunk and the speculative cycle with a
+draft prefix that ends INSIDE a run (of a looped stack: its typed refusal).
 """
 
 import contextlib
@@ -74,14 +76,17 @@ def _loops(counted: bool):
     """The contract of ``decoder._scan_layers`` by a counted loop or by
     plain ones: for each run of like layers, for each of its first layers,
     ``body`` on that layer's leaves, the run's stacks handed from one layer
-    to the next."""
-    def layers(cfg, params, x, kv, body, n_layers=None):
+    to the next. A LOOPED stack (PR 34): that, once a pass, ``body`` handed
+    the pass's own index in the stacks (``u`` times the run's layers
+    further down), the final norm closing every pass, the gate reading each
+    pass's normed state and the exit rule picking among them."""
+    def one_pass(cfg, params, x, kv, body, n_layers, u):
         out, counts = dict(kv), None
         for kind, n, lp, names, stacks in _runs(cfg, params, kv, n_layers):
-            def one(layer, carry, kind=kind, lp=lp):
+            def one(layer, carry, kind=kind, lp=lp, base=u * n):
                 x, st, c = carry
-                x, st, cnt = body(x, _layer_leaves(lp, layer), st, layer,
-                                  kind)
+                x, st, cnt = body(x, _layer_leaves(lp, layer), st,
+                                  layer + base if u else layer, kind)
                 return x, st, (c if cnt is None else c + cnt)
 
             carry = (x, stacks, jnp.zeros((2,), jnp.uint32))
@@ -97,6 +102,23 @@ def _loops(counted: bool):
                 if name is not None and stacks[s] is not None:
                     out[name] = stacks[s]
         return x, out, counts
+
+    def layers(cfg, params, x, kv, body, n_layers=None):
+        if cfg.loops == 1:
+            return (*one_pass(cfg, params, x, kv, body, n_layers, 0), None)
+        assert n_layers is None and cfg.exit_gate and cfg.moe is None
+        states, z = [], []
+        for u in range(cfg.loops):
+            x, kv, _counts = one_pass(cfg, params, x, kv, body, None, u)
+            h = D._rms(x, params["ln_f_scale"], cfg.layer_norm_eps)
+            z.append(jnp.einsum("bsh,ho->bs", h, params["exit_w"],
+                                precision="highest") + params["exit_b"][0])
+            x = h.astype(cfg.dtype)
+            states.append(x)
+        p, step = D.loop_exit(jnp.stack(z), cfg.exit_threshold)
+        x = jnp.take_along_axis(jnp.stack(states),
+                                step[None, :, :, None], axis=0)[0]
+        return x, kv, None, (step, p)
 
     return layers
 
@@ -161,10 +183,26 @@ def _deepseek():
     return cfg, p32, pool, 2            # ... inside the expert layers' run
 
 
+def _ouro():
+    """Three layers run four times, a threshold that lets tokens leave at
+    different passes: twelve cache layers in the one pair of stacks."""
+    from tests import test_looped_decoder as LOOPED
+
+    layout = M.resolve(M.load_manifest(), "layouts", "ouro")
+    params = W.make_params(7, W.STREAM_DECODER,
+                           layout.weight_spec(LOOPED.MODEL, "decoder"))
+    cfg = dataclasses.replace(layout.program_config(LOOPED.MODEL),
+                              dtype=jnp.float32, exit_threshold=0.5)
+    p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    pool = D.pool_init(p32, cfg, SLOTS, CACHE_LEN)
+    assert pool["k"].shape[0] == 12
+    return cfg, p32, pool, None         # no depth prefix is a draft of it
+
+
 LAYOUTS = {
     "gpt2": lambda: _gpt2("dense"), "gpt2-int8": lambda: _gpt2("int8"),
     "gpt2-paged": lambda: _gpt2("paged"), "gpt2-mesh": lambda: _gpt2("mesh"),
-    "afmoe": _afmoe, "deepseek_v2": _deepseek,
+    "afmoe": _afmoe, "deepseek_v2": _deepseek, "ouro": _ouro,
 }
 
 
@@ -197,6 +235,11 @@ def _run(name, op):
     if op == "decode":
         return jax.jit(lambda p, pl: D.pool_decode_chunk(
             p, pl, lanes, jax.random.PRNGKey(0), cfg, 5))(params, pool)
+    if draft is None:
+        with pytest.raises(D.UnsupportedForLayout, match="spec_decode"):
+            D.pool_decode_spec(params, pool, lanes, cfg, 3, draft_layers=1,
+                               n_spec=3)
+        return pool, None
     pool, toks, n_emit = jax.jit(lambda p, pl: D.pool_decode_spec(
         p, pl, lanes, cfg, 3, draft_layers=draft, n_spec=3))(params, pool)
     return pool, (toks, n_emit)

@@ -225,6 +225,84 @@ def test_the_latent_blocks_scopes_and_counters_are_what_the_metric_reads(
     assert "mla.expand" not in step      # a step never expands a row
 
 
+PASSES_METRIC = "answer.loop_passes_per_token"
+
+
+@pytest.fixture(scope="module")
+def looped_run(tmp_path_factory):
+    """One request through a continuous server whose stack runs three times
+    (chunked prefill, then decode), inside a profiler session: the server's
+    ``stats`` once it is answered, and the session's ``pw.`` regions."""
+    from pathway_tpu.models import decoder as D
+    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
+
+    cfg = D.DecoderConfig(
+        vocab_size=64, hidden=16, layers=2, heads=2, intermediate=32,
+        max_position=64, dtype=jnp.float32, norm="rmsnorm",
+        sandwich_norm=True, positions="rotary", mlp="swiglu", bias=False,
+        tied_head=False, loops=3, exit_gate=True)
+    probes.REGISTRY.remove("loop_passes", "loop_exit_step")
+    chat = TPUDecoderChat(
+        params=D.init_params(jax.random.PRNGKey(0), cfg), cfg=cfg,
+        tokenizer=_Ids(), max_new_tokens=4, temperature=0.0,
+        max_prompt_tokens=16, continuous=True, n_slots=2, chunk_steps=4,
+        prefill_chunk=8)
+    trace_dir = str(tmp_path_factory.mktemp("looped"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        request = chat._server.submit(list(range(1, 12)), 4)
+        assert request.done.wait(timeout=300)
+        stats = dict(chat._server.stats)
+    finally:
+        jax.profiler.stop_trace()
+        chat.close()
+    regions = program_trace.load_host_regions(max(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))))
+    return stats, regions
+
+
+def test_the_loops_counters_and_field_are_what_the_benchmark_reads(
+        looped_run):
+    """Both counters under the names and labels the metric's file and
+    ``/metrics`` give them, the ``passes`` field on the two regions a
+    slice's reader would take it from, and the new metric their ratio: 11
+    prompt tokens and 4 emitted through three passes each."""
+    stats, regions = looped_run
+    params = METRICS[PASSES_METRIC]["params"]
+    assert params == {"family": "loop_passes", "label": "phase",
+                      "value": "decode", "per": "pass"}
+    assert probes.METRIC_FAMILIES["loop_passes"][:2] == ("counter", "phase")
+    assert probes.METRIC_FAMILIES["loop_exit_step"][:2] == ("counter",
+                                                            "step")
+    assert stats["prefill_chunks"] == 2 and stats["steps"] == 4
+    assert probes.REGISTRY.labelled("loop_passes", "phase") == {
+        "prefill": 3.0 * 11, "decode": 3.0 * 4}
+    assert probes.REGISTRY.labelled("loop_passes", "pass") == {
+        "1": 15.0, "2": 15.0, "3": 15.0}
+    assert probes.REGISTRY.labelled("loop_exit_step", "step") == {"3": 4.0}
+    assert _reader(PASSES_METRIC)({}, params) == 3.0
+    for name in ("pw.decode.prefill", "pw.decode.chunk"):
+        found = [stats_ for _th, n, _s, _d, stats_ in regions if n == name]
+        assert found and all(int(s["passes"]) == 3 for s in found), name
+    chunk = [s for _th, n, _s, _d, s in regions if n == "pw.decode.chunk"]
+    assert sum(int(s["steps"]) for s in chunk) == 4
+    # a stack run once says so, and counts nothing
+    exported = "\n".join(
+        line for line in _metrics_text().splitlines() if "loop_" in line)
+    assert 'pathway_tpu_loop_passes_total{pass="1",phase="decode"} 4' \
+        in exported
+    assert 'pathway_tpu_loop_exit_step_total{step="3"} 4' in exported
+
+
+def _metrics_text():
+    from pathway_tpu.internals.http_server import registry_text
+
+    return registry_text()
+
+
 STATS_FIELDS = sorted({
     value[len("decoder_"):]
     for m in METRICS.values() for value in m.get("params", {}).values()
@@ -272,7 +350,7 @@ def search_run():
 @pytest.mark.parametrize("name,params", COUNTER_METRICS,
                          ids=_ids(COUNTER_METRICS))
 def test_a_counter_metric_reads_what_the_program_registered(
-        name, params, decoder_run, search_run, latent_run):
+        name, params, decoder_run, search_run, latent_run, looped_run):
     for family, label, value in _series(params):
         assert probes.METRIC_FAMILIES[family][1] == label
         assert probes.REGISTRY.labelled(family, label).get(str(value))

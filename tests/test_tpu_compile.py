@@ -591,6 +591,58 @@ def test_deepseek_width_decode_chunk(shape, deepseek):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 13 << 30
 
 
+def test_ouro_width_piece_and_chunk(shape):
+    """The looped cell (``ouro_rag_reason_closed4``: 4 slots of 656 columns,
+    48 layers run four times, 192 cache layers of 2.06 GB an array): a piece
+    of 256 and a chunk of 16 steps hold ONE pass body (two loops a piece,
+    pass and layer; three a chunk), a piece writes every pass's rows in
+    place and keeps 6.6 MB of temporaries, a chunk keeps the pool once more
+    in the layout its loop prefers (4 copies at entry and exit, nothing
+    whole inside a step: ``PERF.md`` section 4, ROADMAP S10), and either
+    fits the chip beside 9.46 GB of weights and pool."""
+    import re
+
+    from pathway_tpu.models import decoder as D
+
+    cfg, params, pool = _answer_cell(shape, "ouro-2.6b-rag.json", "ouro", 4,
+                                     656)
+    assert _pool_stacks(pool) == [(192, 4, 16, 656, 128)] * 2
+    piece = shape((1, 256), I32)
+    c = _compile(
+        lambda p, i, m, ps, pl, s, st, n: D.pool_prefill_chunk(
+            p, i, m, ps, pl, s, st, n, cfg, first=False, last=True),
+        params, piece, piece, piece, pool,
+        shape((), I32), shape((), I32), shape((1,), I32),
+        donate_argnums=(4,),
+    )
+    text = c.as_text()
+    ops = _whole_array_ops(text, _pool_stacks(pool))
+    assert len(ops) == 2 and {kind for _w, _n, kind in ops} == {"in place"}, \
+        ops
+    assert len(re.findall(r"\bwhile\(", text)) == 2
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 7_300_000        # 6,564,864 + a tenth
+    assert m.argument_size_in_bytes == pytest.approx(9_464_744_960, rel=1e-3)
+    c = _compile(
+        lambda p, pl, a, k: D.pool_decode_chunk(p, pl, a, k, cfg,
+                                                CHUNK_STEPS),
+        params, pool, shape((4,), jnp.bool_), shape((2,), jnp.uint32),
+        donate_argnums=(1,),
+    )
+    text = c.as_text()
+    ops = _whole_array_ops(text, _pool_stacks(pool))
+    assert [kind for where, _n, kind in ops if where == "entry"] \
+        == ["copy"] * 4, ops
+    assert {kind for where, _n, kind in ops if where == "loop"} \
+        == {"in place"}, ops
+    assert len(re.findall(r"\bwhile\(", text)) == 3
+    for scope in ("decoder.pass", "decoder.exit_gate", "decoder.attn.full"):
+        assert scope in text
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 4_541_000_000    # 4,127,930,880 + a tenth
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14 << 30
+
+
 def test_flash_chunk_attn_paged_kernel(shape):
     from pathway_tpu.models.flash_attention import flash_chunk_attn_paged
 
