@@ -164,6 +164,11 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
         "attention read of a prefill piece visited (visited=1) or skipped "
         "because no query of the piece can see a key in them (visited=0), "
         "by kind of layer (full | window | latent), summed over its layers"),
+    "prefill_group_lanes": (
+        "counter", "width", "Prefill pieces dispatched, by the width of the "
+        "dispatch that carried them (width=1: a piece alone; G: the pieces "
+        "of G prompts that share one read of the weights, "
+        "models.decoder.prefill_group_width); host arithmetic, no sync"),
     "latent_rows_expanded": (
         "counter", "phase", "Latent cache rows turned into per-head keys "
         "and values for the time of one attention read (phase=prefill: the "
@@ -584,6 +589,12 @@ def record_prefill_attn_blocks(counts: dict) -> None:
     how much of a long row the blockwise read touches."""
     REGISTRY.counter_add_many(
         "prefill_attn_blocks", ("layer", "visited"), counts)
+
+
+def record_prefill_group_lanes(width: int) -> None:
+    """One prefill dispatch of ``width`` pieces (``_ContinuousServer.
+    _prefill_lanes``): ``width`` more under its width."""
+    REGISTRY.counter_add("prefill_group_lanes", width, width=width)
 
 
 def record_latent_rows_expanded(rows: int, phase: str = "prefill") -> None:

@@ -982,13 +982,20 @@ def _block(x, lp, k, v, mask_bias, cfg: DecoderConfig, k_scale=None,
     byte-identical. Returns ``(x, counts)`` (:func:`_block_finish`)."""
     q, _k_new, _v_new = _block_qkv(x, lp, cfg, kind, pos)
     with jax.named_scope("decoder.attn." + kind[0]):
-        if ctx_fn is not None:
-            ctx = ctx_fn(q, k, v, k_scale, v_scale).astype(cfg.dtype)
-        elif kind[0] == "latent":
-            ctx = _latent_ctx(q, k, lp, mask_bias, cfg)
-        else:
-            ctx = _attn_ctx(q, k, v, mask_bias, cfg, k_scale, v_scale)
+        ctx = _attend(q, k, v, mask_bias, cfg, k_scale, v_scale, ctx_fn,
+                      kind, lp)
     return _block_finish(x, lp, ctx, cfg, kind)
+
+
+def _attend(q, k, v, mask_bias, cfg: DecoderConfig, k_scale, v_scale,
+            ctx_fn, kind: tuple, lp):
+    """:func:`_block`'s attention read of its queries ``q`` over ``k``/``v``
+    (B, nh, Sq, hd): ``ctx_fn``'s, a latent layer's or the dense one."""
+    if ctx_fn is not None:
+        return ctx_fn(q, k, v, k_scale, v_scale).astype(cfg.dtype)
+    if kind[0] == "latent":
+        return _latent_ctx(q, k, lp, mask_bias, cfg)
+    return _attn_ctx(q, k, v, mask_bias, cfg, k_scale, v_scale)
 
 
 def _flash_self_attn_fn(mesh):
@@ -1034,6 +1041,53 @@ def blockwise_chunk_read(heads: int, T: int, columns: int) -> bool:
     shapes in hand: ``T`` queries of ``heads`` heads against a row of
     ``columns`` keys (:func:`pool_prefill_chunk`)."""
     return heads * T * columns * 4 > _DENSE_SCORE_BYTES
+
+
+# The PRACTICAL ridge of a v5e, in FLOP a byte of weights: where a prefill
+# piece's matmuls take as long as reading its weights, at the shares of the
+# peaks this repo's traces reach — the MLP at 77 % of 197 TFLOP/s, the weight
+# reads at 87-89 % of 819 GB/s (PERF.md section 5, PR 34): 0.75 x 197e12 /
+# (0.88 x 819e9) = 205. Below it a piece waits on its weights, above it on its
+# arithmetic.
+PREFILL_RIDGE = 0.75 * 197e12 / (0.88 * 819e9)
+
+
+def prefill_intensity(cfg: DecoderConfig, params: dict, T: int) -> float:
+    """A prefill piece of ``T`` tokens: its matmul FLOPs over the bytes of
+    weights it reads, from the parameter tree itself. The layers' leaves
+    only: a token multiplies every dense leaf in full and a routed expert's
+    by the picks that land on the experts held (``per_token x held /
+    experts``, routing even); the piece reads every leaf. A looped stack
+    multiplies and reads them once a pass, so ``loops`` cancels. The
+    embedding (T rows gathered) and the head (one token, at a prompt's last
+    piece) are neither."""
+    mult = nbytes = 0.0
+    for run in _run_stacks(params, cfg):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(run):
+            size = math.prod(leaf.shape)
+            nbytes += size * jnp.dtype(leaf.dtype).itemsize
+            if getattr(path[-1], "key", None) in _EXPERT_LEAVES:
+                size *= cfg.moe.per_token / cfg.moe.experts
+            mult += size
+    return 2 * T * mult / nbytes
+
+
+def prefill_group_width(cfg: DecoderConfig, params: dict, T: int,
+                        pool: dict, mesh=None) -> int:
+    """THE rule for how many waiting prompts' pieces go to the device as ONE
+    dispatch (:func:`pool_prefill_chunk` with ``G`` lanes): enough tokens a
+    weight read to reach the ridge and no more. ``G`` is the smallest power
+    of two at or over ``PREFILL_RIDGE / prefill_intensity`` — 1 where a
+    piece alone reaches the ridge, since past it more tokens a read no
+    longer shorten a piece that waits on its arithmetic — and never more
+    than the pool's slots. 1 for a paged pool and on a serving mesh."""
+    if mesh is not None or pool_paged(pool):
+        return 1
+    need = PREFILL_RIDGE / prefill_intensity(cfg, params, T)
+    if need <= 1:
+        return 1
+    return min(1 << (math.ceil(need) - 1).bit_length(),
+               pool["slot_mask"].shape[0])
 
 
 def _flash_chunk_attn_fn(mesh, quant, window=0):
@@ -2340,6 +2394,10 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
     written by pieces <= i, so the union of pieces is elementwise
     identical to :func:`pool_admit`'s one-shot prefill. jit per (piece
     length, first, last); ``slot``/``start``/``n_prompt`` are traced.
+    Pieces of ``G`` prompts, ``ids``/``mask``/``pos`` shaped (G, T) and
+    every other argument a traced (G,) vector ``first`` and ``last``
+    included, go as ONE dispatch that reads the weights once for all of
+    them (:func:`_prefill_group`).
 
     ``last_col`` (traced scalar, only meaningful with ``last``) names
     the piece column holding the prompt's REAL last token. The default
@@ -2376,6 +2434,10 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
                 flash=flash, mesh=mesh,
             )
         )
+    if ids.shape[0] > 1:
+        return _prefill_group(params, ids, mask, pos, pool, slot, start,
+                              n_prompt, cfg, first, last, last_col, flash,
+                              mesh)
     C = pool["slot_mask"].shape[1]
     T = ids.shape[1]
     R, W = pool_ring(pool), cfg.sliding_window
@@ -2392,68 +2454,8 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         pool["slot_mask"], row_mask, (slot, 0)
     )
     quant = pool_quantized(pool)
-    # each kind of layer reads blockwise or dense by ONE rule on its shapes
-    # (``flash`` forces the kernel, as it always has): the dense read stays
-    # the small rows' and the decode step's, and the tests' reference
-    full_fn = latent_fn = window_fn = mask_bias = ring_bias = None
-    qcol = (start + jnp.arange(T))[None, :]             # (1, T)
-    if flash or blockwise_chunk_read(cfg.heads, T, C):
-        # the kernel builds the live-&-causal predicate from the column
-        # each key row holds, with int8 dequant fused into the tile read:
-        # no (1, 1, T, C) bias, no f32 KV row, no scores in HBM
-        kcol = jnp.where(row_mask > 0, jnp.arange(C, dtype=jnp.int32), -1)
-        if not cfg.latent:
-            attn_c = _flash_chunk_attn_fn(mesh, quant)
-            full_fn = lambda q, kr, vr, ksr, vsr: \
-                attn_c(q, kr, vr, ksr, vsr, kcol, start)
-        else:
-            from pathway_tpu.models import flash_attention as _fa
-
-            # the same walk over the slot's LATENT row: a block becomes a
-            # head's keys and values inside the kernel, for that step only.
-            # The kernel takes the row with its COLUMNS minor, (width,
-            # columns): keys transposed are what a score multiplies, and it
-            # is how the chip's compiler keeps an array whose rows are 4.5
-            # lane tiles wide, so the row goes in as it lies
-            latent_fn = lambda q, c_row, w_ukv: \
-                _fa.flash_chunk_attn_latent(
-                    q[0], c_row[0, 0].T, w_ukv, kcol[0], start,
-                    nope=cfg.nope_size, sm_scale=attn_scale(cfg))[None]
-    else:
-        # a piece query at cache index start+j attends every LIVE index
-        # of this row <= start+j (earlier pieces + its own causal
-        # prefix) — elementwise the same predicate as prefill()'s
-        # causal & pad mask
-        idxs = jnp.arange(C)[None, None, None, :]
-        allowed = (row_mask[:, None, None, :] > 0) \
-            & (idxs <= qcol[:, None, :, None])
-        mask_bias = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
-    if R:
-        # a window layer reads its ring as the EARLIER pieces left it
-        # (columns < start) beside this piece's own keys, then writes the
-        # piece in: the ring needs no room for the piece itself
-        if T > R:
-            raise ValueError(f"a prefill piece of {T} columns does not "
-                             f"fit a window layer's ring of {R}")
-        cols = _ring_cols(jnp.reshape(start - 1, (1,)), R)      # (1, R)
-        ring_live = _live_at(row_mask, cols)                    # (1, R)
-        if flash or blockwise_chunk_read(cfg.heads, T, R + T):
-            attn_w = _flash_chunk_attn_fn(mesh, quant, W)
-            kcol_w = jnp.concatenate([
-                jnp.where(ring_live, cols, -1),
-                jnp.where(mask > 0, qcol, -1)], axis=1).astype(jnp.int32)
-            window_fn = lambda q, kr, vr, ksr, vsr: \
-                attn_w(q, kr, vr, ksr, vsr, kcol_w, start)
-        else:
-            live = jnp.broadcast_to(ring_live[:, None, :], (1, T, R))
-            old = _ring_bias(cols, live, qcol, W)           # (1, 1, T, R)
-            j = jnp.arange(T)
-            own = (mask[:, None, :] > 0) \
-                & (j[None, None, :] <= j[None, :, None]) \
-                & (j[None, :, None] - j[None, None, :] < W)
-            ring_bias = jnp.concatenate(
-                [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]],
-                axis=-1)
+    full_fn, latent_fn, window_fn, mask_bias, ring_bias = _piece_reads(
+        cfg, row_mask, mask, start, T, C, R, quant, flash, mesh)
 
     def layer(x, lp, st, li, kind):
         # ``st``: the run's stacks, carried whole; this layer's rows of
@@ -2524,6 +2526,185 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
             pool["write"], write_end, (slot,)
         )
     return out
+
+
+def _prefill_group(params, ids, mask, pos, pool, slot, start, n_prompt,
+                   cfg: DecoderConfig, first, last, last_col, flash: bool,
+                   mesh) -> dict:
+    """:func:`pool_prefill_chunk` for a GROUP of ``G`` pieces of ``G``
+    prompts in distinct slots, one LANE each: ``ids``/``mask``/``pos`` (G,
+    T); ``slot``, ``start``, ``n_prompt``, ``first``, ``last`` and
+    ``last_col`` (G,), all traced, so one executable serves every mix of
+    first, middle and last pieces (``last_col`` is every lane's, ``T - 1``
+    where the piece ends on its last real token).
+
+    Whatever multiplies a weight — the projections, the router, the expert
+    walk, the shared expert, the MLP — runs over the ``G x T`` tokens at
+    once, so each held matrix is read ONCE for the group
+    (:func:`prefill_group_width` says how wide a group that pays for). The
+    attention read and the cache writes stay a lane's, in ONE ``lax.scan``
+    over the lanes a layer (the program does not grow with ``G``): a lane's
+    rows go in by the same in-place writes as a piece alone's, one a lane an
+    array, and its queries read its slot's ONE row by the same reads
+    (:func:`_piece_reads`). ``first`` clears a lane's mask row, ``last``
+    installs its logits and cursors; a lane whose flag is off keeps what was
+    there."""
+    G, T = ids.shape
+    C = pool["slot_mask"].shape[1]
+    R = pool_ring(pool)
+    quant = pool_quantized(pool)
+    p = jnp.clip(pos, 0, cfg.max_position - 1)
+    x = _embed(params, ids, p, cfg)
+    rows = jnp.where(first[:, None], 0, pool["slot_mask"][slot])
+    row_mask = jax.vmap(lambda r, m, s: jax.lax.dynamic_update_slice(
+        r, m, (s,)))(rows, mask.astype(jnp.int32), start)        # (G, C)
+
+    def layer(x, lp, st, li, kind):
+        q, k_new, v_new = _project(x, lp, cfg, kind, p, True)
+        window = kind[0] == "window"
+        sk = sv = None
+        if quant and not window:
+            k_new, sk = _kv_quant(k_new)
+            v_new, sv = _kv_quant(v_new)
+
+        def put(st, new, sl, s0, m):
+            # the lane's rows of this layer, written where they lie
+            out = dict(st)
+            for n, a in zip(_KV_SHORT, new):
+                if a is not None:
+                    out[n] = (_ring_put(st[n], a[None], li, sl, s0, m > 0)
+                              if window else
+                              _kv_put(st[n], a[None], li, sl, s0))
+            return out
+
+        def lane(st, xs):
+            # ONE lane: its rows in, its queries over its slot's one row, as
+            # a piece alone (a ring is read as the earlier pieces left it,
+            # then written; a whole row after the piece's own rows went in)
+            q_g, new, sl, s0, rm, m = xs
+            if not window:
+                st = put(st, new, sl, s0, m)
+            full_fn, latent_fn, window_fn, mask_bias, ring_bias = \
+                _piece_reads(cfg, rm[None], m[None], s0, T, C, R, quant,
+                             flash, mesh)
+            ks, vs, kss, vss = (None if st[n] is None
+                                else _kv_rows(st[n], li, sl)
+                                for n in _KV_SHORT)
+            if window:
+                ks = jnp.concatenate([ks, new[0][None].astype(ks.dtype)], 2)
+                vs = jnp.concatenate([vs, new[1][None].astype(vs.dtype)], 2)
+                fn, bias = window_fn, ring_bias
+            elif kind[0] == "latent":
+                fn = latent_fn and (lambda q, c, _v, _ks, _vs:
+                                    latent_fn(q, c, _w_ukv(lp, cfg)))
+                bias = mask_bias
+            else:
+                fn, bias = full_fn, mask_bias
+            with jax.named_scope("decoder.attn." + kind[0]):
+                ctx = _attend(q_g[None], ks, vs, bias, cfg, kss, vss, fn,
+                              kind, lp)[0]
+            if window:
+                st = put(st, new, sl, s0, m)
+            return st, ctx
+
+        st, ctx = jax.lax.scan(lane, st, (q, (k_new, v_new, sk, sv), slot,
+                                          start, row_mask, mask))
+        x, cnt = _block_finish(x, lp, ctx, cfg, kind)
+        return x, st, cnt
+
+    x, kv, counts, exits = _scan_layers(cfg, params, x, pool, layer)
+    slot_mask = pool["slot_mask"].at[slot].set(row_mask)
+    out = _add_counts(pool, {**kv, "slot_mask": slot_mask}, counts)
+
+    def keep(name, new):
+        # a lane's last piece installs ``new``; the others keep the slot's
+        old = pool[name][slot]
+        on = last.reshape((G,) + (1,) * (old.ndim - 1))
+        return pool[name].at[slot].set(jnp.where(on, new.astype(old.dtype),
+                                                 old))
+
+    x_last = x[jnp.arange(G), last_col][:, None, :]
+    out["logits"] = keep("logits", _logits(params, x_last, cfg)[:, 0, :])
+    out["pos"] = keep("pos", n_prompt)
+    # the next cache column: right after the last REAL token
+    out["write"] = keep("write", start + last_col + 1)
+    if exits is not None and "exit_step" in pool:
+        # the exit pass of the prompt's first token
+        out["exit_step"] = keep("exit_step",
+                                exits[0][jnp.arange(G), last_col])
+    return out
+
+
+def _piece_reads(cfg: DecoderConfig, row_mask, mask, start, T: int, C: int,
+                 R: int, quant: bool, flash: bool, mesh):
+    """What ONE piece's attention reads need beside its queries, from the
+    slot's row mask ``row_mask`` (1, C) with the piece's ``mask`` (1, T) at
+    ``start`` in it: ``(full_fn, latent_fn, window_fn, mask_bias,
+    ring_bias)``, the kernels' entries or the dense reads' biases."""
+    # each kind of layer reads blockwise or dense by ONE rule on its shapes
+    # (``flash`` forces the kernel, as it always has): the dense read stays
+    # the small rows' and the decode step's, and the tests' reference
+    W = cfg.sliding_window
+    full_fn = latent_fn = window_fn = mask_bias = ring_bias = None
+    qcol = (start + jnp.arange(T))[None, :]             # (1, T)
+    if flash or blockwise_chunk_read(cfg.heads, T, C):
+        # the kernel builds the live-&-causal predicate from the column
+        # each key row holds, with int8 dequant fused into the tile read:
+        # no (1, 1, T, C) bias, no f32 KV row, no scores in HBM
+        kcol = jnp.where(row_mask > 0, jnp.arange(C, dtype=jnp.int32), -1)
+        if not cfg.latent:
+            attn_c = _flash_chunk_attn_fn(mesh, quant)
+            full_fn = lambda q, kr, vr, ksr, vsr: \
+                attn_c(q, kr, vr, ksr, vsr, kcol, start)
+        else:
+            from pathway_tpu.models import flash_attention as _fa
+
+            # the same walk over the slot's LATENT row: a block becomes a
+            # head's keys and values inside the kernel, for that step only.
+            # The kernel takes the row with its COLUMNS minor, (width,
+            # columns): keys transposed are what a score multiplies, and it
+            # is how the chip's compiler keeps an array whose rows are 4.5
+            # lane tiles wide, so the row goes in as it lies
+            latent_fn = lambda q, c_row, w_ukv: \
+                _fa.flash_chunk_attn_latent(
+                    q[0], c_row[0, 0].T, w_ukv, kcol[0], start,
+                    nope=cfg.nope_size, sm_scale=attn_scale(cfg))[None]
+    else:
+        # a piece query at cache index start+j attends every LIVE index
+        # of this row <= start+j (earlier pieces + its own causal
+        # prefix) — elementwise the same predicate as prefill()'s
+        # causal & pad mask
+        idxs = jnp.arange(C)[None, None, None, :]
+        allowed = (row_mask[:, None, None, :] > 0) \
+            & (idxs <= qcol[:, None, :, None])
+        mask_bias = jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
+    if R:
+        # a window layer reads its ring as the EARLIER pieces left it
+        # (columns < start) beside this piece's own keys, then writes the
+        # piece in: the ring needs no room for the piece itself
+        if T > R:
+            raise ValueError(f"a prefill piece of {T} columns does not "
+                             f"fit a window layer's ring of {R}")
+        cols = _ring_cols(jnp.reshape(start - 1, (1,)), R)      # (1, R)
+        ring_live = _live_at(row_mask, cols)                    # (1, R)
+        if flash or blockwise_chunk_read(cfg.heads, T, R + T):
+            attn_w = _flash_chunk_attn_fn(mesh, quant, W)
+            kcol_w = jnp.concatenate([
+                jnp.where(ring_live, cols, -1),
+                jnp.where(mask > 0, qcol, -1)], axis=1).astype(jnp.int32)
+            window_fn = lambda q, kr, vr, ksr, vsr: \
+                attn_w(q, kr, vr, ksr, vsr, kcol_w, start)
+        else:
+            live = jnp.broadcast_to(ring_live[:, None, :], (1, T, R))
+            old = _ring_bias(cols, live, qcol, W)           # (1, 1, T, R)
+            j = jnp.arange(T)
+            own = (mask[:, None, :] > 0) \
+                & (j[None, None, :] <= j[None, :, None]) \
+                & (j[None, :, None] - j[None, None, :] < W)
+            ring_bias = jnp.concatenate(
+                [old, jnp.where(own, 0.0, -1e9).astype(jnp.float32)[:, None]],
+                axis=-1)
+    return full_fn, latent_fn, window_fn, mask_bias, ring_bias
 
 
 def chunk_rows(cfg: DecoderConfig, T: int, rows: int,

@@ -715,7 +715,11 @@ class _ContinuousServer:
       than ``prefill_chunk`` admit piece-wise via
       ``pool_prefill_chunk``, one piece per loop tick interleaved with
       decode chunks, so a long prompt never stalls every active lane
-      for a whole-prompt prefill dispatch.
+      for a whole-prompt prefill dispatch. The waiting prompts' next
+      pieces go ``prefill_group`` at a time, as ONE dispatch that reads
+      the weights once for all of them; the width is a rule on the
+      piece's arithmetic intensity (``decoder.prefill_group_width``), not
+      a flag: 1 where a piece alone reaches the chip's ridge.
     * **eager refill** (PATHWAY_TPU_EAGER_REFILL) — a lane whose
       DISPATCHED steps already cover its budget frees its slot
       immediately (its remaining tokens drain from the in-flight
@@ -1149,6 +1153,12 @@ class _ContinuousServer:
                 self.params, cfg, self.mesh
             )
         self.pool = self._build_pool()
+        # how many waiting prompts' pieces share one dispatch, and so one
+        # read of the weights: ONE rule on the piece's arithmetic intensity
+        # (models/decoder.py prefill_group_width), 1 where a piece alone
+        # reaches the chip's ridge, for a paged pool and on a mesh
+        self.prefill_group = decoder_mod.prefill_group_width(
+            cfg, self.params, self.prefill_chunk, self.pool, self.mesh)
         self.kv_bytes_saved = 0
         if self.kv_quant:
             # ledger the HBM the int8 pool did NOT allocate vs the same
@@ -1329,6 +1339,17 @@ class _ContinuousServer:
                 if with_col:
                     args += (np.int32(0),)
                 self.pool = self._prefill_fn(P, first, last, with_col)(*args)
+            G = self.prefill_group
+            if G > 1:
+                # the grouped piece: its flags are traced, so ONE executable
+                # serves every mix of first, middle and last pieces
+                lanes = np.zeros(G, np.int32)
+                self.pool = self._prefill_group_fn(P)(
+                    self.params, np.repeat(ids, G, 0), np.repeat(mask, G, 0),
+                    np.repeat(pos, G, 0), self.pool,
+                    np.arange(G, dtype=np.int32), lanes,
+                    np.repeat(n_prompt, G), np.ones(G, bool),
+                    np.zeros(G, bool), lanes + (P - 1))
         if self.prefix is not None and not self.paged_kv and buckets:
             B = self.prefix_block
             most = min(buckets[-1] // B, self.prefix.capacity_blocks)
@@ -1901,6 +1922,30 @@ class _ContinuousServer:
                         n_prompt, cfgc, first=first, last=last,
                         flash=fl, mesh=msh,
                     )
+
+            fn = jax.jit(piece, donate_argnums=(4,))
+            self._prefill_fns[key] = fn
+        return fn
+
+    def _prefill_group_fn(self, t: int):
+        """The grouped piece: ``prefill_group`` lanes of ``t`` columns, each
+        a piece of another prompt, with every lane's slot, offset and flags
+        traced (``pool_prefill_chunk`` over (G, t) pieces)."""
+        key = (t, "group")
+        fn = self._prefill_fns.get(key)
+        if fn is None:
+            import jax
+
+            D, cfgc = self._D, self.cfg
+            fl, msh = self.flash_prefill, self.mesh
+
+            def piece(params_, ids, mask, pos, pool, slot, start, n_prompt,
+                      first, last, last_col):
+                return D.pool_prefill_chunk(
+                    params_, ids, mask, pos, pool, slot, start, n_prompt,
+                    cfgc, first=first, last=last, last_col=last_col,
+                    flash=fl, mesh=msh,
+                )
 
             fn = jax.jit(piece, donate_argnums=(4,))
             self._prefill_fns[key] = fn
@@ -2662,34 +2707,64 @@ class _ContinuousServer:
 
             probes.record_loop_passes(phase, tokens, self.cfg.loops)
 
-    def _prefill_piece(self, slot: int, active) -> None:
-        """Dispatch one pending prefill piece for ``slot`` (a method so
-        supervised serving can rewind just this slot on a fault)."""
+    def _prefill_lanes(self, slots: list, active) -> None:
+        """Dispatch the next pending prefill piece of each of ``slots``: a
+        piece alone, or the pieces of ``prefill_group`` prompts as ONE
+        dispatch that reads the weights once for all of them (a method so
+        supervised serving can rewind just these slots on a fault)."""
         import numpy as np
 
-        from pathway_tpu.engine import tracing
+        from pathway_tpu.engine import probes, tracing
 
-        pieces, n_prompt, meta = self._pending_prefill[slot]
-        p_ids, p_mask, p_pos, off = pieces.pop(0)
-        first = off == (meta.get("first_off", 0) if meta else 0)
-        last = not pieces
-        lc = meta.get("last_col") if (meta and last) else None
-        with tracing.region("pw.decode.prefill", tokens=int(p_ids.shape[1]),
-                            piece=int(off // p_ids.shape[1]),
-                            passes=self.cfg.loops):
-            if lc is None:
-                self.pool = self._prefill_fn(p_ids.shape[1], first, last)(
-                    self.params, p_ids, p_mask, p_pos, self.pool,
-                    np.int32(slot), np.int32(off), n_prompt,
+        lanes = []
+        for slot in slots:
+            pieces, n_prompt, meta = self._pending_prefill[slot]
+            ids, mask, pos, off = pieces.pop(0)
+            meta = meta or {}
+            last = not pieces
+            # ``col``: the prompt's last real token's column where it is
+            # not the piece's last (a right-padded prompt's final piece)
+            lanes.append((slot, ids, mask, pos, off, n_prompt, meta,
+                          off == meta.get("first_off", 0), last,
+                          meta.get("last_col") if last else None))
+        slot, ids, mask, pos, off, n_prompt, meta, first, last, col = \
+            (list(x) for x in zip(*lanes))
+        T = int(ids[0].shape[1])
+        with tracing.region("pw.decode.prefill", tokens=T,
+                            piece=off[0] // T, passes=self.cfg.loops,
+                            lanes=len(lanes)):
+            if len(lanes) > 1:
+                self.pool = self._prefill_group_fn(T)(
+                    self.params, np.concatenate(ids), np.concatenate(mask),
+                    np.concatenate(pos), self.pool,
+                    np.asarray(slot, np.int32), np.asarray(off, np.int32),
+                    np.concatenate(n_prompt), np.asarray(first, bool),
+                    np.asarray(last, bool),
+                    np.asarray([T - 1 if c is None else c for c in col],
+                               np.int32),
+                )
+            elif col[0] is None:
+                self.pool = self._prefill_fn(T, first[0], last[0])(
+                    self.params, ids[0], mask[0], pos[0], self.pool,
+                    np.int32(slot[0]), np.int32(off[0]), n_prompt[0],
                 )
             else:
-                self.pool = self._prefill_fn(
-                    p_ids.shape[1], first, last, True
-                )(
-                    self.params, p_ids, p_mask, p_pos, self.pool,
-                    np.int32(slot), np.int32(off), n_prompt,
-                    np.int32(lc),
+                self.pool = self._prefill_fn(T, first[0], last[0], True)(
+                    self.params, ids[0], mask[0], pos[0], self.pool,
+                    np.int32(slot[0]), np.int32(off[0]), n_prompt[0],
+                    np.int32(col[0]),
                 )
+        probes.record_prefill_group_lanes(len(lanes))
+        for k in range(len(lanes)):
+            self._prefill_done(slot[k], ids[k], mask[k], off[k], n_prompt[k],
+                               meta[k], last[k], active)
+
+    def _prefill_done(self, slot: int, p_ids, p_mask, off, n_prompt, meta,
+                      last: bool, active) -> None:
+        """One dispatched piece's host side: counts, its request's event,
+        and, after its prompt's last piece, the slot's turn to decode."""
+        import numpy as np
+
         self.stats["prefill_chunks"] += 1
         live = np.flatnonzero(p_mask[0])
         if live.size:
@@ -3044,16 +3119,23 @@ class _ContinuousServer:
                     for k in range(self._prefill_budget)
                 ]
                 self._prefill_rr += self._prefill_budget
-            for slot in pend:
+            # the pieces of ``prefill_group`` prompts go as one dispatch
+            # (one read of the weights); the rest, fewer, go one by one
+            G = self.prefill_group
+            n = len(pend) // G * G
+            for lanes in [pend[i:i + G] for i in range(0, n, G)] + [
+                    [slot] for slot in pend[n:]]:
                 try:
-                    self._prefill_piece(slot, active)
+                    self._prefill_lanes(lanes, active)
                 except Exception as exc:  # noqa: BLE001 - isolation gate
-                    req_p = self.slots[slot]
-                    if not self._supervised or req_p is None:
+                    reqs = [self.slots[slot] for slot in lanes]
+                    if not self._supervised or None in reqs:
                         raise
-                    self._isolate_admission_failure(
-                        slot, req_p, exc, active
-                    )
+                    # the dispatch was the group's: every lane rewinds
+                    for slot, req_p in zip(lanes, reqs):
+                        self._isolate_admission_failure(
+                            slot, req_p, exc, active
+                        )
             self._maybe_preempt(active)
             self._lane_counts["prefill"] = len(self._pending_prefill)
             self._lane_counts["decode"] = int(active.sum())

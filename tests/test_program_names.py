@@ -297,6 +297,30 @@ def test_the_loops_counters_and_field_are_what_the_benchmark_reads(
     assert 'pathway_tpu_loop_exit_step_total{step="3"} 4' in exported
 
 
+def test_the_grouped_prefill_keeps_its_names(looped_run, jitted):
+    """A group of pieces is still the module ``jit_piece`` (the prefill
+    roofline's pattern), its region says how many lanes it carried (one
+    here: a lone request's pieces go alone), and the counter of pieces by
+    dispatch width is on ``/metrics`` under the name PERF.md gives it."""
+    _stats, regions = looped_run
+    found = [s for _th, n, _s, _d, s in regions if n == "pw.decode.prefill"]
+    assert found and all(int(s["lanes"]) == 1 for s in found)
+    with open(os.path.join(REPO, "pathway_tpu", "xpacks", "llm", "llms.py"),
+              encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    group = next(node for node in ast.walk(tree)
+                 if isinstance(node, ast.FunctionDef)
+                 and node.name == "_prefill_group_fn")
+    assert [call.args[0].id for call in ast.walk(group)
+            if isinstance(call, ast.Call)
+            and getattr(call.func, "attr", None) == "jit"] == ["piece"]
+    assert "jit_piece" in jitted
+    assert probes.METRIC_FAMILIES["prefill_group_lanes"][:2] == ("counter",
+                                                                 "width")
+    assert 'pathway_tpu_prefill_group_lanes_total{width="1"}' \
+        in _metrics_text()
+
+
 def _metrics_text():
     from pathway_tpu.internals.http_server import registry_text
 

@@ -643,6 +643,83 @@ def test_ouro_width_piece_and_chunk(shape):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14 << 30
 
 
+def _instructions(text: str) -> int:
+    import re
+
+    return sum(1 for line in text.splitlines()
+               if re.match(r"\s*(ROOT\s+)?%?[\w.\-]+\s*=", line))
+
+
+@pytest.mark.parametrize("cell,lanes,temp", [
+    ("trinity", 4, 124_000_000), ("deepseek", 2, 282_000_000)])
+def test_the_grouped_piece_at_the_answer_cells_widths(
+        shape, trinity, deepseek, monkeypatch, cell, lanes, temp):
+    """The grouped piece the rule gives each cell (``decoder.
+    prefill_group_width``: 4 lanes of 512 for Trinity's stage, 2 for
+    DeepSeek-V2's) compiles with the kernel in it, writes every lane's rows
+    in place and holds no whole key-value array, fits the chip beside its
+    arguments (``PERF.md`` section 4: 112,550,912 and 256,144,384 bytes of
+    temporaries), and is NOT unrolled by lane: it holds at most a quarter
+    more instructions than the 1-wide last piece (the lanes' reads and
+    writes are one loop a run of layers, whatever the width)."""
+    from pathway_tpu.models import decoder as D
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, pool = trinity if cell == "trinity" else deepseek
+    assert D.prefill_group_width(cfg, params, 512, pool) == lanes
+    one = _compile(
+        lambda p, i, m, ps, pl, s, st, n: D.pool_prefill_chunk(
+            p, i, m, ps, pl, s, st, n, cfg, first=False, last=True),
+        params, shape((1, 512), I32), shape((1, 512), I32),
+        shape((1, 512), I32), pool, shape((), I32), shape((), I32),
+        shape((1,), I32), donate_argnums=(4,))
+    ids, vec = shape((lanes, 512), I32), shape((lanes,), I32)
+    flag = shape((lanes,), jnp.bool_)
+    c = _compile(
+        lambda p, i, m, ps, pl, s, st, n, f, la, col: D.pool_prefill_chunk(
+            p, i, m, ps, pl, s, st, n, cfg, first=f, last=la, last_col=col),
+        params, ids, ids, ids, pool, vec, vec, vec, flag, flag, vec,
+        donate_argnums=(4,))
+    assert _is_mosaic(c)
+    text = c.as_text()
+    ops = _whole_array_ops(text, _pool_stacks(pool))
+    assert ops and {kind for _w, _n, kind in ops} == {"in place"}, ops
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < temp
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 16 << 30
+    assert _instructions(text) <= 1.25 * _instructions(one.as_text())
+
+
+# the lowered text of the looped cell's piece (a prompt's last) and chunk at
+# PR 35's parent (df6e00a): where the rule gives one lane nothing changed
+OURO_LOWERED = {"piece": "713a35529a604512", "chunk": "38bc835e9f91ddaf"}
+
+
+def test_the_looped_cells_executables_are_the_parents(shape):
+    """Ouro's pieces reach the ridge alone (256 FLOP a byte of its layers),
+    so its server builds no grouped piece, and its 1-wide piece and chunk
+    lower to the text they lowered to before the grouped piece existed."""
+    import hashlib
+
+    from pathway_tpu.models import decoder as D
+
+    cfg, params, pool = _answer_cell(shape, "ouro-2.6b-rag.json", "ouro", 4,
+                                     656)
+    assert D.prefill_group_width(cfg, params, 256, pool) == 1
+    ids = shape((1, 256), I32)
+    one = shape((), I32)
+    texts = {
+        "piece": jax.jit(lambda p, i, pl, s: D.pool_prefill_chunk(
+            p, i, i, i, pl, s, s, s[None], cfg, first=False, last=True),
+            donate_argnums=(2,)).lower(params, ids, pool, one).as_text(),
+        "chunk": jax.jit(lambda p, pl, a, k: D.pool_decode_chunk(
+            p, pl, a, k, cfg, CHUNK_STEPS)).lower(
+                params, pool, shape((4,), jnp.bool_),
+                shape((2,), jnp.uint32)).as_text()}
+    assert {k: hashlib.sha256(t.encode()).hexdigest()[:16]
+            for k, t in texts.items()} == OURO_LOWERED
+
+
 def test_flash_chunk_attn_paged_kernel(shape):
     from pathway_tpu.models.flash_attention import flash_chunk_attn_paged
 
