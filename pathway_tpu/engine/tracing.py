@@ -6,7 +6,9 @@ named ``pw.<layer>.<what>``, so inside a profiler session (the
 benchmark's traced slice, an operator's ``GET /debug/profile?ms=N``) it
 lands in the trace's host plane on the same time base as the device's
 ``XLA Ops``, and outside one it records nothing. A :class:`Span` is for
-work that crosses threads.
+work that crosses threads. Beside them, the executable table
+(:func:`register_executable`, :func:`executable_table`) tells a reader of
+a device trace which executable a run was and what one run carried.
 
 A :class:`Span` is one request's timeline through a serving loop: a
 monotonic start plus timestamped events — ``enqueue`` (implicit, at
@@ -59,19 +61,22 @@ from pathway_tpu.engine import probes
 
 __all__ = [
     "Span", "NULL_SPAN", "start_span", "recent_traces", "reset_traces",
-    "flush_traces", "region",
+    "flush_traces", "region", "register_executable", "executable_table",
+    "hlo_shapes",
 ]
 
 # lock-discipline declaration for module globals (enforced by
 # `python -m pathway_tpu.analysis check`, rule GL401): the span ring,
-# the flight recorder's file-handle state and the lazy telemetry
-# singleton may only be touched under their locks.
+# the flight recorder's file-handle state, the lazy telemetry singleton
+# and the executables the servers registered may only be touched under
+# their locks.
 _GUARDED_BY = {
     "_rings": "_ring_lock",
     "_jsonl_file": "_jsonl_lock",
     "_jsonl_path": "_jsonl_lock",
     "_jsonl_unflushed": "_jsonl_lock",
     "_telemetry": "_telemetry_lock",
+    "_executables": "_executables_lock",
 }
 
 
@@ -290,6 +295,71 @@ def _on_gc(phase: str, info: dict) -> None:
 
 
 gc.callbacks.append(_on_gc)
+
+
+# --------------------------------------------------------------------- #
+# the executables the servers build, by the names the device trace shows
+#
+# A server registers each executable ONCE, where it builds it: the module
+# name the trace gives its runs (``jit_`` + the jitted function's name),
+# what one run carries (``pieces``: 1, or a group's lanes; ``steps``: a
+# decode chunk's; ``passes``: a looped stack's; ``queries``: a search
+# bucket's; ``cycles``: a speculative dispatch's) and the HLO shapes of its
+# arguments and results, which tell apart executables that share a module
+# name (a group's lanes and a chunk's steps are in them). That is a dict
+# insert and nothing is kept alive. The named scopes (``decoder.attn.full``,
+# ``moe.experts``, ``knn.top_k``, ...) are read from the trace itself: a
+# profiler session keeps the compiled HLO of every program that ran, each
+# instruction's ``op_name`` with it, so a reader of the trace names an op by
+# the scope the executable that ran it carries.
+
+# (module, carries, parameters, results), once each
+_executables: set = set()
+_executables_lock = make_lock("tracing.executables")
+# numpy's dtype names -> HLO's
+_HLO_TYPES = {"bool": "pred", "bfloat16": "bf16"}
+
+
+def hlo_shapes(tree) -> tuple[str, ...]:
+    """The array leaves of ``tree`` (arrays or ``jax.ShapeDtypeStruct``s) as
+    HLO writes their shapes, ``bf16[16,512]``; a leaf that is not an array
+    (a static argument) or has no HLO element type of its own (a typed
+    random key) is left out."""
+    import jax
+
+    out = []
+    for leaf in jax.tree.leaves(tree):
+        dtype = getattr(leaf, "dtype", None)
+        if dtype is None or not hasattr(leaf, "shape"):
+            continue
+        name = str(dtype)
+        if "key<" in name:
+            continue
+        name = _HLO_TYPES.get(name) or name.replace("float8_", "f8").replace(
+            "float", "f").replace("uint", "u").replace("int", "s")
+        out.append(f"{name}[{','.join(str(d) for d in leaf.shape)}]")
+    return tuple(out)
+
+
+def register_executable(fn, args: tuple, results, **carries: int) -> None:
+    """``fn(*args)`` gave ``results``: an executable a server builds.
+    Register its module name, what one run carries and its shapes. Call it
+    where the executable is built, never once a dispatch; ``args`` may hold
+    arrays the call donated (their shapes stay)."""
+    key = ("jit_" + fn.__name__, tuple(sorted(carries.items())),
+           hlo_shapes(args), hlo_shapes(results))
+    with _executables_lock:
+        _executables.add(key)
+
+
+def executable_table() -> list[dict]:
+    """One entry for each executable registered: ``module``, ``carries``,
+    ``params`` and ``results`` (their shapes, :func:`hlo_shapes`)."""
+    with _executables_lock:
+        registered = list(_executables)
+    return [{"module": module, "carries": dict(carries), "params": params,
+             "results": results}
+            for module, carries, params, results in registered]
 
 
 # flight-recorder file state: ONE persistent buffered append handle per

@@ -24,7 +24,7 @@ from pathway_tpu.engine.probes import (
     record_device_dispatch,
     record_knn_search,
 )
-from pathway_tpu.engine.tracing import region
+from pathway_tpu.engine.tracing import region, register_executable
 from pathway_tpu.ops import canonical_metric, next_pow2, prep_host_vectors
 from typing import Any
 
@@ -49,25 +49,27 @@ def knn_scores(corpus, valid_mask, queries, metric: str,
     (PATHWAY_TPU_KNN_F32_SCORES, or ``BruteForceKnnIndex(f32_scores=...)``)
     keeps queries f32 and upcasts the corpus for the dot — recall-first at
     roughly half the gemm throughput."""
-    if f32_scores:
-        q = queries.astype(jnp.float32)
-        c = corpus.astype(jnp.float32)
-    else:
-        q = queries.astype(jnp.bfloat16)
-        c = corpus
-    dots = jax.lax.dot_general(
-        q,
-        c,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (Q, N)
-    if metric == "l2":
-        qn = jnp.sum(queries.astype(jnp.float32) ** 2, axis=1, keepdims=True)
-        cn = jnp.sum(c.astype(jnp.float32) ** 2, axis=1)[None, :]
-        scores = -(qn + cn - 2.0 * dots)  # negative squared L2
-    else:  # cosine / dot on normalized vectors
-        scores = dots
-    return jnp.where(valid_mask[None, :], scores, _NEG_INF)
+    with jax.named_scope("knn.scores"):
+        if f32_scores:
+            q = queries.astype(jnp.float32)
+            c = corpus.astype(jnp.float32)
+        else:
+            q = queries.astype(jnp.bfloat16)
+            c = corpus
+        dots = jax.lax.dot_general(
+            q,
+            c,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (Q, N)
+        if metric == "l2":
+            qn = jnp.sum(queries.astype(jnp.float32) ** 2, axis=1,
+                         keepdims=True)
+            cn = jnp.sum(c.astype(jnp.float32) ** 2, axis=1)[None, :]
+            scores = -(qn + cn - 2.0 * dots)  # negative squared L2
+        else:  # cosine / dot on normalized vectors
+            scores = dots
+        return jnp.where(valid_mask[None, :], scores, _NEG_INF)
 
 
 def _normalize(v):
@@ -96,23 +98,24 @@ def topk_scores(scores, k: int):
     static concat compiled into the executable. Pad slots can never win a
     top-k spot against any real score, and downstream resolvers already
     treat ``score <= _NEG_INF / 2`` as an empty slot."""
-    Q, N = scores.shape
-    if N <= 2 * _TOPK_BLOCK:
-        return jax.lax.top_k(scores, k)
-    pad = (-N) % _TOPK_BLOCK
-    if pad:
-        scores = jnp.concatenate(
-            [scores, jnp.full((Q, pad), _NEG_INF, dtype=scores.dtype)],
-            axis=1,
-        )
-    nb = (N + pad) // _TOPK_BLOCK
-    kb = min(k, _TOPK_BLOCK)
-    bs, bi = jax.lax.top_k(scores.reshape(Q, nb, _TOPK_BLOCK), kb)
-    flat_s = bs.reshape(Q, nb * kb)
-    fs, fi = jax.lax.top_k(flat_s, k)
-    within = jnp.take_along_axis(bi.reshape(Q, nb * kb), fi, axis=1)
-    idx = (fi // kb) * _TOPK_BLOCK + within
-    return fs, idx
+    with jax.named_scope("knn.top_k"):
+        Q, N = scores.shape
+        if N <= 2 * _TOPK_BLOCK:
+            return jax.lax.top_k(scores, k)
+        pad = (-N) % _TOPK_BLOCK
+        if pad:
+            scores = jnp.concatenate(
+                [scores, jnp.full((Q, pad), _NEG_INF, dtype=scores.dtype)],
+                axis=1,
+            )
+        nb = (N + pad) // _TOPK_BLOCK
+        kb = min(k, _TOPK_BLOCK)
+        bs, bi = jax.lax.top_k(scores.reshape(Q, nb, _TOPK_BLOCK), kb)
+        flat_s = bs.reshape(Q, nb * kb)
+        fs, fi = jax.lax.top_k(flat_s, k)
+        within = jnp.take_along_axis(bi.reshape(Q, nb * kb), fi, axis=1)
+        idx = (fi // kb) * _TOPK_BLOCK + within
+        return fs, idx
 
 
 @functools.partial(
@@ -480,13 +483,15 @@ class BruteForceKnnIndex:
         a served index meets its largest bucket when requests pile up behind
         a busy engine, the one moment a compile of seconds must not come
         (6.44 GB of rows: 5-9 s an executable). Costs a batch job one
-        executable it may not use."""
+        executable it may not use. Each bucket's executable is registered
+        with the queries one run carries, for the device trace's readers."""
         if (k, self.capacity) in self._compiled:
             return
         self._compiled.add((k, self.capacity))
         for bucket in _SEARCH_BUCKETS:
-            self._dispatch(
-                jnp.asarray(np.zeros((bucket, self.dim), np.float32)), k)
+            q = jnp.asarray(np.zeros((bucket, self.dim), np.float32))
+            register_executable(_search_kernel, (self._corpus, self._valid, q),
+                                self._dispatch(q, k), queries=bucket)
 
     def resolve(self, scores, idx, nq: int, k: int) -> list[list[tuple[Any, float]]]:
         """Map fetched (host) score/index arrays back to [(key, score)] rows."""

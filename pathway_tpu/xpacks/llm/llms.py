@@ -1296,12 +1296,22 @@ class _ContinuousServer:
         can pick and every speculative cycle count those map to. Nothing
         then compiles under traffic. The prompt buckets are the powers of
         two up to ``max_prompt_tokens``'s. The pool is rebuilt afterwards:
-        the warm-up leaves no trace in it."""
+        the warm-up leaves no trace in it. The pieces, chunks and cycles
+        are registered with what one run carries, for the device trace's
+        readers (``tracing.register_executable``)."""
         import time as time_mod
 
         import jax
         import numpy as np
 
+        from pathway_tpu.engine import tracing
+
+        def warm(fn, args: tuple, **carries):
+            out = fn(*args)
+            tracing.register_executable(fn, args, out, **carries)
+            return out
+
+        passes = self.cfg.loops
         t0 = time_mod.perf_counter()
         P = self.prefill_chunk
         buckets, b = [], 8
@@ -1338,18 +1348,20 @@ class _ContinuousServer:
                         np.int32(0), n_prompt)
                 if with_col:
                     args += (np.int32(0),)
-                self.pool = self._prefill_fn(P, first, last, with_col)(*args)
+                self.pool = warm(self._prefill_fn(P, first, last, with_col),
+                                 args, pieces=1, passes=passes)
             G = self.prefill_group
             if G > 1:
                 # the grouped piece: its flags are traced, so ONE executable
                 # serves every mix of first, middle and last pieces
                 lanes = np.zeros(G, np.int32)
-                self.pool = self._prefill_group_fn(P)(
+                self.pool = warm(self._prefill_group_fn(P), (
                     self.params, np.repeat(ids, G, 0), np.repeat(mask, G, 0),
                     np.repeat(pos, G, 0), self.pool,
                     np.arange(G, dtype=np.int32), lanes,
                     np.repeat(n_prompt, G), np.ones(G, bool),
-                    np.zeros(G, bool), lanes + (P - 1))
+                    np.zeros(G, bool), lanes + (P - 1)),
+                    pieces=G, passes=passes)
         if self.prefix is not None and not self.paged_kv and buckets:
             B = self.prefix_block
             most = min(buckets[-1] // B, self.prefix.capacity_blocks)
@@ -1364,14 +1376,15 @@ class _ContinuousServer:
         idle = np.zeros(self.n_slots, dtype=bool)
         cycles = set()
         for steps in self._step_cands:
-            out = self._chunk_fn_for(steps)(
-                self.params, self.pool, idle, self._key)
+            out = warm(self._chunk_fn_for(steps),
+                       (self.params, self.pool, idle, self._key),
+                       steps=steps, passes=passes)
             self.pool = out[0]
             cycles.add(max(1, steps // (self.spec_k + 1)))
         if self.spec_decode:
             for n_cycles in sorted(cycles):
-                out = self._spec_fn_for(n_cycles)(
-                    self.params, self.pool, idle)
+                out = warm(self._spec_fn_for(n_cycles),
+                           (self.params, self.pool, idle), cycles=n_cycles)
                 self.pool = out[0]
         jax.block_until_ready(self.pool)
         self.pool = None        # free it before the fresh one is built

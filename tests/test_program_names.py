@@ -225,6 +225,93 @@ def test_the_latent_blocks_scopes_and_counters_are_what_the_metric_reads(
     assert "mla.expand" not in step      # a step never expands a row
 
 
+SCOPE_METRICS = _params("scopes")
+
+
+@pytest.fixture(scope="module")
+def toy_scopes(tmp_path_factory):
+    """Module name -> every named scope a traced session's own record of
+    the compiled HLO gives toy executables under the names the metrics
+    read: a routed-expert server's and a latent server's pieces and chunks
+    and a search; and a latent piece over a row of 512 columns, whose
+    expanded read is a scope of its own as a long row's is. Compiled with
+    the persistent cache off: it would hand back an executable another
+    build named (its key leaves debug info out)."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from harness import device_scopes
+    from pathway_tpu.models import decoder as D
+    from pathway_tpu.models.moe import MoEConfig
+    from pathway_tpu.ops.knn import BruteForceKnnIndex
+    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
+
+    moe = D.DecoderConfig(
+        vocab_size=64, hidden=16, layers=1, heads=2, intermediate=32,
+        max_position=64, dtype=jnp.float32, mlp="swiglu", bias=False,
+        moe=MoEConfig(experts=4, per_token=2, width=8, held=(0, 2)))
+    trace = str(tmp_path_factory.mktemp("toy_scopes"))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.profiler.start_trace(trace)
+    try:
+        for cfg in (moe, _latent_cfg()):
+            chat = TPUDecoderChat(
+                params=D.init_params(jax.random.PRNGKey(0), cfg), cfg=cfg,
+                tokenizer=_Ids(), max_new_tokens=4, temperature=0.0,
+                max_prompt_tokens=16, continuous=True, n_slots=2,
+                chunk_steps=4, prefill_chunk=8)
+            try:
+                request = chat._server.submit(list(range(1, 12)), 4)
+                assert request.done.wait(timeout=300)
+            finally:
+                chat.close()
+        index = BruteForceKnnIndex(12, reserved_space=16)
+        rows = np.eye(12, dtype=np.float32)
+        index.add(list(range(12)), rows)
+        index.search(rows[:3], 2)
+    finally:
+        jax.profiler.stop_trace()
+    cfg = _latent_cfg()
+
+    def piece(p, i, pl, s):
+        return D.pool_prefill_chunk(p, i, i, i, pl, s, s, s[None], cfg,
+                                    first=False, last=False)
+
+    try:
+        texts = [("jit_piece", jax.jit(piece).lower(
+            jax.eval_shape(lambda: D.init_params(jax.random.PRNGKey(0), cfg)),
+            jax.ShapeDtypeStruct((1, 256), jnp.int32),
+            jax.eval_shape(lambda: D.pool_init(None, cfg, 2, 512)),
+            jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text())]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    (path,) = glob.glob(os.path.join(trace, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    texts += device_scopes.program_hlo(path, {
+        "jit_piece", "jit_chunk", "jit__search_kernel"}).values()
+    out: dict = {}
+    for module, text in texts:
+        out.setdefault(module, set()).update(
+            s for s in device_scopes.hlo_scopes(text).values() if s)
+    return out, device_scopes
+
+
+@pytest.mark.parametrize("name,params", SCOPE_METRICS,
+                         ids=_ids(SCOPE_METRICS))
+def test_a_scope_metric_names_scopes_a_toy_executable_has(
+        name, params, toy_scopes):
+    """Every scope a metric file names (``decoder.attn.`` for all three
+    kinds) names ops of a toy executable of the module it reads."""
+    scopes, device_scopes = toy_scopes
+    have = set().union(*(s for module, s in scopes.items()
+                         if re.search(params["modules"], module)))
+    for wanted in params["scopes"]:
+        assert any(device_scopes._in(s, [wanted]) for s in have), wanted
+
+
 PASSES_METRIC = "answer.loop_passes_per_token"
 
 
