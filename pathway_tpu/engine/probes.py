@@ -180,6 +180,11 @@ METRIC_FAMILIES: dict[str, tuple[str, str | None, str]] = {
         "a chunk's useful slot-steps) and by pass (pass=1..loops: every "
         "token runs pass 1, so the series' sum over pass=1's is the passes a "
         "token); host arithmetic, no sync"),
+    "recurrent_state_resets": (
+        "counter", "cause", "Slots whose recurrent state (a gated "
+        "delta-rule layer's matrix a head and its convolutions' tail) was "
+        "started afresh, by cause (admit: a prompt's first piece or its "
+        "one-shot admission zeroes it); host arithmetic, no sync"),
     "loop_exit_step": (
         "counter", "step", "Emitted tokens by the pass (step=1..loops) the "
         "exit rule of a looped stack took their logits from; all at the "
@@ -610,6 +615,12 @@ def record_loop_passes(phase: str, tokens: int, loops: int) -> None:
     REGISTRY.counter_add_many(
         "loop_passes", ("phase", "pass"),
         {(phase, u): tokens for u in range(1, loops + 1)})
+
+
+def record_state_resets(n: int, cause: str = "admit") -> None:
+    """``n`` slots' recurrent states started afresh (``_ContinuousServer``
+    at admission: host arithmetic, no sync)."""
+    REGISTRY.counter_add("recurrent_state_resets", n, cause=cause)
 
 
 def record_loop_exits(by_step: dict) -> None:

@@ -104,10 +104,24 @@ class DecoderConfig:
     loops: int = 1
     exit_gate: bool = False
     exit_threshold: float = 1.0
+    # gated delta-rule layers (layer_types "kda"): `kda_heads` heads of
+    # `kda_head` key and value channels keep a float32 STATE of (kda_head,
+    # kda_head) each, overwritten every token, beside a causal depthwise
+    # convolution of `kda_conv` taps over the q, k and v projections; a
+    # prefill piece runs the chunked form, a decode step the recurrence
+    # (:func:`_kda_mix`)
+    kda_heads: int = 0
+    kda_head: int = 0
+    kda_conv: int = 4
 
     @property
     def latent(self) -> bool:
         return self.kv_rank > 0
+
+    @property
+    def has_state(self) -> bool:
+        """Some layer keeps a recurrent state a slot (a ``kda`` layer)."""
+        return bool(self.layer_types) and "kda" in self.layer_types
 
     @property
     def head_dim(self) -> int:
@@ -131,10 +145,12 @@ class DecoderConfig:
         return self.kv_heads or self.heads
 
     def layer_kind(self, i: int) -> tuple:
-        """``(window | full | latent, learned | rotary | none, dense |
-        moe)`` of layer ``i``."""
-        attn = "latent" if self.latent else (
-            self.layer_types[i] if self.layer_types else "full")
+        """``(window | full | latent | kda, learned | rotary | none, dense |
+        moe)`` of layer ``i``: ``layer_types`` names each layer's attention
+        where it is given, else every layer is latent (``kv_rank > 0``) or
+        full."""
+        attn = self.layer_types[i] if self.layer_types else (
+            "latent" if self.latent else "full")
         pos = self.positions if isinstance(self.positions, str) \
             else self.positions[i]
         mlp = "moe" if self.moe is not None and i >= self.dense_layers \
@@ -209,7 +225,22 @@ def require_gpt2_block(cfg: DecoderConfig, mechanism: str) -> None:
                "no per-head keys and values)" if cfg.latent else "")
             + (f" (a looped stack: {cfg.loops} passes over the same weights, "
                "a cache layer for every pass of every layer)"
-               if cfg.loops > 1 else ""))
+               if cfg.loops > 1 else "")
+            + (" (a recurrent state: a gated delta-rule layer keeps one "
+               "float32 matrix a head a slot, overwritten every token, and "
+               "no row a token)" if cfg.has_state else ""))
+
+
+def require_stateless(cfg: DecoderConfig, mechanism: str) -> None:
+    """A mechanism that moves, slices or rolls back a cache BY TOKEN (a
+    prefix copied between slots, a speculative tail rejected, a single
+    sequence's KV handed over) has nothing to move where a layer keeps a
+    recurrent state: the state is one matrix that every token overwrote."""
+    if cfg.has_state:
+        raise UnsupportedForLayout(
+            mechanism, "a gated delta-rule (kda) layer keeps a recurrent "
+            "state a slot, overwritten by every token: it cannot be sliced "
+            "by token, copied as a prefix or rolled back to an earlier one")
 
 
 def require_single_pass(cfg: DecoderConfig, mechanism: str) -> None:
@@ -253,6 +284,22 @@ def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
         out["kv_a_norm_scale"] = ((cfg.kv_rank,), "one", None)
         out["kv_b_w"] = ((cfg.kv_rank, nq * (cfg.nope_size + cfg.v_dim)),
                          19, 1)
+    elif _attn == "kda":
+        # q | k | v fused, their short convolutions (a tap a row), the
+        # decay's and the output gate's low-rank pairs, the write strength
+        # a head, the decay's rate a head and bias a channel, the output's
+        # norm over a head's values
+        kh, kd = cfg.kda_heads, cfg.kda_head
+        out["kda_qkv_w"] = ((h, 3 * kh * kd), 21, 1)
+        out["kda_conv_w"] = ((cfg.kda_conv, 3 * kh * kd), 22, 1)
+        out["kda_f_a_w"] = ((h, kd), 23, None)
+        out["kda_f_b_w"] = ((kd, kh * kd), 24, 1)
+        out["kda_b_w"] = ((h, kh), 25, 1)
+        out["kda_a_log"] = ((kh,), "zero", 0)
+        out["kda_dt_bias"] = ((kh * kd,), "zero", 0)
+        out["kda_g_a_w"] = ((h, kd), 26, None)
+        out["kda_g_b_w"] = ((kd, kh * kd), 27, 1)
+        out["kda_o_norm_scale"] = ((kd,), "one", None)
     else:
         out["qkv_w"] = ((h, (nq + 2 * nkv) * hd), 2, 1)
         if cfg.bias:
@@ -262,7 +309,9 @@ def _layer_leaves(cfg: DecoderConfig, kind: tuple) -> dict:
             out["k_norm_scale"] = ((hd,), "one", None)
     if cfg.attn_gate:
         out["gate_w"] = ((h, nq * cfg.v_dim), 6, 1)
-    out["attn_out_w"] = ((nq * cfg.v_dim, h), 3, 0)
+    width = cfg.kda_heads * cfg.kda_head if _attn == "kda" \
+        else nq * cfg.v_dim
+    out["attn_out_w"] = ((width, h), 3, 0)
     if cfg.bias:
         out["attn_out_b"] = ((h,), "zero", None)
     if cfg.sandwich_norm:
@@ -302,7 +351,8 @@ _F32_LEAVES = frozenset(
     [f"{ln}_{leaf}" for ln in ("ln1", "ln2", "ln_f", "ln1p", "ln2p",
                                "q_norm", "k_norm", "q_a_norm", "kv_a_norm")
      for leaf in ("scale", "bias")]
-    + ["router_w", "router_bias", "exit_w", "exit_b"])
+    + ["router_w", "router_bias", "exit_w", "exit_b", "kda_a_log",
+       "kda_dt_bias", "kda_o_norm_scale"])
 
 
 def init_params(rng: jax.Array, cfg: DecoderConfig) -> dict:
@@ -548,12 +598,27 @@ def kv_token_bytes(cfg: DecoderConfig, itemsize: int,
     """Cache bytes one token costs over all cache layers (a looped stack
     keeps one for every pass of every layer): K and V of every key-value head
     (int8 with a float32 scale a head-token where ``quant``), or a latent
-    layer's one row."""
+    layer's one row; a gated delta-rule layer's state costs nothing a token
+    (:func:`state_slot_bytes`)."""
+    if cfg.has_state:
+        return cfg.loops * cfg.n_layers_of("latent") * cfg.latent_width \
+            * itemsize
     cache_layers = cfg.loops * cfg.layers
     if cfg.latent:
         return cache_layers * cfg.latent_width * itemsize
     per_head = cfg.head_dim + 4 if quant else cfg.head_dim * itemsize
     return 2 * cache_layers * cfg.n_kv * per_head
+
+
+def state_slot_bytes(cfg: DecoderConfig, itemsize: int) -> int:
+    """Bytes a SLOT keeps whatever its length, over the gated delta-rule
+    layers: a float32 (kda_head, kda_head) state a head, and the last
+    ``kda_conv - 1`` inputs of the q, k and v convolutions (0 where no layer
+    keeps a state)."""
+    n, kh, kd = cfg.loops * cfg.n_layers_of("kda"), cfg.kda_heads, \
+        cfg.kda_head
+    return n * (kh * kd * kd * 4 + (cfg.kda_conv - 1) * 3 * kh * kd
+                * itemsize)
 
 
 def pool_quantized(pool: dict) -> bool:
@@ -754,7 +819,8 @@ def _project(x, lp, cfg: DecoderConfig, kind: tuple, pos, want_q: bool):
     nq, nkv, hd = cfg.heads, cfg.n_kv, cfg.head_dim
     h1 = _norm(x, lp, "ln1", cfg)
     if kind[0] == "latent":
-        return _project_latent(h1.astype(cfg.dtype), lp, cfg, pos, want_q)
+        return _project_latent(h1.astype(cfg.dtype), lp, cfg, pos, want_q,
+                               kind[1] == "rotary")
     qkv = _wq_matmul("bsh,hk->bsk", h1.astype(cfg.dtype), lp, "qkv_w", cfg)
     if cfg.bias:
         qkv = qkv + lp["qkv_b"].astype(cfg.dtype)
@@ -774,12 +840,15 @@ def _project(x, lp, cfg: DecoderConfig, kind: tuple, pos, want_q: bool):
     return q, k.astype(cfg.dtype), v.astype(cfg.dtype)
 
 
-def _project_latent(h1, lp, cfg: DecoderConfig, pos, want_q: bool):
+def _project_latent(h1, lp, cfg: DecoderConfig, pos, want_q: bool,
+                    rotary: bool = True):
     """A latent layer's projections of the normed ``h1`` (B, S, H):
     ``(q | None, c, None)``. ``q`` (B, nq, S, nope + rope), its last
     ``rope`` values rotated; ``c`` (B, 1, S, kv_rank + rope) what the layer
     CACHES of a token: the latent after its norm, then the one rotary key
-    all heads share, after its rotation."""
+    all heads share, after its rotation. A layer without positions
+    (``rotary`` false: NoPE) computes and caches the same ``rope`` values
+    and rotates nothing."""
     B, S, _H = h1.shape
     nq, rank, rd = cfg.heads, cfg.kv_rank, cfg.rope_size
     eps = cfg.layer_norm_eps
@@ -791,14 +860,16 @@ def _project_latent(h1, lp, cfg: DecoderConfig, pos, want_q: bool):
             cq = _rms(cq, lp["q_a_norm_scale"], eps).astype(cfg.dtype)
         q = _split_heads(_wq_matmul("bsh,hk->bsk", cq, lp, "q_b_w", cfg),
                          nq, cfg.head_dim)
-        q = jnp.concatenate(
-            [q[..., :cfg.nope_size],
-             _rope(q[..., cfg.nope_size:], pos, cfg.rope_theta, cfg
-                   ).astype(cfg.dtype)], axis=-1)
+        if rotary:
+            q = jnp.concatenate(
+                [q[..., :cfg.nope_size],
+                 _rope(q[..., cfg.nope_size:], pos, cfg.rope_theta, cfg
+                       ).astype(cfg.dtype)], axis=-1)
     ckv = _wq_matmul("bsh,hk->bsk", h1, lp, "kv_a_w", cfg)
     c = jnp.concatenate(
         [_rms(ckv[..., :rank], lp["kv_a_norm_scale"], eps),
-         _rope(ckv[:, None, :, rank:], pos, cfg.rope_theta, cfg)[:, 0]],
+         _rope(ckv[:, None, :, rank:], pos, cfg.rope_theta, cfg)[:, 0]
+         if rotary else ckv[..., rank:]],
         axis=-1).astype(cfg.dtype)
     return q, c.reshape(B, 1, S, rank + rd), None
 
@@ -863,6 +934,192 @@ def _latent_ctx(q, c, lp, mask_bias, cfg: DecoderConfig,
                           preferred_element_type=cfg.dtype)
 
 
+# ---- gated delta-rule (KDA) layers -----------------------------------------
+#
+# A ``kda`` layer keeps no row a token: each of its heads carries ONE float32
+# state S (key channels x value channels) that every token decays channel by
+# channel and then corrects by the delta rule (Kimi Linear, arXiv:2510.26692):
+#
+#     q, k = L2(SiLU(conv4(x W_q|k))) (q over sqrt(d)),  v = SiLU(conv4(x W_v))
+#     g = -exp(A_log) softplus(x W_f_a W_f_b + dt_bias)   (log-decay, a channel)
+#     beta = sigmoid(x W_b)                               (write strength)
+#     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+#     o_t = S_t^T q_t;  out = (RMSNorm(o) * sigmoid(x W_g_a W_g_b)) W_o
+#
+# The short convolutions are causal and depthwise, and carry the last
+# ``kda_conv - 1`` inputs of a slot from one piece or step to the next. The
+# matmuls are the compute type's; the gates, the norms, the decays and the
+# state are float32. A prefill piece runs the CHUNKED form (:func:`_kda_chunked`,
+# chunks of ``_KDA_CHUNK``), a decode step the recurrence (:func:`_kda_step`):
+# the same mathematics.
+
+_KDA_L2_EPS = 1e-6
+# tokens a chunk of the chunked form (the flash-linear-attention library's)
+_KDA_CHUNK = 64
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _kda_gates(h1, lp, cfg: DecoderConfig):
+    """The normed input's gates: ``(g (B, T, H, d) float32 log-decay, beta
+    (B, T, H) float32, og (B, T, H d) the output gate's logits)``."""
+    kh, kd = cfg.kda_heads, cfg.kda_head
+    B, T, _ = h1.shape
+    with jax.named_scope("kda.gate"):
+        f = _wq_matmul("bsk,kj->bsj", _wq_matmul(
+            "bsh,hk->bsk", h1, lp, "kda_f_a_w", cfg), lp, "kda_f_b_w", cfg)
+        f = f.astype(jnp.float32).reshape(B, T, kh, kd) \
+            + lp["kda_dt_bias"].astype(jnp.float32).reshape(kh, kd)
+        g = -jnp.exp(lp["kda_a_log"].astype(jnp.float32))[:, None] \
+            * jax.nn.softplus(f)
+        beta = jax.nn.sigmoid(_wq_matmul(
+            "bsh,hk->bsk", h1, lp, "kda_b_w", cfg).astype(jnp.float32))
+        og = _wq_matmul("bsk,kj->bsj", _wq_matmul(
+            "bsh,hk->bsk", h1, lp, "kda_g_a_w", cfg), lp, "kda_g_b_w", cfg)
+    return g, beta, og
+
+
+def _kda_conv(u, tail, w):
+    """The causal depthwise convolutions of the q, k and v projections ``u``
+    (B, T, C) after the inputs ``tail`` (B, K - 1, C) that came before them
+    (zeros at a sequence's start): ``(SiLU(conv) (B, T, C) float32, the last
+    K - 1 inputs)``. Tap ``j`` of ``w`` (K, C) multiplies the input ``K - 1 -
+    j`` tokens back."""
+    K, T = w.shape[0], u.shape[1]
+    full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    wf = w.astype(jnp.float32)
+    y = full[:, :T].astype(jnp.float32) * wf[0]
+    for j in range(1, K):
+        y = y + full[:, j:j + T].astype(jnp.float32) * wf[j]
+    return jax.nn.silu(y), full[:, T:]
+
+
+def _l2(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                             + _KDA_L2_EPS)
+
+
+def _kda_step(q, k, v, g, beta, S):
+    """ONE token of every lane: ``q``, ``k``, ``g`` (B, H, d), ``v`` (B, H,
+    dv), ``beta`` (B, H), the state ``S`` (B, H, d, dv) before it. Returns
+    ``(S after it, o (B, H, dv))``: with ``a = exp g``, ``S' = a S`` and ``w =
+    beta (v - S'^T k)``, ``S_t = S' + k w^T`` and ``o = S_t^T q = S^T (a q) +
+    (k . q) w`` — the old state is read for both products at once."""
+    a = jnp.exp(g)
+    both = jnp.einsum("bhjd,bhdv->bhjv", jnp.stack([a * k, a * q], axis=2),
+                      S, precision=_HI)
+    w = beta[..., None] * (v - both[:, :, 0])
+    o = both[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * w
+    return a[..., None] * S + k[..., :, None] * w[..., None, :], o
+
+
+def _kda_chunked(q, k, v, g, beta, S, c: int):
+    """The same recurrence over ``T`` tokens in chunks of ``c`` (``q``, ``k``,
+    ``g`` (B, T, H, d), ``v`` (B, T, H, dv), ``beta`` (B, T, H), the state
+    before them ``S`` (B, H, d, dv)): ``(S after them, o (B, H, T, dv))``.
+
+    Inside a chunk, with ``b_t`` the cumulative log-decay from its start and
+    ``S_0`` the state entering it, the delta rule's corrections ``w_s = beta_s
+    (v_s - S'_s^T k_s)`` solve ONE unit lower-triangular system (the WY / UT
+    form): ``(I + Diag(beta) M) W = Diag(beta) (V - (k e^b) S_0)`` with
+    ``M_ts = sum_d k_t k_s e^(b_t - b_s)`` for s < t; then ``o_t = S_0^T (q_t
+    e^(b_t)) + sum_{s<=t} (sum_d q_t k_s e^(b_t - b_s)) w_s`` and the state
+    leaving it ``e^(b_c) S_0 + sum_s (k_s e^(b_c - b_s)) w_s^T``. Every decay
+    is a DIFFERENCE of cumulative log-gates taken before the ``exp``, never
+    above 0, so none overflows however long the chunk. A ragged end is padded
+    with tokens that change nothing (``beta`` 0, ``g`` 0)."""
+    B, T, H, d = q.shape
+    pad = -T % c
+    if pad:
+        def grow(a):
+            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+
+        q, k, v, g, beta = (grow(a) for a in (q, k, v, g, beta))
+    n = (T + pad) // c
+
+    def chunks(a):          # (B, n c, H, ...) -> (n, B, H, c, ...)
+        a = a.reshape(B, n, c, H, *a.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 3, 2)
+
+    upto = jnp.tril(jnp.ones((c, c), jnp.bool_))
+    before = jnp.tril(jnp.ones((c, c), jnp.bool_), -1)
+
+    def one(S, xs):
+        qc, kc, vc, gc, bc = xs
+        b = jnp.cumsum(gc, axis=2)                           # (B, H, c, d)
+        e = jnp.exp(jnp.where(upto[:, :, None],
+                              b[:, :, :, None, :] - b[:, :, None, :, :],
+                              -jnp.inf))                     # (B,H,t,s,d)
+        kk = jnp.einsum("bhtd,bhsd,bhtsd->bhts", kc, kc, e, precision=_HI)
+        qk = jnp.einsum("bhtd,bhsd,bhtsd->bhts", qc, kc, e, precision=_HI)
+        eb = jnp.exp(b)
+        rhs = bc[..., None] * (vc - jnp.einsum(
+            "bhcd,bhdv->bhcv", kc * eb, S, precision=_HI))
+        a = jnp.eye(c, dtype=jnp.float32) \
+            + jnp.where(before, bc[..., :, None] * kk, 0.0)
+        w = jax.lax.linalg.triangular_solve(a, rhs, left_side=True,
+                                            lower=True, unit_diagonal=True)
+        o = jnp.einsum("bhcd,bhdv->bhcv", qc * eb, S, precision=_HI) \
+            + jnp.einsum("bhts,bhsv->bhtv", qk, w, precision=_HI)
+        last = b[:, :, -1:, :]
+        S = jnp.exp(last[:, :, 0, :, None]) * S + jnp.einsum(
+            "bhsd,bhsv->bhdv", kc * jnp.exp(last - b), w, precision=_HI)
+        return S, o
+
+    S, o = jax.lax.scan(one, S, (chunks(q), chunks(k), chunks(v), chunks(g),
+                                 chunks(beta)))
+    o = jnp.moveaxis(o, 0, 2).reshape(B, H, n * c, -1)      # (B, H, T', dv)
+    return S, o[:, :, :T]
+
+
+def _kda_mix(x, lp, cfg: DecoderConfig, state, tail, mask=None,
+             step: bool = False):
+    """A gated delta-rule layer's token mixing of ``x`` (B, T, H): its norm,
+    projections, convolutions after ``tail`` (B, 1, K - 1, 3 H d), gates, the
+    recurrence from ``state`` (B, H, d, dv) float32 — chunked, or the step
+    where ``step`` (T = 1) — and the output's norm and gate. ``mask`` (B, T)
+    marks real tokens: a pad (left padding) feeds zeros to the convolutions
+    and leaves the state as it was. Returns ``(out (B, H, T, dv) in the
+    compute type, for :func:`_block_finish`, state, tail)``."""
+    kh, kd = cfg.kda_heads, cfg.kda_head
+    B, T, _H = x.shape
+    h1 = _norm(x, lp, "ln1", cfg).astype(cfg.dtype)
+    with jax.named_scope("kda.conv"):
+        u = _wq_matmul("bsh,hk->bsk", h1, lp, "kda_qkv_w", cfg)
+    g, beta, og = _kda_gates(h1, lp, cfg)
+    if mask is not None:
+        real = mask > 0
+        u = jnp.where(real[..., None], u, jnp.zeros((), u.dtype))
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+    with jax.named_scope("kda.conv"):
+        y, tail = _kda_conv(u, tail[:, 0], lp["kda_conv_w"])
+        y = y.reshape(B, T, 3, kh, kd)
+        q = _l2(y[:, :, 0]) * (kd ** -0.5)
+        k, v = _l2(y[:, :, 1]), y[:, :, 2]
+    if step:
+        with jax.named_scope("kda.step"):
+            state, o = _kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                 beta[:, 0], state)
+            o = o[:, :, None, :]
+    else:
+        with jax.named_scope("kda.chunk"):
+            state, o = _kda_chunked(q, k, v, g, beta, state,
+                                    min(_KDA_CHUNK, T))
+    with jax.named_scope("kda.out"):
+        gate = jax.nn.sigmoid(og.astype(jnp.float32)).reshape(
+            B, T, kh, kd).transpose(0, 2, 1, 3)
+        out = (_rms(o, lp["kda_o_norm_scale"], cfg.layer_norm_eps) * gate
+               ).astype(cfg.dtype)
+    return out, state, tail[:, None]
+
+
+def _kda_zero(cfg: DecoderConfig, B: int):
+    """A fresh sequence's state and convolution tail, ``B`` of each."""
+    kh, kd = cfg.kda_heads, cfg.kda_head
+    return (jnp.zeros((B, kh, kd, kd), jnp.float32),
+            jnp.zeros((B, 1, cfg.kda_conv - 1, 3 * kh * kd), cfg.dtype))
+
+
 def _block_qkv(x, lp, cfg: DecoderConfig, kind: tuple = _GPT2_KIND,
                pos=None):
     """First norm + fused QKV projection, head-split: ``(q, k_new,
@@ -917,7 +1174,7 @@ def _block_finish(x, lp, ctx, cfg: DecoderConfig,
     is the attention read (B, nh, S, hd). Returns ``(x, counts)``:
     ``counts`` the expert layer's (held, all) assignments, else None."""
     B, S, _H = x.shape
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, cfg.heads * cfg.v_dim)
+    ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, ctx.shape[1] * ctx.shape[3])
     if cfg.attn_gate:
         h1 = _norm(x, lp, "ln1", cfg).astype(cfg.dtype)
         gate = _wq_matmul("bsh,hk->bsk", h1, lp, "gate_w", cfg)
@@ -1194,6 +1451,11 @@ def _kv_names(cfg: DecoderConfig, r: int, kind: tuple) -> tuple:
         # key row of the absorbed read, whose unit axis is the one key-value
         # head every query head shares; there is no second array
         return (f"cl{r}", None, None, None)
+    if kind[0] == "kda":
+        # a gated delta-rule run keeps no row a token: its STATE (layers,
+        # slots, heads, d, dv) float32 and its convolutions' tail (layers,
+        # slots, 1, kda_conv - 1, 3 heads d) ride where a run's K and V do
+        return (f"sk{r}", f"sc{r}", None, None)
     if cfg.uniform:
         return ("k", "v", "k_scale", "v_scale")
     t = "w" if kind[0] == "window" else "f"
@@ -1210,10 +1472,16 @@ def _is_kv(name: str, window: bool | None = None) -> bool:
     return ok and (window is None or (name[1] == "w") == window)
 
 
+def _is_state(name: str) -> bool:
+    """``name`` is a gated delta-rule run's state or convolution tail."""
+    return len(name) > 2 and name[:2] in ("sk", "sc") and name[2:].isdigit()
+
+
 def _kv_stacks(pool: dict) -> dict:
-    """The pool's KV arrays (and int8 scale planes), by name."""
+    """The pool's KV arrays (and int8 scale planes) and recurrent states,
+    by name."""
     return {n: a for n, a in pool.items()
-            if _is_kv(n) or n in ("k_scale", "v_scale")}
+            if _is_kv(n) or n in ("k_scale", "v_scale") or _is_state(n)}
 
 
 def _run_stacks(params: dict, cfg: DecoderConfig) -> list:
@@ -1527,6 +1795,13 @@ def _self_attend(params, input_ids, attention_mask, cfg: DecoderConfig,
 
     def body(x, lp, none, _layer, kind):
         # nothing is carried: the layer's keys and values are its outputs
+        if kind[0] == "kda":
+            # a fresh state a sequence; its last state and tail are outputs
+            out, st, tail = _kda_mix(x, lp, cfg, *_kda_zero(cfg, B),
+                                     mask=attention_mask)
+            x, cnt = _block_finish(x, lp, out, cfg, kind)
+            return x, ({**none, "k": st, "v": tail} if keep_kv
+                       else none), cnt
         k, v = _prefill_kv(x, lp, cfg, kind, pos)
         x, cnt = _block(x, lp, k, v, bias[kind[0]], cfg, ctx_fn=ctx_fn,
                         kind=kind, pos=pos)
@@ -1588,6 +1863,7 @@ def prefill(params: dict, input_ids: jax.Array, attention_mask: jax.Array,
     read, so decode streams see identical attention inputs."""
     B, S = input_ids.shape
     assert cache_len >= S
+    require_stateless(cfg, "generate")
     x, kv, _counts, _exits = _self_attend(
         params, input_ids, attention_mask, cfg, flash, mesh, True)
     pad = [(0, 0), (0, 0), (0, 0), (0, cache_len - S), (0, 0)]
@@ -1609,6 +1885,7 @@ def decode_step(params: dict, token: jax.Array, step_pos: jax.Array,
     applied to decode: the shallow stack is the self-speculative DRAFT
     model, its KV a depth-prefix of the same cache (layers >= N pass
     through untouched), no second parameter set anywhere."""
+    require_stateless(cfg, "generate")
     pos = step_pos[:, None]
     x = _embed(params, token[:, None], pos, cfg)
     live = slot_mask[:, None, None, :] > 0
@@ -1827,6 +2104,13 @@ def pool_init(params: dict, cfg: DecoderConfig, n_slots: int,
         # pass u of the run's layer l at u * n + l (the arena's blocks too: a
         # prefix hit restores every pass's rows)
         n = cfg.loops * n
+        if kind[0] == "kda":
+            # a slot's state whatever its length: nothing a column
+            kh, kd = cfg.kda_heads, cfg.kda_head
+            pool[kn] = jnp.zeros((n, n_slots, kh, kd, kd), jnp.float32)
+            pool[vn] = jnp.zeros(
+                (n, n_slots, 1, cfg.kda_conv - 1, 3 * kh * kd), cfg.dtype)
+            continue
         if kind[0] == "latent":
             # the normed latent and the rotated shared key of every column:
             # kv_rank + rope values a token a layer, nothing per head
@@ -1937,6 +2221,7 @@ def _component_keys(pool: dict) -> dict:
            for c, keys in _HBM_COMPONENT_KEYS.items()}
     out["slot_pool_window"] = []
     out["slot_pool_latent"] = []
+    out["slot_state"] = [n for n in pool if _is_state(n)]
     for name in pool:
         if name in ("k", "v"):
             continue
@@ -2310,7 +2595,9 @@ def _prefill_for_pool(params, ids, mask, pool, cfg, flash, mesh):
     R = pool_ring(pool)
     cache = {}
     for name, new in kv.items():
-        if not _is_kv(name, window=True):
+        if _is_state(name):
+            cache[name] = new
+        elif not _is_kv(name, window=True):
             cache[name] = jnp.pad(
                 new, [(0, 0), (0, 0), (0, 0), (0, C - S), (0, 0)])
         elif S <= R:
@@ -2461,6 +2748,15 @@ def pool_prefill_chunk(params: dict, ids: jax.Array, mask: jax.Array,
         # ``st``: the run's stacks, carried whole; this layer's rows of
         # ``slot`` are written where they lie and read back as ONE row
         ks, vs, kss, vss = (st[n] for n in _KV_SHORT)
+        if kind[0] == "kda":
+            # the slot's state and tail (fresh for a prompt's first piece)
+            # carried through the piece and written back where they lie
+            s0, t0 = _kda_zero(cfg, 1) if first else (
+                _kv_rows(ks, li, slot), _kv_rows(vs, li, slot))
+            out, s1, t1 = _kda_mix(x, lp, cfg, s0, t0, mask=mask)
+            x, cnt = _block_finish(x, lp, out, cfg, kind)
+            return x, {**st, "k": _kv_put(ks, s1, li, slot, 0),
+                       "v": _kv_put(vs, t1, li, slot, 0)}, cnt
         k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (1, nh, T, hd)
         if kind[0] == "latent":
             # the piece's latent rows go in, then its queries read the row:
@@ -2560,6 +2856,19 @@ def _prefill_group(params, ids, mask, pos, pool, slot, start, n_prompt,
         r, m, (s,)))(rows, mask.astype(jnp.int32), start)        # (G, C)
 
     def layer(x, lp, st, li, kind):
+        if kind[0] == "kda":
+            # every lane's state and tail at once (fresh where ``first``),
+            # through the group's tokens, and back into its slot
+            ks, vs = st["k"], st["v"]
+            z0, zt = _kda_zero(cfg, G)
+            on = first.reshape(G, 1, 1, 1)
+            out, s1, t1 = _kda_mix(x, lp, cfg,
+                                   jnp.where(on, z0, ks[li, slot]),
+                                   jnp.where(on, zt, vs[li, slot]),
+                                   mask=mask)
+            x, cnt = _block_finish(x, lp, out, cfg, kind)
+            return x, {**st, "k": ks.at[li, slot].set(s1),
+                       "v": vs.at[li, slot].set(t1)}, cnt
         q, k_new, v_new = _project(x, lp, cfg, kind, p, True)
         window = kind[0] == "window"
         sk = sv = None
@@ -2791,7 +3100,7 @@ def kv_extract(pool: dict, slot: jax.Array, start: jax.Array,
             "kv_extract is dense-arena machinery; a paged pool publishes "
             "prefixes by pinning its own blocks (paged_admit_cached)"
         )
-    del cfg
+    require_stateless(cfg, "prefix_cache")
     n = idxs.shape[0]
     out = dict(pool)
     for c, a in _kv_channels(pool):
@@ -2818,7 +3127,7 @@ def kv_insert(pool: dict, slot: jax.Array, start: jax.Array,
             "kv_insert is dense-arena machinery; a paged pool admits "
             "cached prefixes by table edit (paged_admit_cached)"
         )
-    del cfg
+    require_stateless(cfg, "prefix_cache")
     n = idxs.shape[0]
     out = dict(pool)
     for c, a in _kv_channels(pool):
@@ -2988,6 +3297,20 @@ def pool_decode_chunk(params: dict, pool: dict, active: jax.Array,
             col["window"] = jnp.mod(w, R)[:, None]
 
         def layer(x, lp, st, li, kind):
+            if kind[0] == "kda":
+                # every slot's state and tail, one step of the recurrence; a
+                # lane that is not active steps as a pad does (decay 1, write
+                # strength 0: its state comes back as it was, and is written
+                # where it lies with the others) and keeps its own tail
+                t0 = _kv_rows(st["v"], li)
+                out, s1, t1 = _kda_mix(x, lp, cfg, _kv_rows(st["k"], li), t0,
+                                       mask=active[:, None], step=True)
+                x, cnt = _block_finish(x, lp, out, cfg, kind)
+                with jax.named_scope("kda.step"):
+                    sk = _kv_put(st["k"], s1, li, 0, 0)
+                on = active.reshape(-1, 1, 1, 1)
+                return x, {**st, "k": sk, "v": _kv_put(
+                    st["v"], jnp.where(on, t1, t0), li, 0, 0)}, cnt
             k_new, v_new = _prefill_kv(x, lp, cfg, kind, p)  # (B, nh, 1, hd)
             # per-ROW write position (each lane is at its own slot); a
             # latent layer has one row a lane to write and no V: the step
@@ -3242,6 +3565,7 @@ def pool_decode_draft(params: dict, pool: dict, active: jax.Array,
     Paged pools gather-run-scatter (see :func:`pool_admit`); drafting
     never writes, so only the gather side is needed."""
     require_single_pass(cfg, "spec_decode")
+    require_stateless(cfg, "spec_decode")
     if pool_paged(pool):
         return pool_decode_draft(
             params, _paged_gather(pool), active, cfg,
@@ -3291,6 +3615,7 @@ def pool_decode_spec(params: dict, pool: dict, active: jax.Array,
     kernel does not apply to the spec path — verify scores ``n_spec+1``
     query positions, while the kernel is single-query decode."""
     require_single_pass(cfg, "spec_decode")
+    require_stateless(cfg, "spec_decode")
     if pool_paged(pool):
         view, toks, n_emit = pool_decode_spec(
             params, _paged_gather(pool), active, cfg, n_cycles,

@@ -839,10 +839,15 @@ class _ContinuousServer:
             # the mechanisms below are; the flag's default resolves to
             # plain chunks for such a stack, as it does for one layer
             decoder_mod.require_single_pass(cfg, "spec_decode")
+        if spec_decode:
+            # a rejected speculative tail would have to roll a recurrent
+            # state back: refused by type where ASKED for, plain chunks by
+            # the flag's default
+            decoder_mod.require_stateless(cfg, "spec_decode")
         self.spec_decode = bool(
             want_spec and float(temperature) == 0.0
             and top_k is None and top_p is None and cfg.layers >= 2
-            and cfg.loops == 1
+            and cfg.loops == 1 and not cfg.has_state
         )
         d = (
             pathway_config.spec_draft_layers
@@ -959,7 +964,11 @@ class _ContinuousServer:
         # * chunk_autotune — decode-chunk steps shrink (halving, floor 4)
         #   against the observed arrival rate / queue pressure so chunk
         #   boundaries (admission + drain points) come sooner under load.
-        self.batch_admit = pathway_config.batch_admit
+        # A layout with a recurrent state admits one prompt a dispatch: a
+        # batched admission runs the chunked form over every lane's whole
+        # bucket at once (8.4 GB of temporaries at 64 x 512), and the warm
+        # start would build one such executable a batch size a bucket.
+        self.batch_admit = pathway_config.batch_admit and not cfg.has_state
         self.prefill_overlap = pathway_config.prefill_overlap
         self.chunk_autotune = pathway_config.chunk_autotune
         # disaggregated prefill/decode lanes (PATHWAY_TPU_DISAGG):
@@ -1026,10 +1035,15 @@ class _ContinuousServer:
 
         self.prefix = None
         self.prefix_block = 0
+        if prefix_cache:
+            # a cached prefix is rows copied by token; a recurrent state
+            # has none to copy: refused by type where ASKED for, off by the
+            # flag's default
+            decoder_mod.require_stateless(cfg, "prefix_cache")
         want_prefix = (
             pathway_config.prefix_cache
             if prefix_cache is None else bool(prefix_cache)
-        )
+        ) and not cfg.has_state
         if want_prefix and self.chunked_prefill:
             from pathway_tpu.engine.prefix_cache import PrefixCache
 
@@ -1153,6 +1167,10 @@ class _ContinuousServer:
                 self.params, cfg, self.mesh
             )
         self.pool = self._build_pool()
+        it = _np_mod.dtype(cfg.dtype).itemsize
+        # what every slot keeps whatever its length (the regions' field)
+        self._state_mib = round(
+            n_slots * decoder_mod.state_slot_bytes(cfg, it) / (1 << 20), 3)
         # how many waiting prompts' pieces share one dispatch, and so one
         # read of the weights: ONE rule on the piece's arithmetic intensity
         # (models/decoder.py prefill_group_width), 1 where a piece alone
@@ -1271,9 +1289,12 @@ class _ContinuousServer:
         self._device_seen: dict = {}
         logger.info(
             "decoder server pool: %d slots x %d columns x %d cache layers "
-            "(%d layers x %d passes) = %d bytes",
+            "(%d layers x %d passes), %d bytes a token and %d a slot of "
+            "recurrent state = %d bytes",
             n_slots, self.cache_len, cfg.loops * cfg.layers, cfg.layers,
-            cfg.loops, decoder_mod.pool_bytes(self.pool))
+            cfg.loops, decoder_mod.kv_token_bytes(cfg, it),
+            decoder_mod.state_slot_bytes(cfg, it),
+            decoder_mod.pool_bytes(self.pool))
         # in-flight chunk records, oldest first; an attribute (not a loop
         # local) so the failure sweep can fail eagerly-freed requests
         # whose tokens never drained
@@ -2501,7 +2522,7 @@ class _ContinuousServer:
                 "insert": (req, e, 0, n),
             }
             self._pending_prefill[slot] = (pieces, n_prompt, meta)
-            self.stats["admitted"] += 1
+            self._admitted()
             return
         ins = (
             (req, e, 0) if self.prefix is not None and n >= B
@@ -2544,7 +2565,7 @@ class _ContinuousServer:
             direct.append((slot, ids, mask, s))
             if ins is not None:
                 direct_inserts.append((slot, ins))
-        self.stats["admitted"] += 1
+        self._admitted()
 
     def _unpark(self, slot: int, req, e: list, n: int,
                 row: list) -> bool:
@@ -2592,7 +2613,7 @@ class _ContinuousServer:
         if self.prefix is not None and n >= B:
             meta["insert"] = (req, e, 0, 0)
         self._pending_prefill[slot] = (pieces, n_prompt, meta)
-        self.stats["admitted"] += 1
+        self._admitted()
         self._update_fragmentation()
         return True
 
@@ -2709,8 +2730,18 @@ class _ContinuousServer:
         if self.prefix is not None and n >= B:
             meta["insert"] = (req, e, 0, 0)
         self._pending_prefill[slot] = (pieces, n_prompt, meta)
-        self.stats["admitted"] += 1
+        self._admitted()
         self._update_fragmentation()
+
+    def _admitted(self) -> None:
+        """One request admitted into a slot: its prompt's first piece (or
+        its one-shot admission) starts the slot's recurrent state afresh,
+        where the layers keep one (``recurrent_state_resets``)."""
+        self.stats["admitted"] += 1
+        if self.cfg.has_state:
+            from pathway_tpu.engine import probes
+
+            probes.record_state_resets(1)
 
     def _loop_account(self, phase: str, tokens: int) -> None:
         """``loop_passes{phase, pass}``: every pass of a looped stack ran
@@ -2745,7 +2776,7 @@ class _ContinuousServer:
         T = int(ids[0].shape[1])
         with tracing.region("pw.decode.prefill", tokens=T,
                             piece=off[0] // T, passes=self.cfg.loops,
-                            lanes=len(lanes)):
+                            lanes=len(lanes), state_mib=self._state_mib):
             if len(lanes) > 1:
                 self.pool = self._prefill_group_fn(T)(
                     self.params, np.concatenate(ids), np.concatenate(mask),
@@ -2884,7 +2915,8 @@ class _ContinuousServer:
                 self._last_dispatch_steps = n_cycles
                 with tracing.region("pw.decode.chunk", steps=n_cycles,
                                     lanes=int(lanes.sum()), spec=1,
-                                    passes=self.cfg.loops):
+                                    passes=self.cfg.loops,
+                                    state_mib=self._state_mib):
                     self.pool, toks_dev, emit_dev, counts_dev = \
                         self._spec_fn_for(n_cycles)(
                             self.params, self.pool, lanes)
@@ -2897,7 +2929,8 @@ class _ContinuousServer:
                 key = jax.random.fold_in(self._key, self._ticks)
                 with tracing.region("pw.decode.chunk", steps=steps,
                                     lanes=int(lanes.sum()), spec=0,
-                                    passes=self.cfg.loops):
+                                    passes=self.cfg.loops,
+                                    state_mib=self._state_mib):
                     self.pool, toks_dev, counts_dev = self._chunk_fn_for(
                         steps)(self.params, self.pool, lanes, key)
                 payload = toks_dev

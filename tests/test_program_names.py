@@ -152,6 +152,19 @@ def _latent_cfg():
         rope_mscale_all_dim=0.707)
 
 
+def _kda_cfg():
+    """Gated delta-rule layers beside a NoPE latent one (Kimi-Linear's
+    block at toy widths)."""
+    from pathway_tpu.models import decoder as D
+
+    return D.DecoderConfig(
+        vocab_size=64, hidden=16, layers=2, heads=2, intermediate=32,
+        max_position=64, dtype=jnp.float32, norm="rmsnorm",
+        positions="none", mlp="swiglu", bias=False, tied_head=False,
+        layer_types=("kda", "latent"), kv_rank=8, nope_size=6, rope_size=2,
+        v_size=6, kda_heads=2, kda_head=4)
+
+
 @pytest.fixture(scope="module")
 def latent_run():
     """One request through a continuous server whose block is latent
@@ -256,7 +269,7 @@ def toy_scopes(tmp_path_factory):
     compilation_cache.reset_cache()
     jax.profiler.start_trace(trace)
     try:
-        for cfg in (moe, _latent_cfg()):
+        for cfg in (moe, _latent_cfg(), _kda_cfg()):
             chat = TPUDecoderChat(
                 params=D.init_params(jax.random.PRNGKey(0), cfg), cfg=cfg,
                 tokenizer=_Ids(), max_new_tokens=4, temperature=0.0,
@@ -406,6 +419,55 @@ def test_the_grouped_prefill_keeps_its_names(looped_run, jitted):
                                                                  "width")
     assert 'pathway_tpu_prefill_group_lanes_total{width="1"}' \
         in _metrics_text()
+
+
+@pytest.fixture(scope="module")
+def kda_run(tmp_path_factory):
+    """Two requests through a continuous server whose layers keep a
+    recurrent state, on two slots, inside a profiler session: the session's
+    ``pw.`` regions and the server's state a slot."""
+    from pathway_tpu.models import decoder as D
+    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
+
+    cfg = _kda_cfg()
+    probes.REGISTRY.remove("recurrent_state_resets")
+    chat = TPUDecoderChat(
+        params=D.init_params(jax.random.PRNGKey(0), cfg), cfg=cfg,
+        tokenizer=_Ids(), max_new_tokens=4, temperature=0.0,
+        max_prompt_tokens=16, continuous=True, n_slots=2, chunk_steps=4,
+        prefill_chunk=8)
+    trace_dir = str(tmp_path_factory.mktemp("kda"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        requests = [chat._server.submit(list(range(1, 12)), 4),
+                    chat._server.submit(list(range(3, 9)), 4)]
+        for request in requests:
+            assert request.done.wait(timeout=300)
+        mib = chat._server._state_mib
+    finally:
+        jax.profiler.stop_trace()
+        chat.close()
+    regions = program_trace.load_host_regions(max(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))))
+    return regions, mib, D.state_slot_bytes(cfg, 4)
+
+
+def test_the_states_counter_and_field_are_what_the_program_names(kda_run):
+    """``recurrent_state_resets{cause=admit}``, one an admission, under the
+    name and label PERF.md gives it on ``/metrics``; the ``state_mib`` field
+    (the pool's recurrent state, every slot's) on both decode regions."""
+    regions, mib, per_slot = kda_run
+    assert probes.METRIC_FAMILIES["recurrent_state_resets"][:2] == (
+        "counter", "cause")
+    assert probes.REGISTRY.labelled("recurrent_state_resets", "cause") == {
+        "admit": 2.0}
+    assert 'pathway_tpu_recurrent_state_resets_total{cause="admit"} 2' \
+        in _metrics_text()
+    assert mib == round(2 * per_slot / (1 << 20), 3) > 0
+    for name in ("pw.decode.prefill", "pw.decode.chunk"):
+        found = [s for _th, n, _s, _d, s in regions if n == name]
+        assert found and all(float(s["state_mib"]) == mib for s in found), \
+            name
 
 
 def _metrics_text():

@@ -769,3 +769,181 @@ def test_sharded_ivf_exhaustive_search_four_chips(topo, shape):
     ).compile()
     m = c.memory_analysis()
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 4 << 30
+
+
+@pytest.fixture(scope="module")
+def kimi(shape):
+    """The decode-heavy cell: 64 slots of 1,024 + 2,048 + 5 x 16 columns,
+    six gated delta-rule layers' states beside two latent layers' rows."""
+    cell = _answer_cell(shape, "kimi-linear-48b-ep8-rag.json", "kimi_linear",
+                        64, 3152)
+    from pathway_tpu.models import decoder as D
+
+    assert D.pool_bytes(cell[2]) == 1_298_399_232
+    return cell
+
+
+def test_kimi_width_piece_and_decode_chunk(shape, kimi, monkeypatch):
+    """A prefill piece of 512 (the chunked form of the recurrence in chunks
+    of 64, the latent layers' blockwise read) and a decode chunk of 16 steps
+    (the recurrence over every slot's float32 state) at the cell's widths:
+    both compile for the chip, carry the states and rows in place (the
+    chunk copies no run array whole inside its loop), and fit beside 5.5 GB
+    of weights and pool (``PERF.md`` section 4: 101,329,920 and 605,176,320
+    bytes of temporaries)."""
+    from pathway_tpu.models import decoder as D
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, pool = kimi
+    piece = shape((1, 512), I32)
+    c = _compile(
+        lambda p, i, m, ps, pl, s, st, n: D.pool_prefill_chunk(
+            p, i, m, ps, pl, s, st, n, cfg, first=False, last=True),
+        params, piece, piece, piece, pool, shape((), I32), shape((), I32),
+        shape((1,), I32), donate_argnums=(4,))
+    assert _is_mosaic(c)
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 112_000_000        # 101,329,920 + a tenth
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14 << 30
+    c = _compile(
+        lambda p, pl, a, k: D.pool_decode_chunk(p, pl, a, k, cfg,
+                                                CHUNK_STEPS),
+        params, pool, shape((64,), jnp.bool_), shape((2,), jnp.uint32),
+        donate_argnums=(1,))
+    # the states and rows (the convolutions' tails, 4.7 MB a layer, are
+    # small enough to move): inside the loop every slot's new states are
+    # written where they lie (a run of one layer's into a buffer that takes
+    # the old one's place), an idle lane's as an identity step, and no run
+    # array is copied; the latent runs' rows are laid out the loop's way at
+    # entry and back at exit, as every latent cell's are (ROADMAP S10)
+    stacks = [a.shape for n, a in D._kv_stacks(pool).items()
+              if not n.startswith("sc")]
+    ops = _whole_array_ops(c.as_text(), stacks)
+    assert {kind for where, _n, kind in ops if where == "loop"} \
+        == {"in place", "add"}, ops
+    assert [kind for where, _n, kind in ops if where == "entry"] \
+        == ["copy"] * 4, ops
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 666_000_000        # 605,176,320 + a tenth
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14 << 30
+
+
+# the lowered text (debug info left out) of the accepted answer cells'
+# executables at their widths, at commit 1b7ca61: a first, middle
+# and last piece, the grouped piece, the decode chunks of 16, 8 and 4 steps,
+# one-shot admissions alone and in pairs at two buckets, DeepSeek-V2's
+# speculative cycle: what a layout without a recurrent state runs did not
+# change a byte
+OTHER_LOWERED = {
+    "deepseek.admit256": "07d3db582ba352a7",
+    "deepseek.admit8": "cd54addd89f06ee7",
+    "deepseek.admit_batch256": "b2a5244dcb948a47",
+    "deepseek.admit_batch8": "08973270b15322cc",
+    "deepseek.chunk16": "dd412713ad1b1881",
+    "deepseek.chunk4": "3fdb2e99ebe493c4",
+    "deepseek.chunk8": "b2279964e5718106",
+    "deepseek.group": "5853816cf9f998ad",
+    "deepseek.piece00": "573105af4768467e",
+    "deepseek.piece01": "b9d19678979020e5",
+    "deepseek.piece10": "2a7e65b3163b9299",
+    "deepseek.spec": "64c003dce663833d",
+    "ouro.admit256": "88c59dd6bd66aaf1",
+    "ouro.admit8": "b669e8f43973a8d0",
+    "ouro.admit_batch256": "228cb8b796a88ad3",
+    "ouro.admit_batch8": "6023dca7c22da0e7",
+    "ouro.chunk16": "823118d091fbe84f",
+    "ouro.chunk4": "d4105f34bea9fdb9",
+    "ouro.chunk8": "fe26dea30ca81a19",
+    "ouro.piece00": "09cbade67f5e001c",
+    "ouro.piece01": "2937b00bd0af34bf",
+    "ouro.piece10": "9b64d2d8b3006f1f",
+    "trinity.admit256": "aed38c4007d2215e",
+    "trinity.admit8": "1f9bd5a503ab9c45",
+    "trinity.admit_batch256": "2b6ff37f6e307392",
+    "trinity.admit_batch8": "d2819816c4f95482",
+    "trinity.chunk16": "44c4758c60a62844",
+    "trinity.chunk4": "b589d4e954694306",
+    "trinity.chunk8": "3abb9d5eed8bf13c",
+    "trinity.group": "d5154a779a771449",
+    "trinity.piece00": "8544ab5c6339a1fa",
+    "trinity.piece01": "2c702fd6c1218a71",
+    "trinity.piece10": "c70171acec754940",
+}
+
+
+def _lowered_texts(name, config, layout, slots, columns, T, G, **pool_kw):
+    """The lowered text of each executable the answer cell's server builds,
+    from shapes alone (no device, no compile)."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import manifest as M
+
+    from pathway_tpu.models import decoder as D
+
+    S_ = jax.ShapeDtypeStruct
+    with open(os.path.join(bench, "configs", config)) as f:
+        model = json.load(f)["models"]["decoder"]
+    cfg = M.resolve(M.load_manifest(), "layouts", layout).program_config(
+        model)
+    params = jax.eval_shape(lambda: D.cast_params_for_inference(
+        D.init_params(jax.random.PRNGKey(0), cfg), cfg))
+    pool = jax.eval_shape(
+        lambda: D.pool_init(None, cfg, slots, columns, **pool_kw))
+    ids, one = S_((1, T), I32), S_((), I32)
+    out = {}
+    for first, last in ((True, False), (False, False), (False, True)):
+        out[f"piece{int(first)}{int(last)}"] = jax.jit(
+            lambda p, i, pl, s, first=first, last=last: D.pool_prefill_chunk(
+                p, i, i, i, pl, s, s, s[None], cfg, first=first, last=last),
+            donate_argnums=(2,)).lower(params, ids, pool, one)
+    if G > 1:
+        vec, flag = S_((G,), I32), S_((G,), jnp.bool_)
+        out["group"] = jax.jit(
+            lambda p, i, pl, s, f, la: D.pool_prefill_chunk(
+                p, i, i, i, pl, s, s, s, cfg, first=f, last=la,
+                last_col=s), donate_argnums=(2,)).lower(
+                    params, S_((G, T), I32), pool, vec, flag, flag)
+    for steps in (16, 8, 4):
+        out[f"chunk{steps}"] = jax.jit(
+            lambda p, pl, a, k, steps=steps: D.pool_decode_chunk(
+                p, pl, a, k, cfg, steps), donate_argnums=(1,)).lower(
+                    params, pool, S_((slots,), jnp.bool_),
+                    S_((2,), jnp.uint32))
+    for s in (8, 256):
+        out[f"admit{s}"] = jax.jit(
+            lambda p, i, pl, sl: D.pool_admit(p, i, i, pl, sl, cfg),
+            donate_argnums=(2,)).lower(params, S_((1, s), I32), pool, one)
+        out[f"admit_batch{s}"] = jax.jit(
+            lambda p, i, pl, sl: D.pool_admit_batch(p, i, i, pl, sl, cfg),
+            donate_argnums=(2,)).lower(params, S_((2, s), I32), pool,
+                                       S_((2,), I32))
+    if name == "deepseek":
+        out["spec"] = jax.jit(
+            lambda p, pl, a: D.pool_decode_spec(
+                p, pl, a, cfg, 4, draft_layers=1, n_spec=3),
+            donate_argnums=(1,)).lower(params, pool, S_((slots,), jnp.bool_))
+    return {f"{name}.{k}": v.as_text() for k, v in out.items()}
+
+
+def test_the_other_cells_executables_are_the_parents():
+    """What the recurrent state added to the decoder lowers to nothing in a
+    configuration without it: every executable the three other answer
+    cells' servers build lowers to the text it lowered to before."""
+    import hashlib
+
+    texts = {}
+    texts.update(_lowered_texts("trinity", "trinity-large-ep8-rag.json",
+                                "afmoe", 16, 8304, 512, 4))
+    texts.update(_lowered_texts("deepseek", "deepseek-v2-ep8-rag.json",
+                                "deepseek_v2", 8, 16528, 512, 2,
+                                arena_blocks=18, arena_block=512))
+    texts.update(_lowered_texts("ouro", "ouro-2.6b-rag.json", "ouro", 4,
+                                656, 256, 1))
+    assert {k: hashlib.sha256(t.encode()).hexdigest()[:16]
+            for k, t in texts.items()} == OTHER_LOWERED
